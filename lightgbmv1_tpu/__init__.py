@@ -11,10 +11,32 @@ train / cv / sklearn wrappers) so existing LightGBM scripts port with an
 import change.
 """
 
+import os
+
 from .config import Config
 from .utils.log import LightGBMError, register_callback, set_verbosity
 
 __version__ = "0.1.0"
+
+
+def _place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home every entry point
+    shares.  ``JAX_COMPILATION_CACHE_DIR`` set from outside wins and this
+    sets nothing (JAX reads the variable itself).  Otherwise the cache
+    lives at ``<checkout>/.jax_cache``, derived from this file's own
+    location: the directory is part of the cache key, so it must be the
+    same for every process of every run — never a temp dir, a pid or a
+    time.  A config update only; no device is touched."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(checkout, ".jax_cache"))
+
+
+_place_compile_cache()
 
 __all__ = [
     "Config",

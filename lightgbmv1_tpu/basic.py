@@ -832,8 +832,11 @@ class Booster:
         key discipline as the native pack cache: any ensemble mutation
         (update/rollback/DART drop-rescale) moves ``model_version`` and
         drops the predictor, its serving tables and its compiled-walk
-        cache wholesale.  None -> host fallback (e.g. categorical model
-        without raw category sets, scan with K>1)."""
+        cache wholesale.  None -> host path, only for the predictor's
+        own stated refusals (``ValueError``: a categorical model without
+        raw category sets, scan with K>1); anything else — a device or
+        compiler error — propagates instead of being answered from the
+        host behind the caller's back."""
         key = (start_iteration, len(trees),
                self._gbdt.model_version if self._gbdt is not None else -1,
                method)
@@ -855,9 +858,9 @@ class Booster:
                 chunk_rows=int(p("predict_chunk_rows", 131072)),
                 cache_entries=int(p("predict_cache_entries", 64)),
             )
-        except Exception as e:  # noqa: BLE001 — host fallback
-            log_warning(f"device predict unavailable "
-                        f"({type(e).__name__}: {e}); using the host path")
+        except ValueError as e:
+            log_warning(f"device predict unavailable ({e}); using the "
+                        "host path")
             bp = None
         self._device_pred_cache = (key, bp)
         return bp

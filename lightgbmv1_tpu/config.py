@@ -326,8 +326,9 @@ class Config:
     # bit-identical to hist_method=pallas (interpret-mode pin,
     # tests/test_wave_fused.py).  Ineligible configs (categorical,
     # extra_trees, EFB/packed/int16 bins, row-sharded learners,
-    # non-wave growth, Mosaic lowering failure) fall back to the staged
-    # path with a logged reason (the fallback taxonomy, BASELINE.md).
+    # non-wave growth) run the staged path with a logged reason (the
+    # fallback taxonomy, BASELINE.md); a kernel the backend cannot
+    # lower or compile raises.
     hist_method: str = "auto"  # auto | bench | scatter | onehot | pallas | fused
     # device bin-matrix layout (the reference's DenseBin<VAL_T, IS_4BIT>
     # choice, bin.h): "packed4" stores two 4-bit bins per byte —
@@ -466,12 +467,13 @@ class Config:
     # predictor above the work threshold, vectorized numpy below);
     # "native"/"host" force those; "depthwise" is the depth-stepped
     # all-trees device walk; "pallas" pins the node tables in VMEM
-    # (ops/predict_pallas.py, falls back to depthwise if Mosaic cannot
-    # lower on the backend); "fused" is the serving megakernel — one
+    # (ops/predict_pallas.py; raises if the backend cannot lower it);
+    # "fused" is the serving megakernel — one
     # Pallas pass per row tile walks every tree AND accumulates the
     # per-class scores in VMEM (plan_predict_tiles tiles the node
-    # tables when they exceed the VMEM budget; staged fallback with a
-    # logged reason when the planner refuses or Mosaic cannot lower);
+    # tables when they exceed the VMEM budget; staged walk with a
+    # logged reason when the planner refuses, an error when the backend
+    # cannot lower the kernel);
     # "scan" is the legacy per-tree scan walk, kept as the bit-parity
     # pin.
     predict_method: str = "auto"

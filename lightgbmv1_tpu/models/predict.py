@@ -41,8 +41,10 @@ Three layers:
   ``plan_predict_tiles`` tiles oversized ensembles into VMEM-sized tree
   groups, and with <= 15 serving codes per feature the codes ship 4-bit
   PACKED (two per byte), halving the H2D stream.  Node-exactness is
-  pinned against the staged walk on the CPU interpret lane; a Mosaic
-  lowering failure falls back to the staged walk, warned ONCE.
+  pinned against the staged walk on the CPU interpret lane.  A planner
+  refusal (``plan_predict_tiles``) serves the staged walk with its
+  reason; a lowering or compile failure of a kernel the caller asked
+  for (``pallas`` / ``fused``) raises with the compiler's message.
 
 Row-sharded multi-chip serving reuses the training mesh helpers
 (`parallel/cluster.make_mesh` + `parallel/trainer.shard_rows`): rows are
@@ -649,13 +651,11 @@ class BatchPredictor:
         self.cache_misses = 0
         self.cache_evictions = 0
         self._scan_stacked = None
-        self._pallas_broken = False
         # -- serving-megakernel plan (static, recorded in BENCH): tiles
         # trees into VMEM-sized groups; refusal = staged walk + one
         # honest reason line
         self.fused_plan = None
         self._fused_tables = None
-        self._fused_broken = False
         if method == "fused":
             from ..ops.predict_pallas import plan_predict_tiles
 
@@ -822,73 +822,12 @@ class BatchPredictor:
             # instrument
             return obs_xla.instrument_jit(fn, "predict.leaf")
 
-        jfn = self._shared_jit(bucket, "leaf", build)
-        if self.method == "pallas":
-            # the lowering-failure guard is PER INSTANCE (it reads this
-            # predictor's broken flag and fallback tables); only the
-            # inner jitted walk is shared
-            jfn = self._pallas_guard(jfn, bucket)
-        return self._cache_put(key, jfn)
-
-    def _pallas_guard(self, jfn, bucket):
-        """First-call fallback: if the Pallas kernel fails to lower on
-        this backend, swap in the pure-XLA walk (the bit-parity pin) for
-        every subsequent call.  The warning is deduplicated process-wide
-        (``_log_once``): a chunked streaming predict previously re-logged
-        it per chunk."""
-
-        def guarded(arrays, xb):
-            if self._pallas_broken:
-                return self._xla_fallback(bucket)(arrays, xb)
-            try:
-                return jfn(arrays, xb)
-            except Exception as e:  # noqa: BLE001 — Mosaic lowering gap
-                _log_once(f"pallas:lower:{type(e).__name__}",
-                          f"predict_method=pallas failed to lower "
-                          f"({type(e).__name__}); falling back to the "
-                          "XLA depth-stepped walk", warn=True)
-                self._pallas_broken = True
-                return self._xla_fallback(bucket)(arrays, xb)
-
-        return guarded
-
-    def _xla_fallback(self, bucket):
-        key = (bucket, "leaf_xla")
-        cached = self._cache_get(key)
-        if cached is not None:
-            return cached
-        import jax
-
-        depth, has_cat = self.depth, self.has_cat
-        zc, nc = self.binner.zero_code, self.binner.nan_code
-        prebin, packed, F = self.prebin, self.packed, self.F
-        tc = self._tc
-
-        def build():
-            def walk(arrays, xb):
-                tc.bump()
-                if packed:
-                    xb = unpack_serving_codes(xb, F)
-                if prebin:
-                    return serving_leaf_binned(arrays, xb, depth, zc, nc,
-                                               has_cat)
-                return serving_leaf_raw(arrays, xb, depth, has_cat)
-
-            fn = walk
-            if self._mesh is not None:
-                from ..parallel.trainer import shard_rows
-
-                fn = shard_rows(walk, self._mesh, "rows", n_replicated=1)
-            return obs_xla.instrument_jit(fn, "predict.leaf")
-
-        return self._cache_put(key, self._shared_jit(
-            bucket, "leaf_xla", build))
+        return self._cache_put(key, self._shared_jit(bucket, "leaf", build))
 
     # -- serving megakernel (predict_method=fused) -----------------------
     def _fused_engaged(self) -> bool:
         return bool(self.method == "fused" and self.fused_plan is not None
-                    and self.fused_plan["eligible"]
-                    and not self._fused_broken)
+                    and self.fused_plan["eligible"])
 
     def _fused_walk(self, mode: str = "scores", transform=None):
         """The raw (unjitted) megakernel call for one bucket — exposed
@@ -933,37 +872,7 @@ class BatchPredictor:
                 fn = shard_rows(fn, self._mesh, "rows", n_replicated=1)
             return obs_xla.instrument_jit(fn, "predict.fused")
 
-        jfn = self._shared_jit(bucket, kind, build)
-        return self._cache_put(
-            key, self._fused_guard(jfn, bucket, mode, transform))
-
-    def _fused_guard(self, jfn, bucket, mode, transform):
-        """Mosaic probe for the megakernel: a lowering failure swaps in
-        the staged walk (+ the out-of-kernel epilogue) for every
-        subsequent call, warned ONCE process-wide — the chunked stream
-        must not re-log per chunk."""
-
-        def staged(xb):
-            leaf = self._xla_fallback(bucket)(self.arrays, xb)
-            if mode == "leaf":
-                return leaf
-            s = self._scores_fn(bucket)(self.arrays.leaf_value, leaf)
-            return _transform_scores(s, transform)
-
-        def guarded(tables, xb):
-            if self._fused_broken:
-                return staged(xb)
-            try:
-                return jfn(tables, xb)
-            except Exception as e:  # noqa: BLE001 — Mosaic lowering gap
-                _log_once(f"fused:lower:{type(e).__name__}",
-                          f"predict_method=fused failed to lower "
-                          f"({type(e).__name__}); falling back to the "
-                          "staged depth-stepped walk", warn=True)
-                self._fused_broken = True
-                return staged(xb)
-
-        return guarded
+        return self._cache_put(key, self._shared_jit(bucket, kind, build))
 
     def _scan_fn(self, bucket: int):
         """The parity-pin scan walk (models/tree.ensemble_predict_raw) as

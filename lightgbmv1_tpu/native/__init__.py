@@ -41,7 +41,12 @@ def _compile_and_load(src_path: str, lib_path: str, what: str):
         try:
             res = subprocess.run(cmd, capture_output=True, text=True,
                                  timeout=120)
-        except (OSError, subprocess.TimeoutExpired):
+        except (OSError, subprocess.TimeoutExpired) as e:
+            # each library's loader caches the failure, so this is one
+            # line per library per process
+            log_warning(f"native {what}: g++ could not be run "
+                        f"({type(e).__name__}: {e}); using the Python "
+                        "fallback")
             return None
         if res.returncode != 0:
             log_warning(f"native {what} build failed; using the Python "

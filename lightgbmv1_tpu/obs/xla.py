@@ -24,10 +24,13 @@ surfaces:
   ``jax.jit`` path (pinned by tests/test_xla_obs.py).
 
   Safety: a call whose arguments are tracers (the wrapper nested inside
-  an outer jit) passes straight through to the inlined jit; any failure
-  of the AOT bookkeeping path falls back PERMANENTLY (per wrapper) to
-  plain ``jax.jit`` dispatch and counts the fallback — telemetry may
-  never take training down.
+  an outer jit) passes straight through to the inlined jit.  A compile
+  or dispatch failure PROPAGATES — execution never silently changes
+  path (a retry through plain ``jax.jit`` would also re-use arguments
+  the failed dispatch may already have donated).  Only the signature
+  bookkeeping (an unhashable argument structure) may degrade a wrapper
+  to plain ``jax.jit``, counted as a fallback; telemetry extraction
+  (``cost_analysis`` / ``memory_analysis``) may be absent, never fatal.
 
 * **Live device-memory gauges** (:func:`sample_device_memory`) — the
   runtime allocator's view via ``device.memory_stats()`` (``None`` on
@@ -57,8 +60,6 @@ import threading
 import time
 from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple
-
-from ..utils.log import log_warning
 
 ANCHOR_FILE = "profile.anchor.json"
 
@@ -111,14 +112,12 @@ def _metric(kind: str, name: str, help_text: str):
 
 
 def _extract_cost(compiled) -> Tuple[Optional[float], Optional[float]]:
-    """(flops, bytes accessed) from ``cost_analysis()`` — list-of-dict on
-    older jax, dict on newer; ``None`` where the backend reports none."""
+    """(flops, bytes accessed) from ``cost_analysis()``; ``None`` where
+    the backend reports none."""
     try:
         ca = compiled.cost_analysis()
     except Exception:   # noqa: BLE001 — absent on some backends
         return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return None, None
 
@@ -341,31 +340,10 @@ class InstrumentedJit:
             return self._jit(*args, **kwargs)
         compiled = self._compiled.get(sig)
         if compiled is None:
-            try:
-                compiled = self._compile_now(sig, args, kwargs)
-            except Exception:   # noqa: BLE001
-                # run the plain path FIRST: a genuine user error raises
-                # identically there (and propagates); only an AOT-specific
-                # failure survives to be counted as a fallback
-                out = self._jit(*args, **kwargs)
-                self._broken = True
-                _record_fallback(self._label)
-                log_warning(
-                    f"obs/xla: lower/compile bookkeeping failed for "
-                    f"{self._label!r}; falling back to plain jax.jit "
-                    "dispatch for this wrapper")
-                return out
+            compiled = self._compile_now(sig, args, kwargs)
         else:
             self._compiled.move_to_end(sig)
-        try:
-            return compiled(*args, **kwargs)
-        except Exception:   # noqa: BLE001 — e.g. sharding-layout mismatch
-            self._broken = True
-            _record_fallback(self._label)
-            log_warning(
-                f"obs/xla: compiled-executable dispatch failed for "
-                f"{self._label!r}; falling back to plain jax.jit")
-            return self._jit(*args, **kwargs)
+        return compiled(*args, **kwargs)
 
 
 def instrument_jit(fn, label: str,

@@ -82,7 +82,10 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     budget.  The estimate is deliberately conservative: per-chunk f32
     temporaries (repeat buffer, compare, select, cast) can coexist, and
     narrow feature blocks pay lane-padding amplification (observed OOM at
-    B=256 with 3 features and T=1024)."""
+    B=256 with 3 features and T=1024).  No ``compiler_params`` is passed,
+    so the chip's default scoped limit applies: on TPU v5 lite every
+    shape chip_smoke.py runs (28 features, 16/64 bins, 1-64 slots, all
+    default-policy precisions, packed4) compiles under it."""
     out_bytes = m_pad * num_lanes * 4
     per_row = 14 * min(num_lanes, 512) + 16 * m_pad
     t0 = 1024 if kernel_width(num_bins) <= 64 else 512

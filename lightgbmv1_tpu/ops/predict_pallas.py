@@ -35,9 +35,9 @@ not row-bound.  Two kernels fix that:
 Scope: the PREBINNED, non-categorical serving path (where the table-pin
 pays; categorical ensembles ride the XLA walk).  The pure-XLA walk is the
 bit-parity pin: `tests/test_predict_engine.py` pins kernel-vs-XLA leaf
-equality (interpret mode on CPU), and `BatchPredictor` falls back to the
-XLA walk with a warning if Mosaic cannot lower the gathers on the local
-backend — `predict_method=pallas`/``fused`` are opt-in.
+equality (interpret mode on CPU).  `predict_method=pallas`/``fused`` are
+opt-in, and a backend that cannot lower or compile them raises — there
+is no silent switch to the XLA walk.
 """
 
 from __future__ import annotations

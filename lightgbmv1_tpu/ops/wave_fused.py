@@ -87,12 +87,12 @@ parallel/trainer.py):
   contrast, IS in-kernel now: ``decision_bins`` gathers the packed
   byte by ``feature >> 1`` and selects the nibble by feature parity
   (the ``packed_bins_of_rows`` layout contract),
-* Mosaic lowering failure on a device backend — auto-fallback with a
-  warning, the ``predict_pallas`` precedent; the CPU backend always runs
-  the kernel in interpret mode (the bit-parity lane the tests pin).
-  The lowering probe compiles the ROUTED round (partition folded in)
-  plus the valid-set router, so a backend that can fuse histograms but
-  not the routing stage still falls back cleanly.
+
+A lowering or compile failure on a device backend is NOT a fallback leg:
+an eligible config that asks for this kernel runs it or raises with the
+compiler's message (tests/test_tpu_lowering.py cross-lowers every kernel
+for ``tpu`` from the CPU suite).  The CPU backend always runs the kernel
+in interpret mode (the bit-parity lane the tests pin).
 """
 
 from __future__ import annotations
@@ -1373,128 +1373,3 @@ def fused_ineligible_reason(*, meta, params, bin_dtype, num_bins,
     if params.extra_trees:
         return "extra_trees draws per-node randomness inside the scan"
     return ""
-
-
-_BACKEND_LOWERS: dict = {}
-
-
-def backend_lowers_fused() -> bool:
-    """One cached trial compile of a tiny fused round on the current
-    backend — the Mosaic-lowering auto-fallback probe (the
-    ``predict_pallas`` precedent: opt-in kernel, warn + staged fallback
-    when the local backend cannot lower it).  CPU always passes: the
-    kernel runs in interpret mode there (the bit-parity lane)."""
-    backend = jax.default_backend()
-    if backend in _BACKEND_LOWERS:
-        return _BACKEND_LOWERS[backend]
-    if backend == "cpu":
-        _BACKEND_LOWERS[backend] = True
-        return True
-    from ..utils.log import log_warning
-
-    try:
-        F, B, N, S = 4, 8, 64, 2
-        meta = FeatureMeta(
-            num_bins=jnp.full(F, B, jnp.int32),
-            missing_type=jnp.zeros(F, jnp.int32),
-            nan_bin=jnp.full(F, -1, jnp.int32),
-            zero_bin=jnp.zeros(F, jnp.int32),
-            is_categorical=jnp.zeros(F, bool),
-            usable=jnp.ones(F, bool),
-            monotone_type=jnp.zeros(F, jnp.int32),
-        )
-        from .split import SplitParams
-
-        fn = make_fused_round(meta=meta, params=SplitParams(),
-                              num_bins=B, precision="bf16x2",
-                              deep_precision="bf16")
-        rng = np.random.RandomState(0)
-        binned_t = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
-        g3_t = jnp.asarray(rng.randn(N, 3).astype(np.float32))
-        lids_t = jnp.asarray(rng.randint(0, 2 * S, N).astype(np.int32))
-        kw = dict(mask=jnp.ones((2 * S, F), bool),
-                  csums=jnp.abs(jnp.asarray(
-                      rng.randn(2 * S, 3).astype(np.float32))),
-                  constr=jnp.tile(jnp.asarray([-3e38, 3e38], jnp.float32),
-                                  (2 * S, 1)),
-                  depth=jnp.ones(2 * S, jnp.int32),
-                  pout=jnp.zeros(2 * S, jnp.float32))
-        # probe the ROUTED round (ISSUE 15: partition folded in) — the
-        # superset the serial trainer dispatches — plus the valid-set
-        # router; a backend that lowers histograms but not the routing
-        # stage must fall back whole, never half
-        rkw = dict(feats=jnp.arange(S, dtype=jnp.int32),
-                   thrs=jnp.full(S, B // 2, jnp.int32),
-                   dls=jnp.zeros(S, bool),
-                   leafs=jnp.arange(S, dtype=jnp.int32),
-                   nls=jnp.arange(S, dtype=jnp.int32) + S,
-                   num_leaves=2 * S)
-        jax.jit(lambda b, g, l: fn(
-            b, g, None, S, **kw, route=dict(leaf_id=l, **rkw))
-        ).lower(binned_t, g3_t, lids_t).compile()
-        jax.jit(lambda b, l: fn.route_rows(b, l, **rkw)) \
-            .lower(binned_t, lids_t).compile()
-        _BACKEND_LOWERS[backend] = True
-    except Exception as e:  # noqa: BLE001 — any lowering failure falls back
-        log_warning(
-            f"hist_method=fused: Mosaic could not lower the fused "
-            f"wave-round kernel on backend {backend!r} "
-            f"({type(e).__name__}); falling back to the staged "
-            "histogram+split path")
-        _BACKEND_LOWERS[backend] = False
-    return _BACKEND_LOWERS[backend]
-
-
-def backend_lowers_fused_loop() -> bool:
-    """One cached trial compile of a tiny R=2 persistent wave loop on
-    the current backend — the loop's own Mosaic probe.  The loop adds
-    kernel constructs the single-round probe never exercises (scatter
-    updates on scratch, in-kernel top-k, dynamic leaf-slice writes,
-    threefry for the int8sr stream), so a backend that lowers the
-    single-round kernel but not the loop must fall back WHOLE to the
-    single-round dispatch — never half.  CPU always passes (interpret
-    mode, the bit-parity lane)."""
-    backend = ("loop", jax.default_backend())
-    if backend in _BACKEND_LOWERS:
-        return _BACKEND_LOWERS[backend]
-    if backend[1] == "cpu":
-        _BACKEND_LOWERS[backend] = True
-        return True
-    from ..utils.log import log_warning
-
-    try:
-        F, B, N, K, L = 4, 8, 64, 2, 8
-        meta = FeatureMeta(
-            num_bins=jnp.full(F, B, jnp.int32),
-            missing_type=jnp.zeros(F, jnp.int32),
-            nan_bin=jnp.full(F, -1, jnp.int32),
-            zero_bin=jnp.zeros(F, jnp.int32),
-            is_categorical=jnp.zeros(F, bool),
-            usable=jnp.ones(F, bool),
-            monotone_type=jnp.zeros(F, jnp.int32),
-        )
-        from .split import SplitParams
-
-        fn = make_fused_wave_loop(
-            meta=meta, params=SplitParams(), num_bins=B, precision="f32",
-            deep_precision="f32", rounds=2)
-        rng = np.random.RandomState(0)
-        binned_t = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
-        g3_t = jnp.asarray(rng.randn(N, 3).astype(np.float32))
-        lids_t = jnp.zeros(N, jnp.int32)
-        ft_t = jnp.zeros((L, 12), jnp.float32).at[0, 0].set(1.0)
-        pool_t = jnp.zeros((L, F, B, 3), jnp.float32)
-        key_t = jnp.zeros(2, jnp.uint32)
-        jax.jit(lambda b, g, l, f, p, k: fn(
-            b, g, l, f, 1, k, K=K, slot_buckets=(K,), quant_buckets=(),
-            max_depth=0, base_mask=jnp.ones(F, bool), pool=p)
-        ).lower(binned_t, g3_t, lids_t, ft_t, pool_t, key_t).compile()
-        _BACKEND_LOWERS[backend] = True
-    except Exception as e:  # noqa: BLE001 — any lowering failure falls back
-        log_warning(
-            f"wave_loop_rounds: Mosaic could not lower the persistent "
-            f"wave-loop kernel on backend {backend[1]!r} "
-            f"({type(e).__name__}); falling back to single-round fused "
-            "dispatch")
-        _BACKEND_LOWERS[backend] = False
-    return _BACKEND_LOWERS[backend]

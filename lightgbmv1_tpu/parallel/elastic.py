@@ -321,6 +321,14 @@ class ElasticCoordinator:
     # -- spawn one generation -------------------------------------------
     def _spawn(self, generation: int, port: int,
                world: Optional[int] = None) -> List[subprocess.Popen]:
+        """Start one generation of workers.  Workers run on virtual CPU
+        devices (``JAX_PLATFORMS=cpu`` unless the caller's env names
+        another platform): this rig puts N jax.distributed processes on
+        ONE host, and a chip belongs to one process at a time — on a
+        one-chip host N workers could not share it.  The recovery
+        protocol under test (leases, abort, re-bootstrap, resume) is
+        platform-independent; a real multi-host fleet passes its own
+        ``env``."""
         cfg = self.config
         world = cfg.world if world is None else int(world)
         procs = []

@@ -51,9 +51,6 @@ def main(argv) -> int:
     port = kv["port"]
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from ..obs import dump as obs_dump
     from ..obs import events as obs_events
 

@@ -77,27 +77,6 @@ from .cluster import (comm_table_per_round, hier_comm_table_per_round,
                       make_hier_mesh, make_mesh, publish_comm_metrics,
                       publish_hier_comm_metrics)
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-def shard_map(*args, **kwargs):
-    """shard_map across jax versions: new jax spells the replication check
-    ``check_vma``, jax <= 0.4.x spells it ``check_rep`` — map the call
-    rather than pinning a version (the container and the device driver
-    run different jax releases)."""
-    try:
-        return _shard_map(*args, **kwargs)
-    except TypeError:
-        if "check_vma" not in kwargs:
-            raise
-        kwargs = dict(kwargs)
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-
 def _make_mesh(num_shards: int, axis: str) -> Mesh:
     return make_mesh(num_shards, axis)   # parallel/cluster.py (topology home)
 
@@ -114,7 +93,7 @@ def shard_rows(fn, mesh: Mesh, axis: str = "rows", n_replicated: int = 0):
     def wrapped(*args):
         in_specs = tuple([P()] * n_replicated
                          + [P(axis)] * (len(args) - n_replicated))
-        sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                             out_specs=P(axis), check_vma=False)
         return sharded(*args)
 
@@ -273,12 +252,18 @@ def resolve_deep_dtype(requested: str, precision: str, backend: str) -> str:
     ``"auto"`` (ROADMAP item 3a) resolves by backend: ``int8sr`` on TPU —
     the int8 MXU path the mode was built for, with the default flip gated
     on bench.py's ``precision_expt`` AUC-parity record — and full
-    ``bf16x2`` everywhere else (no int8 MXU economics off-TPU; full
-    precision is the honest default there).  Opt out by setting any
+    ``bf16x2`` on CPU (no int8 MXU economics there; full precision is the
+    honest default).  Any other platform is an error naming it: the
+    policy has never been decided there.  Opt out by setting any
     explicit dtype.  ``""`` keeps the legacy policy: bf16x2 drops to
     single-pass bf16 on sustained rounds, any other explicit
     ``hist_dtype`` is used unchanged."""
     if requested == "auto":
+        if backend not in ("tpu", "cpu"):
+            raise ValueError(
+                f"hist_dtype_deep=auto is undecided for platform "
+                f"{backend!r} (tpu -> int8sr, cpu -> bf16x2); set an "
+                "explicit dtype")
         requested = "int8sr" if backend == "tpu" else "bf16x2"
     return requested or ("bf16" if precision == "bf16x2" else precision)
 
@@ -591,8 +576,10 @@ def build_trainer(
     # ---- hist_method=fused: the wave-round megakernel dispatch ----------
     # (ops/wave_fused.py — histogram + smaller-child subtraction + split
     # scan in one Pallas invocation, histograms resident in VMEM).  The
-    # static gates below are the documented fallback taxonomy; every
-    # ineligible config logs its reason once and runs the staged path.
+    # static gates below are planner DECISIONS with a stated reason
+    # (logged once; the staged path runs).  There is no compile probe: an
+    # eligible config runs the kernel, and a kernel the backend cannot
+    # lower or compile raises with the compiler's message.
     fused_builder = None
     if config.hist_method == "fused":
         from ..ops import wave_fused
@@ -610,9 +597,6 @@ def build_trainer(
             fused_reason = (f"tree_learner={learner} reduces histograms "
                             "across row shards (the collective needs the "
                             "explicit histogram)")
-        if not fused_reason and jax.default_backend() != "cpu" \
-                and not wave_fused.backend_lowers_fused():
-            fused_reason = "Mosaic lowering failed (warned above)"
         if fused_reason:
             log_warning(f"hist_method=fused: {fused_reason}; running the "
                         "staged histogram+split path")
@@ -648,12 +632,12 @@ def build_trainer(
             # wave_loop_rounds > 1 on the fused path: ONE Pallas launch
             # runs R consecutive rounds with the frontier state resident
             # in VMEM (ops/wave_fused.make_fused_wave_loop).  The gates
-            # below are the loop's own fallback-taxonomy legs — every
-            # staged leg the kernel cannot replicate in-loop (per-node
-            # feature re-masking, monotone constraint propagation) and
-            # the Mosaic probe, each falling back to SINGLE-ROUND fused
-            # dispatch with a logged reason.  The VMEM planner runs at
-            # trace time inside the grower (shape-dependent).
+            # below are the loop's own planner refusals — every staged
+            # leg the kernel cannot replicate in-loop (per-node feature
+            # re-masking, monotone constraint propagation), each running
+            # SINGLE-ROUND fused dispatch with a logged reason.  The VMEM
+            # planner runs at trace time inside the grower
+            # (shape-dependent).
             fused_loop = None
             if fused_fn is not None and config.wave_loop_rounds > 1:
                 from ..models import grower_wave as _gw
@@ -670,9 +654,6 @@ def build_trainer(
                     loop_reason = ("monotone constraints propagate "
                                    "child bounds between rounds outside "
                                    "the kernel")
-                elif jax.default_backend() != "cpu" \
-                        and not wave_fused.backend_lowers_fused_loop():
-                    loop_reason = "Mosaic lowering failed (warned above)"
                 if loop_reason:
                     log_warning(f"wave_loop_rounds="
                                 f"{config.wave_loop_rounds}: "
@@ -943,7 +924,7 @@ def build_trainer(
             grow = make_leafwise_grower(
                 hist_fn=hist_fn, split_fn=split_fn, sums_fn=sums_fn,
                 bins_of_fn=bins_feat_fn, **lw_pool, **common)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             grow,
             mesh=mesh,
             in_specs=(P(None, row_axes), P(row_axes, None), P(), P(), P()),
@@ -1180,7 +1161,7 @@ def build_trainer(
                                         bins_of_fn=bins_feat_fn,
                                         forced_splits=forced,
                                         **lw_pool, **common)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             grow,
             mesh=mesh,
             in_specs=(P(None, row_axes), P(row_axes, None), P(), P(), P()),
@@ -1415,7 +1396,7 @@ def build_trainer(
                 hist_fn=hist_fn, split_fn=split_fn, cegb_coupled=coupled_fp,
                 hist_pool_mb=config.histogram_pool_size,
                 num_features=F_pad, **fp_kwargs)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             grow,
             mesh=mesh,
             in_specs=(P(None, None), P(None, None), P(), P(), P()),

@@ -107,17 +107,13 @@ def test_named_scopes_reach_lowered_hlo():
     from lightgbmv1_tpu.ops.histogram import hist_frontier
     from lightgbmv1_tpu.ops.split import (FeatureMeta, SplitParams,
                                           find_best_split)
-    # jax <= 0.4.x has no as_text(debug_info=...); the compat wrapper
-    # recovers the debug locations from the MLIR module on both releases
-    # (utils/compat.py — the trainer shard_map check_vma pattern)
-    from lightgbmv1_tpu.utils.compat import lowered_text
 
     binned = jnp.zeros((3, 64), jnp.uint8)
     g3 = jnp.zeros((64, 3), jnp.float32)
     lid = jnp.zeros(64, jnp.int32)
-    txt = lowered_text(jax.jit(
+    txt = jax.jit(
         lambda b, g, l: hist_frontier(b, g, l, 2, 8)).lower(
-        binned, g3, lid), debug_info=True)
+        binned, g3, lid).as_text(debug_info=True)
     assert "lgbm.hist" in txt
 
     meta = FeatureMeta(
@@ -130,7 +126,7 @@ def test_named_scopes_reach_lowered_hlo():
         monotone_type=jnp.zeros(3, jnp.int32),
     )
     hist = jnp.zeros((3, 8, 3), jnp.float32)
-    txt2 = lowered_text(jax.jit(lambda h, p, m: find_best_split(
+    txt2 = jax.jit(lambda h, p, m: find_best_split(
         h, p, meta, m, SplitParams())).lower(
-        hist, jnp.zeros(3), jnp.ones(3, bool)), debug_info=True)
+        hist, jnp.zeros(3), jnp.ones(3, bool)).as_text(debug_info=True)
     assert "lgbm.split" in txt2

@@ -14,7 +14,7 @@ The contracts under test (ISSUE 9):
 * **metrics registry** — Prometheus text exposition PINNED (label
   escaping, monotone cumulative histogram buckets, ``+Inf`` = count),
   thread-safe counters, JSON snapshot, serve-metrics adapter parity.
-* **sentinel** — tools/bench_trend.py: the repo's real BENCH_r01–r05
+* **sentinel** — tools/bench_trend.py: a healthy five-record
   trajectory exits 0; a synthetic regressed record and a guard flip
   exit 1; tools/ci_gate.py combines trend + tier-1 budget into one
   exit code.
@@ -786,13 +786,16 @@ def _write_rec(d, name, parsed):
         json.dump({"n": 1, "parsed": parsed}, fh)
 
 
-def test_bench_trend_real_records_pass():
+def test_bench_trend_healthy_series_passes(tmp_path):
     import bench_trend
 
-    result = bench_trend.run(REPO)
+    for i, value in enumerate((0.53, 1.87, 5.39, 6.30, 6.57), start=1):
+        _write_rec(tmp_path, f"BENCH_r{i:02d}.json",
+                   {"value": value, "auc": 0.8767, "serve_ok": True})
+    result = bench_trend.run(str(tmp_path))
     assert result["ok"], result["flags"]
     assert len(result["bench_records"]) >= 5
-    assert bench_trend.main(["--dir", REPO]) == 0
+    assert bench_trend.main(["--dir", str(tmp_path)]) == 0
 
 
 def test_bench_trend_flags_regression_and_guard_flip(tmp_path):

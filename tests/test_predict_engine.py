@@ -182,7 +182,6 @@ def test_pallas_kernel_bit_parity_interpret(bin_model, xt_nan):
     ref = BatchPredictor(trees, 1, 8).predict_leaf(xt_nan)
     bpp = BatchPredictor(trees, 1, 8, method="pallas", interpret=True)
     got = bpp.predict_leaf(xt_nan)
-    assert not bpp._pallas_broken
     assert np.array_equal(got, ref), (
         "Pallas serving kernel diverged from the XLA depth-stepped walk")
 
@@ -500,7 +499,7 @@ def _fused_assert_parity(booster, X, K=1, **bpk):
     if K == 1:
         raw32 = raw32[:, 0]
     np.testing.assert_allclose(raw32, raw_host, rtol=1e-4, atol=1e-5)
-    assert not bp._fused_broken, "megakernel silently fell back staged"
+    assert bp._fused_engaged()
     return bp
 
 
@@ -604,38 +603,35 @@ def test_fused_zero_retraces_within_bucket(bin_model, rng):
     assert bp._fused_engaged()
 
 
-def test_fused_warn_once_dedup(bin_model, monkeypatch):
-    """A lowering failure mid-stream warns ONCE process-wide, not once
-    per chunk — and every chunk still serves staged, oracle-exact."""
-    from lightgbmv1_tpu.models import predict as predict_mod
+def test_kernel_failure_propagates(bin_model, monkeypatch):
+    """A kernel the caller asked for that cannot lower RAISES with the
+    compiler's message — no staged walk is swapped in behind the
+    caller's back (the fused and the pallas lane alike), and the next
+    call raises again rather than serving from a remembered fallback."""
     from lightgbmv1_tpu.ops import predict_pallas as pp_mod
 
     def boom(*a, **k):
-        raise RuntimeError("no Mosaic on this backend")
+        raise NotImplementedError("Only 2D gather is supported")
 
-    monkeypatch.setattr(pp_mod, "serving_fused_pallas", boom)
-    monkeypatch.setattr(predict_mod, "_logged_once", set())
-    warnings = []
-    monkeypatch.setattr(predict_mod, "log_warning",
-                        lambda m: warnings.append(m))
     rng = np.random.RandomState(31)
     Xt = rng.randn(600, 8)
     trees = bin_model._all_trees()
+    monkeypatch.setattr(pp_mod, "serving_fused_pallas", boom)
     bp = BatchPredictor(trees, 1, 8, method="fused", bucket_min=64,
-                        chunk_rows=128)          # 5 chunks
-    leaf_host = np.stack([t.predict_leaf_index(Xt) for t in trees],
-                         axis=1)
-    assert np.array_equal(bp.predict_leaf(Xt), leaf_host)
-    assert bp._fused_broken
-    fused_warns = [m for m in warnings if "fused" in m]
-    assert len(fused_warns) == 1, warnings
-    # same idiom on the pallas lane: chunked stream, one warning
+                        chunk_rows=128)
+    for call in (bp.predict_leaf, bp.predict_raw, bp.predict_leaf):
+        with pytest.raises(NotImplementedError, match="2D gather"):
+            call(Xt)
+    assert bp._fused_engaged()       # still the fused plan, not re-routed
     monkeypatch.setattr(pp_mod, "serving_leaf_pallas", boom)
     bpp = BatchPredictor(trees, 1, 8, method="pallas", bucket_min=64,
                          chunk_rows=128)
-    warnings.clear()
-    assert np.array_equal(bpp.predict_leaf(Xt), leaf_host)
-    assert len([m for m in warnings if "pallas" in m]) == 1, warnings
+    for _ in range(2):
+        with pytest.raises(NotImplementedError, match="2D gather"):
+            bpp.predict_leaf(Xt)
+    # through the user-facing entry point too
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        bin_model.predict(Xt, pred_leaf=True, predict_method="pallas")
 
 
 def test_booster_fused_route(bin_model, xt_nan):
@@ -790,7 +786,6 @@ def _geometry_assert(trees, F, X):
     bpf = BatchPredictor(trees, 1, F, method="fused", bucket_min=64)
     assert bpf.fused_plan["eligible"], bpf.fused_plan
     assert np.array_equal(bpf.predict_leaf(X), leaf_host)
-    assert not bpf._fused_broken
     bps = BatchPredictor(trees, 1, F, bucket_min=64)
     assert np.array_equal(bps.predict_leaf(X), leaf_host)
     return bpf

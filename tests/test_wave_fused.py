@@ -494,13 +494,33 @@ def test_wave_loop_planner_gates():
         rounds=6, vmem_budget=1 << 10, **base)["reason"]
 
 
-def test_wave_loop_backend_probe_cpu():
-    # CPU is the bit-parity lane: the Mosaic probe always passes there
-    # (interpret mode), and its verdict is cached per backend
+def test_fused_lowering_failure_propagates(monkeypatch):
+    # there is no compile probe and no staged fallback behind it: when
+    # the kernel the user asked for cannot be lowered, train() raises
+    # with the compiler's message (single-round and wave-loop alike)
+    import lightgbmv1_tpu as lgb
     from lightgbmv1_tpu.ops import wave_fused as wf
 
-    assert wf.backend_lowers_fused_loop()
-    assert wf.backend_lowers_fused_loop()   # cached second hit
+    assert not hasattr(wf, "backend_lowers_fused")
+    assert not hasattr(wf, "backend_lowers_fused_loop")
+
+    def boom(*a, **k):
+        raise NotImplementedError(
+            "Unimplemented primitive in Pallas TPU lowering: cumsum")
+
+    # the kernel body's own cumsum site (child_scan_residue), which is
+    # where the TPU lowering fails; the staged path does not use wf's name
+    monkeypatch.setattr(wf, "scan_left_sums", boom)
+    rng = np.random.RandomState(3)
+    X = rng.randn(600, 6)
+    y = (X[:, 0] - X[:, 1] > 0).astype(float)
+    base = {"objective": "binary", "num_leaves": 15, "max_bin": 15,
+            "min_data_in_leaf": 5, "verbosity": -1,
+            "leafwise_wave_size": 4, "hist_method": "fused"}
+    for extra in ({}, {"wave_loop_rounds": 4}):
+        with pytest.raises(NotImplementedError, match="cumsum"):
+            lgb.train({**base, **extra}, lgb.Dataset(X, label=y),
+                      num_boost_round=1, verbose_eval=False)
 
 
 def test_wave_loop_ffbynode_falls_back_with_reason():

@@ -155,7 +155,6 @@ def test_fused_step_donates_score_caches():
     from lightgbmv1_tpu.config import Config
     from lightgbmv1_tpu.io.dataset import BinnedDataset
     from lightgbmv1_tpu.models.gbdt import create_boosting
-    from lightgbmv1_tpu.utils.compat import lowered_text
 
     X, y = make_binary_problem(n=400)
     cfg = Config.from_dict({"objective": "binary", "num_leaves": 7,
@@ -169,7 +168,7 @@ def test_fused_step_donates_score_caches():
     lowered = step.lower(gb._grow_binned, (), gb._train_scores.score, (),
                          jnp.asarray(0, jnp.int32), feat_masks,
                          gb._cegb_used)
-    txt = lowered_text(lowered)
+    txt = lowered.as_text()
     assert "tf.aliasing_output" in txt or "jax.buffer_donor" in txt, (
         "fused step lost score-cache donation (no aliasing attribute in "
         "the lowered module)")
@@ -180,7 +179,7 @@ def test_fused_step_donates_score_caches():
     lowered2 = step2.lower(gb2._grow_binned, (), gb2._train_scores.score,
                            (), jnp.asarray(0, jnp.int32), feat_masks,
                            gb2._cegb_used)
-    assert "tf.aliasing_output" not in lowered_text(lowered2)
+    assert "tf.aliasing_output" not in lowered2.as_text()
 
 
 def test_rollback_survives_donation_snapshot():
@@ -214,7 +213,8 @@ def test_resolve_deep_dtype_policy():
 
     assert resolve_deep_dtype("auto", "bf16x2", "tpu") == "int8sr"
     assert resolve_deep_dtype("auto", "bf16x2", "cpu") == "bf16x2"
-    assert resolve_deep_dtype("auto", "bf16x2", "gpu") == "bf16x2"
+    with pytest.raises(ValueError, match="gpu"):
+        resolve_deep_dtype("auto", "bf16x2", "gpu")
     assert resolve_deep_dtype("", "bf16x2", "tpu") == "bf16"
     assert resolve_deep_dtype("", "f32", "tpu") == "f32"
     assert resolve_deep_dtype("int8sr", "bf16x2", "cpu") == "int8sr"

@@ -8,12 +8,17 @@ capture campaign an executable procedure:
 1. **Arm** — the XLA profiler (obs/xla.py ``profiler_session`` — device
    lane + wall-clock anchor sidecar) and the span tracer around a
    dedicated profiled training window whose host artifacts (trace /
-   metrics / events) are exported next to the capture.
+   metrics / events) are exported next to the capture.  The window is a
+   STAGE SUBPROCESS like the other two (``capture.py --window DIR``): a
+   chip belongs to one process at a time, so this parent never imports
+   JAX before its stages have run — a parent that trained in-process
+   would hold the chip and starve ``bench.py``.
 2. **Run** — ``bench.py`` (ALL blocks: train/predict/serve/chaos/stream/
    fleet/obs incl. the new device-truth block) and the
    ``__graft_entry__.py`` smoke battery (compile-check + serve_smoke +
    chaos_smoke + ``dryrun_multichip``), each as a subprocess with
-   ``LGBMV1_OBS_DIR`` pointed at the capture's artifact directory.
+   ``LGBMV1_OBS_DIR`` pointed at the capture's artifact directory.  The
+   stages run one after another, each releasing the chip when it exits.
 3. **Merge** — every artifact + the profiler capture into ONE Perfetto
    trace (obs/agg.py ``aggregate_dir(profile_dir=...)``): host span
    lanes, per-process metric/event artifacts and the device lane on one
@@ -177,15 +182,16 @@ def validate_merged_trace(path: str) -> dict:
 
 def run_capture(records_dir: str = ROOT, out_dir: str = None,
                 round_no: int = None, dry_run: bool = False,
-                bench_cmd=None, smoke_cmd=None, skip_t1: bool = True,
+                bench_cmd=None, smoke_cmd=None, window_cmd=None,
+                skip_t1: bool = True,
                 t1_log: str = "/tmp/_t1.log", window_rows: int = 4096,
                 stage_timeout_s: float = 7200.0, out=print) -> dict:
     """The full capture pipeline (module docstring).  ``dry_run`` writes
     the records into a SCRATCH records dir and gates them in isolation —
     the repo's captured history is never touched by a rehearsal.
-    ``bench_cmd``/``smoke_cmd`` override the stage commands (tests stub
-    them); ``skip_t1`` passes through to the gate (a capture box has no
-    tier-1 log unless the suite just ran)."""
+    ``bench_cmd``/``smoke_cmd``/``window_cmd`` override the stage
+    commands (tests stub them); ``skip_t1`` passes through to the gate
+    (a capture box has no tier-1 log unless the suite just ran)."""
     import ci_gate  # noqa: E402 — sibling tool, path set above
 
     out_dir = out_dir or tempfile.mkdtemp(prefix="capture_")
@@ -196,8 +202,18 @@ def run_capture(records_dir: str = ROOT, out_dir: str = None,
     summary = {"round": n, "out_dir": out_dir, "records_dir": rec_out,
                "dry_run": bool(dry_run), "ok": False}
 
-    # 1. armed profiled window (device lane + host artifacts)
-    window = profiled_window(out_dir, rows=window_rows)
+    # 1. armed profiled window (device lane + host artifacts) — its own
+    # process, which holds the chip only until it exits
+    window_cmd = window_cmd or [
+        sys.executable, os.path.abspath(__file__), "--window", out_dir,
+        "--window-rows", str(int(window_rows))]
+    out(f"capture: running window stage: {' '.join(map(str, window_cmd))}")
+    win = run_stage(window_cmd, timeout_s=stage_timeout_s)
+    window = win["parsed"]
+    summary["window_rc"] = win["rc"]
+    if win["rc"] != 0 or not isinstance(window, dict):
+        summary["window_error"] = win["tail"][-2000:]
+        return summary
     summary["window"] = window
     art_dir = window["artifact_dir"]
 
@@ -378,6 +394,10 @@ def main(argv=None) -> int:
                     help="also enforce the tier-1 wall budget guard "
                          "(requires --t1-log from a suite run)")
     ap.add_argument("--window-rows", type=int, default=4096)
+    ap.add_argument("--window", metavar="OUT_DIR", default=None,
+                    help="stage mode: run ONLY the profiled training "
+                         "window into OUT_DIR and print its summary as "
+                         "the last line (run_capture spawns this)")
     ap.add_argument("--stage-timeout-s", type=float, default=7200.0)
     ap.add_argument("--flip-defaults", action="store_true",
                     help="rehearse ROADMAP item 1's default flip "
@@ -385,6 +405,10 @@ def main(argv=None) -> int:
                          "parity battery under the flipped defaults + "
                          "the required-guards gate; no records written")
     args = ap.parse_args(argv)
+    if args.window:
+        print(json.dumps(profiled_window(args.window,
+                                         rows=args.window_rows)))
+        return 0
     if args.flip_defaults:
         summary = run_flip_rehearsal(records_dir=args.records_dir)
         print(json.dumps(summary, default=str))

@@ -66,7 +66,7 @@ def train(over):
     gb.add_valid(dv, "test")
     t0 = time.time()
     gb.train_iters(IT)
-    jax.device_get(gb._train_scores.score)
+    jax.block_until_ready(gb._train_scores.score)
     wall = time.time() - t0
     mll = None
     for (_, name, value, _) in gb.eval_valid():

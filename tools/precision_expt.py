@@ -52,11 +52,11 @@ for name, over in VARIANTS:
     gb = create_boosting(cfg, ds)
     gb.add_valid(dt, "test")
     gb.train_iters(100)
-    jax.device_get(gb._train_scores.score)
+    jax.block_until_ready(gb._train_scores.score)
     t0 = time.time()
     for _ in range(4):
         gb.train_iters(100)
-    jax.device_get(gb._train_scores.score)
+    jax.block_until_ready(gb._train_scores.score)
     wall500 = (time.time() - t0) * 500.0 / 400.0
     auc = None
     for (_, mname, value, _) in gb.eval_valid():
