@@ -1921,10 +1921,7 @@ def measure_obs(X, y, backend: str, phase_fields=None):
     * **train trace validity** — the armed run's Chrome export must be
       valid trace-event JSON whose ``train.iteration`` spans sum to the
       measured train wall within 10% (``obs_span_cover_frac`` /
-      ``obs_trace_ok``).  When the capture carries phase fields, the
-      measured ``phase_attrib`` breakdown is installed as the tracer's
-      phase profile first, so the estimated phase child spans in the
-      trace agree with the record's attribution by construction.
+      ``obs_trace_ok``).
     * **serve trace + exposition** — a short traced loadgen window: every
       completed request must appear as ``serve.queue``/``serve.walk``
       span pairs carrying its trace id (``obs_serve_trace_ok``), and the
@@ -1992,14 +1989,6 @@ def measure_obs(X, y, backend: str, phase_fields=None):
     def train_once(armed):
         if armed:
             trace.arm(ring_events=1 << 16)
-            if phase_fields:
-                from tools.phase_attrib import phase_ms_from_fields
-
-                # the canonical phase list (tools/phase_attrib.py): a
-                # fused capture's merged hist+split row rides along
-                trace.set_phase_profile(
-                    phase_ms_from_fields(phase_fields),
-                    phase_fields.get("wave_rounds_per_tree"))
         else:
             trace.disarm()
         t0 = time.perf_counter()

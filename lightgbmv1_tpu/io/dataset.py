@@ -20,6 +20,7 @@ Representation decisions (SURVEY.md §7):
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Union
@@ -40,6 +41,26 @@ from .binning import (
 
 def _next_pow2(x: int) -> int:
     return 1 << max(0, (x - 1)).bit_length()
+
+
+@contextlib.contextmanager
+def construct_phase(phase: str):
+    """One phase of building a dataset (``convert``, where ``Dataset``
+    copies the caller's matrix; ``sample`` / ``find_bins`` / ``apply_bins``
+    / ``bundle``; ``place``, where a booster puts the bins on the
+    device): a ``data.<phase>`` span in the profiler's host
+    lane (obs/trace.py) and its seconds, always, in the registry's
+    ``dataset_construct_seconds{phase=...}`` gauge, which adds up over
+    the datasets a process builds."""
+    from ..obs import trace
+    from ..obs.metrics import default_registry
+
+    with trace.bridged_span("data." + phase, "data") as sp:
+        yield
+    default_registry().gauge(
+        "dataset_construct_seconds",
+        "Seconds spent in each phase of dataset construction",
+        label_names=("phase",)).labels(phase=phase).inc(sp.dur_ns / 1e9)
 
 
 @dataclass
@@ -188,47 +209,50 @@ class BinnedDataset:
             mappers = reference.bin_mappers
             feature_names = feature_names or reference.feature_names
         else:
-            # sampling (reference: bin_construct_sample_cnt, dataset_loader.cpp:823)
-            sample_cnt = min(num_data, config.bin_construct_sample_cnt)
-            rng = np.random.RandomState(config.data_random_seed)
-            if sample_cnt < num_data:
-                sample_idx = rng.choice(num_data, size=sample_cnt, replace=False)
-            else:
-                sample_idx = np.arange(num_data)
-            max_bins = list(config.max_bin_by_feature) or [config.max_bin] * num_features
-            if len(max_bins) != num_features:
-                log_fatal("max_bin_by_feature length must equal number of features")
-            samples = [np.asarray(X[sample_idx, j], dtype=np.float64) for j in range(num_features)]
-            if bin_finder is not None:
-                mappers = bin_finder(samples, sample_cnt, max_bins, categorical,
-                                     config, num_data)
-            else:
-                from .binning import get_forced_bins
+            with construct_phase("sample"):
+                # sampling (reference: bin_construct_sample_cnt, dataset_loader.cpp:823)
+                sample_cnt = min(num_data, config.bin_construct_sample_cnt)
+                rng = np.random.RandomState(config.data_random_seed)
+                if sample_cnt < num_data:
+                    sample_idx = rng.choice(num_data, size=sample_cnt, replace=False)
+                else:
+                    sample_idx = np.arange(num_data)
+                max_bins = list(config.max_bin_by_feature) or [config.max_bin] * num_features
+                if len(max_bins) != num_features:
+                    log_fatal("max_bin_by_feature length must equal number of features")
+                samples = [np.asarray(X[sample_idx, j], dtype=np.float64) for j in range(num_features)]
+            with construct_phase("find_bins"):
+                if bin_finder is not None:
+                    mappers = bin_finder(samples, sample_cnt, max_bins, categorical,
+                                         config, num_data)
+                else:
+                    from .binning import get_forced_bins
 
-                forced = get_forced_bins(config.forcedbins_filename,
-                                         num_features, categorical)
-                mappers = [
-                    BinMapper.find_bin(
-                        samples[j],
-                        total_sample_cnt=sample_cnt,
-                        max_bin=max_bins[j],
-                        min_data_in_bin=config.min_data_in_bin,
-                        bin_type=BIN_CATEGORICAL if j in categorical else BIN_NUMERICAL,
-                        use_missing=config.use_missing,
-                        zero_as_missing=config.zero_as_missing,
-                        forced_bounds=forced[j],
-                        pre_filter=config.feature_pre_filter,
-                        filter_cnt=int(config.min_data_in_leaf * sample_cnt
-                                       / max(num_data, 1)),
-                    )
-                    for j in range(num_features)
-                ]
+                    forced = get_forced_bins(config.forcedbins_filename,
+                                             num_features, categorical)
+                    mappers = [
+                        BinMapper.find_bin(
+                            samples[j],
+                            total_sample_cnt=sample_cnt,
+                            max_bin=max_bins[j],
+                            min_data_in_bin=config.min_data_in_bin,
+                            bin_type=BIN_CATEGORICAL if j in categorical else BIN_NUMERICAL,
+                            use_missing=config.use_missing,
+                            zero_as_missing=config.zero_as_missing,
+                            forced_bounds=forced[j],
+                            pre_filter=config.feature_pre_filter,
+                            filter_cnt=int(config.min_data_in_leaf * sample_cnt
+                                           / max(num_data, 1)),
+                        )
+                        for j in range(num_features)
+                    ]
 
-        max_nb = max(m.num_bin for m in mappers) if mappers else 2
-        dtype = np.uint8 if max_nb <= 256 else np.int16
-        binned = np.empty((num_features, num_data), dtype=dtype)
-        for j, m in enumerate(mappers):
-            binned[j] = m.value_to_bin(X[:, j]).astype(dtype)
+        with construct_phase("apply_bins"):
+            max_nb = max(m.num_bin for m in mappers) if mappers else 2
+            dtype = np.uint8 if max_nb <= 256 else np.int16
+            binned = np.empty((num_features, num_data), dtype=dtype)
+            for j, m in enumerate(mappers):
+                binned[j] = m.value_to_bin(X[:, j]).astype(dtype)
 
         meta = Metadata()
         if label is not None:
@@ -247,7 +271,8 @@ class BinnedDataset:
             f"({n_used} informative), max {ds.num_total_bin} bins"
         )
         if config.enable_bundle:
-            ds.bundle_features(config, reference=reference)
+            with construct_phase("bundle"):
+                ds.bundle_features(config, reference=reference)
         return ds
 
     # ------------------------------------------------------------------
@@ -289,43 +314,45 @@ class BinnedDataset:
             mappers = reference.bin_mappers
             feature_names = feature_names or reference.feature_names
         else:
-            sample_cnt = min(num_data, config.bin_construct_sample_cnt)
-            rng = np.random.RandomState(config.data_random_seed)
-            samp = (rng.choice(num_data, size=sample_cnt, replace=False)
-                    if sample_cnt < num_data else np.arange(num_data))
-            in_sample = np.zeros(num_data, bool)
-            in_sample[samp] = True
-            sel = in_sample[rows]
-            f_sel, v_sel = indices[sel], values[sel]
-            order = np.argsort(f_sel, kind="stable")
-            f_sorted, v_sorted = f_sel[order], v_sel[order]
-            starts = np.searchsorted(f_sorted, np.arange(num_features + 1))
-            max_bins = (list(config.max_bin_by_feature)
-                        or [config.max_bin] * num_features)
-            if len(max_bins) != num_features:
-                log_fatal("max_bin_by_feature length must equal number of "
-                          "features")
-            from .binning import get_forced_bins
+            with construct_phase("sample"):
+                sample_cnt = min(num_data, config.bin_construct_sample_cnt)
+                rng = np.random.RandomState(config.data_random_seed)
+                samp = (rng.choice(num_data, size=sample_cnt, replace=False)
+                        if sample_cnt < num_data else np.arange(num_data))
+                in_sample = np.zeros(num_data, bool)
+                in_sample[samp] = True
+                sel = in_sample[rows]
+                f_sel, v_sel = indices[sel], values[sel]
+                order = np.argsort(f_sel, kind="stable")
+                f_sorted, v_sorted = f_sel[order], v_sel[order]
+                starts = np.searchsorted(f_sorted, np.arange(num_features + 1))
+                max_bins = (list(config.max_bin_by_feature)
+                            or [config.max_bin] * num_features)
+                if len(max_bins) != num_features:
+                    log_fatal("max_bin_by_feature length must equal number of "
+                              "features")
+            with construct_phase("find_bins"):
+                from .binning import get_forced_bins
 
-            forced = get_forced_bins(config.forcedbins_filename,
-                                     num_features, categorical)
-            mappers = [
-                BinMapper.find_bin(
-                    v_sorted[starts[j]:starts[j + 1]],
-                    total_sample_cnt=sample_cnt,
-                    max_bin=max_bins[j],
-                    min_data_in_bin=config.min_data_in_bin,
-                    bin_type=(BIN_CATEGORICAL if j in categorical
-                              else BIN_NUMERICAL),
-                    use_missing=config.use_missing,
-                    zero_as_missing=config.zero_as_missing,
-                    forced_bounds=forced[j],
-                    pre_filter=config.feature_pre_filter,
-                    filter_cnt=int(config.min_data_in_leaf * sample_cnt
-                                   / max(num_data, 1)),
-                )
-                for j in range(num_features)
-            ]
+                forced = get_forced_bins(config.forcedbins_filename,
+                                         num_features, categorical)
+                mappers = [
+                    BinMapper.find_bin(
+                        v_sorted[starts[j]:starts[j + 1]],
+                        total_sample_cnt=sample_cnt,
+                        max_bin=max_bins[j],
+                        min_data_in_bin=config.min_data_in_bin,
+                        bin_type=(BIN_CATEGORICAL if j in categorical
+                                  else BIN_NUMERICAL),
+                        use_missing=config.use_missing,
+                        zero_as_missing=config.zero_as_missing,
+                        forced_bounds=forced[j],
+                        pre_filter=config.feature_pre_filter,
+                        filter_cnt=int(config.min_data_in_leaf * sample_cnt
+                                       / max(num_data, 1)),
+                    )
+                    for j in range(num_features)
+                ]
 
         meta = Metadata()
         if label is not None:
@@ -343,60 +370,62 @@ class BinnedDataset:
 
         # bin the non-zero entries feature-by-feature (host, vectorized via
         # one stable sort over the nnz instead of F passes)
-        bin_values = np.zeros(len(values), np.int32)
-        order_all = np.argsort(indices, kind="stable")
-        starts_all = np.searchsorted(indices[order_all],
-                                     np.arange(num_features + 1))
-        for j in range(num_features):
-            seg = order_all[starts_all[j]:starts_all[j + 1]]
-            if len(seg):
-                bin_values[seg] = mappers[j].value_to_bin(values[seg])
+        with construct_phase("apply_bins"):
+            bin_values = np.zeros(len(values), np.int32)
+            order_all = np.argsort(indices, kind="stable")
+            starts_all = np.searchsorted(indices[order_all],
+                                         np.arange(num_features + 1))
+            for j in range(num_features):
+                seg = order_all[starts_all[j]:starts_all[j + 1]]
+                if len(seg):
+                    bin_values[seg] = mappers[j].value_to_bin(values[seg])
 
-        if reference is not None and reference.bundle_layout is not None:
-            layout = reference.bundle_layout
-        elif reference is not None:
-            # unbundled reference (e.g. dense training data that found no
-            # exclusivity): identity bundles keep bundle bins == original
-            # bins so the matrices stay directly comparable
-            layout = BundleLayout(
-                bundle_of=np.arange(num_features, dtype=np.int32),
-                offset=np.zeros(num_features, np.int32),
-                is_bundled=np.zeros(num_features, bool),
-                bundle_nbins=np.asarray(ds.num_bins, np.int32),
-            )
-        else:
-            # conflict masks from the sampled non-zero pattern
-            sample_cnt_c = min(num_data, 32768)
-            rng2 = np.random.RandomState(config.data_random_seed + 1)
-            samp2 = (rng2.choice(num_data, size=sample_cnt_c, replace=False)
-                     if sample_cnt_c < num_data else np.arange(num_data))
-            pos = np.full(num_data, -1, np.int64)
-            pos[samp2] = np.arange(len(samp2))
-            masks = np.zeros((num_features, len(samp2)), bool)
-            r_pos = pos[rows]
-            hit = (r_pos >= 0) & (bin_values != ds.zero_bins[indices])
-            masks[indices[hit], r_pos[hit]] = True
-            layout = (find_bundles(masks, ds.num_bins,
-                                   config.max_conflict_rate)
-                      if config.enable_bundle else None)
-            if layout is None:
-                # no exclusivity to exploit: fall back to identity bundles
+        with construct_phase("bundle"):
+            if reference is not None and reference.bundle_layout is not None:
+                layout = reference.bundle_layout
+            elif reference is not None:
+                # unbundled reference (e.g. dense training data that found no
+                # exclusivity): identity bundles keep bundle bins == original
+                # bins so the matrices stay directly comparable
                 layout = BundleLayout(
                     bundle_of=np.arange(num_features, dtype=np.int32),
                     offset=np.zeros(num_features, np.int32),
                     is_bundled=np.zeros(num_features, bool),
                     bundle_nbins=np.asarray(ds.num_bins, np.int32),
                 )
-        built = apply_bundles_csr(indptr, indices, bin_values,
-                                  num_data, ds.zero_bins, layout)
-        if not layout.is_bundled.any():
-            # identity layout: bundle bins == original bins, so this IS the
-            # plain dense binned matrix — record it as such (no decode path,
-            # no spurious EFB incompatibility gates)
-            ds.binned = built
-        else:
-            ds.bundle_layout = layout
-            ds.bundled = built
+            else:
+                # conflict masks from the sampled non-zero pattern
+                sample_cnt_c = min(num_data, 32768)
+                rng2 = np.random.RandomState(config.data_random_seed + 1)
+                samp2 = (rng2.choice(num_data, size=sample_cnt_c, replace=False)
+                         if sample_cnt_c < num_data else np.arange(num_data))
+                pos = np.full(num_data, -1, np.int64)
+                pos[samp2] = np.arange(len(samp2))
+                masks = np.zeros((num_features, len(samp2)), bool)
+                r_pos = pos[rows]
+                hit = (r_pos >= 0) & (bin_values != ds.zero_bins[indices])
+                masks[indices[hit], r_pos[hit]] = True
+                layout = (find_bundles(masks, ds.num_bins,
+                                       config.max_conflict_rate)
+                          if config.enable_bundle else None)
+                if layout is None:
+                    # no exclusivity to exploit: fall back to identity bundles
+                    layout = BundleLayout(
+                        bundle_of=np.arange(num_features, dtype=np.int32),
+                        offset=np.zeros(num_features, np.int32),
+                        is_bundled=np.zeros(num_features, bool),
+                        bundle_nbins=np.asarray(ds.num_bins, np.int32),
+                    )
+            built = apply_bundles_csr(indptr, indices, bin_values,
+                                      num_data, ds.zero_bins, layout)
+            if not layout.is_bundled.any():
+                # identity layout: bundle bins == original bins, so this IS the
+                # plain dense binned matrix — record it as such (no decode path,
+                # no spurious EFB incompatibility gates)
+                ds.binned = built
+            else:
+                ds.bundle_layout = layout
+                ds.bundled = built
         log_info(
             f"Constructed sparse binned dataset: {num_data} rows, "
             f"{num_features} features -> {layout.num_bundles} bundle "

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Config
-from ..io.dataset import BinnedDataset
+from ..io.dataset import BinnedDataset, construct_phase
 from ..metrics import Metric, create_metrics
 from ..objectives import ObjectiveFunction, create_objective
 from ..obs import trace as obs_trace
@@ -162,7 +162,8 @@ class GBDT:
                           "tree_learner=data")
             self.binned = None
         else:
-            self.binned = jnp.asarray(self._host_matrix)
+            with construct_phase("place"):
+                self.binned = jnp.asarray(self._host_matrix)
         self.meta = make_feature_meta(train_set, config.monotone_constraints,
                                       config.feature_contri)
         rv = getattr(train_set, "row_valid", None)
@@ -299,19 +300,22 @@ class GBDT:
     def _build_trainer(self):
         from ..parallel.trainer import build_trainer
 
-        self._grow, self._grow_binned, _ = build_trainer(
-            self.config,
-            self._host_matrix,
-            self.meta,
-            self.split_params,
-            self.num_bins,
-            bin_mappers=self.train_set.bin_mappers,
-            bundle=self._bundle,
-            bundle_num_bins=(self.train_set.padded_bundle_bin
-                             if self._bundle is not None else None),
-            row_sharded=getattr(self.train_set, "is_row_sharded", False),
-            packed=self._packed,
-        )
+        # the learner places its own copy of the bins (sharded where the
+        # learner is): host time of the transfer's enqueue, not its end
+        with construct_phase("place"):
+            self._grow, self._grow_binned, _ = build_trainer(
+                self.config,
+                self._host_matrix,
+                self.meta,
+                self.split_params,
+                self.num_bins,
+                bin_mappers=self.train_set.bin_mappers,
+                bundle=self._bundle,
+                bundle_num_bins=(self.train_set.padded_bundle_bin
+                                 if self._bundle is not None else None),
+                row_sharded=getattr(self.train_set, "is_row_sharded", False),
+                packed=self._packed,
+            )
         if self.binned is None:
             self.binned = self._grow_binned
         self._step = None  # fused per-iteration step, built lazily
@@ -339,20 +343,21 @@ class GBDT:
         )
         if cfg.bagging_freq <= 0 or (cfg.bagging_fraction >= 1.0 and not use_pos_neg):
             return None
-        kk = jax.random.fold_in(
-            jax.random.PRNGKey(cfg.bagging_seed),
-            iteration // max(cfg.bagging_freq, 1),
-        )
-        if use_pos_neg:
-            label = self.objective.label
-            pos = jax.random.bernoulli(kk, cfg.pos_bagging_fraction, (self.num_data,))
-            neg = jax.random.bernoulli(
-                jax.random.fold_in(kk, 1), cfg.neg_bagging_fraction, (self.num_data,)
+        with jax.named_scope("lgbm.sample"):
+            kk = jax.random.fold_in(
+                jax.random.PRNGKey(cfg.bagging_seed),
+                iteration // max(cfg.bagging_freq, 1),
             )
-            mask = jnp.where(label > 0, pos, neg)
-        else:
-            mask = jax.random.bernoulli(kk, cfg.bagging_fraction, (self.num_data,))
-        return mask.astype(jnp.float32)
+            if use_pos_neg:
+                label = self.objective.label
+                pos = jax.random.bernoulli(kk, cfg.pos_bagging_fraction, (self.num_data,))
+                neg = jax.random.bernoulli(
+                    jax.random.fold_in(kk, 1), cfg.neg_bagging_fraction, (self.num_data,)
+                )
+                mask = jnp.where(label > 0, pos, neg)
+            else:
+                mask = jax.random.bernoulli(kk, cfg.bagging_fraction, (self.num_data,))
+            return mask.astype(jnp.float32)
 
     def _build_step(self):
         cfg = self.config
@@ -392,21 +397,22 @@ class GBDT:
                 if self._cegb_enabled:
                     cegb_used = self._update_cegb_state(
                         cegb_used, tree_dev, leaf_id)
-                shrunk = tree_dev._replace(leaf_value=tree_dev.leaf_value * rate)
-                train_preds.append(leaf_lookup(shrunk.leaf_value, leaf_id))
-                for vi, vb in enumerate(valid_binned):
-                    if vlids is not None:
-                        # native gather, NOT leaf_lookup: this path is
-                        # pinned bit-exact against the tree walk
-                        # (test_valid_row_routing_matches_tree_walk), and
-                        # valid sets are small enough that the gather tax
-                        # does not matter
-                        valid_preds[vi].append(shrunk.leaf_value[vlids[vi]])
-                    else:
-                        valid_preds[vi].append(tree_predict_binned(
-                            shrunk, vb, self.meta.nan_bin,
-                            self.meta.missing_type, self._bundle,
-                            self._packed, zero_bins=self.meta.zero_bin))
+                with jax.named_scope("lgbm.score"):
+                    shrunk = tree_dev._replace(leaf_value=tree_dev.leaf_value * rate)
+                    train_preds.append(leaf_lookup(shrunk.leaf_value, leaf_id))
+                    for vi, vb in enumerate(valid_binned):
+                        if vlids is not None:
+                            # native gather, NOT leaf_lookup: this path is
+                            # pinned bit-exact against the tree walk
+                            # (test_valid_row_routing_matches_tree_walk), and
+                            # valid sets are small enough that the gather tax
+                            # does not matter
+                            valid_preds[vi].append(shrunk.leaf_value[vlids[vi]])
+                        else:
+                            valid_preds[vi].append(tree_predict_binned(
+                                shrunk, vb, self.meta.nan_bin,
+                                self.meta.missing_type, self._bundle,
+                                self._packed, zero_bins=self.meta.zero_bin))
                 trees.append(shrunk)
                 leaf_ids.append(leaf_id)
             # Deferred score bookkeeping: every class's leaf values land in
@@ -419,13 +425,15 @@ class GBDT:
             # row-streaming ops inside the same fused dispatch as the
             # trees' round-0 histogram passes (tools/phase_attrib.py
             # itemizes the cost under grad_g3_ms / score_update_ms).
-            train_score = train_score + jnp.stack(train_preds, axis=1)
-            if valid_binned:
-                valid_scores = tuple(
-                    vs + jnp.stack(vp, axis=1)
-                    for vs, vp in zip(valid_scores, valid_preds))
-            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-            return (train_score, valid_scores, stacked, jnp.stack(leaf_ids),
+            with jax.named_scope("lgbm.score"):
+                train_score = train_score + jnp.stack(train_preds, axis=1)
+                if valid_binned:
+                    valid_scores = tuple(
+                        vs + jnp.stack(vp, axis=1)
+                        for vs, vp in zip(valid_scores, valid_preds))
+                stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+                leaf_ids = jnp.stack(leaf_ids)
+            return (train_score, valid_scores, stacked, leaf_ids,
                     cegb_used)
 
         self._step_fn = step
@@ -439,11 +447,12 @@ class GBDT:
             donate_argnums=(2, 3) if self._donate else ())
 
     def _objective_grads(self, s, iteration=None):
-        if getattr(self.objective, "is_stochastic", False):
-            grad, hess = self.objective.get_gradients(s, iteration=iteration)
-        else:
-            grad, hess = self.objective.get_gradients(s)
-        return self._guard_grads(grad, hess, iteration)
+        with jax.named_scope("lgbm.objective"):
+            if getattr(self.objective, "is_stochastic", False):
+                grad, hess = self.objective.get_gradients(s, iteration=iteration)
+            else:
+                grad, hess = self.objective.get_gradients(s)
+            return self._guard_grads(grad, hess, iteration)
 
     def _guard_grads(self, grad, hess, iteration):
         """Finite-guard + fault-injection seam on the grad/hess pass.
@@ -560,75 +569,84 @@ class GBDT:
                 donate_argnums=(2, 3) if self._donate else ())
 
         K = self.num_class
-        feat_masks = jnp.asarray(np.stack([
-            np.stack([self._tree_feature_mask() for _ in range(K)])
-            for _ in range(n)
-        ]))
-        vscores = tuple(vs.score for vs in self._valid_scores)
-        self._save_rollback_state()
-        t0_ns = obs_trace.now_ns()
-        with global_timer.section("GBDT::TrainIters(dispatch)"):
-            new_train, new_valid, trees, self._cegb_used = self._scan(
-                self._grow_binned, tuple(self._valid_binned),
-                self._train_scores.score, vscores,
-                jnp.asarray(self.iter, jnp.int32), feat_masks,
-                self._cegb_used,
-            )
-        self._train_scores.score = new_train
-        for vs, s in zip(self._valid_scores, new_valid):
-            vs.score = s
-        if obs_trace.enabled():
-            # the scanned block is ONE device dispatch — the host cannot
-            # see iteration boundaries inside it, so the trace carries
-            # one block span (args say how many iterations it amortized)
-            obs_trace.add_span(
-                "train.iterations", t0_ns, obs_trace.now_ns() - t0_ns,
-                cat="train", args={"n": n, "start_iter": int(self.iter)})
-        for i in range(n):
-            for k in range(K):
-                self._device_trees.append(
-                    jax.tree_util.tree_map(lambda a: a[i, k], trees)
+        # the scanned block is ONE device dispatch — the host cannot see
+        # iteration boundaries inside it, so the trace carries one block
+        # span (its args say how many iterations it amortized) around the
+        # block's own prepare / dispatch / bookkeep
+        with obs_trace.bridged_span(
+                "train.iterations", "train",
+                {"n": n, "start_iter": int(self.iter)}):
+            with obs_trace.phase_span("prepare"):
+                feat_masks = jnp.asarray(np.stack([
+                    np.stack([self._tree_feature_mask() for _ in range(K)])
+                    for _ in range(n)
+                ]))
+                vscores = tuple(vs.score for vs in self._valid_scores)
+                self._save_rollback_state()
+            with obs_trace.phase_span("dispatch"):
+                new_train, new_valid, trees, self._cegb_used = self._scan(
+                    self._grow_binned, tuple(self._valid_binned),
+                    self._train_scores.score, vscores,
+                    jnp.asarray(self.iter, jnp.int32), feat_masks,
+                    self._cegb_used,
                 )
+            with obs_trace.phase_span("bookkeep"):
+                self._train_scores.score = new_train
+                for vs, s in zip(self._valid_scores, new_valid):
+                    vs.score = s
+                for i in range(n):
+                    for k in range(K):
+                        self._device_trees.append(
+                            jax.tree_util.tree_map(lambda a: a[i, k], trees)
+                        )
+                        self.models.append(None)
+                        self._model_shrink.append(
+                            self.config.learning_rate if not isinstance(self, RF) else 1.0
+                        )
+                        self._model_bias.append(self._tree_bias(k))
+                    self.iter += 1
+        self.check_finite_boundary()
+
+    def _fused_train_one_iter(self, save_state: bool = True) -> None:
+        """One fused iteration as three host phases (obs/trace.py): the
+        ``train.prepare`` / ``train.dispatch`` / ``train.bookkeep`` spans
+        of the tree; the caller adds ``train.wait`` where it reads the
+        result.  ``save_state=False``: the caller took the rollback
+        snapshot already (DART, before it selects its drops)."""
+        with obs_trace.phase_span("prepare"):
+            if save_state:
+                self._save_rollback_state()
+            if self._step is None:
+                self._step = self._build_step()
+            feat_masks = jnp.asarray(
+                np.stack([self._tree_feature_mask() for _ in range(self.num_class)])
+            )
+            vscores = tuple(vs.score for vs in self._valid_scores)
+            args = (self._grow_binned, tuple(self._valid_binned),
+                    self._train_scores.score, vscores,
+                    jnp.asarray(self.iter, jnp.int32), feat_masks,
+                    self._cegb_used)
+        with obs_trace.phase_span("dispatch"):
+            (new_train, new_valid, stacked, leaf_ids,
+             self._cegb_used) = self._step(*args)
+        with obs_trace.phase_span("bookkeep"):
+            self._train_scores.score = new_train
+            for vs, s in zip(self._valid_scores, new_valid):
+                vs.score = s
+            store = getattr(self, "_maybe_store_lids", None)
+            if store is not None:
+                # DART keeps each tree's training-row leaf assignment so a
+                # later drop re-predicts via a cheap (L,)-table gather instead
+                # of a per-row tree walk (see DART._fused_dart_iter)
+                store(leaf_ids)
+            for k in range(self.num_class):
+                tree_k = jax.tree_util.tree_map(lambda a: a[k], stacked)
+                self._device_trees.append(tree_k)
                 self.models.append(None)
                 self._model_shrink.append(
                     self.config.learning_rate if not isinstance(self, RF) else 1.0
                 )
                 self._model_bias.append(self._tree_bias(k))
-            self.iter += 1
-        self.check_finite_boundary()
-
-    def _fused_train_one_iter(self) -> None:
-        if self._step is None:
-            self._step = self._build_step()
-        feat_masks = jnp.asarray(
-            np.stack([self._tree_feature_mask() for _ in range(self.num_class)])
-        )
-        vscores = tuple(vs.score for vs in self._valid_scores)
-        with global_timer.section("GBDT::TrainOneIter(dispatch)"):
-            (new_train, new_valid, stacked, leaf_ids,
-             self._cegb_used) = self._step(
-                self._grow_binned, tuple(self._valid_binned),
-                self._train_scores.score, vscores,
-                jnp.asarray(self.iter, jnp.int32), feat_masks,
-                self._cegb_used,
-            )
-        self._train_scores.score = new_train
-        for vs, s in zip(self._valid_scores, new_valid):
-            vs.score = s
-        store = getattr(self, "_maybe_store_lids", None)
-        if store is not None:
-            # DART keeps each tree's training-row leaf assignment so a
-            # later drop re-predicts via a cheap (L,)-table gather instead
-            # of a per-row tree walk (see DART._fused_dart_iter)
-            store(leaf_ids)
-        for k in range(self.num_class):
-            tree_k = jax.tree_util.tree_map(lambda a: a[k], stacked)
-            self._device_trees.append(tree_k)
-            self.models.append(None)
-            self._model_shrink.append(
-                self.config.learning_rate if not isinstance(self, RF) else 1.0
-            )
-            self._model_bias.append(self._tree_bias(k))
 
     # ------------------------------------------------------------------
     def add_valid(self, valid_set: BinnedDataset, name: str,
@@ -700,28 +718,10 @@ class GBDT:
     def _bagging_mask(self, iteration: int) -> Optional[jax.Array]:
         """reference: GBDT::Bagging gbdt.cpp:209-243 (+ balanced bagging
         :180-207). Mask-based Bernoulli sampling."""
-        cfg = self.config
-        use_pos_neg = (
-            cfg.objective == "binary"
-            and (cfg.pos_bagging_fraction < 1.0 or cfg.neg_bagging_fraction < 1.0)
-        )
-        if cfg.bagging_freq <= 0 or (cfg.bagging_fraction >= 1.0 and not use_pos_neg):
-            return None
-        if self._bag_mask is not None and iteration % cfg.bagging_freq != 0:
-            return self._bag_mask
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(cfg.bagging_seed), iteration // max(cfg.bagging_freq, 1)
-        )
-        if use_pos_neg:
-            label = self.objective.label
-            pos = jax.random.bernoulli(key, cfg.pos_bagging_fraction, (self.num_data,))
-            neg = jax.random.bernoulli(
-                jax.random.fold_in(key, 1), cfg.neg_bagging_fraction, (self.num_data,)
-            )
-            mask = jnp.where(label > 0, pos, neg)
-        else:
-            mask = jax.random.bernoulli(key, cfg.bagging_fraction, (self.num_data,))
-        self._bag_mask = mask.astype(jnp.float32)
+        freq = self.config.bagging_freq
+        if self._bag_mask is not None and freq > 0 and iteration % freq != 0:
+            return self._bag_mask     # redrawn every bagging_freq trees
+        self._bag_mask = self._bag_fraction_mask(None, iteration)
         return self._bag_mask
 
     # ------------------------------------------------------------------
@@ -754,13 +754,14 @@ class GBDT:
         Process-sharded datasets carry phantom pad rows (weight 0): they
         must also have count 0 so min_data_in_leaf gating and count-based
         smoothing see only real rows."""
-        if bag is None:
-            cnt = jnp.ones_like(grad_k)
-        else:
-            grad_k, hess_k, cnt = grad_k * bag, hess_k * bag, bag
-        if self._row_valid is not None:
-            cnt = cnt * self._row_valid
-        return jnp.stack([grad_k, hess_k, cnt], axis=1)
+        with jax.named_scope("lgbm.sample"):
+            if bag is None:
+                cnt = jnp.ones_like(grad_k)
+            else:
+                grad_k, hess_k, cnt = grad_k * bag, hess_k * bag, bag
+            if self._row_valid is not None:
+                cnt = cnt * self._row_valid
+            return jnp.stack([grad_k, hess_k, cnt], axis=1)
 
     # ------------------------------------------------------------------
     def train_one_iter(
@@ -775,47 +776,50 @@ class GBDT:
         skips the device->host sync — the benchmark path."""
         cfg = self.config
         if custom_grad is None and self._supports_fused_step():
-            self._save_rollback_state()
             self._fused_train_one_iter()
             self.iter += 1
-            if check_stop:
-                new = self._device_trees[-self.num_class:]
-                stopped = all(int(t.num_leaves) <= 1 for t in new)
-                if stopped:
-                    log_warning(
-                        "Stopped training because there are no more leaves "
-                        "that meet the split requirements"
-                    )
-                return stopped
-            return False
-        self._save_rollback_state()
-        if custom_grad is not None:
-            grad = jnp.asarray(np.asarray(custom_grad).reshape(self.num_data, -1), jnp.float32)
-            hess = jnp.asarray(np.asarray(custom_hess).reshape(self.num_data, -1), jnp.float32)
-        else:
-            grad, hess = self._gradients()
-
-        bag = self._bagging_mask(self.iter)
-        new_trees = []
-        for k in range(self.num_class):
-            g3 = self._sample_g3(grad[:, k], hess[:, k], bag, self.iter)
-            key = jax.random.fold_in(self._rng_key, self.iter * self.num_class + k)
-            base_mask = jnp.asarray(self._tree_feature_mask())
-            tree_dev, leaf_id, root_sum = self._grow(
-                self._grow_binned, g3, base_mask, key, self._cegb_used)
-            if self._cegb_enabled:
-                self._cegb_used = self._update_cegb_state(
-                    self._cegb_used, tree_dev, leaf_id)
-            new_trees.append(self._finish_tree(tree_dev, leaf_id, k))
+            return check_stop and self._stopped(
+                self._device_trees[-self.num_class:])
+        with obs_trace.phase_span("prepare"):
+            self._save_rollback_state()
+            if custom_grad is not None:
+                grad = jnp.asarray(np.asarray(custom_grad).reshape(self.num_data, -1), jnp.float32)
+                hess = jnp.asarray(np.asarray(custom_hess).reshape(self.num_data, -1), jnp.float32)
+        # the host loop has no single dispatch: gradients, each class's
+        # grower and its score update are enqueued one after another
+        # (``_finish_tree`` syncs only where a leaf renewal needs the host)
+        with obs_trace.phase_span("dispatch"):
+            if custom_grad is None:
+                grad, hess = self._gradients()
+            bag = self._bagging_mask(self.iter)
+            new_trees = []
+            for k in range(self.num_class):
+                g3 = self._sample_g3(grad[:, k], hess[:, k], bag, self.iter)
+                key = jax.random.fold_in(self._rng_key, self.iter * self.num_class + k)
+                base_mask = jnp.asarray(self._tree_feature_mask())
+                tree_dev, leaf_id, root_sum = self._grow(
+                    self._grow_binned, g3, base_mask, key, self._cegb_used)
+                if self._cegb_enabled:
+                    self._cegb_used = self._update_cegb_state(
+                        self._cegb_used, tree_dev, leaf_id)
+                new_trees.append(self._finish_tree(tree_dev, leaf_id, k))
         self.iter += 1
-        stopped = False
-        if check_stop:
+        return check_stop and self._stopped(new_trees)
+
+    def _stopped(self, new_trees) -> bool:
+        """True when no tree of the iteration split.  ``train.wait`` times
+        the read of ``num_leaves``, the host's one explicit sync of a
+        tree; it holds the wait for the device only where nothing blocked
+        before it.  On a TPU the runtime stops enqueueing a few ops into a
+        running step, so the host blocks inside ``train.bookkeep``'s
+        one-field slices and this read finds the step done (PERF.md §5)."""
+        with obs_trace.phase_span("wait"):
             stopped = all(int(t.num_leaves) <= 1 for t in new_trees)
-            if stopped:
-                log_warning(
-                    "Stopped training because there are no more leaves that "
-                    "meet the split requirements"
-                )
+        if stopped:
+            log_warning(
+                "Stopped training because there are no more leaves that "
+                "meet the split requirements"
+            )
         return stopped
 
     # ------------------------------------------------------------------
@@ -862,18 +866,19 @@ class GBDT:
         else:
             self.models.append(None)  # materialized lazily in one batch
 
-        shrunk = tree_dev._replace(leaf_value=tree_dev.leaf_value * rate)
         self._model_shrink.append(rate)
         self._model_bias.append(bias)
 
         # score updates: train via partition gather, valid via binned predict
-        self._train_scores.add_leaf_values(shrunk.leaf_value, leaf_id, k)
-        for vb, vs in zip(self._valid_binned, self._valid_scores):
-            pred = tree_predict_binned(
-                shrunk, vb, self.meta.nan_bin, self.meta.missing_type,
-                self._bundle, self._packed, zero_bins=self.meta.zero_bin
-            )
-            vs.add_pred(pred, k)
+        with jax.named_scope("lgbm.score"):
+            shrunk = tree_dev._replace(leaf_value=tree_dev.leaf_value * rate)
+            self._train_scores.add_leaf_values(shrunk.leaf_value, leaf_id, k)
+            for vb, vs in zip(self._valid_binned, self._valid_scores):
+                pred = tree_predict_binned(
+                    shrunk, vb, self.meta.nan_bin, self.meta.missing_type,
+                    self._bundle, self._packed, zero_bins=self.meta.zero_bin
+                )
+                vs.add_pred(pred, k)
 
         self._device_trees.append(shrunk)
         return shrunk
@@ -1256,25 +1261,26 @@ class GOSS(GBDT):
     grad/hess by (1 - top_rate) / other_rate."""
 
     def _sample_g3(self, grad_k, hess_k, bag, iteration):
-        cfg = self.config
-        n = self.num_data
-        top_k = max(1, int(cfg.top_rate * n))
-        other_k = max(1, int(cfg.other_rate * n))
-        score = jnp.abs(grad_k * hess_k)
-        thresh = jnp.sort(score)[-top_k]
-        is_top = score >= thresh
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(cfg.seed + 17), iteration
-        )
-        rest_prob = other_k / jnp.maximum(n - top_k, 1)
-        sampled_rest = (~is_top) & jax.random.bernoulli(key, rest_prob, (n,))
-        amp = (1.0 - cfg.top_rate) / cfg.other_rate
-        w = jnp.where(is_top, 1.0, jnp.where(sampled_rest, amp, 0.0))
-        cnt = (is_top | sampled_rest).astype(jnp.float32)
-        if bag is not None:
-            w = w * bag
-            cnt = cnt * bag
-        return jnp.stack([grad_k * w, hess_k * w, cnt], axis=1)
+        with jax.named_scope("lgbm.sample"):
+            cfg = self.config
+            n = self.num_data
+            top_k = max(1, int(cfg.top_rate * n))
+            other_k = max(1, int(cfg.other_rate * n))
+            score = jnp.abs(grad_k * hess_k)
+            thresh = jnp.sort(score)[-top_k]
+            is_top = score >= thresh
+            key = jax.random.fold_in(
+                jax.random.PRNGKey(cfg.seed + 17), iteration
+            )
+            rest_prob = other_k / jnp.maximum(n - top_k, 1)
+            sampled_rest = (~is_top) & jax.random.bernoulli(key, rest_prob, (n,))
+            amp = (1.0 - cfg.top_rate) / cfg.other_rate
+            w = jnp.where(is_top, 1.0, jnp.where(sampled_rest, amp, 0.0))
+            cnt = (is_top | sampled_rest).astype(jnp.float32)
+            if bag is not None:
+                w = w * bag
+                cnt = cnt * bag
+            return jnp.stack([grad_k * w, hess_k * w, cnt], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -1477,18 +1483,19 @@ class DART(GBDT):
             # gathers the (F, N) matrix per node and dominated DART cost);
             # drop_stack (full TreeArrays over P slots) is only needed for
             # valid-set removal, where no assignments were recorded.
-            if use_lids:
-                preds = jax.vmap(leaf_lookup)(drop_lv, drop_lids)  # (P, N)
-            else:
-                preds = jax.vmap(lambda t: pred_with(t, binned))(drop_stack)
-            drop_delta = preds.T @ drop_weight                   # (N, K)
-            s_drop = train_score - drop_delta
-            v_drops, v_deltas = [], []
-            for vb, vscore in zip(valid_binned, valid_scores):
-                vp = jax.vmap(lambda t: pred_with(t, vb))(drop_stack)
-                vd = vp.T @ drop_weight
-                v_deltas.append(vd)
-                v_drops.append(vscore - vd)
+            with jax.named_scope("lgbm.score"):
+                if use_lids:
+                    preds = jax.vmap(leaf_lookup)(drop_lv, drop_lids)  # (P, N)
+                else:
+                    preds = jax.vmap(lambda t: pred_with(t, binned))(drop_stack)
+                drop_delta = preds.T @ drop_weight                   # (N, K)
+                s_drop = train_score - drop_delta
+                v_drops, v_deltas = [], []
+                for vb, vscore in zip(valid_binned, valid_scores):
+                    vp = jax.vmap(lambda t: pred_with(t, vb))(drop_stack)
+                    vd = vp.T @ drop_weight
+                    v_deltas.append(vd)
+                    v_drops.append(vscore - vd)
 
             s = s_drop[:, 0] if K == 1 else s_drop
             grad, hess = self._objective_grads(s, iteration)
@@ -1505,13 +1512,17 @@ class DART(GBDT):
                 if self._cegb_enabled:
                     cegb_used = self._update_cegb_state(cegb_used, tree_dev,
                                                         leaf_id)
-                shrunk = tree_dev._replace(
-                    leaf_value=tree_dev.leaf_value * shrink_new)
+                with jax.named_scope("lgbm.score"):
+                    shrunk = tree_dev._replace(
+                        leaf_value=tree_dev.leaf_value * shrink_new)
                 trees.append(shrunk)
                 leaf_ids.append(leaf_id)
+            with jax.named_scope("lgbm.score"):
+                stacked = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *trees)
+                leaf_ids = jnp.stack(leaf_ids)
             return (s_drop, tuple(v_drops), drop_delta, tuple(v_deltas),
-                    jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees),
-                    jnp.stack(leaf_ids), cegb_used)
+                    stacked, leaf_ids, cegb_used)
 
         def full(binned, valid_binned, train_score, valid_scores, iteration,
                  feat_masks, cegb_used, drop_stack, drop_weight, shrink_new,
@@ -1521,17 +1532,18 @@ class DART(GBDT):
                                valid_scores, iteration, feat_masks,
                                cegb_used, drop_stack, drop_weight,
                                shrink_new, drop_lv, drop_lids)
-            new_train = s_drop + old_factor * d_delta
-            new_valids = [vs + old_factor * vd
-                          for vs, vd in zip(v_drops, v_deltas)]
-            for k in range(K):
-                tree_k = jax.tree_util.tree_map(lambda a: a[k], stacked)
-                new_train = new_train.at[:, k].add(
-                    leaf_lookup(tree_k.leaf_value, leaf_ids[k]))
-                new_valids = [
-                    nv.at[:, k].add(pred_with(tree_k, vb))
-                    for nv, vb in zip(new_valids, valid_binned)
-                ]
+            with jax.named_scope("lgbm.score"):
+                new_train = s_drop + old_factor * d_delta
+                new_valids = [vs + old_factor * vd
+                              for vs, vd in zip(v_drops, v_deltas)]
+                for k in range(K):
+                    tree_k = jax.tree_util.tree_map(lambda a: a[k], stacked)
+                    new_train = new_train.at[:, k].add(
+                        leaf_lookup(tree_k.leaf_value, leaf_ids[k]))
+                    new_valids = [
+                        nv.at[:, k].add(pred_with(tree_k, vb))
+                        for nv, vb in zip(new_valids, valid_binned)
+                    ]
             return (new_train, tuple(new_valids), stacked, leaf_ids,
                     cegb_used)
 
@@ -1548,56 +1560,57 @@ class DART(GBDT):
         return self._dart_steps[key]
 
     def _fused_dart_iter(self, drop_iters: List[int]) -> None:
-        cfg = self.config
-        K = self.num_class
-        k_drop = len(drop_iters)
-        shrink_new, old_factor, w_dec = self._normalization(k_drop)
-        self._snapshot_dropped(drop_iters)
+        with obs_trace.phase_span("prepare"):
+            cfg = self.config
+            K = self.num_class
+            k_drop = len(drop_iters)
+            shrink_new, old_factor, w_dec = self._normalization(k_drop)
+            self._snapshot_dropped(drop_iters)
 
-        # padded drop stack: fixed bucket sizes keep the number of compiled
-        # step variants tiny (each new P is a full recompile of the fused
-        # iteration — the dominant DART cost if P tracked k_drop exactly)
-        n_real = k_drop * K
-        P = next(b for b in (4, 16, 64, 256, 1024) if b >= n_real) \
-            if n_real <= 1024 else n_real
-        # leaf-id fast path only while every past iteration recorded its
-        # assignments (a host-path iteration, e.g. custom fobj, breaks the
-        # alignment — then drops fall back to tree walks)
-        use_lids = self._drop_lids_usable()
-        need_stack = (not use_lids) or bool(self._valid_binned)
-        entries, weights = [], np.zeros((P, K), np.float32)
-        lv_tables, lid_rows = [], []
-        for j, it in enumerate(drop_iters):
-            for k in range(K):
-                idx = it * K + k
-                t = self._device_trees[idx]
-                b = self._model_bias[idx]
-                if b:
-                    t = t._replace(leaf_value=t.leaf_value + b)
-                if need_stack:
-                    entries.append(t)
-                if use_lids:
-                    lv_tables.append(t.leaf_value)
-                    lid_rows.append(self._train_leaf_ids[it][k])
-                weights[j * K + k, k] = 1.0
-        drop_stack = drop_lv = drop_lids = None
-        if need_stack:
-            while len(entries) < P:
-                entries.append(entries[0])    # padding; weight row is 0
-            drop_stack = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                                *entries)
-        if use_lids:
-            while len(lv_tables) < P:
-                lv_tables.append(lv_tables[0])
-                lid_rows.append(lid_rows[0])
-            drop_lv = jnp.stack(lv_tables)
-            drop_lids = jnp.stack(lid_rows)
+            # padded drop stack: fixed bucket sizes keep the number of compiled
+            # step variants tiny (each new P is a full recompile of the fused
+            # iteration — the dominant DART cost if P tracked k_drop exactly)
+            n_real = k_drop * K
+            P = next(b for b in (4, 16, 64, 256, 1024) if b >= n_real) \
+                if n_real <= 1024 else n_real
+            # leaf-id fast path only while every past iteration recorded its
+            # assignments (a host-path iteration, e.g. custom fobj, breaks the
+            # alignment — then drops fall back to tree walks)
+            use_lids = self._drop_lids_usable()
+            need_stack = (not use_lids) or bool(self._valid_binned)
+            entries, weights = [], np.zeros((P, K), np.float32)
+            lv_tables, lid_rows = [], []
+            for j, it in enumerate(drop_iters):
+                for k in range(K):
+                    idx = it * K + k
+                    t = self._device_trees[idx]
+                    b = self._model_bias[idx]
+                    if b:
+                        t = t._replace(leaf_value=t.leaf_value + b)
+                    if need_stack:
+                        entries.append(t)
+                    if use_lids:
+                        lv_tables.append(t.leaf_value)
+                        lid_rows.append(self._train_leaf_ids[it][k])
+                    weights[j * K + k, k] = 1.0
+            drop_stack = drop_lv = drop_lids = None
+            if need_stack:
+                while len(entries) < P:
+                    entries.append(entries[0])    # padding; weight row is 0
+                drop_stack = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                    *entries)
+            if use_lids:
+                while len(lv_tables) < P:
+                    lv_tables.append(lv_tables[0])
+                    lid_rows.append(lid_rows[0])
+                drop_lv = jnp.stack(lv_tables)
+                drop_lids = jnp.stack(lid_rows)
 
-        step = self._dart_step_for(P, use_lids)
-        feat_masks = jnp.asarray(
-            np.stack([self._tree_feature_mask() for _ in range(K)]))
-        vscores = tuple(vs.score for vs in self._valid_scores)
-        with global_timer.section("DART::TrainOneIter(dispatch)"):
+            step = self._dart_step_for(P, use_lids)
+            feat_masks = jnp.asarray(
+                np.stack([self._tree_feature_mask() for _ in range(K)]))
+            vscores = tuple(vs.score for vs in self._valid_scores)
+        with obs_trace.phase_span("dispatch"):
             (new_train, new_valid, stacked, leaf_ids,
              self._cegb_used) = step(
                 self._grow_binned, tuple(self._valid_binned),
@@ -1607,21 +1620,22 @@ class DART(GBDT):
                 jnp.float32(shrink_new), jnp.float32(old_factor),
                 drop_lv, drop_lids,
             )
-        self._train_scores.score = new_train
-        for vs, s in zip(self._valid_scores, new_valid):
-            vs.score = s
-        self._maybe_store_lids(leaf_ids)
-        for k in range(K):
-            self._device_trees.append(
-                jax.tree_util.tree_map(lambda a: a[k], stacked))
-            self.models.append(None)
-            self._model_shrink.append(shrink_new)
-            self._model_bias.append(self._tree_bias(k))
+        with obs_trace.phase_span("bookkeep"):
+            self._train_scores.score = new_train
+            for vs, s in zip(self._valid_scores, new_valid):
+                vs.score = s
+            self._maybe_store_lids(leaf_ids)
+            for k in range(K):
+                self._device_trees.append(
+                    jax.tree_util.tree_map(lambda a: a[k], stacked))
+                self.models.append(None)
+                self._model_shrink.append(shrink_new)
+                self._model_bias.append(self._tree_bias(k))
 
-        self._rescale_dropped(drop_iters, old_factor, w_dec)
-        if not cfg.uniform_drop:
-            self._tree_weight.append(shrink_new)
-            self._sum_weight += shrink_new
+            self._rescale_dropped(drop_iters, old_factor, w_dec)
+            if not cfg.uniform_drop:
+                self._tree_weight.append(shrink_new)
+                self._sum_weight += shrink_new
 
     def train_one_iter(self, custom_grad=None, custom_hess=None,
                        check_stop: bool = True) -> bool:
@@ -1630,12 +1644,13 @@ class DART(GBDT):
                     and self.objective.renew_percentile is None
                     and not self._needs_host_tree)
         if fused_ok:
-            self._save_rollback_state()
-            self._prev_weights = (list(self._tree_weight), self._sum_weight)
-            drop_iters = self._select_drops()
+            with obs_trace.phase_span("prepare"):
+                self._save_rollback_state()
+                self._prev_weights = (list(self._tree_weight), self._sum_weight)
+                drop_iters = self._select_drops()
             if not drop_iters:
                 # no drop: exactly a plain GBDT iteration at rate lr
-                self._fused_train_one_iter()
+                self._fused_train_one_iter(save_state=False)
                 if not cfg.uniform_drop:
                     lr = cfg.learning_rate
                     self._tree_weight.append(lr)
@@ -1643,11 +1658,8 @@ class DART(GBDT):
             else:
                 self._fused_dart_iter(drop_iters)
             self.iter += 1
-            if check_stop:
-                new = self._device_trees[-self.num_class:]
-                stopped = all(int(t.num_leaves) <= 1 for t in new)
-                return stopped
-            return False
+            return check_stop and self._stopped(
+                self._device_trees[-self.num_class:])
         return self._host_train_one_iter(custom_grad, custom_hess,
                                          check_stop)
 
@@ -1816,7 +1828,8 @@ class RF(GBDT):
                 jnp.float32,
             )
             s = init[:, 0] if self.num_class == 1 else init
-            grad, hess = self.objective.get_gradients(s)
+            with jax.named_scope("lgbm.objective"):
+                grad, hess = self.objective.get_gradients(s)
             if grad.ndim == 1:
                 grad, hess = grad[:, None], hess[:, None]
             self._cached_grads = (grad, hess)
@@ -1827,11 +1840,7 @@ class RF(GBDT):
         init = jnp.asarray(self._init_scores, jnp.float32)
         const = jnp.broadcast_to(init[None, :], (self.num_data, self.num_class))
         sc = const[:, 0] if self.num_class == 1 else const
-        if getattr(self.objective, "is_stochastic", False):
-            grad, hess = self.objective.get_gradients(sc, iteration=iteration)
-        else:
-            grad, hess = self.objective.get_gradients(sc)
-        return self._guard_grads(grad, hess, iteration)
+        return super()._objective_grads(sc, iteration)
 
     def train_one_iter(self, custom_grad=None, custom_hess=None,
                        check_stop: bool = True) -> bool:

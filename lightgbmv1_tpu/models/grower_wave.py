@@ -898,13 +898,14 @@ def make_wave_grower(
         # parity follows transitively and is pinned under both flags.
         pipeline = (async_wave_pipeline and (use_sub or bool(valids))
                     and not use_loop)
-        root_sum = sums_fn(g3)
-        mask0 = _node_feature_mask(key, 0, base_mask, feature_fraction_bynode)
-        mask0 = mask0 & allowed_features(jnp.zeros(F, bool))
-        no_constr = jnp.asarray(NO_CONSTRAINT, jnp.float32)
-        out0 = leaf_output(root_sum[0], root_sum[1], params)
-        if params.path_smooth > 0:
-            out0 = smooth_output(out0, root_sum[2], 0.0, params)
+        with jax.named_scope("lgbm.select"):
+            root_sum = sums_fn(g3)
+            mask0 = _node_feature_mask(key, 0, base_mask, feature_fraction_bynode)
+            mask0 = mask0 & allowed_features(jnp.zeros(F, bool))
+            no_constr = jnp.asarray(NO_CONSTRAINT, jnp.float32)
+            out0 = leaf_output(root_sum[0], root_sum[1], params)
+            if params.path_smooth > 0:
+                out0 = smooth_output(out0, root_sum[2], 0.0, params)
         res0 = split_fn(hist0, root_sum, mask0, key, 0, no_constr, 0, out0)
 
         # round-invariant work hoisted out of the while-loop body: with
@@ -912,33 +913,34 @@ def make_wave_grower(
         # children's feature mask is the same every round, and with no
         # monotone constraints every child's constraint is the NO_CONSTRAINT
         # constant — neither needs per-round gathers/scatters
-        cmask_const = (jnp.broadcast_to(base_mask, (2 * K, F))
-                       if feature_fraction_bynode >= 1.0 and not use_groups
-                       else None)
-        pconstr_const = (None if use_mc
-                         else jnp.tile(no_constr, (K, 1)))
-        cconstr_const = (None if use_mc
-                         else jnp.tile(no_constr, (2 * K, 1)))
+        with jax.named_scope("lgbm.select"):
+            cmask_const = (jnp.broadcast_to(base_mask, (2 * K, F))
+                           if feature_fraction_bynode >= 1.0 and not use_groups
+                           else None)
+            pconstr_const = (None if use_mc
+                             else jnp.tile(no_constr, (K, 1)))
+            cconstr_const = (None if use_mc
+                             else jnp.tile(no_constr, (2 * K, 1)))
 
-        # pipelined schedule: the pending no-op of round -1 — every index
-        # is a drop slot and every routing slot is dead (leaf id L matches
-        # no row), so the first body's drain is a bit-exact no-op
-        pend0 = {}
-        if pipeline:
-            pend0 = dict(
-                cidx=jnp.full(2 * K, L + 1, jnp.int32),
-                feats=jnp.zeros(K, jnp.int32),
-                thrs=jnp.zeros(K, jnp.int32),
-                dls=jnp.zeros(K, bool),
-                leafs=jnp.full(K, L, jnp.int32),
-                nls=jnp.zeros(K, jnp.int32),
-            )
-            if use_sub:
-                pend0["hist"] = jnp.zeros((2 * K,) + hist0.shape,
-                                          jnp.float32)
-            if use_cat:
-                pend0["iscats"] = jnp.zeros(K, bool)
-                pend0["bitsets"] = jnp.zeros((K, W), jnp.uint32)
+            # pipelined schedule: the pending no-op of round -1 — every index
+            # is a drop slot and every routing slot is dead (leaf id L matches
+            # no row), so the first body's drain is a bit-exact no-op
+            pend0 = {}
+            if pipeline:
+                pend0 = dict(
+                    cidx=jnp.full(2 * K, L + 1, jnp.int32),
+                    feats=jnp.zeros(K, jnp.int32),
+                    thrs=jnp.zeros(K, jnp.int32),
+                    dls=jnp.zeros(K, bool),
+                    leafs=jnp.full(K, L, jnp.int32),
+                    nls=jnp.zeros(K, jnp.int32),
+                )
+                if use_sub:
+                    pend0["hist"] = jnp.zeros((2 * K,) + hist0.shape,
+                                              jnp.float32)
+                if use_cat:
+                    pend0["iscats"] = jnp.zeros(K, bool)
+                    pend0["bitsets"] = jnp.zeros((K, W), jnp.uint32)
 
         def route_pending(p, vb, vl):
             """Apply one pending round's split decisions to a valid set's
@@ -976,26 +978,27 @@ def make_wave_grower(
             return vl + jnp.sum(
                 jnp.where(go_rv, nls_k[:, None] - vl[None, :], 0), axis=0)
 
-        st = WaveState(
-            leaf_id=leaf_id0,
-            valid_lids=tuple(jnp.zeros(v.shape[1], jnp.int32)
-                             for v in valids),
-            leaf_hist=(jnp.zeros((L,) + hist0.shape,
-                                 jnp.float32).at[0].set(hist0)
-                       if use_sub
-                       else jnp.zeros((1,) + hist0.shape, jnp.float32)),
-            store=store.init(res0, out0),
-            leaf_box=(jnp.zeros((L, F, 2), jnp.int32)
-                      .at[0, :, 1].set(meta.num_bins)
-                      if use_inter else jnp.zeros((1, 1, 2), jnp.int32)),
-            leaf_used=(jnp.zeros((L, F), bool) if use_groups
-                       else jnp.zeros((1, 1), bool)),
-            num_leaves=jnp.asarray(1, jnp.int32),
-            done=jnp.asarray(L <= 1),
-            pending=pend0,
-        )
+        with jax.named_scope("lgbm.select"):
+            st = WaveState(
+                leaf_id=leaf_id0,
+                valid_lids=tuple(jnp.zeros(v.shape[1], jnp.int32)
+                                 for v in valids),
+                leaf_hist=(jnp.zeros((L,) + hist0.shape,
+                                     jnp.float32).at[0].set(hist0)
+                           if use_sub
+                           else jnp.zeros((1,) + hist0.shape, jnp.float32)),
+                store=store.init(res0, out0),
+                leaf_box=(jnp.zeros((L, F, 2), jnp.int32)
+                          .at[0, :, 1].set(meta.num_bins)
+                          if use_inter else jnp.zeros((1, 1, 2), jnp.int32)),
+                leaf_used=(jnp.zeros((L, F), bool) if use_groups
+                           else jnp.zeros((1, 1), bool)),
+                num_leaves=jnp.asarray(1, jnp.int32),
+                done=jnp.asarray(L <= 1),
+                pending=pend0,
+            )
 
-        kiota = jnp.arange(K, dtype=jnp.int32)
+            kiota = jnp.arange(K, dtype=jnp.int32)
 
         def cond(st: WaveState):
             # max(best_gain) > 0 stops BEFORE a zero-split round: the old
@@ -1005,8 +1008,9 @@ def make_wave_grower(
             # schedule (replay_wave_schedule) could not see.  A positive
             # frontier gain guarantees n_split >= 1 (the intermediate-
             # monotone deferral never clears the FIRST valid pick).
-            return (~st.done) & (st.num_leaves < L) & \
-                (jnp.max(store.gains(st.store)) > 0)
+            with jax.named_scope("lgbm.select"):
+                return (~st.done) & (st.num_leaves < L) & \
+                    (jnp.max(store.gains(st.store)) > 0)
 
         def body(st: WaveState) -> WaveState:
             # ---- pipelined drain of the PREVIOUS round's deferred work ----
@@ -1020,195 +1024,197 @@ def make_wave_grower(
             # parent rows are value-forwarded from the pending commit.
             if pipeline:
                 p_hist = st.pending.get("hist")
-                leaf_hist_in = (st.leaf_hist.at[st.pending["cidx"]]
-                                .set(p_hist, mode="drop")
-                                if use_sub else st.leaf_hist)
+                with jax.named_scope("lgbm.select"):
+                    leaf_hist_in = (st.leaf_hist.at[st.pending["cidx"]]
+                                    .set(p_hist, mode="drop")
+                                    if use_sub else st.leaf_hist)
                 vlids_in = tuple(
                     route_pending(st.pending, vb, vl)
                     for vb, vl in zip(valids, st.valid_lids))
             else:
                 leaf_hist_in = st.leaf_hist
                 vlids_in = st.valid_lids
-            budget = L - st.num_leaves
-            # routed fused rounds label the WHOLE round — the O(L) top-k
-            # slot ranking, the in-kernel routing + histogram + scan and
-            # the residue pick — as one `lgbm.fused_round` region, so
-            # compile/cost/roofline telemetry (and the trace phase
-            # profile's merged `phase_round_fused_ms` row) see a single
-            # labeled executable instead of a partition/top-k residue
-            fr_scope = (jax.named_scope("lgbm.fused_round") if use_fused
-                        else contextlib.nullcontext())
-            with fr_scope:
-                vals, leafs = _topk_by_rank(store.gains(st.store),
-                                            K)             # (K,)
-            valid = (vals > 0) & (kiota < budget)
-            if use_inter and K > 1:
-                # soundness: two leaves ADJACENT along a monotone feature
-                # must not split in the same round — each child would be
-                # clamped against the neighbour's stale pre-round output
-                # and monotonicity could break between the new children.
-                # Defer the lower-ranked leaf of any adjacent pair to a
-                # later round (it stays in the queue); the sequential
-                # reference orders such splits implicitly.
-                kb = st.leaf_box[leafs]                        # (K, F, 2)
-                adj = jnp.zeros((K, K), bool)
-                for _f, adj_up, adj_dn in _box_adjacency_per_feature(
-                        kb[..., 0], kb[..., 1], inter_feats):
-                    adj = adj | adj_up | adj_dn
-                kept = valid
-                for j in range(1, K):
-                    clash = jnp.any(adj[j, :j] & kept[:j])
-                    kept = kept.at[j].set(kept[j] & (~clash))
-                valid = kept
-            n_split = valid.sum()
-            if _ROUND_PROBE is not None:   # bench round-schedule probe
-                jax.debug.callback(_ROUND_PROBE, n_split)
-            order = jnp.cumsum(valid.astype(jnp.int32)) - 1
-            nodes = st.num_leaves - 1 + order                 # (K,) int32
-            nls = st.num_leaves + order                       # new right leaves
+            with jax.named_scope("lgbm.select"):
+                budget = L - st.num_leaves
+                # routed fused rounds label the WHOLE round — the O(L) top-k
+                # slot ranking, the in-kernel routing + histogram + scan and
+                # the residue pick — as one `lgbm.fused_round` region, so
+                # compile/cost/roofline telemetry (and the trace phase
+                # profile's merged `phase_round_fused_ms` row) see a single
+                # labeled executable instead of a partition/top-k residue
+                fr_scope = (jax.named_scope("lgbm.fused_round") if use_fused
+                            else contextlib.nullcontext())
+                with fr_scope:
+                    vals, leafs = _topk_by_rank(store.gains(st.store),
+                                                K)             # (K,)
+                valid = (vals > 0) & (kiota < budget)
+                if use_inter and K > 1:
+                    # soundness: two leaves ADJACENT along a monotone feature
+                    # must not split in the same round — each child would be
+                    # clamped against the neighbour's stale pre-round output
+                    # and monotonicity could break between the new children.
+                    # Defer the lower-ranked leaf of any adjacent pair to a
+                    # later round (it stays in the queue); the sequential
+                    # reference orders such splits implicitly.
+                    kb = st.leaf_box[leafs]                        # (K, F, 2)
+                    adj = jnp.zeros((K, K), bool)
+                    for _f, adj_up, adj_dn in _box_adjacency_per_feature(
+                            kb[..., 0], kb[..., 1], inter_feats):
+                        adj = adj | adj_up | adj_dn
+                    kept = valid
+                    for j in range(1, K):
+                        clash = jnp.any(adj[j, :j] & kept[:j])
+                        kept = kept.at[j].set(kept[j] & (~clash))
+                    valid = kept
+                n_split = valid.sum()
+                if _ROUND_PROBE is not None:   # bench round-schedule probe
+                    jax.debug.callback(_ROUND_PROBE, n_split)
+                order = jnp.cumsum(valid.astype(jnp.int32)) - 1
+                nodes = st.num_leaves - 1 + order                 # (K,) int32
+                nls = st.num_leaves + order                       # new right leaves
 
-            # one store read for every frontier field of the K split leaves
-            # (the packed store turns 10+ per-field gathers into a single
-            # (K, CF) table row gather)
-            rd = store.read(st.store, leafs)
-            feats, thrs, dls = rd["feats"], rd["thrs"], rd["dls"]
-            iscats, bitsets = rd["iscats"], rd["bitsets"]     # (K,), (K, W)
-            lsums, rsums = rd["lsums"], rd["rsums"]           # (K, 3)
-            sm_left = lsums[:, 2] <= rsums[:, 2]              # (K,) smaller
-            order_c = jnp.clip(order, 0, K - 1)
-            # per-round rounding key for the quantized pass: the per-tree
-            # key (unique per iteration x class) folded with the round's
-            # leaf count, which strictly increases every round — the
-            # (iteration, round) legs of the counter-based PRNG contract
-            # (ops/quantize.py); the row block is the third leg, drawn
-            # inside sr_quantize_g3
-            rkey = (jax.random.fold_in(key, 8_000_011 + st.num_leaves)
-                    if quant_buckets else None)
+                # one store read for every frontier field of the K split leaves
+                # (the packed store turns 10+ per-field gathers into a single
+                # (K, CF) table row gather)
+                rd = store.read(st.store, leafs)
+                feats, thrs, dls = rd["feats"], rd["thrs"], rd["dls"]
+                iscats, bitsets = rd["iscats"], rd["bitsets"]     # (K,), (K, W)
+                lsums, rsums = rd["lsums"], rd["rsums"]           # (K, 3)
+                sm_left = lsums[:, 2] <= rsums[:, 2]              # (K,) smaller
+                order_c = jnp.clip(order, 0, K - 1)
+                # per-round rounding key for the quantized pass: the per-tree
+                # key (unique per iteration x class) folded with the round's
+                # leaf count, which strictly increases every round — the
+                # (iteration, round) legs of the counter-based PRNG contract
+                # (ops/quantize.py); the row block is the third leg, drawn
+                # inside sr_quantize_g3
+                rkey = (jax.random.fold_in(key, 8_000_011 + st.num_leaves)
+                        if quant_buckets else None)
 
-            # value-forwarded parent histogram rows, hoisted ahead of the
-            # slot-bucket switch: the staged subtraction and the fused
-            # kernel (which streams the parent stack as a kernel input)
-            # must read the SAME forwarded values
-            h_parent = None
-            if use_sub and pipeline:
-                # value forwarding: gather the parents from the ONE-
-                # ROUND-STALE table and patch rows whose slot was
-                # (over)written by the pending commit — identical
-                # values to a post-scatter gather, but the subtracted
-                # sibling's split scan starts without waiting for the
-                # drained scatter (or the partition) to complete
-                h_parent = st.leaf_hist[leafs]
-                match = leafs[:, None] == st.pending["cidx"][None, :]
-                hit = jnp.any(match, axis=1)
-                src = jnp.argmax(match, axis=1)
-                h_parent = jnp.where(hit[:, None, None, None],
-                                     p_hist[src], h_parent)
-            elif use_fused and use_sub:
-                h_parent = leaf_hist_in[leafs]
+                # value-forwarded parent histogram rows, hoisted ahead of the
+                # slot-bucket switch: the staged subtraction and the fused
+                # kernel (which streams the parent stack as a kernel input)
+                # must read the SAME forwarded values
+                h_parent = None
+                if use_sub and pipeline:
+                    # value forwarding: gather the parents from the ONE-
+                    # ROUND-STALE table and patch rows whose slot was
+                    # (over)written by the pending commit — identical
+                    # values to a post-scatter gather, but the subtracted
+                    # sibling's split scan starts without waiting for the
+                    # drained scatter (or the partition) to complete
+                    h_parent = st.leaf_hist[leafs]
+                    match = leafs[:, None] == st.pending["cidx"][None, :]
+                    hit = jnp.any(match, axis=1)
+                    src = jnp.argmax(match, axis=1)
+                    h_parent = jnp.where(hit[:, None, None, None],
+                                         p_hist[src], h_parent)
+                elif use_fused and use_sub:
+                    h_parent = leaf_hist_in[leafs]
 
-            # ---- children metadata --------------------------------------
-            # Hoisted ahead of the histogram dispatch (it depends only on
-            # the store read): the fused kernel consumes the per-child
-            # masks/constraints/outputs INSIDE its scan, so they must
-            # exist before the slot-bucket switch; the staged split reads
-            # the identical values after it.
-            cleafs = jnp.stack([leafs, nls], axis=1).reshape(2 * K)
-            csums = jnp.stack([lsums, rsums], axis=1).reshape(2 * K, 3)
-            if use_inter:
-                # fresh per-round constraints from leaf-region adjacency —
-                # the outputs of neighbouring leaves may have changed since
-                # this leaf's constraint was stored (the reference's
-                # leaves_to_update_ propagation, monotone_constraints.hpp)
-                constr_tab = intermediate_constraints(
-                    st.leaf_box, store.leaf_out_full(st.store),
-                    st.num_leaves, inter_feats, inter_types)
-                pconstr = constr_tab[leafs]                   # (K, 2)
-            elif use_mc:
-                pconstr = rd["pconstr"]                       # (K, 2)
-            else:
-                pconstr = pconstr_const     # hoisted NO_CONSTRAINT rows
-            pout = rd["pout"]                                 # (K,)
-            out_l = jax.vmap(clamp_out)(lsums, pconstr, pout)
-            out_r = jax.vmap(clamp_out)(rsums, pconstr, pout)
-            if use_inter:
-                # children bounded by the SIBLING's actual output
-                # (UpdateConstraintsWithOutputs, monotone_constraints.hpp:154)
-                mono = meta.monotone_type[feats]
-                upd = (~iscats) & (mono != 0)
-                max_l = jnp.where(upd & (mono > 0),
-                                  jnp.minimum(pconstr[:, 1], out_r),
-                                  pconstr[:, 1])
-                min_l = jnp.where(upd & (mono < 0),
-                                  jnp.maximum(pconstr[:, 0], out_r),
-                                  pconstr[:, 0])
-                max_r = jnp.where(upd & (mono < 0),
-                                  jnp.minimum(pconstr[:, 1], out_l),
-                                  pconstr[:, 1])
-                min_r = jnp.where(upd & (mono > 0),
-                                  jnp.maximum(pconstr[:, 0], out_l),
-                                  pconstr[:, 0])
-                constr_l = jnp.stack([min_l, max_l], axis=1)
-                constr_r = jnp.stack([min_r, max_r], axis=1)
-            elif use_mc:
-                # BasicLeafConstraints::Update (monotone_constraints.hpp:99)
-                mono = meta.monotone_type[feats]
-                mid = 0.5 * (out_l + out_r)
-                upd = (~iscats) & (mono != 0)
-                max_l = jnp.where(upd & (mono > 0),
-                                  jnp.minimum(pconstr[:, 1], mid), pconstr[:, 1])
-                min_l = jnp.where(upd & (mono < 0),
-                                  jnp.maximum(pconstr[:, 0], mid), pconstr[:, 0])
-                max_r = jnp.where(upd & (mono < 0),
-                                  jnp.minimum(pconstr[:, 1], mid), pconstr[:, 1])
-                min_r = jnp.where(upd & (mono > 0),
-                                  jnp.maximum(pconstr[:, 0], mid), pconstr[:, 0])
-                constr_l = jnp.stack([min_l, max_l], axis=1)
-                constr_r = jnp.stack([min_r, max_r], axis=1)
-            if use_mc:
-                cconstr = jnp.stack([constr_l, constr_r],
-                                    axis=1).reshape(2 * K, 2)
-            else:
-                cconstr = cconstr_const     # hoisted NO_CONSTRAINT rows
-            couts = jnp.stack([out_l, out_r], axis=1).reshape(2 * K)
-            d = rd["pdepth"] + 1                              # (K,)
-            cdepth = jnp.stack([d, d], axis=1).reshape(2 * K)
-            depth_ok = (max_depth <= 0) | (cdepth < max_depth)
+                # ---- children metadata --------------------------------------
+                # Hoisted ahead of the histogram dispatch (it depends only on
+                # the store read): the fused kernel consumes the per-child
+                # masks/constraints/outputs INSIDE its scan, so they must
+                # exist before the slot-bucket switch; the staged split reads
+                # the identical values after it.
+                cleafs = jnp.stack([leafs, nls], axis=1).reshape(2 * K)
+                csums = jnp.stack([lsums, rsums], axis=1).reshape(2 * K, 3)
+                if use_inter:
+                    # fresh per-round constraints from leaf-region adjacency —
+                    # the outputs of neighbouring leaves may have changed since
+                    # this leaf's constraint was stored (the reference's
+                    # leaves_to_update_ propagation, monotone_constraints.hpp)
+                    constr_tab = intermediate_constraints(
+                        st.leaf_box, store.leaf_out_full(st.store),
+                        st.num_leaves, inter_feats, inter_types)
+                    pconstr = constr_tab[leafs]                   # (K, 2)
+                elif use_mc:
+                    pconstr = rd["pconstr"]                       # (K, 2)
+                else:
+                    pconstr = pconstr_const     # hoisted NO_CONSTRAINT rows
+                pout = rd["pout"]                                 # (K,)
+                out_l = jax.vmap(clamp_out)(lsums, pconstr, pout)
+                out_r = jax.vmap(clamp_out)(rsums, pconstr, pout)
+                if use_inter:
+                    # children bounded by the SIBLING's actual output
+                    # (UpdateConstraintsWithOutputs, monotone_constraints.hpp:154)
+                    mono = meta.monotone_type[feats]
+                    upd = (~iscats) & (mono != 0)
+                    max_l = jnp.where(upd & (mono > 0),
+                                      jnp.minimum(pconstr[:, 1], out_r),
+                                      pconstr[:, 1])
+                    min_l = jnp.where(upd & (mono < 0),
+                                      jnp.maximum(pconstr[:, 0], out_r),
+                                      pconstr[:, 0])
+                    max_r = jnp.where(upd & (mono < 0),
+                                      jnp.minimum(pconstr[:, 1], out_l),
+                                      pconstr[:, 1])
+                    min_r = jnp.where(upd & (mono > 0),
+                                      jnp.maximum(pconstr[:, 0], out_l),
+                                      pconstr[:, 0])
+                    constr_l = jnp.stack([min_l, max_l], axis=1)
+                    constr_r = jnp.stack([min_r, max_r], axis=1)
+                elif use_mc:
+                    # BasicLeafConstraints::Update (monotone_constraints.hpp:99)
+                    mono = meta.monotone_type[feats]
+                    mid = 0.5 * (out_l + out_r)
+                    upd = (~iscats) & (mono != 0)
+                    max_l = jnp.where(upd & (mono > 0),
+                                      jnp.minimum(pconstr[:, 1], mid), pconstr[:, 1])
+                    min_l = jnp.where(upd & (mono < 0),
+                                      jnp.maximum(pconstr[:, 0], mid), pconstr[:, 0])
+                    max_r = jnp.where(upd & (mono < 0),
+                                      jnp.minimum(pconstr[:, 1], mid), pconstr[:, 1])
+                    min_r = jnp.where(upd & (mono > 0),
+                                      jnp.maximum(pconstr[:, 0], mid), pconstr[:, 0])
+                    constr_l = jnp.stack([min_l, max_l], axis=1)
+                    constr_r = jnp.stack([min_r, max_r], axis=1)
+                if use_mc:
+                    cconstr = jnp.stack([constr_l, constr_r],
+                                        axis=1).reshape(2 * K, 2)
+                else:
+                    cconstr = cconstr_const     # hoisted NO_CONSTRAINT rows
+                couts = jnp.stack([out_l, out_r], axis=1).reshape(2 * K)
+                d = rd["pdepth"] + 1                              # (K,)
+                cdepth = jnp.stack([d, d], axis=1).reshape(2 * K)
+                depth_ok = (max_depth <= 0) | (cdepth < max_depth)
 
-            cuids = jnp.stack([2 * nodes + 1, 2 * nodes + 2],
-                              axis=1).reshape(2 * K)
-            if use_groups:
-                # branch-feature tracking feeds ONLY the interaction-
-                # constraint mask — with no groups the whole block is
-                # hoisted away (dead per-round one-hot + scatter)
-                used_child = st.leaf_used[leafs] | jax.nn.one_hot(
-                    feats, F, dtype=bool)                     # (K, F)
-                cused = jnp.stack([used_child, used_child], axis=1) \
-                    .reshape(2 * K, F)
-                allow = jax.vmap(allowed_features)(cused)     # (2K, F)
-            else:
-                cused = allow = None
-            if feature_fraction_bynode < 1.0:
-                cmask = jax.vmap(
-                    lambda u: _node_feature_mask(key, u, base_mask,
-                                                 feature_fraction_bynode)
-                )(cuids)
-                if allow is not None:
-                    cmask = cmask & allow
-            elif allow is not None:
-                cmask = jnp.broadcast_to(base_mask, (2 * K, F)) & allow
-            else:
-                cmask = cmask_const         # hoisted: same mask every round
+                cuids = jnp.stack([2 * nodes + 1, 2 * nodes + 2],
+                                  axis=1).reshape(2 * K)
+                if use_groups:
+                    # branch-feature tracking feeds ONLY the interaction-
+                    # constraint mask — with no groups the whole block is
+                    # hoisted away (dead per-round one-hot + scatter)
+                    used_child = st.leaf_used[leafs] | jax.nn.one_hot(
+                        feats, F, dtype=bool)                     # (K, F)
+                    cused = jnp.stack([used_child, used_child], axis=1) \
+                        .reshape(2 * K, F)
+                    allow = jax.vmap(allowed_features)(cused)     # (2K, F)
+                else:
+                    cused = allow = None
+                if feature_fraction_bynode < 1.0:
+                    cmask = jax.vmap(
+                        lambda u: _node_feature_mask(key, u, base_mask,
+                                                     feature_fraction_bynode)
+                    )(cuids)
+                    if allow is not None:
+                        cmask = cmask & allow
+                elif allow is not None:
+                    cmask = jnp.broadcast_to(base_mask, (2 * K, F)) & allow
+                else:
+                    cmask = cmask_const         # hoisted: same mask every round
 
-            if use_inter:
-                # child regions: a numerical split cuts the parent's box at
-                # thr+1 along the split feature; categorical children keep
-                # the parent box (conservative: more adjacency, never less)
-                pbox = st.leaf_box[leafs]                     # (K, F, 2)
-                kio = jnp.arange(K)
-                cut = jnp.where(iscats, pbox[kio, feats, 1], thrs + 1)
-                box_l = pbox.at[kio, feats, 1].set(cut)
-                cut_lo = jnp.where(iscats, pbox[kio, feats, 0], thrs + 1)
-                box_r = pbox.at[kio, feats, 0].set(cut_lo)
+                if use_inter:
+                    # child regions: a numerical split cuts the parent's box at
+                    # thr+1 along the split feature; categorical children keep
+                    # the parent box (conservative: more adjacency, never less)
+                    pbox = st.leaf_box[leafs]                     # (K, F, 2)
+                    kio = jnp.arange(K)
+                    cut = jnp.where(iscats, pbox[kio, feats, 1], thrs + 1)
+                    box_l = pbox.at[kio, feats, 1].set(cut)
+                    cut_lo = jnp.where(iscats, pbox[kio, feats, 0], thrs + 1)
+                    box_r = pbox.at[kio, feats, 0].set(cut_lo)
 
             # ---- decision + labeling + histogram, sliced to S slots -------
             # One vectorized (S, N) decision pass (the analog of K
@@ -1218,21 +1224,23 @@ def make_wave_grower(
             # ``order`` (cumsum of valid — dense even when the intermediate-
             # monotone deferral clears mid-prefix picks).
             def round_pass(S):
-                sidx = jnp.where(valid, order_c, S)          # (K,) slot|drop
+                with jax.named_scope("lgbm.select"):
+                    sidx = jnp.where(valid, order_c, S)          # (K,) slot|drop
 
-                def to_slot(v, fill):
-                    base = jnp.full((S,) + v.shape[1:], fill, v.dtype)
-                    return base.at[sidx].set(v, mode="drop")
+                    def to_slot(v, fill):
+                        base = jnp.full((S,) + v.shape[1:], fill, v.dtype)
+                        return base.at[sidx].set(v, mode="drop")
 
-                feats_s = to_slot(feats, 0)
-                thrs_s = to_slot(thrs, 0)
-                dls_s = to_slot(dls, False)
-                # empty slots carry leaf id L: matches no row's leaf
-                leafs_s = to_slot(leafs, L)
-                nls_s = to_slot(nls, 0)
-                sml_s = to_slot(sm_left, False)
-                iscats_s = to_slot(iscats, False) if use_cat else None
-                bitsets_s = to_slot(bitsets, 0) if use_cat else None
+                    feats_s = to_slot(feats, 0)
+                    thrs_s = to_slot(thrs, 0)
+                    dls_s = to_slot(dls, False)
+                    # empty slots carry leaf id L: matches no row's leaf
+                    leafs_s = to_slot(leafs, L)
+                    nls_s = to_slot(nls, 0)
+                    sml_s = to_slot(sm_left, False)
+                    iscats_s = to_slot(iscats, False) if use_cat else None
+                    bitsets_s = to_slot(bitsets, 0) if use_cat else None
+                    siota = jnp.arange(S, dtype=jnp.int32)
 
                 def go_left_s(matrix):
                     """(S, rows) left-decision of this round's splits —
@@ -1255,7 +1263,6 @@ def make_wave_grower(
                         g = jnp.where(iscats_s[:, None], in_set, g)
                     return g
 
-                siota = jnp.arange(S, dtype=jnp.int32)
                 if use_fused_route:
                     # ---- single-pass round (ISSUE 15): NO staged
                     # partition — the fused kernel evaluates the go-left
@@ -1380,21 +1387,23 @@ def make_wave_grower(
                     hsc = jnp.ones((nsl, 3), jnp.float32)
                 full = 2 * K if not use_sub else K
                 if h.shape[0] < full:   # pad to the bucket-invariant width
-                    h = jnp.concatenate(
-                        [h, jnp.zeros((full - h.shape[0],) + h.shape[1:],
-                                      h.dtype)], axis=0)
-                    # padded slots dequantize as identity
-                    hsc = jnp.concatenate(
-                        [hsc, jnp.ones((full - hsc.shape[0], 3), hsc.dtype)],
-                        axis=0)
+                    with jax.named_scope("lgbm.select"):
+                        h = jnp.concatenate(
+                            [h, jnp.zeros((full - h.shape[0],) + h.shape[1:],
+                                          h.dtype)], axis=0)
+                        # padded slots dequantize as identity
+                        hsc = jnp.concatenate(
+                            [hsc, jnp.ones((full - hsc.shape[0], 3),
+                                           hsc.dtype)], axis=0)
                 return (h, hsc, leaf_id) + tuple(vl_new)
 
             with (jax.named_scope("lgbm.fused_round") if use_fused
                   else contextlib.nullcontext()):
                 if len(slot_buckets) > 1:
-                    s_idx = jnp.zeros((), jnp.int32)
-                    for S in slot_buckets[:-1]:
-                        s_idx = s_idx + (n_split > S).astype(jnp.int32)
+                    with jax.named_scope("lgbm.select"):
+                        s_idx = jnp.zeros((), jnp.int32)
+                        for S in slot_buckets[:-1]:
+                            s_idx = s_idx + (n_split > S).astype(jnp.int32)
                     outs = lax.switch(
                         s_idx,
                         [lambda S=S: round_pass(S) for S in slot_buckets])
@@ -1414,42 +1423,43 @@ def make_wave_grower(
                 new_vlids = vlids_in if pipeline else tuple(outs[3:])
 
             cscale = None                   # per-child dequant (quant rounds)
-            if use_fused:
-                # the kernel already scanned the children in VMEM; what
-                # remains is the per-leaf table bookkeeping (subtraction
-                # mode: the SAME subtract the kernel ran, recomputed on
-                # the emitted smaller-child stack for the state scatter)
-                # and the slot->rank gather of the packed SplitInfo
-                if use_sub:
+            with jax.named_scope("lgbm.select"):
+                if use_fused:
+                    # the kernel already scanned the children in VMEM; what
+                    # remains is the per-leaf table bookkeeping (subtraction
+                    # mode: the SAME subtract the kernel ran, recomputed on
+                    # the emitted smaller-child stack for the state scatter)
+                    # and the slot->rank gather of the packed SplitInfo
+                    if use_sub:
+                        hist, h_left, h_right = subtract_child_hists(
+                            h_slot, leaf_hist_in, leafs, order_c, sm_left,
+                            slot_scale=hscale if quant_buckets else None,
+                            h_parent=h_parent)
+                    ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
+                                       axis=1).reshape(2 * K)
+                    res = _unpack_children(packed[ch_idx], B)
+                elif use_sub:
+                    # ---- smaller-child histograms + subtraction --------------
+                    # quant rounds fold the per-slot dequantization into the
+                    # subtraction pass (slot_scale); non-quant rounds carry
+                    # all-ones scales and skip the multiply entirely
                     hist, h_left, h_right = subtract_child_hists(
                         h_slot, leaf_hist_in, leafs, order_c, sm_left,
                         slot_scale=hscale if quant_buckets else None,
                         h_parent=h_parent)
-                ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
-                                   axis=1).reshape(2 * K)
-                res = _unpack_children(packed[ch_idx], B)
-            elif use_sub:
-                # ---- smaller-child histograms + subtraction --------------
-                # quant rounds fold the per-slot dequantization into the
-                # subtraction pass (slot_scale); non-quant rounds carry
-                # all-ones scales and skip the multiply entirely
-                hist, h_left, h_right = subtract_child_hists(
-                    h_slot, leaf_hist_in, leafs, order_c, sm_left,
-                    slot_scale=hscale if quant_buckets else None,
-                    h_parent=h_parent)
-            else:
-                ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
-                                   axis=1).reshape(2 * K)
-                hist = h_slot[ch_idx]              # slot-order -> rank-order
-                if quant_buckets:
-                    # children come straight from the (possibly quantized)
-                    # pass: hand the split scan the integer histograms +
-                    # per-child scales (dequantize-aware scan) when the
-                    # split accepts them, else dequantize here
-                    cscale = hscale[ch_idx]                       # (2K, 3)
-                    if not takes_scale:
-                        hist = hist * cscale[:, None, None, :]
-                        cscale = None
+                else:
+                    ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
+                                       axis=1).reshape(2 * K)
+                    hist = h_slot[ch_idx]              # slot-order -> rank-order
+                    if quant_buckets:
+                        # children come straight from the (possibly quantized)
+                        # pass: hand the split scan the integer histograms +
+                        # per-child scales (dequantize-aware scan) when the
+                        # split accepts them, else dequantize here
+                        cscale = hscale[ch_idx]                       # (2K, 3)
+                        if not takes_scale:
+                            hist = hist * cscale[:, None, None, :]
+                            cscale = None
 
             # ---- batched split finding over the 2K children ---------------
             # (fused rounds already hold `res` — the kernel's packed
@@ -1468,82 +1478,83 @@ def make_wave_grower(
                     lambda h, p, m, u, c, dd, po: split_fn(h, p, m, key, u,
                                                            c, dd, po)
                 )(hist, csums, cmask, cuids, cconstr, cdepth, couts)
-            cgain = jnp.where(depth_ok, res.gain, -jnp.inf)
-            cvalid = jnp.stack([valid, valid], axis=1).reshape(2 * K)
-            cidx = jnp.where(cvalid, cleafs, L + 1)           # drop slot
+            with jax.named_scope("lgbm.select"):
+                cgain = jnp.where(depth_ok, res.gain, -jnp.inf)
+                cvalid = jnp.stack([valid, valid], axis=1).reshape(2 * K)
+                cidx = jnp.where(cvalid, cleafs, L + 1)           # drop slot
 
-            # ---- tree assembly + frontier commit ------------------------
-            # One store.write per round: the packed store coalesces the
-            # whole commit into a 2K-row frontier-table scatter, a K-row
-            # node-table scatter and a 2-column pointer fixup; the legacy
-            # store reproduces the historical ~30 per-field scatters.
-            nidx = jnp.where(valid, nodes, L1 + 1)
-            lidx = jnp.where(valid, leafs, L + 1)
-            nlidx = jnp.where(valid, nls, L + 1)
-            p = rd["parent"]
-            was_left = rd["was_left"]
-            fix_l = jnp.where(valid & (p >= 0) & was_left,
-                              jnp.maximum(p, 0), L1 + 1)
-            fix_r = jnp.where(valid & (p >= 0) & (~was_left),
-                              jnp.maximum(p, 0), L1 + 1)
-            psum_k = lsums + rsums                            # parent sums
-            new_store = store.write(st.store, dict(
-                res=res, cgain=cgain, cidx=cidx, nidx=nidx,
-                lidx=lidx, nlidx=nlidx, fix_l=fix_l, fix_r=fix_r,
-                leafs=leafs, nls=nls,
-                feats=feats, thrs=thrs, dls=dls,
-                iscats=iscats, bitsets=bitsets,
-                mtypes=meta.missing_type[feats],
-                vals=vals, pout=pout, psum=psum_k,
-                lsums=lsums, rsums=rsums, csums=csums,
-                out_l=out_l, out_r=out_r, couts=couts,
-                cdepth=cdepth, cconstr=cconstr,
-                num_leaves_new=st.num_leaves + n_split,
-            ))
-
-            if pipeline:
-                # this round's commits become the NEXT round's pending:
-                # the (already drained-in) table rides forward unchanged
-                # and the scatter + valid routing defer one round
-                leaf_hist = leaf_hist_in
-                new_pending = dict(
-                    cidx=cidx,
+                # ---- tree assembly + frontier commit ------------------------
+                # One store.write per round: the packed store coalesces the
+                # whole commit into a 2K-row frontier-table scatter, a K-row
+                # node-table scatter and a 2-column pointer fixup; the legacy
+                # store reproduces the historical ~30 per-field scatters.
+                nidx = jnp.where(valid, nodes, L1 + 1)
+                lidx = jnp.where(valid, leafs, L + 1)
+                nlidx = jnp.where(valid, nls, L + 1)
+                p = rd["parent"]
+                was_left = rd["was_left"]
+                fix_l = jnp.where(valid & (p >= 0) & was_left,
+                                  jnp.maximum(p, 0), L1 + 1)
+                fix_r = jnp.where(valid & (p >= 0) & (~was_left),
+                                  jnp.maximum(p, 0), L1 + 1)
+                psum_k = lsums + rsums                            # parent sums
+                new_store = store.write(st.store, dict(
+                    res=res, cgain=cgain, cidx=cidx, nidx=nidx,
+                    lidx=lidx, nlidx=nlidx, fix_l=fix_l, fix_r=fix_r,
+                    leafs=leafs, nls=nls,
                     feats=feats, thrs=thrs, dls=dls,
-                    leafs=jnp.where(valid, leafs, L), nls=nls,
-                )
-                if use_sub:
-                    new_pending["hist"] = hist
-                if use_cat:
-                    new_pending["iscats"] = iscats
-                    new_pending["bitsets"] = bitsets
-            elif use_sub:
-                # packed: ONE interleaved scatter at cidx (hist is already
-                # the rank-interleaved (2K, ...) child stack); legacy: the
-                # historical two half-scatters
-                leaf_hist = (
-                    st.leaf_hist.at[cidx].set(hist, mode="drop")
-                    if store.fused else
-                    st.leaf_hist.at[lidx].set(h_left, mode="drop")
-                    .at[nlidx].set(h_right, mode="drop"))
-                new_pending = st.pending
-            else:
-                leaf_hist = st.leaf_hist
-                new_pending = st.pending
+                    iscats=iscats, bitsets=bitsets,
+                    mtypes=meta.missing_type[feats],
+                    vals=vals, pout=pout, psum=psum_k,
+                    lsums=lsums, rsums=rsums, csums=csums,
+                    out_l=out_l, out_r=out_r, couts=couts,
+                    cdepth=cdepth, cconstr=cconstr,
+                    num_leaves_new=st.num_leaves + n_split,
+                ))
 
-            return WaveState(
-                leaf_id=leaf_id,
-                valid_lids=new_vlids,
-                leaf_hist=leaf_hist,
-                store=new_store,
-                leaf_box=(st.leaf_box.at[lidx].set(box_l, mode="drop")
-                          .at[nlidx].set(box_r, mode="drop")
-                          if use_inter else st.leaf_box),
-                leaf_used=(st.leaf_used.at[cidx].set(cused, mode="drop")
-                           if use_groups else st.leaf_used),
-                num_leaves=st.num_leaves + n_split,
-                done=st.done | (n_split == 0),
-                pending=new_pending,
-            )
+                if pipeline:
+                    # this round's commits become the NEXT round's pending:
+                    # the (already drained-in) table rides forward unchanged
+                    # and the scatter + valid routing defer one round
+                    leaf_hist = leaf_hist_in
+                    new_pending = dict(
+                        cidx=cidx,
+                        feats=feats, thrs=thrs, dls=dls,
+                        leafs=jnp.where(valid, leafs, L), nls=nls,
+                    )
+                    if use_sub:
+                        new_pending["hist"] = hist
+                    if use_cat:
+                        new_pending["iscats"] = iscats
+                        new_pending["bitsets"] = bitsets
+                elif use_sub:
+                    # packed: ONE interleaved scatter at cidx (hist is already
+                    # the rank-interleaved (2K, ...) child stack); legacy: the
+                    # historical two half-scatters
+                    leaf_hist = (
+                        st.leaf_hist.at[cidx].set(hist, mode="drop")
+                        if store.fused else
+                        st.leaf_hist.at[lidx].set(h_left, mode="drop")
+                        .at[nlidx].set(h_right, mode="drop"))
+                    new_pending = st.pending
+                else:
+                    leaf_hist = st.leaf_hist
+                    new_pending = st.pending
+
+                return WaveState(
+                    leaf_id=leaf_id,
+                    valid_lids=new_vlids,
+                    leaf_hist=leaf_hist,
+                    store=new_store,
+                    leaf_box=(st.leaf_box.at[lidx].set(box_l, mode="drop")
+                              .at[nlidx].set(box_r, mode="drop")
+                              if use_inter else st.leaf_box),
+                    leaf_used=(st.leaf_used.at[cidx].set(cused, mode="drop")
+                               if use_groups else st.leaf_used),
+                    num_leaves=st.num_leaves + n_split,
+                    done=st.done | (n_split == 0),
+                    pending=new_pending,
+                )
 
         R_loop = loop_plan["rounds"] if use_loop else 0
 
@@ -1661,7 +1672,8 @@ def make_wave_grower(
 
         if L > 1:
             st = lax.while_loop(cond, body_loop if use_loop else body, st)
-        tree = store.finalize(st.store, st.num_leaves)
+        with jax.named_scope("lgbm.select"):
+            tree = store.finalize(st.store, st.num_leaves)
         vlids_out = st.valid_lids
         if pipeline and valids:
             # drain: the final round's valid routing is still pending when

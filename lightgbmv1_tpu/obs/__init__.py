@@ -1,20 +1,19 @@
 """Unified observability layer (span tracing + one metrics registry).
 
-Three pillars (ISSUE 9), replacing the five one-off telemetry mechanisms
-that grew PR by PR (phase timers, JSON-only serve counters, analytic comm
-tables, the streaming DeviceLedger, differential attribution) with one
-schema that crosses the train/serve boundary:
+Two pillars (ISSUE 9), one schema across the train/serve boundary:
 
 * :mod:`~lightgbmv1_tpu.obs.trace` — a low-overhead nested-span tracer
-  (thread-local span stack, monotonic clocks, ring-buffered events,
-  hard-off by default) exporting Chrome trace-event JSON viewable in
-  Perfetto; serving requests carry a propagated trace id end to end.
+  (thread-local span stack, monotonic clocks, ring-buffered events)
+  exporting Chrome trace-event JSON viewable in Perfetto.  Per-request
+  and per-block spans (``serve.*``, ``stream.*``) are hard-off by
+  default; per-tree and coarser spans (``train.*``, ``data.*``) are
+  always also ``jax.profiler.TraceAnnotation``s, so any profiler session
+  holds them on the device ops' clock, and every tree leaves one
+  always-on record (``trace.iteration_records()``).  Serving requests
+  carry a propagated trace id end to end.
 * :mod:`~lightgbmv1_tpu.obs.metrics` — counters / gauges / histograms
-  with labels in one registry; JSON snapshots for the existing BENCH
-  plumbing and Prometheus text exposition for everything else.
-* ``tools/bench_trend.py`` — the regression sentinel over the
-  ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` trajectory (guard flips and
-  >10% regressions exit non-zero so captures can be gated).
+  with labels in one registry; JSON snapshots and Prometheus text
+  exposition.
 
 The forensics-and-fleet half (ISSUE 10) builds on those:
 
@@ -57,11 +56,14 @@ The model-quality half (ISSUE 14) watches the MODEL, not the system:
   ``GET /drift``, capped-cardinality Prometheus gauges (top-K), and
   ``drift.alert`` events.
 
-Contract: tracing is OFF by default and its off-path must cost nothing
-measurable (one module-level flag check, no allocation); armed tracing
-must stay within 2% of train wall (the BENCH ``obs_ok`` guard measures
-both).  Metrics are always on — counter bumps are nanoseconds against
-millisecond iterations and requests.
+Contract: the ring is OFF by default and ``span()``'s off path must cost
+nothing measurable (one module-level flag check, no allocation).  The
+always-bridged per-tree spans cost a few microseconds each with no
+profiler session open (five a tree; tests/test_train_spans.py holds a
+ceiling), and the per-tree record one tuple append.  What an armed ring
+costs a training cell on the chip is in PERF.md (PR 26).  Metrics are
+always on — counter bumps are nanoseconds against millisecond
+iterations and requests.
 """
 
 from . import agg, drift, dump, events, metrics, model, trace, xla

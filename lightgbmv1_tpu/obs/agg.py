@@ -36,13 +36,11 @@ ISSUE 12 adds the **device lane**: a ``jax.profiler`` capture directory
 (``profile_dir`` / the ``tools/capture.py`` harness) is ingested as one
 more trace source per ``*.trace.json(.gz)`` it holds, rebased onto the
 shared wall axis via the ``profile.anchor.json`` sidecar obs/xla.py
-writes at ``start_trace``.  Host phase spans that PR 9 rendered as
-ESTIMATED (``phase.*`` children with ``estimated: true`` — the host
-cannot see inside the jitted while-loop) are then RECONCILED against the
-measured device rows carrying the ``lgbm.*`` named scopes: when a phase
-has measured device milliseconds, its spans flip ``estimated: false``
-and the per-phase agreement ratio (measured / estimated) is recorded in
-``otherData.phase_agreement``.
+writes at ``start_trace``.  That anchor is the CROSS-PROCESS fallback:
+inside the capturing process the program's own per-tree spans
+(``train.*`` / ``data.*``, obs/trace.py ``bridged_span``) are already in
+the profiler's host lane on the device ops' clock, and that is the
+alignment to read a tree's phases against its device time by.
 """
 
 from __future__ import annotations
@@ -110,21 +108,8 @@ def export_process_artifacts(out_dir: str,
 
 
 # ---------------------------------------------------------------------------
-# device lane: jax.profiler capture ingestion + phase reconciliation
+# device lane: jax.profiler capture ingestion
 # ---------------------------------------------------------------------------
-
-# host phase span name -> the jax.named_scope tokens the device rows
-# carry (ops/histogram.py, ops/split.py, models/grower*.py); phases
-# without a scope (valid_route, other) stay estimated by construction
-PHASE_SCOPE_TOKENS: Dict[str, Tuple[str, ...]] = {
-    "hist": ("lgbm.hist",),
-    "split": ("lgbm.split",),
-    "partition": ("lgbm.partition",),
-    # hist_method=fused single-pass round (ISSUE 15): top-k + routing +
-    # histogram + scan all carry this one label (grower + kernel)
-    "round_fused": ("lgbm.fused_round",),
-}
-
 
 def load_profiler_traces(profile_dir: str) -> List[Tuple[str, dict]]:
     """``[(label, chrome_doc)]`` from a ``jax.profiler`` capture
@@ -186,50 +171,6 @@ def load_profiler_traces(profile_dir: str) -> List[Tuple[str, dict]]:
         stem = os.path.basename(path).split(".trace.json")[0]
         docs.append(("device-" + _safe_label(stem), doc))
     return docs
-
-
-def reconcile_estimated(doc: dict) -> Dict[str, Optional[float]]:
-    """Reconcile estimated host phase spans against measured device rows
-    in a MERGED trace document (mutates ``doc``; see module docstring).
-
-    Returns ``{phase: agreement ratio}`` for every phase that had both
-    an estimated span total and measured ``lgbm.<phase>``-scoped device
-    milliseconds; those spans flip to ``estimated: false`` and carry
-    ``measured_device_ms`` + ``agreement``.  Phases with no measured
-    rows are untouched — an estimate stays labeled an estimate."""
-    sources = (doc.get("otherData") or {}).get("sources") or []
-    device_lanes = {s.get("lane") for s in sources
-                    if s.get("role") == "device"}
-    est: Dict[str, List[dict]] = {}
-    meas: Dict[str, float] = {}
-    for ev in doc.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
-        name = str(ev.get("name", ""))
-        if name.startswith("phase.") and (ev.get("args") or {}).get(
-                "estimated"):
-            est.setdefault(name[len("phase."):], []).append(ev)
-        elif ev.get("pid") in device_lanes:
-            low = name.lower()
-            for phase, tokens in PHASE_SCOPE_TOKENS.items():
-                if any(t in low for t in tokens):
-                    meas[phase] = meas.get(phase, 0.0) \
-                        + float(ev.get("dur", 0) or 0) / 1e3
-    agreement: Dict[str, Optional[float]] = {}
-    for phase, spans in est.items():
-        measured_ms = meas.get(phase)
-        if not measured_ms:
-            continue
-        est_ms = sum(float(e.get("dur", 0) or 0) for e in spans) / 1e3
-        ratio = round(measured_ms / est_ms, 4) if est_ms > 0 else None
-        agreement[phase] = ratio
-        for e in spans:
-            args = e.setdefault("args", {})
-            args["estimated"] = False
-            args["measured_device_ms"] = round(measured_ms, 3)
-            args["agreement"] = ratio
-    doc.setdefault("otherData", {})["phase_agreement"] = agreement
-    return agreement
 
 
 # ---------------------------------------------------------------------------
@@ -382,8 +323,7 @@ def aggregate_dir(art_dir: str, out_trace: Optional[str] = None,
     """One-call aggregation: scan ``art_dir``, merge, optionally write
     ``merged.trace.json`` / ``merged.metrics.json`` (defaults inside
     ``art_dir``), return a summary dict.  ``profile_dir`` additionally
-    ingests a ``jax.profiler`` capture as device lane(s) and reconciles
-    the estimated host phase spans against the measured device rows."""
+    ingests a ``jax.profiler`` capture as device lane(s)."""
     from ..utils import fileio
 
     arts = load_artifact_dir(art_dir)
@@ -391,7 +331,6 @@ def aggregate_dir(art_dir: str, out_trace: Optional[str] = None,
     if profile_dir:
         traces.extend(load_profiler_traces(profile_dir))
     trace_doc = merge_trace_docs(traces)
-    agreement = reconcile_estimated(trace_doc)
     metrics_doc = merge_metrics_snapshots(arts["metrics"])
     merged_events = merge_event_lists(arts["events"])
     out_trace = out_trace or os.path.join(str(art_dir), MERGED_TRACE)
@@ -414,7 +353,6 @@ def aggregate_dir(art_dir: str, out_trace: Optional[str] = None,
                     trace_doc["otherData"]["sources"]],
         "lanes": len(lanes),
         "device_lanes": len(device_lanes & lanes),
-        "phase_agreement": agreement,
         "trace_events": sum(1 for e in trace_doc["traceEvents"]
                             if e.get("ph") == "X"),
         "merged_events": len(merged_events),

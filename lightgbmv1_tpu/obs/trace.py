@@ -1,13 +1,39 @@
 """Low-overhead nested-span tracer with Chrome trace-event export.
 
-Design points (the ISSUE-9 contract):
+Two kinds of span, one ring:
 
-* **Hard-off by default.**  ``span()`` checks ONE module-level flag and
-  returns a shared no-op context manager when tracing is disarmed — no
-  dict, no object, no clock read is allocated on the off path
+* **``span()`` — per-request and per-block sites** (``serve.*``,
+  ``stream.*``).  Hard-off by default: it checks ONE module-level flag
+  and returns a shared no-op context manager when tracing is disarmed —
+  no dict, no object, no clock read is allocated on the off path
   (tests/test_obs.py pins the zero-allocation property with
   tracemalloc).  Hot paths that want to skip even argument construction
   guard with ``trace.enabled()``.
+* **``bridged_span()`` — per-tree and coarser sites** (``train.*``,
+  ``data.*``).  It ALWAYS enters a ``jax.profiler.TraceAnnotation`` of
+  the same name, which the runtime drops while no profiler session is
+  open: a ``jax.profiler.start_trace`` started by anyone (a benchmark's
+  ``--trace 1``, ``profile_dir=``, an operator) then holds the program's
+  spans in its host lane, on the same clock as the device ops, with no
+  arming by the caller.  Armed, it also records into the ring like
+  ``span()``.  A few microseconds a span with no session open
+  (tests/test_train_spans.py holds a ceiling on it).
+* **One record a tree, always on.**  ``iteration_span()`` is the
+  bridged ``train.iteration`` span ``Booster.update`` runs a tree under;
+  the ``phase_span()`` children inside it (``train.prepare`` /
+  ``train.dispatch`` / ``train.bookkeep`` / ``train.wait``) carry the
+  tree's ``iteration`` and add their time to it, and when it closes it
+  writes ONE tuple ``(iteration, t0_ns, prepare_ns, dispatch_ns,
+  bookkeep_ns, wait_ns, total_ns)`` into a bounded ring
+  (``iteration_records()``): which host phase a slow tree's extra
+  milliseconds passed in, without a profiler and without arming.  It is
+  host time: where the runtime blocks the host in a phase until the
+  device is done (a TPU does, in ``bookkeep``; ``GBDT._stopped``), that
+  phase holds the device's time too, and the record cannot tell them
+  apart.
+
+Common to both:
+
 * **Monotonic clocks.**  All timestamps are ``time.perf_counter_ns()``
   — immune to wall-clock steps; the export rebases to the arm instant.
 * **Thread-local span stack.**  Nesting needs no global coordination;
@@ -27,25 +53,26 @@ Export is the Chrome trace-event JSON format (``{"traceEvents": [...]}``
 of ``"ph": "X"`` complete events) — open the file at https://ui.perfetto.dev
 or chrome://tracing.
 
-Within-dispatch training phases (top-k / partition / histogram / split)
-run inside ONE jitted ``lax.while_loop`` the host cannot observe
-per-round; when a phase profile is installed (``set_phase_profile`` —
-bench.py installs the measured ``phase_attrib`` breakdown), iteration
-spans additionally emit wave-round and phase child spans laid out
-proportionally to the ATTRIBUTED milliseconds and flagged
-``{"estimated": true}``, so the Perfetto view and the ``phase_attrib``
-figures agree by construction.  Without a profile, iteration spans have
-only the host-observable children (dispatch / materialize / eval).
+What runs inside one jitted dispatch (objective / sample / grower phases
+/ score update) the host cannot see; it is on the device lane of a
+profiler trace under the ``lgbm.*`` named scopes, never invented here.
 """
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from ..utils.timer import global_timer
+
 DEFAULT_RING_EVENTS = 65536
+ITERATION_RING = 4096           # always-on per-tree records kept
+ITERATION_PHASES = ("prepare", "dispatch", "bookkeep", "wait")
 
 _armed = False                  # THE hot-path flag: checked once per span
 _lock = threading.Lock()        # guards the ring and arm/disarm
@@ -57,7 +84,9 @@ _t_arm_ns = 0                   # export rebases timestamps to this
 _t_arm_unix_ns = 0              # wall-clock anchor of the SAME instant —
                                 # the cross-process alignment key agg.py
                                 # merges timelines on
-_phase_profile: Optional[Dict] = None
+# (iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns, wait_ns,
+# total_ns), one a tree, armed or not
+_iterations: collections.deque = collections.deque(maxlen=ITERATION_RING)
 
 _tls = threading.local()
 
@@ -90,14 +119,14 @@ def disarm() -> None:
 
 
 def reset() -> None:
-    """Disarm and drop all buffered events / the phase profile."""
-    global _armed, _ring, _ring_pos, _dropped, _phase_profile
+    """Disarm and drop all buffered events and iteration records."""
+    global _armed, _ring, _ring_pos, _dropped
     with _lock:
         _armed = False
         _ring = []
         _ring_pos = 0
         _dropped = 0
-        _phase_profile = None
+        _iterations.clear()
 
 
 def _record(name: str, cat: str, t0_ns: int, dur_ns: int,
@@ -147,7 +176,10 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
+        self._finish(time.perf_counter_ns() - self.t0)
+        return False
+
+    def _finish(self, dur_ns: int) -> None:
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -157,8 +189,7 @@ class _Span:
             if tid is not None:
                 args = dict(args) if args else {}
                 args["trace_id"] = tid
-            _record(self.name, self.cat, self.t0, t1 - self.t0, args)
-        return False
+            _record(self.name, self.cat, self.t0, dur_ns, args)
 
 
 def span(name: str, cat: str = "app", args: Optional[dict] = None):
@@ -218,63 +249,102 @@ def current_trace_id() -> Optional[str]:
 
 
 # ---------------------------------------------------------------------------
-# estimated phase children (the attributed within-dispatch decomposition)
+# bridged spans (per-tree and coarser) and the always-on per-tree record
 # ---------------------------------------------------------------------------
 
-def set_phase_profile(parts: Optional[Dict[str, float]],
-                      rounds_per_iter: Optional[float] = None) -> None:
-    """Install the attributed per-iteration phase decomposition
-    (``{"hist": ms, "partition": ms, "split": ms, ...}``).  Iteration
-    spans emitted via :func:`iteration_span_end` then carry wave-round
-    and phase child spans proportional to these parts, flagged
-    ``estimated`` — the host cannot observe phases inside the jitted
-    while-loop, so the trace renders the same attribution that
-    ``tools/phase_attrib.py`` and the BENCH phase fields report."""
-    global _phase_profile
-    if parts is None:
-        _phase_profile = None
-        return
-    clean = {str(k): float(v) for k, v in parts.items() if v and v > 0}
-    _phase_profile = {
-        "parts": clean,
-        "rounds": max(float(rounds_per_iter or 0.0), 0.0),
-    } if clean else None
+class _BridgedSpan(_Span):
+    """A span that also lives in the JAX profiler's host lane (see the
+    module docstring).  ``dur_ns`` holds its time after exit."""
+
+    __slots__ = ("dur_ns", "_ann")
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **(self.args or {}))
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        self.dur_ns = time.perf_counter_ns() - self.t0
+        self._ann.__exit__(*exc)
+        self._finish(self.dur_ns)
+        if global_timer.enabled:
+            global_timer.totals[self.name] += self.dur_ns / 1e9
+            global_timer.counts[self.name] += 1
+        return False
 
 
-def phase_profile() -> Optional[Dict]:
-    return _phase_profile
+def bridged_span(name: str, cat: str = "app",
+                 args: Optional[dict] = None) -> _BridgedSpan:
+    """Context manager for a per-tree or coarser site: always a
+    ``jax.profiler.TraceAnnotation`` (dropped by the runtime while no
+    profiler session is open), a ring event when armed, a
+    ``global_timer`` section when that is enabled.  ``args`` become the
+    annotation's and the event's arguments."""
+    return _BridgedSpan(name, cat, args)
 
 
-def iteration_span_end(t0_ns: int, iteration: int,
-                       cat: str = "train") -> None:
-    """Record one training-iteration span ending NOW, plus the estimated
-    wave-round/phase children when a phase profile is installed."""
-    if not _armed:
-        return
-    t1 = time.perf_counter_ns()
-    _record("train.iteration", cat, t0_ns, t1 - t0_ns,
-            {"iteration": int(iteration)})
-    prof = _phase_profile
-    if not prof:
-        return
-    parts = prof["parts"]
-    total = sum(parts.values())
-    if total <= 0:
-        return
-    span_ns = t1 - t0_ns
-    n_rounds = int(round(prof["rounds"])) if prof["rounds"] >= 2 else 1
-    round_ns = span_ns // n_rounds
-    for r in range(n_rounds):
-        r0 = t0_ns + r * round_ns
-        if n_rounds > 1:
-            _record("wave.round", cat, r0, round_ns,
-                    {"round": r, "estimated": True})
-        cursor = r0
-        for name, ms in parts.items():
-            dur = int(round_ns * (ms / total))
-            _record(f"phase.{name}", cat, cursor, dur,
-                    {"estimated": True, "attributed_ms": ms})
-            cursor += dur
+class _IterationSpan(_BridgedSpan):
+    """``train.iteration``: the bridged span one tree runs under, which
+    its ``phase_span`` children add their time to."""
+
+    __slots__ = ("iteration", "phase_ns", "_outer")
+
+    def __init__(self, iteration: int):
+        super().__init__("train.iteration", "train",
+                         {"iteration": iteration})
+        self.iteration = iteration
+        self.phase_ns = dict.fromkeys(ITERATION_PHASES, 0)
+
+    def __enter__(self):
+        self._outer = getattr(_tls, "iteration", None)
+        _tls.iteration = self
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        _tls.iteration = self._outer
+        # the one place an iteration is written down, armed or not
+        _iterations.append((self.iteration, self.t0,
+                            *(self.phase_ns[p] for p in ITERATION_PHASES),
+                            self.dur_ns))
+        return False
+
+
+class _PhaseSpan(_BridgedSpan):
+    __slots__ = ("phase", "owner")
+
+    def __init__(self, phase: str, owner: Optional[_IterationSpan]):
+        super().__init__(
+            "train." + phase, "train",
+            None if owner is None else {"iteration": owner.iteration})
+        self.phase, self.owner = phase, owner
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        if self.owner is not None:
+            self.owner.phase_ns[self.phase] += self.dur_ns
+        return False
+
+
+def iteration_span(iteration: int) -> _IterationSpan:
+    """``train.iteration``: the enclosing span of one boosting iteration
+    (``Booster.update``); closing it writes the tree's record."""
+    return _IterationSpan(int(iteration))
+
+
+def phase_span(phase: str) -> _PhaseSpan:
+    """``train.<phase>`` (one of ``ITERATION_PHASES``): a bridged child of
+    the thread's open ``iteration_span``, carrying its ``iteration``; with
+    none open (a caller driving ``train_one_iter`` itself) it is a plain
+    bridged span."""
+    return _PhaseSpan(phase, getattr(_tls, "iteration", None))
+
+
+def iteration_records() -> List[tuple]:
+    """The last ``ITERATION_RING`` iterations, oldest first, each
+    ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns, wait_ns,
+    total_ns)`` on the ``perf_counter_ns`` clock."""
+    return list(_iterations)
 
 
 # ---------------------------------------------------------------------------

@@ -420,19 +420,21 @@ def ledger_agreement(ledger_peak_bytes: Optional[float],
 def start_profiler(out_dir: str) -> Dict[str, Any]:
     """Arm ``jax.profiler`` writing into ``out_dir`` and return the
     session dict (wall-clock anchor + identity).  The anchor is the wall
-    instant of ``start_trace`` — the device trace's ``ts=0`` epoch that
-    obs/agg.py rebases the lane with."""
+    instant ``start_trace`` RETURNS at (it sets the tracers up for
+    hundreds of ms on a TPU before the session's ``ts=0``) — the epoch
+    obs/agg.py rebases the lane with across processes; inside the
+    capturing process the program's own ``train.*`` spans are in the
+    profiler's host lane and need no anchor."""
     import jax
 
     from . import events as obs_events
 
     os.makedirs(str(out_dir), exist_ok=True)
-    session = {"profile_dir": str(out_dir),
-               "t0_unix_ns": time.time_ns(),
-               "identity": obs_events.identity(),
-               "_open": True}
     jax.profiler.start_trace(str(out_dir))
-    return session
+    return {"profile_dir": str(out_dir),
+            "t0_unix_ns": time.time_ns(),
+            "identity": obs_events.identity(),
+            "_open": True}
 
 
 def stop_profiler(session: Optional[Dict[str, Any]]) -> bool:

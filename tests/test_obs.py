@@ -187,31 +187,6 @@ def test_export_carries_wall_anchor_and_identity():
     assert other["role"] == ident["role"]
 
 
-def test_phase_profile_children_agree_with_attribution():
-    """Installed phase profile (the phase_attrib breakdown) => iteration
-    spans carry estimated wave-round/phase children whose durations
-    split the iteration proportionally to the attributed ms."""
-    trace.arm(ring_events=1024)
-    trace.set_phase_profile({"hist": 60.0, "split": 30.0, "other": 10.0},
-                            rounds_per_iter=3)
-    t0 = trace.now_ns()
-    time.sleep(0.01)
-    trace.iteration_span_end(t0, iteration=7)
-    evs = trace.export_chrome()["traceEvents"]
-    it = [e for e in evs if e["name"] == "train.iteration"]
-    rounds = [e for e in evs if e["name"] == "wave.round"]
-    phases = [e for e in evs if e["name"].startswith("phase.")]
-    assert len(it) == 1 and it[0]["args"]["iteration"] == 7
-    assert len(rounds) == 3 and all(e["args"]["estimated"] for e in rounds)
-    assert len(phases) == 9            # 3 phases per round
-    hist = sum(e["dur"] for e in phases if e["name"] == "phase.hist")
-    split = sum(e["dur"] for e in phases if e["name"] == "phase.split")
-    assert hist / split == pytest.approx(2.0, rel=0.05)   # 60:30
-    # children tile the iteration interval (within integer-division slack)
-    assert sum(e["dur"] for e in phases) <= it[0]["dur"] * 1.001
-    trace.set_phase_profile(None)
-    assert trace.phase_profile() is None
-
 
 def test_train_iteration_spans_and_registry(booster):
     """An armed tracer records one span per boosting iteration, and the

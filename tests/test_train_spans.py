@@ -1,0 +1,258 @@
+"""The boosting step and the binning seen from inside (PR 26).
+
+Device side: every op of an iteration falls under an ``lgbm.*`` named
+scope (HLO metadata only: a model trained with the scopes patched away is
+the same model).  Host side: the phases of ``train_one_iter`` are real
+spans that reach a ``jax.profiler`` trace started by anyone, with the
+tracer disarmed, and one always-on record a tree says where its time
+went.  Binning reports its phases to the registry.
+"""
+
+import contextlib
+import glob
+import gzip
+import json
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import lightgbmv1_tpu as lgb
+from lightgbmv1_tpu.obs import metrics as obs_metrics
+from lightgbmv1_tpu.obs import trace
+
+from conftest import make_binary_problem
+
+PHASES = ("train.prepare", "train.dispatch", "train.bookkeep", "train.wait")
+PARAMS = {"objective": "binary", "num_leaves": 15, "max_bin": 31,
+          "min_data_in_leaf": 5, "verbosity": -1}
+
+
+@pytest.fixture(autouse=True)
+def _tracer_clean():
+    trace.reset()
+    yield
+    trace.reset()
+
+
+def _booster(objective="binary", rows=1200, **over):
+    params = dict(PARAMS, objective=objective, **over)
+    X, y = make_binary_problem(rows, 6, seed=5)
+    extra = {}
+    if objective == "lambdarank":
+        y = np.random.RandomState(5).randint(0, 4, rows).astype(float)
+        extra["group"] = [40] * (rows // 40)
+    ds = lgb.Dataset(X, label=y, params=dict(params), **extra).construct()
+    return lgb.Booster(params=dict(params), train_set=ds)
+
+
+# ---------------------------------------------------------------------------
+# host: spans on the profiler's clock, the always-on record, the ring
+# ---------------------------------------------------------------------------
+
+
+def test_spans_reach_a_profiler_trace_with_the_tracer_disarmed(tmp_path):
+    """Two ``update()`` calls under a ``jax.profiler`` session nobody told
+    the program about: the four phases are in the profiler's host lane,
+    twice each, inside the two ``train.iteration`` intervals, carrying
+    the tree's ``iteration``."""
+    b = _booster()
+    b.update()                               # compile outside the capture
+    assert not trace.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        b.update()
+        b.update()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.trace.json.gz"),
+                      recursive=True)
+    with gzip.open(path, "rt") as fh:
+        events = [e for e in json.load(fh)["traceEvents"]
+                  if e.get("ph") == "X"
+                  and str(e.get("name", "")).startswith("train.")]
+    iters = sorted((e for e in events if e["name"] == "train.iteration"),
+                   key=lambda e: e["ts"])
+    assert [int(e["args"]["iteration"]) for e in iters] == [1, 2]
+    for name in PHASES:
+        mine = sorted((e for e in events if e["name"] == name),
+                      key=lambda e: e["ts"])
+        assert len(mine) == 2, (name, len(mine))
+        for e, it in zip(mine, iters):
+            assert int(e["args"]["iteration"]) == \
+                int(it["args"]["iteration"])
+            assert it["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= it["ts"] + it["dur"] + 1.0  # us
+    assert trace.drain()["events"] == []     # nothing armed the ring
+
+
+def test_one_record_a_tree_with_the_tracer_disarmed():
+    b = _booster(rows=20000)
+    b.update()
+    trace.reset()
+    for _ in range(5):
+        b.update()
+    recs = trace.iteration_records()
+    assert [r[0] for r in recs] == [1, 2, 3, 4, 5]
+    covered = []
+    for it, t0, prepare, dispatch, bookkeep, wait, total in recs:
+        assert min(prepare, dispatch, bookkeep, wait) > 0
+        assert prepare + dispatch + bookkeep + wait <= total
+        covered.append((prepare + dispatch + bookkeep + wait) / total)
+    # the phases are the iteration but for a few span exits: within 5%
+    # (the median, so that one descheduled tree of a loaded test box
+    # does not fail it)
+    assert sorted(covered)[len(covered) // 2] >= 0.95, covered
+    assert [r[1] for r in recs] == sorted(r[1] for r in recs)
+
+
+def test_iteration_ring_is_bounded():
+    for i in range(trace.ITERATION_RING + 150):
+        with trace.iteration_span(i):
+            pass
+    recs = trace.iteration_records()
+    assert len(recs) == trace.ITERATION_RING
+    assert recs[0][0] == 150 and recs[-1][0] == trace.ITERATION_RING + 149
+
+
+def test_armed_spans_nest_under_the_iteration_and_export():
+    b = _booster()
+    b.update()
+    trace.arm(ring_events=4096)
+    b.update()
+    b.update()
+    evs = [e for e in trace.export_chrome()["traceEvents"]
+           if e.get("ph") == "X"]
+    iters = [e for e in evs if e["name"] == "train.iteration"]
+    assert [e["args"]["iteration"] for e in iters] == [1, 2]
+    for it in iters:
+        kids = [e for e in evs if e["name"] in PHASES
+                and e["args"]["iteration"] == it["args"]["iteration"]]
+        assert sorted(e["name"] for e in kids) == sorted(PHASES)
+        for e in kids:
+            assert it["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= it["ts"] + it["dur"] + 1e-3
+            assert e["tid"] == it["tid"]
+        assert sum(e["dur"] for e in kids) <= it["dur"]
+
+
+def test_scanned_block_is_one_span_with_its_phases():
+    b = _booster()
+    trace.arm(ring_events=1024)
+    b._gbdt.train_iters(3)
+    evs = [e for e in trace.export_chrome()["traceEvents"]
+           if e.get("ph") == "X"]
+    block, = [e for e in evs if e["name"] == "train.iterations"]
+    assert block["args"] == {"n": 3, "start_iter": 0}
+    assert sorted(e["name"] for e in evs if e["name"] in PHASES) == \
+        ["train.bookkeep", "train.dispatch", "train.prepare"]
+    assert trace.iteration_records() == []   # a block is not a tree
+
+
+def test_bridged_span_is_cheap_with_no_profiler_session():
+    """Per-tree sites pay for a ``TraceAnnotation`` that nobody reads:
+    a few microseconds; the ceiling keeps a later edit from making it
+    dear unnoticed (a 724 ms tree opens five)."""
+    n = 2000
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter_ns()
+        for _ in range(n):
+            with trace.phase_span("dispatch"):
+                pass
+        best = min(best, (time.perf_counter_ns() - t0) / n)
+    assert best < 20_000, f"{best:.0f} ns a span"
+
+
+# ---------------------------------------------------------------------------
+# device: named scopes, and nothing but metadata
+# ---------------------------------------------------------------------------
+
+
+def _name_stacks(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        out.add(str(eqn.source_info.name_stack))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _name_stacks(inner, out)
+    return out
+
+
+STEP_SCOPES = ("lgbm.objective", "lgbm.sample", "lgbm.score")
+GROWER_SCOPES = ("lgbm.select", "lgbm.hist", "lgbm.split",
+                 "lgbm.partition")
+
+
+@pytest.mark.parametrize("objective", ["binary", "lambdarank"])
+@pytest.mark.parametrize("path", ["fused", "host_loop", "dart"])
+def test_every_phase_of_an_iteration_is_a_named_scope(path, objective):
+    """One iteration traced as a whole: the step's own phases and the
+    grower's are all in the jaxpr's name stacks, on the fused step, on
+    the host loop and on DART's step with trees dropped."""
+    over = {}
+    if path == "dart":
+        over = dict(boosting="dart", drop_rate=1.0, skip_drop=0.0)
+    b = _booster(objective, **over)
+    b.update()
+    g = b._gbdt
+    if path == "host_loop":
+        g._supports_fused_step = lambda: False
+    jaxpr = jax.make_jaxpr(
+        lambda: g.train_one_iter(check_stop=False))()
+    stacks = _name_stacks(jaxpr.jaxpr, set())
+    for scope in STEP_SCOPES + GROWER_SCOPES:
+        assert any(scope in s.replace("(", "/").replace(")", "/").split("/")
+                   for s in stacks), (scope, path, objective)
+
+
+def test_scopes_are_metadata_only(monkeypatch):
+    """The same data and parameters with ``jax.named_scope`` patched to a
+    no-op give the same model, to the last digit of its text."""
+    def train():
+        b = _booster("lambdarank")
+        for _ in range(4):
+            b.update()
+        return b.model_to_string()
+
+    scoped = train()
+    calls = []
+
+    def no_scope(name):
+        calls.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    bare = train()
+    assert {"lgbm.objective", "lgbm.sample", "lgbm.score",
+            "lgbm.select"} <= set(calls)
+    assert bare == scoped
+
+
+# ---------------------------------------------------------------------------
+# binning from inside
+# ---------------------------------------------------------------------------
+
+
+def test_dataset_construct_seconds_has_every_phase():
+    reg = obs_metrics.default_registry()
+    key = 'dataset_construct_seconds{phase="%s"}'
+    phases = ("convert", "sample", "find_bins", "apply_bins", "bundle")
+    X, y = make_binary_problem(40000, 12, seed=2)
+    X = X.astype(np.float32)
+    lgb.Dataset(X[:200], label=y[:200]).construct()   # first-use imports
+    before = reg.snapshot()
+    t0 = time.perf_counter()
+    ds = lgb.Dataset(X, label=y, params={"max_bin": 63})
+    ds.construct()
+    wall = time.perf_counter() - t0
+    after = reg.snapshot()
+    spent = {p: after[key % p] - before.get(key % p, 0) for p in phases}
+    assert all(v > 0 for v in spent.values()), spent
+    assert sum(spent.values()) == pytest.approx(wall, rel=0.10)
+    # the booster's placement of the bins is a phase of its own
+    lgb.Booster(params=dict(PARAMS), train_set=ds)
+    assert reg.snapshot()[key % "place"] > 0

@@ -132,10 +132,6 @@ def profiled_window(out_dir: str, rows: int = 4096, iters: int = 3) -> dict:
               "verbosity": -1, "seed": 5}
     obs_trace.reset()
     obs_trace.arm(ring_events=1 << 15)
-    # a nominal profile so iteration spans carry estimated phase
-    # children for the device-row reconciliation to grade
-    obs_trace.set_phase_profile(
-        {"hist": 1.0, "partition": 0.5, "split": 0.3}, 4.0)
     try:
         with obs_xla.profiler_session(prof_dir):
             ds = lgb.Dataset(X, label=y, params=dict(params))
@@ -176,8 +172,7 @@ def validate_merged_trace(path: str) -> dict:
     if not n_complete:
         raise ValueError("merged trace: no complete events")
     return {"events": n_complete, "lanes": len(lanes),
-            "sources": len(other["sources"]),
-            "phase_agreement": other.get("phase_agreement") or {}}
+            "sources": len(other["sources"])}
 
 
 def run_capture(records_dir: str = ROOT, out_dir: str = None,
@@ -247,7 +242,6 @@ def run_capture(records_dir: str = ROOT, out_dir: str = None,
         summary["merged_trace_error"] = str(e)
         trace_ok = False
     summary["device_lanes"] = agg_summary.get("device_lanes", 0)
-    summary["phase_agreement"] = agg_summary.get("phase_agreement") or {}
 
     # 4. emit the records in the captured format
     def write_record(name: str, stage: dict) -> str:
