@@ -33,6 +33,7 @@ from ..metrics import Metric, create_metrics
 from ..objectives import ObjectiveFunction, create_objective
 from ..obs import trace as obs_trace
 from ..obs import xla as obs_xla
+from ..ops.hist_pallas import bin_matrix
 from ..ops.split import SplitParams, make_feature_meta
 from ..utils.log import log_fatal, log_info, log_warning
 from ..utils.timer import global_timer
@@ -1487,7 +1488,8 @@ class DART(GBDT):
                 if use_lids:
                     preds = jax.vmap(leaf_lookup)(drop_lv, drop_lids)  # (P, N)
                 else:
-                    preds = jax.vmap(lambda t: pred_with(t, binned))(drop_stack)
+                    preds = jax.vmap(lambda t: pred_with(
+                        t, bin_matrix(binned)))(drop_stack)
                 drop_delta = preds.T @ drop_weight                   # (N, K)
                 s_drop = train_score - drop_delta
                 v_drops, v_deltas = [], []

@@ -38,6 +38,7 @@ import numpy as np
 from jax import lax
 
 from ..io.binning import MISSING_NAN, MISSING_ZERO
+from ..ops.hist_pallas import bin_matrix
 from ..ops.split import (
     NO_CONSTRAINT,
     FeatureMeta,
@@ -317,7 +318,11 @@ def make_leafwise_grower(
                              leaf_id)
 
     def grow(binned, g3, base_mask, key, cegb_used=None):
-        N = binned.shape[1]
+        # ``binned`` may arrive prepared for the histogram kernel
+        # (hist_pallas.HistBins): whole-matrix passes take it as it is,
+        # decisions and the segment gather read its (F, N) matrix
+        bins = bin_matrix(binned)
+        N = bins.shape[1]
         F = base_mask.shape[0]    # ORIGINAL features (binned may be the
                                   # narrower EFB bundle matrix)
         B = num_bins
@@ -352,7 +357,7 @@ def make_leafwise_grower(
                                   iscat, bitset):
                 """Stable two-way partition of one leaf's segment
                 (reference DataPartition::Split, data_partition.hpp:101)."""
-                bins_row = bins_of_fn(binned, feat)        # (N,) orig bins
+                bins_row = bins_of_fn(bins, feat)          # (N,) orig bins
 
                 def make_branch(CAP):
                     def br(op):
@@ -403,7 +408,7 @@ def make_leafwise_grower(
                         order, s_begin, n_s = op
                         rows = lax.dynamic_slice(order, (s_begin,), (CAP,))
                         in_seg = jnp.arange(CAP) < n_s
-                        bins_sub = jnp.take(binned, rows, axis=1,
+                        bins_sub = jnp.take(bins, rows, axis=1,
                                             mode="fill", fill_value=0)
                         g3_sub = jnp.take(g3, rows, axis=0, mode="fill",
                                           fill_value=0.0)
@@ -543,7 +548,7 @@ def make_leafwise_grower(
                     leaf_id = st.leaf_id      # reconstructed once at the end
                 else:
                     order2, n_l_phys = st.order, jnp.asarray(0, jnp.int32)
-                    leaf_id = apply_decision(binned, st.leaf_id, leaf, nl,
+                    leaf_id = apply_decision(bins, st.leaf_id, leaf, nl,
                                              feat, thr, dl, iscat, bitset)
 
                 # monotone constraint propagation (reference:
@@ -851,7 +856,8 @@ def make_levelwise_grower(
         return jnp.clip(out, constr[:, 0], constr[:, 1])
 
     def grow(binned, g3, base_mask, key, cegb_used=None):
-        N = binned.shape[1]
+        bins = bin_matrix(binned)     # see the leaf-wise grower's note
+        N = bins.shape[1]
         F = base_mask.shape[0]    # ORIGINAL features (EFB: binned narrower)
         if cegb_used is None:
             cegb_used = jnp.zeros(F, bool)
@@ -1021,7 +1027,7 @@ def make_levelwise_grower(
             for c0 in range(0, Ld, _LEVEL_CHUNK):
                 c1 = min(c0 + _LEVEL_CHUNK, Ld)
                 fk = feat_k[c0:c1]
-                bk = jax.vmap(lambda f: bins_of_fn(binned, f))(fk) \
+                bk = jax.vmap(lambda f: bins_of_fn(bins, f))(fk) \
                     .astype(jnp.int32)                       # (<=C, N)
                 mt_k = meta.missing_type[fk][:, None]
                 na_k = ((mt_k == MISSING_NAN)

@@ -99,6 +99,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..ops.hist_pallas import bin_matrix
 from ..ops.split import (
     NO_CONSTRAINT,
     FeatureMeta,
@@ -838,7 +839,11 @@ def make_wave_grower(
                                  use_mc=use_mc)
 
     def grow(binned, g3, base_mask, key, cegb_used=None, valids=()):
-        N = binned.shape[1]
+        # ``binned`` may arrive prepared for the histogram kernel
+        # (hist_pallas.HistBins): the passes take it as it is, everything
+        # else reads its (F, N) matrix
+        bins = bin_matrix(binned)
+        N = bins.shape[1]
         F = base_mask.shape[0]    # ORIGINAL feature count (binned may be
                                   # the narrower EFB bundle matrix)
         del cegb_used  # CEGB routes to the sequential grower (order-exact)
@@ -1280,7 +1285,7 @@ def make_wave_grower(
                             for vb, vl in zip(valids, st.valid_lids)]
                 else:
                     with jax.named_scope("lgbm.partition"):
-                        gl = go_left_s(binned)                # (S, N)
+                        gl = go_left_s(bins)                  # (S, N)
                         mine = st.leaf_id[None, :] == leafs_s[:, None]
                         go_r = mine & (~gl)                   # disjoint rows
                         leaf_id = st.leaf_id + jnp.sum(
@@ -1350,7 +1355,7 @@ def make_wave_grower(
                                      leafs=leafs_s, nls=nls_s,
                                      num_leaves=L)
                     fr_out = fused_round_fn(
-                        binned, g3, label, S, deep=deep,
+                        bins, g3, label, S, deep=deep,
                         quant_key=rkey if S in quant_buckets else None,
                         scaled=bool(quant_buckets),
                         mask=to_cslot(cmask, False),
@@ -1581,7 +1586,7 @@ def make_wave_grower(
                 rows_all["pdepth"].astype(jnp.float32)[:, None]], axis=1)
             with jax.named_scope("lgbm.fused_loop"):
                 packed_R, leaf_id_new, pool_new = fused_loop_fn(
-                    binned, g3, st.leaf_id, ft12, st.num_leaves, key,
+                    bins, g3, st.leaf_id, ft12, st.num_leaves, key,
                     K=K, slot_buckets=slot_buckets,
                     quant_buckets=quant_buckets, max_depth=max_depth,
                     base_mask=base_mask,

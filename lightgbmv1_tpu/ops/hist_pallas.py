@@ -40,12 +40,27 @@ TPUs have no atomics; the design maps the OpenCL structure onto the MXU:
     - ``bf16x2``— hi/lo-split bf16, ~fp32 accuracy at 2 MXU passes.
     - ``f32``   — exact; used by tests/CPU.
 
-HBM traffic per pass ≈ bins (N·F bytes) + g3 + leaf_id — nothing else.
+The bin operand — per feature block a row-major ``u8[n_pad, tile_cols]``
+array — is made by ONE function, ``prepare_hist_bins``.  A learner calls it
+once at placement and hands ``hist_leaves_pallas`` the result (``HistBins``);
+a caller that hands over the raw ``(F, N)`` matrix gets the same layout made
+inside the pass, every pass (pad + transposition + one slice per block: 7-9x
+the bins in temporaries).  With a prepared operand the HBM traffic of a pass
+is the blocks + g3 + leaf_id and nothing else.  A TPU tiles a ``u8`` array
+``T(8,128)(4,1)``, so a block narrower than 128 byte columns occupies 128
+lanes a row whatever its shape says: the 32-column blocks of a 64-bin pass
+read 4x the bins' own bytes.  ``HistBins`` stores each block AT that width
+(pad columns included) so that the array's default device layout is the
+row-major one the kernel's call takes — a tall ``u8[n, 32]`` array would be
+stored column-major and copied in every pass — and the pass's column slice
+back to ``tile_cols`` is a bitcast there.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +70,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 MAX_LANES = 2048          # lanes per one-hot block: FBLK * num_bins
+MAX_ROW_TILE = 1024       # the largest row tile _row_tile_for returns: rows
+                          # padded to it serve every slot bucket's tile
+_LANES = 128              # byte columns a stored u8 row occupies at least
 _COUNT_SCALE = 64.0       # power-of-two count quantizer => exact counts
 
 
@@ -88,8 +106,8 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     default-policy precisions, packed4) compiles under it."""
     out_bytes = m_pad * num_lanes * 4
     per_row = 14 * min(num_lanes, 512) + 16 * m_pad
-    t0 = 1024 if kernel_width(num_bins) <= 64 else 512
-    for t in (1024, 512, 256, 128):
+    t0 = MAX_ROW_TILE if kernel_width(num_bins) <= 64 else 512
+    for t in (MAX_ROW_TILE, 512, 256, 128):
         if t <= t0 and out_bytes + t * per_row <= 8 * 2**20:
             return t
     return 128
@@ -267,13 +285,111 @@ def packed_bins_of_rows(binned, f_row):
     return (byte >> (4 * (f_row & 1))) & 15
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["matrix", "blocks"],
+                   meta_fields=["tile_cols"])
+@dataclasses.dataclass(frozen=True)
+class HistBins:
+    """The kernel's bin operand, made once for a dataset by
+    ``prepare_hist_bins``: ``blocks[fb][:, :tile_cols]`` is feature block
+    ``fb`` as a row-major ``u8[n_pad, tile_cols]`` array, the operand of that
+    block's ``pallas_call``; the columns beyond, up to a multiple of 128,
+    are the lane padding the device would add anyway (module docstring).
+    ``matrix`` is the untouched ``(F, N)`` matrix (packed:
+    ``(ceil(F/2), N)``) the blocks were cut from, which everything but the
+    histogram pass (partition decisions, tree walks) keeps reading.  A
+    pytree of arrays; ``tile_cols`` is static."""
+
+    matrix: jax.Array
+    blocks: Tuple[jax.Array, ...]
+    tile_cols: int
+
+
+def bin_matrix(binned) -> jax.Array:
+    """The ``(F, N)`` bin matrix of a ``binned`` argument, prepared or raw."""
+    return binned.matrix if isinstance(binned, HistBins) else binned
+
+
+def _feature_blocks(stored_rows: int, num_bins: int, packed: bool):
+    """``(fblk, tile_cols, nfb)`` of a matrix with ``stored_rows`` feature
+    rows: features per one-hot block, byte columns per block, and the
+    number of blocks.  Packed, ``fblk`` counts UNPACKED features and
+    must be even (each byte column contributes its lo and hi nibble
+    feature)."""
+    if packed:
+        fblk = max(2, min(2 * stored_rows, MAX_LANES // num_bins) & ~1)
+        tile_cols = fblk // 2
+    else:
+        fblk = max(1, min(stored_rows, MAX_LANES // num_bins))
+        tile_cols = fblk
+    return fblk, tile_cols, -(-stored_rows // tile_cols)
+
+
+def prepared_bins_bytes(stored_rows: int, num_rows: int, num_bins: int,
+                        packed: bool = False) -> int:
+    """Bytes of the blocks ``prepare_hist_bins`` makes of such a matrix."""
+    _, tile_cols, nfb = _feature_blocks(stored_rows, num_bins, packed)
+    n_pad = -(-num_rows // MAX_ROW_TILE) * MAX_ROW_TILE
+    return nfb * n_pad * (-(-tile_cols // _LANES) * _LANES)
+
+
+def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
+                      row_tile: int = MAX_ROW_TILE,
+                      resident: bool = True) -> HistBins:
+    """The ONLY place the kernel's bin layout is made: ``(F, N)`` uint8
+    bins (packed: ``(ceil(F/2), N)``) -> ``HistBins``.
+
+    Rows are padded to a multiple of ``row_tile`` — by default the largest
+    tile ``_row_tile_for`` returns, so one layout serves every slot
+    bucket's tile; padded rows carry zero g3, so a pass whose tile is
+    smaller adds zeros from the extra all-padding tiles and its sums stay
+    bit-identical.  Padded features get bin 255 (matches no b < 256 when
+    B < 256; for B == 256 they land in bin 255 of a feature the caller
+    slices away); packed pad bytes are 0 -> phantom features collect bin 0
+    and are dropped by the caller's permutation.
+
+    ``resident`` blocks are what a learner keeps on the device: stored at
+    lane width (module docstring; the padding columns are never read).
+    ``hist_leaves_pallas`` passes False where it was handed the raw matrix
+    and makes the layout inside the pass, consumed at once at its own
+    width.  Traceable; each trace counts in ``hist_bins_layout_total``
+    under ``site="placement"`` (resident) or ``"pass"``."""
+    if binned.dtype not in (jnp.uint8, np.uint8):
+        raise ValueError(
+            "hist_leaves_pallas requires uint8 bins (num_bins <= 256); "
+            "route int16-binned data to the onehot/scatter path")
+    if packed and num_bins > 16:
+        raise ValueError("packed (4-bit) bins require num_bins <= 16")
+    from ..obs.metrics import default_registry
+
+    default_registry().counter(
+        "hist_bins_layout_total",
+        "Traces that lay the histogram kernel's bin operand out, by site",
+        label_names=("site",)).labels(
+            site="placement" if resident else "pass").inc()
+    stored, N = binned.shape
+    _, tile_cols, nfb = _feature_blocks(stored, num_bins, packed)
+    n_pad = -(-N // row_tile) * row_tile
+    fill = 0 if packed else 255
+    binned_rm = jnp.pad(
+        binned, ((0, nfb * tile_cols - stored), (0, n_pad - N)),
+        constant_values=fill).T                     # (n_pad, nfb*tile_cols)
+    blocks = [binned_rm[:, fb * tile_cols:(fb + 1) * tile_cols]
+              for fb in range(nfb)]
+    if resident and tile_cols % _LANES:
+        blocks = [jnp.pad(b, ((0, 0), (0, -tile_cols % _LANES)),
+                          constant_values=fill) for b in blocks]
+    return HistBins(binned, tuple(blocks), tile_cols)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("num_leaves", "num_bins", "precision", "row_tile",
                      "interpret", "packed", "num_features"),
 )
 def hist_leaves_pallas(
-    binned: jax.Array,      # (F, N) uint8; packed: (ceil(F/2), N)
+    binned,                 # HistBins, or the raw (F, N) uint8 matrix
+                            # (packed: (ceil(F/2), N)) laid out in the pass
     g3: jax.Array,          # (N, 3) f32
     leaf_id: jax.Array,     # (N,) int32
     num_leaves: int,
@@ -285,46 +401,28 @@ def hist_leaves_pallas(
     num_features: int = 0,  # REAL feature count when packed (else derived)
 ) -> jax.Array:             # (L, F, B, 3) f32
     L, B = num_leaves, num_bins
-    if binned.dtype not in (jnp.uint8, np.uint8):
-        raise ValueError(
-            "hist_leaves_pallas requires uint8 bins (num_bins <= 256); "
-            "route int16-binned data to the onehot/scatter path")
-    if packed:
-        if B > 16:
-            raise ValueError("packed (4-bit) bins require num_bins <= 16")
-        Fp, N = binned.shape
-        F = num_features or 2 * Fp
-    else:
-        F, N = binned.shape
-
-    if packed:
-        # fblk counts UNPACKED features and must be even (each byte column
-        # contributes its lo and hi nibble feature)
-        fblk = max(2, min(2 * Fp, MAX_LANES // B) & ~1)
-        fpb = fblk // 2                      # packed byte columns per block
-        nfb = -(-Fp // fpb)
-        f_pad = nfb * fblk
-    else:
-        fblk = max(1, min(F, MAX_LANES // B))
-        nfb = -(-F // fblk)
-        f_pad = nfb * fblk
+    stored, N = bin_matrix(binned).shape
+    F = (num_features or 2 * stored) if packed else stored
+    fblk, tile_cols, nfb = _feature_blocks(stored, B, packed)
+    f_pad = nfb * fblk
     lpad = -(-L // 8) * 8
     m_pad = 3 * lpad
     T = row_tile if row_tile > 0 else _row_tile_for(m_pad, fblk * B, B)
-    nrt = -(-N // T)
-    n_pad = nrt * T
 
-    # row-major bins; padded features get bin 255 (matches no b < 256 when
-    # B < 256; for B == 256 padded features land in bin 255 of a feature
-    # that is sliced away below; packed pad bytes are 0 -> phantom features
-    # collect bin 0 and are dropped by the permutation below). padded rows
-    # carry zero g3 => no effect.
-    tile_cols = fpb if packed else fblk      # stored byte columns per block
-    stored_pad = nfb * tile_cols
-    binned_rm = jnp.pad(
-        binned,
-        ((0, stored_pad - binned.shape[0]), (0, n_pad - N)),
-        constant_values=0 if packed else 255).T     # (n_pad, stored_pad)
+    if not isinstance(binned, HistBins):
+        binned = prepare_hist_bins(binned, B, packed, row_tile=T,
+                                   resident=False)
+    n_pad = binned.blocks[0].shape[0]
+    if (n_pad < N or n_pad % T or len(binned.blocks) != nfb
+            or binned.tile_cols != tile_cols):
+        raise ValueError(
+            f"prepared bins ({len(binned.blocks)} blocks of "
+            f"{binned.tile_cols} columns, {n_pad} rows) do not fit this "
+            f"pass ({nfb} blocks of {tile_cols} columns, {N} rows in tiles "
+            f"of {T}): prepare them with the pass's num_bins / packed")
+    nrt = n_pad // T
+
+    # padded rows carry zero g3 => no effect
     g3t = jnp.pad(g3.astype(jnp.float32), ((0, n_pad - N), (0, 0))).T  # (3, n_pad)
     leaf_p = jnp.pad(leaf_id.astype(jnp.int32), (0, n_pad - N),
                      constant_values=lpad)[None, :]      # (1, n_pad)
@@ -356,8 +454,7 @@ def hist_leaves_pallas(
             interpret=interpret,
         )(iota_bins, bins_block, g3t, leaf_p)
 
-    blocks = [one_block(binned_rm[:, fb * tile_cols:(fb + 1) * tile_cols])
-              for fb in range(nfb)]
+    blocks = [one_block(b[:, :tile_cols]) for b in binned.blocks]
     out = jnp.concatenate(blocks, axis=0) if nfb > 1 else blocks[0]
 
     # (nfb, 3*Lpad, B*fblk) -> (L, F, B, 3)
@@ -369,7 +466,7 @@ def hist_leaves_pallas(
         perm = np.empty(f_pad, np.int64)
         pos = 0
         for fb in range(nfb):
-            ps = np.arange(fb * fpb, (fb + 1) * fpb)
+            ps = np.arange(fb * tile_cols, (fb + 1) * tile_cols)
             perm[pos:pos + fblk] = np.concatenate([2 * ps, 2 * ps + 1])
             pos += fblk
         inv = np.argsort(perm)

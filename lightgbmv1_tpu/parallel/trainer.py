@@ -54,6 +54,7 @@ over ICI/DCN. Multi-host scaling initializes ``jax.distributed`` through
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Callable, Optional, Tuple
 
@@ -314,6 +315,51 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
              "per byte, the (F, N) binned read and the streaming cache "
              "shards halve (ops/hist_pallas.pack4bit)")
     return "packed4"
+
+
+# share of the device's memory (``bytes_limit``) the prepared histogram
+# operands may hold resident; over it the passes lay the bins out on the fly
+_HIST_BINS_SHARE = 0.25
+
+
+def _hist_bins_budget() -> Optional[int]:
+    """Bytes the prepared histogram operands may occupy on this process's
+    first device; ``None`` where the backend reports no limit (XLA:CPU)."""
+    stats = obs_xla.device_memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        return None
+    return int(stats["bytes_limit"] * _HIST_BINS_SHARE)
+
+
+def _place_hist_bins(binned_dev: jax.Array, num_bins: int, packed: bool):
+    """Lay the placed bins out for the histogram kernel ONCE
+    (ops/hist_pallas.prepare_hist_bins, one jitted call on the device), or
+    hand the matrix back where the operands would not fit the budget: the
+    kernel tells the two apart by type and lays a raw matrix out in every
+    pass."""
+    from ..io.dataset import construct_phase
+    from ..obs.metrics import default_registry
+    from ..ops.hist_pallas import prepare_hist_bins, prepared_bins_bytes
+
+    need = prepared_bins_bytes(binned_dev.shape[0], binned_dev.shape[1],
+                               num_bins, packed)
+    budget = _hist_bins_budget()
+    prepared = budget is None or need <= budget
+    default_registry().gauge(
+        "hist_bins_prepared_bytes",
+        "Device bytes of the histogram kernel's prepared bin operands "
+        "(0: the passes lay the bins out on the fly)"
+    ).set(need if prepared else 0)
+    if not prepared:
+        log_info(f"histogram bins stay raw: the prepared operands "
+                 f"({need >> 20} MiB) exceed {_HIST_BINS_SHARE:.0%} of "
+                 f"device memory ({budget >> 20} MiB)")
+        return binned_dev
+    with construct_phase("layout"):
+        # only the blocks leave the jit: the matrix stays the placed buffer
+        made = jax.jit(lambda b: dataclasses.replace(
+            prepare_hist_bins(b, num_bins, packed), matrix=None))(binned_dev)
+    return dataclasses.replace(made, matrix=binned_dev)
 
 
 def build_trainer(
@@ -735,8 +781,13 @@ def build_trainer(
                  else "grow.fused_round" if fused_builder is not None
                  else "grow.serial")   # gates above null the builder
                                        # whenever a non-wave grower runs
-        return obs_xla.instrument_jit(grow, label), \
-            jnp.asarray(binned_np), N
+        binned_dev = jnp.asarray(binned_np)
+        # the wave rounds of an engaged fused kernel lay the rows out
+        # themselves (ops/wave_fused.py): only the staged passes read
+        # prepared bins
+        if method == "pallas" and fused_builder is None:
+            binned_dev = _place_hist_bins(binned_dev, Bh, packed)
+        return obs_xla.instrument_jit(grow, label), binned_dev, N
 
     if learner == "voting" and levelwise:
         log_warning("tree_learner=voting requires the leaf-wise grower; "

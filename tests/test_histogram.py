@@ -216,3 +216,92 @@ def test_hist_method_bench_end_to_end():
     from sklearn_free_auc import auc_score
 
     assert auc_score(y, p) > 0.95
+
+
+# ---------------------------------------------------------------------------
+# prepared bins (hist_pallas.HistBins): the kernel's operand laid out once
+# ---------------------------------------------------------------------------
+
+# (num_bins, packed, F): F leaves the last feature block part-filled
+# (blocks of 128 / 32 / 8 features at 16 / 64 / 256 bins)
+_PREPARED_SHAPES = [(16, False, 130), (16, True, 131), (64, False, 37),
+                    (256, False, 11)]
+
+
+@pytest.mark.parametrize("precision", ["bf16x2", "bf16", "int8sr"])
+@pytest.mark.parametrize("slots", [1, 5, 17, 64])
+@pytest.mark.parametrize("num_bins,packed,F", _PREPARED_SHAPES)
+def test_prepared_bins_bit_identical(num_bins, packed, F, slots, precision):
+    """A prepared operand and the raw matrix give the SAME bits: the layout
+    is the same function run once instead of in the pass, and the rows it
+    pads beyond the pass's own tile carry zero g3.  N is no multiple of
+    1024, so the 64-slot pass (512-row tiles) sees one all-padding tile
+    more through the prepared operand than through the raw matrix."""
+    from lightgbmv1_tpu.ops.hist_pallas import (HistBins, MAX_ROW_TILE,
+                                                hist_leaves_pallas, pack4bit,
+                                                prepare_hist_bins)
+
+    rng = np.random.RandomState(num_bins + slots)
+    N = 1500
+    bins = rng.randint(0, num_bins, size=(F, N)).astype(np.uint8)
+    matrix = jnp.asarray(pack4bit(bins) if packed else bins)
+    if precision == "int8sr":       # rows arrive pre-quantized
+        g3 = rng.randint(-127, 128, size=(N, 3)).astype(np.float32)
+    else:
+        g3 = rng.randn(N, 3).astype(np.float32)
+    leaf = jnp.asarray(rng.randint(0, slots, N).astype(np.int32))
+    kw = dict(precision=precision, interpret=_PALLAS_INTERPRET,
+              packed=packed, num_features=F)
+    prepared = prepare_hist_bins(matrix, num_bins, packed)
+    assert isinstance(prepared, HistBins) and prepared.matrix is matrix
+    assert all(b.shape == (2 * MAX_ROW_TILE, 128) for b in prepared.blocks)
+    raw = hist_leaves_pallas(matrix, jnp.asarray(g3), leaf, slots, num_bins,
+                             **kw)
+    got = hist_leaves_pallas(prepared, jnp.asarray(g3), leaf, slots,
+                             num_bins, **kw)
+    assert raw.shape == (slots, F, num_bins, 3)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(raw))
+
+
+def test_prepared_bins_refuse_another_pass(rng):
+    """Bins prepared for one kernel width do not fit a pass of another."""
+    from lightgbmv1_tpu.ops.hist_pallas import (hist_leaves_pallas,
+                                                prepare_hist_bins)
+
+    binned, g3, leaf_id = make_inputs(rng, N=300, F=40, B=16, L=2)
+    prepared = prepare_hist_bins(binned, 64)       # blocks of 32 features
+    with pytest.raises(ValueError, match="do not fit this pass"):
+        hist_leaves_pallas(prepared, g3, leaf_id, 2, 16,
+                           interpret=_PALLAS_INTERPRET)
+
+
+@pytest.mark.parametrize("grower,params", [
+    ("wave", {"num_leaves": 15}),
+    ("levelwise", {"num_leaves": 15, "tree_growth": "levelwise"}),
+    ("leafwise", {"num_leaves": 7}),      # auto wave size 1: sequential
+])
+def test_prepared_bins_train_same_model(monkeypatch, grower, params):
+    """Boosters on the prepared operand (the serial learner's placement)
+    dump the same model text as on the parent's path, where every pass
+    lays the raw matrix out: forced here through the bytes rule."""
+    import lightgbmv1_tpu as lgb
+    from lightgbmv1_tpu.ops.hist_pallas import HistBins
+    from lightgbmv1_tpu.parallel import trainer
+
+    rng = np.random.RandomState(3)
+    X = rng.randn(1300, 9)
+    y = (X[:, 0] - 0.7 * X[:, 1] + 0.3 * rng.randn(1300) > 0).astype(float)
+    p = {"objective": "binary", "verbosity": -1, "seed": 11,
+         "hist_method": "pallas", "min_data_in_leaf": 5, **params}
+
+    def train():
+        bst = lgb.train(p, lgb.Dataset(X, label=y), num_boost_round=3)
+        return bst, bst.model_to_string()
+
+    a, text_prepared = train()
+    assert isinstance(a._gbdt._grow_binned, HistBins)
+    assert a._gbdt.binned.shape == (9, 1300)
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 0)
+    b, text_raw = train()
+    assert not isinstance(b._gbdt._grow_binned, HistBins)
+    assert text_prepared == text_raw

@@ -471,7 +471,9 @@ def test_cli_task_serve_bounded_run(boosters, tmp_path):
 
     def client():
         u = f"http://127.0.0.1:{port}"
-        deadline = time.monotonic() + 1.8
+        # the bounded window opens when the server is up, however long the
+        # model load and the predictor's compile take on a busy machine
+        deadline = time.monotonic() + 30.0
         while time.monotonic() < deadline:
             try:
                 urllib.request.urlopen(u + "/healthz", timeout=0.2)

@@ -13,6 +13,8 @@ message, so a JAX upgrade or a repair flips it visibly (XPASS fails the
 suite until the marker is removed).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,6 +79,143 @@ def test_hist_kernel_packed4_lowers(rows, precision):
                                            precision=precision, packed=True,
                                            num_features=27),
         packed, g3, leaf)
+
+
+def _big_u8_relayouts(txt, min_elems):
+    """``(op, operand shape, result shape)`` of the ``pad`` / ``transpose``
+    / ``slice`` ops in a lowered module whose result is a ``ui8`` tensor
+    of at least ``min_elems`` elements."""
+    found = []
+    for line in txt.splitlines():
+        m = re.search(r"stablehlo\.(pad|transpose|slice)\b.*\(tensor<"
+                      r"([0-9x]+)xui8>.*->\s*tensor<([0-9x]+)xui8>", line)
+        if m and np.prod([int(d) for d in m.group(3).split("x")]) >= min_elems:
+            found.append(m.groups())
+    return found
+
+
+def _kernel_u8_operands(txt):
+    """The ``ui8`` operand types of every ``tpu_custom_call``."""
+    out = []
+    for line in txt.splitlines():
+        if "stablehlo.custom_call @tpu_custom_call" not in line:
+            continue
+        operands = line.rsplit(" : (", 1)[1].split(") -> ")[0]
+        out.append(re.findall(r"tensor<([0-9x]+)xui8>", operands))
+    return out
+
+
+@pytest.mark.parametrize("what", ["pass", "grow"])
+def test_prepared_bins_leave_no_relayout(rows, monkeypatch, what):
+    """With bins prepared at placement (hist_pallas.HistBins) no pad or
+    transposition of a ``ui8`` tensor as large as one feature block of
+    every row is left in the lowered histogram pass, nor anywhere in a
+    wave grower's ``grow`` (so none in its while loop); the only slice
+    cuts a stored block's lane padding off (a bitcast once compiled:
+    test_prepared_pass_compiles_without_relayout); and every kernel call
+    takes a 2-D ``ui8`` operand whose first dimension is the padded row
+    count — what benchmarks/roofline.hist_call_shapes reads as the call's
+    rows.  A raw matrix still gets pad, transposition and the cut into
+    blocks in every pass."""
+    from lightgbmv1_tpu.models import grower_wave as gw
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.hist_pallas import (MAX_ROW_TILE,
+                                                prepare_hist_bins)
+
+    rng, N, g3 = rows
+    N, F, B = N - 100, 37, 64            # 2 blocks of 32; rows pad to 2048
+    g3 = g3[:N]
+    raw = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
+    prepared = prepare_hist_bins(raw, B)
+    n_pad = -(-N // MAX_ROW_TILE) * MAX_ROW_TILE
+    block_elems = N * 32                 # one feature block of every row
+    if what == "pass":
+        leaf = jnp.asarray(rng.randint(0, 17, N).astype(np.int32))
+
+        def fn(b):
+            return hist_leaves_pallas(b, g3, leaf, 17, B, precision="bf16x2")
+    else:
+        # every slot bucket (4 / 16 / K) in the while body, at a small N
+        monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
+        grow = gw.make_wave_grower(
+            num_leaves=63, num_bins=B, meta=_probe_meta(F, B),
+            params=SplitParams(min_data_in_leaf=2.0), wave_size=31,
+            hist_wave_fn=lambda b, g, l, n, deep=False: hist_wave(
+                b, g, l, n, B, method="pallas",
+                precision="bf16" if deep else "bf16x2"))
+
+        def fn(b):
+            return grow(b, g3, jnp.ones(F, bool), jax.random.PRNGKey(0))
+
+    txt = lower_for_tpu(fn, prepared)
+    assert "stablehlo.while" in txt or what == "pass"
+    assert set(_big_u8_relayouts(txt, block_elems)) == {
+        ("slice", f"{n_pad}x128", f"{n_pad}x32")}
+    calls = _kernel_u8_operands(txt)
+    assert calls and all(c == [f"{n_pad}x32"] for c in calls), calls
+
+    txt_raw = lower_for_tpu(fn, raw)
+    assert {(op, res) for op, _, res in _big_u8_relayouts(
+        txt_raw, block_elems)} == {("pad", f"64x{n_pad}"),
+                                   ("transpose", f"{n_pad}x64"),
+                                   ("slice", f"{n_pad}x32")}
+    assert all(len(c) == 1 and c[0].endswith("x32")
+               for c in _kernel_u8_operands(txt_raw))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: the TPU compiler proper."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_prepared_pass_compiles_without_relayout(one_chip):
+    """Compiled for the chip, the pass over prepared bins holds no
+    temporary as large as one stored block: the blocks' default device
+    layout is the row-major one the kernel's call takes and the cut of
+    their lane padding is a bitcast.  The raw matrix's pass holds the
+    padded transposition and every block (what ``bytes_probe.py`` reads)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+
+    N, F, B = 1_000_000, 37, 64         # too large for on-chip memory
+    n_pad, block_bytes = 1_000_448, 1_000_448 * 128
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def fn(b, g, l):
+        return hist_leaves_pallas(b, g, l, 64, B, precision="bf16")
+
+    raw = shape((F, N), jnp.uint8)
+    prepared = jax.tree_util.tree_map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda b: prepare_hist_bins(b, B), raw))
+    rest = (shape((N, 3), jnp.float32), shape((N,), jnp.int32))
+    # an entry compiled for a described chip cannot be read back (it warns)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        got = jax.jit(fn).lower(prepared, *rest).compile()
+        base = jax.jit(fn).lower(raw, *rest).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    assert got.memory_analysis().temp_size_in_bytes < block_bytes
+    assert base.memory_analysis().temp_size_in_bytes >= 2 * block_bytes
+    txt = got.as_text()
+    operand = rf"u8\[{n_pad},32\]\{{1,0:"
+    assert len(re.findall(rf"= {operand}\S* bitcast\(", txt)) == 2
+    assert not re.search(rf"= {operand}\S* (copy|slice|fusion)\(", txt)
+    assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,2048\]", txt)
 
 
 def _probe_meta(F, B):

@@ -256,3 +256,54 @@ def test_dataset_construct_seconds_has_every_phase():
     # the booster's placement of the bins is a phase of its own
     lgb.Booster(params=dict(PARAMS), train_set=ds)
     assert reg.snapshot()[key % "place"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the histogram kernel's bin operand: laid out once, at placement
+# ---------------------------------------------------------------------------
+
+
+def _layout_counts():
+    snap = obs_metrics.default_registry().snapshot()
+    return {site: snap.get('hist_bins_layout_total{site="%s"}' % site, 0)
+            for site in ("placement", "pass")}
+
+
+def test_serial_booster_lays_bins_out_once_at_placement():
+    from lightgbmv1_tpu.ops.hist_pallas import HistBins, prepared_bins_bytes
+
+    reg = obs_metrics.default_registry()
+    key = 'dataset_construct_seconds{phase="layout"}'
+    before, spent = _layout_counts(), reg.snapshot().get(key, 0)
+    bst = _booster(rows=1234, hist_method="pallas")
+    bst.update()
+    after = _layout_counts()
+    assert after["placement"] - before["placement"] == 1
+    assert after["pass"] - before["pass"] == 0
+    snap = reg.snapshot()
+    assert snap[key] > spent
+    grow_binned = bst._gbdt._grow_binned
+    assert isinstance(grow_binned, HistBins)
+    assert bst._gbdt.binned.shape == grow_binned.matrix.shape == (6, 1234)
+    # 6 byte columns of 2048 padded rows, lane-padded to 128
+    assert snap["hist_bins_prepared_bytes"] == prepared_bins_bytes(
+        6, 1234, 32) == 2048 * 128
+
+
+def test_streaming_booster_lays_bins_out_in_the_pass(monkeypatch):
+    """Row blocks arrive raw, so each traced pass makes its own layout."""
+    import functools
+
+    import lightgbmv1_tpu.ops.hist_pallas as hp
+
+    # the streaming grower has no interpreter switch: bind it here
+    monkeypatch.setattr(hp, "hist_leaves_pallas", functools.partial(
+        hp.hist_leaves_pallas, interpret=True))
+    before = _layout_counts()
+    bst = _booster(rows=1144, hist_method="pallas", num_leaves=7,
+                   stream_enable=True, stream_block_rows=104)
+    bst.update()
+    after = _layout_counts()
+    assert after["pass"] > before["pass"]
+    assert after["placement"] == before["placement"]
+    assert bst._gbdt._grow_binned is None
