@@ -557,6 +557,7 @@ class Booster:
         # always, a ring event when the tracer is armed) whose closing
         # writes the tree's always-on record; its phases are the
         # ``train.*`` children opened inside ``train_one_iter``
+        first_tree = len(self._gbdt._device_trees)
         with trace.iteration_span(self._gbdt.iter) as it:
             if fobj is None:
                 finished = self._gbdt.train_one_iter()
@@ -571,6 +572,7 @@ class Booster:
             # finite_guard=warn|raise: one scalar device read per iteration
             # boundary; off (default) costs nothing (models/gbdt.py)
             self._gbdt.check_finite_boundary()
+            it.renewed = self._gbdt.renewed_count_later(first_tree)
         # observability: per-iteration wall into the shared registry
         # (always on — one histogram observe vs a ms-scale iteration)
         _obs_iteration_metrics().observe(it.dur_ns / 1e6)

@@ -482,6 +482,20 @@ class GBDT:
             hess = jnp.where(finite, hess, 0.0)
         return grad, hess
 
+    def renewed_count_later(self, first_tree: int):
+        """For the iteration record (obs/trace.py): a callable giving the
+        nodes and leaves, over the trees from ``first_tree`` on, whose
+        stored sums were measured again from the rows (models/renew.py).
+        It reads the marks back from the trees' shape and counts when the
+        record is read, so that nothing waits for the device here."""
+        from .renew import count_marked
+
+        policy = getattr(getattr(self, "_grow", None), "_renew_policy", None)
+        trees = [t for t in self._device_trees[first_tree:] if t is not None]
+        if policy is None or not trees:
+            return 0
+        return lambda: sum(count_marked(t, policy) for t in trees)
+
     def check_finite_boundary(self) -> None:
         """Iteration-boundary finite check (``finite_guard=warn|raise``).
 

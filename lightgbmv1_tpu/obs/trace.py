@@ -287,13 +287,18 @@ class _IterationSpan(_BridgedSpan):
     """``train.iteration``: the bridged span one tree runs under, which
     its ``phase_span`` children add their time to."""
 
-    __slots__ = ("iteration", "phase_ns", "_outer")
+    __slots__ = ("iteration", "phase_ns", "renewed", "_outer")
 
     def __init__(self, iteration: int):
         super().__init__("train.iteration", "train",
                          {"iteration": iteration})
         self.iteration = iteration
         self.phase_ns = dict.fromkeys(ITERATION_PHASES, 0)
+        # nodes and leaves of this iteration's trees whose stored sums were
+        # measured again from the rows (models/renew.py): a number, or a
+        # callable that gives it when the record is read, so that writing
+        # the record never waits for the device
+        self.renewed = None
 
     def __enter__(self):
         self._outer = getattr(_tls, "iteration", None)
@@ -306,8 +311,21 @@ class _IterationSpan(_BridgedSpan):
         # the one place an iteration is written down, armed or not
         _iterations.append((self.iteration, self.t0,
                             *(self.phase_ns[p] for p in ITERATION_PHASES),
-                            self.dur_ns))
+                            self.dur_ns, _Later(self.renewed)))
         return False
+
+
+class _Later:
+    """A value, or a callable resolved once, on first read."""
+    __slots__ = ("_v",)
+
+    def __init__(self, v):
+        self._v = v
+
+    def get(self):
+        if callable(self._v):
+            self._v = self._v()
+        return self._v
 
 
 class _PhaseSpan(_BridgedSpan):
@@ -343,8 +361,10 @@ def phase_span(phase: str) -> _PhaseSpan:
 def iteration_records() -> List[tuple]:
     """The last ``ITERATION_RING`` iterations, oldest first, each
     ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns, wait_ns,
-    total_ns)`` on the ``perf_counter_ns`` clock."""
-    return list(_iterations)
+    total_ns, renewed)``: times on the ``perf_counter_ns`` clock, and
+    ``renewed`` the nodes and leaves of the iteration's trees whose stored
+    sums were measured again from the rows (None where nobody said)."""
+    return [(*r[:-1], r[-1].get()) for r in _iterations]
 
 
 # ---------------------------------------------------------------------------

@@ -106,7 +106,8 @@ def sr_prequantize_g3(g3: jax.Array, nslots: int, axis_name=None):
     g = g3[:, :2].astype(jnp.float32)
     amax = jnp.max(jnp.abs(g), axis=0)                       # (2,)
     if axis_name is not None:
-        amax = _lax.pmax(amax, axis_name)
+        with jax.named_scope("lgbm.collective"):
+            amax = _lax.pmax(amax, axis_name)
     # grad/hess scales snap DOWN to a power of two (inv = 2^floor(log2(
     # 127/amax)), scale = 1/inv): a power-of-two dequantization multiply
     # is EXACT in f32, so `parent - q*scale` rounds identically whether a
@@ -129,7 +130,8 @@ def sr_prequantize_g3(g3: jax.Array, nslots: int, axis_name=None):
     c = g3[:, 2].astype(jnp.float32)
     cmax = jnp.max(jnp.abs(c))
     if axis_name is not None:
-        cmax = _lax.pmax(cmax, axis_name)
+        with jax.named_scope("lgbm.collective"):
+            cmax = _lax.pmax(cmax, axis_name)
     inv_c = jnp.where(
         cmax > 0,
         jnp.minimum(jnp.exp2(jnp.floor(jnp.log2(INT8_QMAX / cmax))), 64.0),

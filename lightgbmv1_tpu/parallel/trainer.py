@@ -128,8 +128,38 @@ def _unpack_split(v: jnp.ndarray) -> SplitResult:
     )
 
 
+COLLECTIVE_SCOPE = "lgbm.collective"
+
+
+def _reduce_bytes(what: str, x, times: int = 1) -> None:
+    """Trace time: ``dp_reduce_bytes_per_round{what}`` holds the bytes one
+    device hands the largest cross-chip op of that kind in a round (the
+    histogram block before its reduction, a round's gathered split
+    records, the root's and the renewal's sums), from the static shapes."""
+    from ..obs.metrics import default_registry
+
+    default_registry().gauge(
+        "dp_reduce_bytes_per_round",
+        "Bytes a device hands one round's cross-chip op, by what it carries",
+        label_names=("what",)).labels(what=what).set_max(
+            float(x.size * x.dtype.itemsize * times))
+
+
+def _psum(what: str, x, axes):
+    """``lax.psum`` of a row-sharded learner, traced: under
+    ``lgbm.collective``, its operand counted in the gauge."""
+    _reduce_bytes(what, x)
+    with jax.named_scope(COLLECTIVE_SCOPE):
+        return lax.psum(x, axes)
+
+
+def _psum_scatter(x, axis, dim):
+    with jax.named_scope(COLLECTIVE_SCOPE):
+        return lax.psum_scatter(x, axis, scatter_dimension=dim, tiled=True)
+
+
 def _sync_best_split(local: SplitResult, parent_sum, params: SplitParams,
-                     axis) -> SplitResult:
+                     axis, children: int = 1) -> SplitResult:
     """Elect the global best split from per-shard locals — the reference's
     ``SyncUpGlobalBestSplit`` Allreduce-max over serialized SplitInfo
     (parallel_tree_learner.h:190-213), shared by the feature-parallel,
@@ -145,7 +175,11 @@ def _sync_best_split(local: SplitResult, parent_sum, params: SplitParams,
     first-feature-in-band rule exactly (SplitInfo::operator> tie-break,
     split_info.hpp:147-152)."""
     packed = _pack_split(local)
-    allp = lax.all_gather(packed, axis)            # (ndev, 11 + W)
+    with jax.named_scope(COLLECTIVE_SCOPE):
+        allp = lax.all_gather(packed, axis)        # (ndev, 11 + W)
+    # ``children``: how many of these one round gathers (the wave grower
+    # vmaps this over its 2K children)
+    _reduce_bytes("split", allp, children)
     g = allp[:, 0]
     m = jnp.max(g)
     scale = leaf_gain(parent_sum[0], parent_sum[1], params)
@@ -619,6 +653,54 @@ def build_trainer(
             forced = parse_forced_splits(config.forcedsplits_filename,
                                          bin_mappers, config.num_leaves)
 
+    # ---- stored sums that hold at small leaves (models/renew.py) --------
+    # every grower's tree passes through ``renew_tree`` before anything
+    # reads it.  The policy is what this function can see: the method and
+    # precisions of the passes, whether the wave has a deep bucket at the
+    # rows one device sums, whether the grower subtracts.
+    from ..models import renew
+    from ..models.grower_wave import _SUB_STATE_CAP_BYTES, slot_buckets_for
+
+    wave_grows = use_wave and forced is None and not levelwise
+    # split records one round gathers (the wave vmaps its 2K children)
+    round_children = 2 * wave_size if wave_grows else 1
+    renew_policy = None        # what the returned grow_fn carries
+
+    def local_leaf_sums(leaf_id, g3):
+        return renew.leaf_sums(leaf_id, g3, config.num_leaves,
+                               method=method, precision=precision,
+                               interpret=pallas_interpret)
+
+    def renewing(grow, rows, is_wave, leaf_sums_fn=local_leaf_sums):
+        """``grow`` with its marked sums renewed; ``rows`` are what one
+        device's passes sum, ``leaf_sums_fn`` the learner's direct sums (a
+        row-sharded learner adds its shards up inside)."""
+        nonlocal renew_policy
+        if (has_mono or params.path_smooth > 0 or config.num_leaves < 2
+                or config.num_leaves > renew.MAX_LEAVES):
+            return grow
+        later = [precision]
+        if is_wave:
+            K_eff = max(1, min(wave_size, max(config.num_leaves - 1, 1)))
+            if len(slot_buckets_for(K_eff, rows)) > 1:
+                if K_eff >= 32:
+                    later.append(deep_precision)
+                if use_int8sr:
+                    later.append("int8sr")
+        cols = binned_np.shape[0] if bundle is not None else F
+        renew_policy = renew.RenewPolicy(
+            eps_root=renew.pass_rounding(method, precision, rows),
+            eps_rest=max(renew.pass_rounding(method, p, rows)
+                         for p in later),
+            subtracts=not (is_wave and config.num_leaves * cols * Bh * 12
+                           > _SUB_STATE_CAP_BYTES),
+            gains=meta.contri is None and not use_cegb)
+        return renew.with_renewal(grow, params, renew_policy, leaf_sums_fn)
+
+    def finished(grow_fn, label):
+        grow_fn._renew_policy = renew_policy
+        return obs_xla.instrument_jit(grow_fn, label)
+
     # ---- hist_method=fused: the wave-round megakernel dispatch ----------
     # (ops/wave_fused.py — histogram + smaller-child subtraction + split
     # scan in one Pallas invocation, histograms resident in VMEM).  The
@@ -787,7 +869,8 @@ def build_trainer(
         # prepared bins
         if method == "pallas" and fused_builder is None:
             binned_dev = _place_hist_bins(binned_dev, Bh, packed)
-        return obs_xla.instrument_jit(grow, label), binned_dev, N
+        grow = renewing(grow, N, wave_grows)
+        return finished(grow, label), binned_dev, N
 
     if learner == "voting" and levelwise:
         log_warning("tree_learner=voting requires the leaf-wise grower; "
@@ -869,7 +952,7 @@ def build_trainer(
             return local_hist(binned, g3, leaf_id, target)
 
         def sums_fn(g3):
-            return lax.psum(g3.sum(axis=0), row_axes)
+            return _psum("root", g3.sum(axis=0), row_axes)
 
         def voting_wave_quant(binned, g3, label, nslots, key):
             # global (pmax'd) scales: the selective reduce in split_fn can
@@ -901,7 +984,7 @@ def build_trainer(
             _, local_top = lax.top_k(gains, top_k)
             votes = jnp.zeros(F, jnp.float32).at[local_top].add(
                 jnp.where(jnp.isfinite(gains[local_top]), 1.0, 0.0))
-            votes = lax.psum(votes, row_axes)             # GlobalVoting
+            votes = _psum("vote", votes, row_axes)        # GlobalVoting
             # tie-break deterministically by feature index
             order_score = votes * (F + 1) - jnp.arange(F, dtype=jnp.float32)
             _, selected = lax.top_k(order_score, sel_k)   # (sel_k,)
@@ -921,19 +1004,17 @@ def build_trainer(
                 # reduces+keeps sel_k/D of the voted features, searches
                 # them, and only SplitInfo crosses chips
                 wire = jnp.pad(wire, ((0, sel_pad - sel_k), (0, 0), (0, 0)))
+                _reduce_bytes("hist", wire)
                 if use_hier:
                     # two-level selective reduce: full (sel_pad, B, 3)
                     # wire rides the fast ICI ring only; the slow DCN hop
                     # carries the 1/C chip slice of the ELECTED features
-                    sl = lax.psum_scatter(wire, "chip", scatter_dimension=0,
-                                          tiled=True)      # (sel_pad/C,...)
-                    sl = lax.psum_scatter(sl, "host", scatter_dimension=0,
-                                          tiled=True)      # (sel_loc, B, 3)
+                    sl = _psum_scatter(wire, "chip", 0)    # (sel_pad/C,...)
+                    sl = _psum_scatter(sl, "host", 0)      # (sel_loc, B, 3)
                     lo = (lax.axis_index("chip") * (sel_pad // NC)
                           + lax.axis_index("host") * sel_loc)
                 else:
-                    sl = lax.psum_scatter(wire, "data", scatter_dimension=0,
-                                          tiled=True)      # (sel_loc, B, 3)
+                    sl = _psum_scatter(wire, "data", 0)    # (sel_loc, B, 3)
                     lo = lax.axis_index("data") * sel_loc
                 sl = sl.astype(jnp.float32)
                 sel_p = jnp.pad(selected, (0, sel_pad - sel_k),
@@ -947,8 +1028,9 @@ def build_trainer(
                                         config.monotone_penalty,
                                         parent_output, rk, cegb_pen,
                                         hist_scale=hist_scale)
-                return _sync_best_split(local, parent, params, row_axes)
-            hist_sel = lax.psum(wire, row_axes).astype(jnp.float32)
+                return _sync_best_split(local, parent, params, row_axes,
+                                        round_children)
+            hist_sel = _psum("hist", wire, row_axes).astype(jnp.float32)
             full = jnp.zeros((F, B, 3), jnp.float32).at[selected].set(hist_sel)
             sel_mask = jnp.zeros(F, bool).at[selected].set(True)
             return find_best_split(full, parent, meta, mask & sel_mask,
@@ -975,6 +1057,10 @@ def build_trainer(
             grow = make_leafwise_grower(
                 hist_fn=hist_fn, split_fn=split_fn, sums_fn=sums_fn,
                 bins_of_fn=bins_feat_fn, **lw_pool, **common)
+        grow = renewing(
+            grow, N_pad // ndev, use_wave,
+            lambda lid, g3: _psum("renew", local_leaf_sums(lid, g3),
+                                  row_axes))
         sharded = jax.shard_map(
             grow,
             mesh=mesh,
@@ -995,8 +1081,7 @@ def build_trainer(
                                           cegb_used)
             return tree, leaf_id[:N], root
 
-        return obs_xla.instrument_jit(grow_fn, f"grow.{learner}"), \
-            binned_dev, N
+        return finished(grow_fn, f"grow.{learner}"), binned_dev, N
 
     if learner == "data":
         collective = config.data_parallel_collective
@@ -1090,17 +1175,15 @@ def build_trainer(
                          + [(0, FH_pad - FH), (0, 0), (0, 0)])
             if int_domain:
                 hp = hp.astype(jnp.int32)
+            _reduce_bytes("hist", hp)
             if use_hier:
                 # level 1 (ICI): the full FH_pad block rides the fast
                 # intra-host ring; level 2 (DCN): only the FH_pad/C chip
                 # slice crosses hosts — 1/C of the flat wire volume
-                sl = lax.psum_scatter(hp, "chip", scatter_dimension=nb,
-                                      tiled=True)
-                sl = lax.psum_scatter(sl, "host", scatter_dimension=nb,
-                                      tiled=True)
+                sl = _psum_scatter(hp, "chip", nb)
+                sl = _psum_scatter(sl, "host", nb)
             else:
-                sl = lax.psum_scatter(hp, "data", scatter_dimension=nb,
-                                      tiled=True)
+                sl = _psum_scatter(hp, "data", nb)
             lo = _shard_lo()
             full = jnp.zeros(hp.shape, jnp.float32)
             full = lax.dynamic_update_slice(
@@ -1143,7 +1226,8 @@ def build_trainer(
                                     params, constraint, depth,
                                     config.monotone_penalty, parent_output,
                                     rk, cegb_pen, hist_scale=hist_scale)
-            return _sync_best_split(local, parent, params, row_axes)
+            return _sync_best_split(local, parent, params, row_axes,
+                                    round_children)
 
         # integer histograms cannot cross expand_bundle_hist (its zero-bin
         # fix mixes real-unit parent sums in), so EFB keeps the grower's
@@ -1152,17 +1236,19 @@ def build_trainer(
 
         def hist_fn(binned, g3, leaf_id, target):
             h = local_hist(binned, g3, leaf_id, target)
-            return _scatter_keep(h) if use_rs else lax.psum(h, row_axes)
+            return (_scatter_keep(h) if use_rs
+                    else _psum("hist", h, row_axes))
 
         def sums_fn(g3):
-            return lax.psum(g3.sum(axis=0), row_axes)
+            return _psum("root", g3.sum(axis=0), row_axes)
 
         split_dp = _split_sharded if use_rs else split_local
 
         if levelwise:
             def frontier_fn(binned, g3, leaf_id, L_level):
                 h = local_frontier(binned, g3, leaf_id, L_level)
-                return _scatter_keep(h) if use_rs else lax.psum(h, row_axes)
+                return (_scatter_keep(h) if use_rs
+                        else _psum("hist", h, row_axes))
 
             grow = make_levelwise_grower(
                 hist_frontier_fn=frontier_fn, sums_fn=sums_fn,
@@ -1174,7 +1260,8 @@ def build_trainer(
             # schedule's distributed dividend
             def wave_fn(binned, g3, label, nslots, deep=False):
                 h = local_wave(binned, g3, label, nslots, deep)
-                return _scatter_keep(h) if use_rs else lax.psum(h, row_axes)
+                return (_scatter_keep(h) if use_rs
+                        else _psum("hist", h, row_axes))
 
             if use_rs:
                 def wave_quant_fn(binned, g3, label, nslots, key):
@@ -1197,7 +1284,7 @@ def build_trainer(
                     # dequantized f32 and the grower sees identity scales
                     h, sc = local_wave_quant(binned, g3, label, nslots,
                                              key)
-                    h = lax.psum(h * sc[:, None, None, :], row_axes)
+                    h = _psum("hist", h * sc[:, None, None, :], row_axes)
                     return h, jnp.ones_like(sc)
 
             grow = make_wave_grower(hist_wave_fn=wave_fn, sums_fn=sums_fn,
@@ -1212,6 +1299,10 @@ def build_trainer(
                                         bins_of_fn=bins_feat_fn,
                                         forced_splits=forced,
                                         **lw_pool, **common)
+        grow = renewing(
+            grow, N_pad // ndev, wave_grows,
+            lambda lid, g3: _psum("renew", local_leaf_sums(lid, g3),
+                                  row_axes))
         sharded = jax.shard_map(
             grow,
             mesh=mesh,
@@ -1232,8 +1323,7 @@ def build_trainer(
                                           cegb_used)
             return tree, leaf_id[:N], root
 
-        return obs_xla.instrument_jit(grow_fn, f"grow.{learner}"), \
-            binned_dev, N
+        return finished(grow_fn, f"grow.{learner}"), binned_dev, N
 
     if learner == "feature":
         mesh = _make_mesh(config.num_shards, "feature")
@@ -1447,6 +1537,8 @@ def build_trainer(
                 hist_fn=hist_fn, split_fn=split_fn, cegb_coupled=coupled_fp,
                 hist_pool_mb=config.histogram_pool_size,
                 num_features=F_pad, **fp_kwargs)
+        # every device holds all rows: each sums its leaves alone
+        grow = renewing(grow, N, use_wave and not levelwise)
         sharded = jax.shard_map(
             grow,
             mesh=mesh,
@@ -1465,7 +1557,7 @@ def build_trainer(
             return sharded(binned, g3, maskp, key,
                            jnp.pad(cegb_used, (0, pad_f)))
 
-        return obs_xla.instrument_jit(
+        return finished(
             grow_fn, ("grow.fused_round" if fused_fp is not None
                       else f"grow.{learner}")), binned_dev, N
 

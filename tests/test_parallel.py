@@ -523,3 +523,33 @@ def test_hierarchical_int8sr_collective_moves_int32(monkeypatch):
     # both levels lower to real collectives: 4-chip groups and 2-host
     # groups (a single flat 8-group would mean the hierarchy collapsed)
     assert {2, 4} <= group_sizes, group_sizes
+
+
+def test_data_parallel_matches_serial_with_renewed_sums():
+    """PR 28: at bf16 histograms nearly every child's stored sums are
+    measured again from the rows (models/renew.py); the data learner adds
+    its four shards' leaf sums up and has to store what the serial learner
+    stores."""
+    from lightgbmv1_tpu.models.renew import count_marked
+
+    X, y = make_binary_problem(2000, f=6)
+    common = {"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 20,
+              "hist_method": "onehot", "hist_dtype": "bf16"}
+    serial = _train(common, X, y, 3)
+    par = _train({**common, "tree_learner": "data", "num_shards": 4}, X, y, 3)
+    for g in (serial, par):
+        assert sum(count_marked(t, g._grow._renew_policy)
+                   for t in g._device_trees) > 0
+    for s, p in zip(_tree_signature(serial), _tree_signature(par)):
+        assert s[:3] == p[:3]          # leaves, split features, thresholds
+        np.testing.assert_allclose(s[3], p[3], rtol=1e-3, atol=1e-5)
+    for ts, tp in zip(serial.materialize_host_trees(),
+                      par.materialize_host_trees()):
+        np.testing.assert_allclose(ts.leaf_weight, tp.leaf_weight, rtol=1e-3)
+        np.testing.assert_allclose(ts.internal_weight, tp.internal_weight,
+                                   rtol=1e-3)
+        np.testing.assert_allclose(ts.split_gain, tp.split_gain, rtol=1e-3,
+                                   atol=1e-4)
+    np.testing.assert_allclose(
+        serial.raw_train_scores(), par.raw_train_scores(), rtol=1e-3,
+        atol=1e-5)

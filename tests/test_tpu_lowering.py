@@ -251,6 +251,44 @@ def _round_args(F=4, B=8, N=64, S=2):
     return binned, g3, lids, kw, route
 
 
+@pytest.mark.parametrize("precision", ["bf16x2", "bf16", "f32", "int8"])
+@pytest.mark.parametrize("leaves", [31, 255, 2048])
+def test_leaf_sums_kernel_lowers(rows, leaves, precision):
+    """PR 28's leaf-sum kernel (ops/leaf_sums.py): the leaves' one-hot
+    against the rows' values, every precision the passes have."""
+    from lightgbmv1_tpu.ops.leaf_sums import leaf_sums_pallas
+
+    rng, N, g3 = rows
+    leaf = jnp.asarray(rng.randint(0, leaves, N).astype(np.int32))
+    lower_for_tpu(
+        lambda l, g: leaf_sums_pallas(l, g, leaves, precision=precision),
+        leaf, g3)
+
+
+def test_leaf_sums_kernel_compiles_at_the_cells_sizes(one_chip):
+    """Compiled for the chip at the rows a device sums in the benchmark's
+    two cells (4,000,000 and 2,270,296) and at the most leaves the
+    renewal takes: what the chip's compiler would refuse, it refuses
+    here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from lightgbmv1_tpu.ops.leaf_sums import leaf_sums_pallas
+
+    # an entry compiled for a described chip cannot be read back (it warns)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        for N, L in ((4_000_000, 255), (2_270_296, 255), (4_000_000, 2048)):
+            jax.jit(lambda l, g: leaf_sums_pallas(l, g, L)).lower(
+                jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((N, 3), jnp.float32,
+                                     sharding=one_chip),
+            ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
 def test_fused_route_rows_lowers():
     """The valid-set router of the fused round — the one piece of
     ops/wave_fused.py the installed JAX can put on a TPU."""

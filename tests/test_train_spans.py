@@ -97,7 +97,8 @@ def test_one_record_a_tree_with_the_tracer_disarmed():
     recs = trace.iteration_records()
     assert [r[0] for r in recs] == [1, 2, 3, 4, 5]
     covered = []
-    for it, t0, prepare, dispatch, bookkeep, wait, total in recs:
+    for it, t0, prepare, dispatch, bookkeep, wait, total, renewed in recs:
+        assert isinstance(renewed, int) and renewed >= 0
         assert min(prepare, dispatch, bookkeep, wait) > 0
         assert prepare + dispatch + bookkeep + wait <= total
         covered.append((prepare + dispatch + bookkeep + wait) / total)
