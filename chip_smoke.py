@@ -194,13 +194,19 @@ class Smoke:
                  compile_labels=sorted(stats), fallbacks=0)
 
     # -- histogram kernel vs numpy.bincount --------------------------------
-    def check_hist(self, binned, bins_np, B, L, precisions, packed=False):
+    def check_hist(self, binned, bins_np, B, L, precisions, packed=False,
+                   dead=False):
         """hist_leaves_pallas over ``binned`` with ``L`` slots against
         float64 numpy.bincount over ``bins_np`` (the same bins, (F, N),
-        unpacked).  Returns {precision: worst error / sum|values|}."""
-        rng = np.random.RandomState(100 + L)
-        label = rng.randint(0, L, self.n).astype(np.int32)
-        base = label.astype(np.int64) * B
+        unpacked).  ``dead``: as the wave grower calls it —
+        ``L`` live slots and rows labelled ``L`` (some far above it) that
+        must land nowhere.  Returns {precision: worst error / sum|values|}."""
+        rng = np.random.RandomState(100 + L + 1000 * dead)
+        label = rng.randint(0, L + dead, self.n).astype(np.int32)
+        live = label < L
+        if dead:
+            label[::97] = np.where(live[::97], label[::97], L + 1000)
+        base = np.where(live, label, 0).astype(np.int64) * B
         interpret = self.device["platform"] == "cpu"
 
         def reference(w):
@@ -209,7 +215,7 @@ class Smoke:
                 idx = base + bins_np[f]
                 for c in range(w.shape[1]):
                     out[:, f, :, c] = np.bincount(
-                        idx, weights=w[:, c], minlength=L * B
+                        idx, weights=w[:, c] * live, minlength=L * B
                     ).reshape(L, B)
             return out
 
@@ -253,17 +259,24 @@ class Smoke:
         B = int(gb.num_bins)
         bins_np = np.asarray(gb.binned)
         # 1 slot = the root pass; then every bucket of the wave ladder the
-        # 255-leaf grower runs, each with its dead-row slot
+        # 255-leaf grower runs, once with a slot more for its dead rows
+        # (the scatter / onehot methods' form, every slot live) and once as
+        # the trainer asks the kernel: the live slots, dead rows dropped
         ladder = slot_buckets_for(auto_wave_size(PARAMS["num_leaves"]),
                                   self.n)
-        worst = {}
+        worst, worst_live = {}, {}
         for L in [1] + [S + 1 for S in ladder]:
             precisions = ["bf16x2", "bf16"] + (["int8sr"] if L > 5 else [])
             worst[f"slots{L}"] = self.check_hist(gb.binned, bins_np, B, L,
                                                  precisions)
+        for S in ladder:
+            precisions = ["bf16x2", "bf16"] + (["int8sr"] if S > 4 else [])
+            worst_live[f"live{S}"] = self.check_hist(
+                gb.binned, bins_np, B, S, precisions, dead=True)
         self.say("hist", shape=[F, self.n], num_bins=B, ladder=ladder,
                  counts="exact", int8sr="exact",
                  worst_error_over_sum_abs=worst,
+                 live_slots_worst_error_over_sum_abs=worst_live,
                  tolerance={k: float(v) for k, v in HIST_TOL.items()})
 
     # -- device predict vs host -------------------------------------------

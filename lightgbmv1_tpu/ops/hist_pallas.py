@@ -13,8 +13,10 @@ TPUs have no atomics; the design maps the OpenCL structure onto the MXU:
   ``lane // FBLK`` iota — nothing intermediate ever touches HBM, which is
   what made the pure-XLA one-hot path bandwidth-bound,
 * the histogram update is ONE MXU matmul per tile:
-  ``(3·leaves, rows) @ (rows, bins*features)``, with the per-leaf-masked
-  gradient rows built by an iota//3-vs-leaf compare (cheap VPU work),
+  ``(M, rows) @ (rows, bins*features)``, whose left operand holds the
+  per-leaf-masked gradient rows and nothing else (``pass_rows``: 3 rows a
+  leaf, 5 under ``bf16x2``, padded as a whole to the MXU's row granule),
+  built by an iota-vs-leaf compare (cheap VPU work),
 * the per-workgroup local histogram of the OpenCL kernels becomes a VMEM
   f32 accumulator block revisited across the row-tile grid dimension (the
   analog of ``within_kernel_reduction256x4``, histogram256.cl:139-310,
@@ -94,10 +96,32 @@ def kernel_width(num_bins: int) -> int:
                      "int16-binned data to the onehot/scatter path")
 
 
+# rows the MXU's left operand packs into one sublane tile, by its dtype
+_ROW_GRANULE = {"f32": 8, "bf16": 16, "bf16x2": 16, "int8": 32, "int8sr": 32}
+
+
+def pass_rows(num_leaves: int, precision: str) -> Tuple[int, int, int]:
+    """``(m_pad, m_live, out_rows)`` of a pass over ``num_leaves`` slots.
+
+    The left operand of the kernel's product is channel-major: rows
+    ``[g | h | c]``, ``num_leaves`` each, and under ``bf16x2`` the lo terms
+    ``[g | h]`` below them — the count is 0.0 or 1.0, exact in bfloat16, so
+    its lo term is zero in every row and is not built.  ``m_live`` of its
+    rows carry data; ``m_pad`` rounds THAT up to the dtype's row granule
+    (slots are never rounded: a 1-slot root pass is 5 live rows in 16).
+    The result block is the hi + lo sum, ``3 * num_leaves`` rows rounded
+    up to the f32 tile's 8."""
+    m_live = (5 if precision == "bf16x2" else 3) * num_leaves
+    g = _ROW_GRANULE[precision]
+    return -(-m_live // g) * g, m_live, -(-3 * num_leaves // 8) * 8
+
+
 def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     """Row-tile size keeping the VMEM working set (chunked one-hot + repeat
     buffer + lg rows + out accumulator) within Mosaic's ~16MB scoped-vmem
-    budget.  The estimate is deliberately conservative: per-chunk f32
+    budget.  ``m_pad`` is the result block's rows (``pass_rows``' third):
+    the left operand's own rows, up to 5/3 of them, ride in the estimate's
+    16 bytes a row.  The estimate is deliberately conservative: per-chunk f32
     temporaries (repeat buffer, compare, select, cast) can coexist, and
     narrow feature blocks pay lane-padding amplification (observed OOM at
     B=256 with 3 features and T=1024).  No ``compiler_params`` is passed,
@@ -113,12 +137,12 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     return 128
 
 
-def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, lpad, num_bins,
-            fblk, precision, interpret, packed=False):
+def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
+            num_bins, fblk, precision, interpret, packed=False):
     """Grid: (feature_blocks, row_tiles); out revisited across row tiles.
 
-    iota_ref: (1, FBLK*B) bf16         — precomputed ``lane // FBLK`` pattern
-                                         (bin ids are < 256 => exact in bf16;
+    iota_ref: (1, FBLK*B) f32          — precomputed ``lane // FBLK`` pattern
+                                         (bin ids are < 256 => exact;
                                          v5e has no int8 vector compare)
     bins_ref: (T, FBLK) uint8          — row-major bin tile; with ``packed``
                                          each byte holds TWO 4-bit bins
@@ -128,14 +152,16 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, lpad, num_bins,
                                          effective feature block is 2*FBLK
                                          wide, ordered [lo nibbles | hi]
     g3_ref:   (3, T) f32               — grad / hess / count (pre-transposed)
-    leaf_ref: (1, T) int32             — leaf id per row
-    out_ref:  (1, 3*Lpad, FBLK*B) f32  — rows are (leaf-major, channel-minor)
+    leaf_ref: (1, T) int32             — leaf id per row, never negative;
+                                         a row whose id is num_leaves or
+                                         more adds to nothing
+    out_ref:  (1, out_rows, FBLK*B) f32 — rows are (channel-major,
+                                         leaf-minor): ``pass_rows``
     """
     rt = pl.program_id(1)
     B = num_bins
-    T = bins_ref.shape[0]
-    m_pad = out_ref.shape[1]
-    lanes = B * fblk
+    L = num_leaves
+    m_pad, m_live, out_rows = pass_rows(L, precision)
 
     @pl.when(rt == 0)
     def _():
@@ -148,20 +174,32 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, lpad, num_bins,
             return jnp.tile(x, reps)
         return pltpu.repeat(x, n, axis)
 
-    # --- per-leaf-masked gradient rows (3*Lpad, T), built once -------------
-    leaf = leaf_ref[...]                                     # (1, T)
-    row_leaf = lax.broadcasted_iota(jnp.int32, (m_pad, T), 0) // 3
-    loh = row_leaf == leaf                                   # (3*Lpad, T) bool
+    # --- the left operand's rows, described once on an (M, 1) column -------
+    # row r is piece r // L (0..2: g / h / c; 3..4: the lo terms of g / h)
+    # of leaf r % L.  A padding row (r >= m_live) takes leaf id -1, which
+    # no row carries: it stays zero whatever m_pad is.
+    r = lax.broadcasted_iota(jnp.int32, (m_pad, 1), 0)
+    piece = r // L
+    row_leaf = jnp.where(r < m_live, r - piece * L, -1)
+    chan = jnp.where(piece >= 3, piece - 3, piece)
+    is_lo = piece >= 3
+
+    def rows_of(v3):
+        """(3, n) channel values -> (M, n): each row its channel's."""
+        return jnp.where(chan == 0, v3[0:1],
+                         jnp.where(chan == 1, v3[1:2], v3[2:3]))
+
+    mine = row_leaf == leaf_ref[...]                         # (M, T) bool
     g3 = g3_ref[...]                                         # (3, T) f32
 
     # VPU constraints on this target: vector compare/select only in i32/f32;
     # narrow dtypes appear only via a final astype feeding the MXU.
+    scale_rep = None
     if precision == "int8sr":
         # rows arrive PRE-quantized to exact integers in [-127, 127]
         # (ops/quantize.sr_quantize_g3); the leaf mask runs in f32 and the
         # int8 cast is the final op feeding the MXU — no scale math here
-        lg_parts = [jnp.where(loh, rep(g3, lpad, 0), 0.0).astype(jnp.int8)]
-        scale_rep = None
+        lhs = jnp.where(mine, rows_of(g3), 0.0).astype(jnp.int8)
     elif precision == "int8":
         amax = jnp.max(jnp.abs(g3[:2]), axis=1, keepdims=True)       # (2, 1)
         inv = jnp.where(amax > 0, 127.0 / amax, 0.0)
@@ -171,16 +209,19 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, lpad, num_bins,
         scale3 = jnp.concatenate(
             [scale, jnp.full((1, 1), 1.0 / _COUNT_SCALE, jnp.float32)], axis=0)
         q3 = jnp.round(g3 * inv3)                                    # (3, T)
-        lg_parts = [jnp.where(loh, rep(q3, lpad, 0), 0.0).astype(jnp.int8)]
-        scale_rep = rep(scale3, lpad, 0)                             # (M, 1)
+        lhs = jnp.where(mine, rows_of(q3), 0.0).astype(jnp.int8)
+        scale_rep = rows_of(scale3)                                  # (M, 1)
     elif precision in ("bf16", "bf16x2"):
-        lg = jnp.where(loh, rep(g3, lpad, 0), 0.0)            # (3*Lpad, T)
-        hi = lg.astype(jnp.bfloat16)
-        lg_parts = [hi]
+        lg = jnp.where(mine, rows_of(g3), 0.0)                # (M, T) f32
         if precision == "bf16x2":
-            lg_parts.append((lg - hi.astype(jnp.float32)).astype(jnp.bfloat16))
+            # hi rows hold bf16(x), lo rows bf16(x - bf16(x)): the split
+            # sits inside the kernel (outside it the compiler keeps the
+            # excess precision and the lo term reads zero)
+            hi = lg.astype(jnp.bfloat16).astype(jnp.float32)
+            lg = jnp.where(is_lo, lg - hi, hi)
+        lhs = lg.astype(jnp.bfloat16)
     else:  # f32 — exact (HIGHEST forces true-f32 MXU passes)
-        lg_parts = [jnp.where(loh, rep(g3, lpad, 0), 0.0)]
+        lhs = jnp.where(mine, rows_of(g3), 0.0)
 
     # --- bin one-hot, built in column chunks to bound VMEM -----------------
     # column b*FBLK + f is (feature f, bin b); the repeat pattern of the bin
@@ -206,40 +247,33 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, lpad, num_bins,
         # slot-count-independent floor of the whole pass, so every VPU op
         # here is measurable in the roofline fraction
         if precision in ("int8", "int8sr"):
-            oh = oh_cmp.astype(jnp.int8)
-            acc = lax.dot_general(lg_parts[0], oh, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
-            upd = acc.astype(jnp.float32)
+            prod = lax.dot_general(lhs, oh_cmp.astype(jnp.int8),
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.int32
+                                   ).astype(jnp.float32)
             if scale_rep is not None:       # int8sr stays in integer units
-                upd = upd * scale_rep
-            out_ref[0, :, sl] += upd
+                prod = prod * scale_rep
         elif precision in ("bf16", "bf16x2"):
-            oh = oh_cmp.astype(jnp.bfloat16)
-            if len(lg_parts) > 1:
-                # bf16x2: ONE stacked (2·M, T) @ (T, lanes) pass sharing the
-                # built one-hot block across the hi and lo accumulations,
-                # instead of two matmuls that each re-stream it — the
-                # one-hot build + stream is the slot-count-independent
-                # floor of the pass.  Splitting the output and adding
-                # hi + lo afterwards is bit-identical to the two-matmul
-                # form: each output row's fp32 dot is unchanged and the
-                # final add keeps the same operand order.
-                stacked = lax.dot_general(
-                    jnp.concatenate(lg_parts, axis=0), oh,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                upd = stacked[:m_pad] + stacked[m_pad:]
-            else:
-                upd = lax.dot_general(lg_parts[0], oh,
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            out_ref[0, :, sl] += upd
+            # bf16x2: ONE (5·L, T) @ (T, lanes) product shares the built
+            # one-hot block between the hi and the lo accumulation —
+            # the one-hot build + stream is the slot-count-independent
+            # floor of the pass
+            prod = lax.dot_general(lhs, oh_cmp.astype(jnp.bfloat16),
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
         else:
-            oh = oh_cmp.astype(jnp.float32)
-            out_ref[0, :, sl] += lax.dot_general(
-                lg_parts[0], oh, (((1,), (0,)), ((), ())),
+            prod = lax.dot_general(
+                lhs, oh_cmp.astype(jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=lax.Precision.HIGHEST)
+        if precision == "bf16x2":
+            # g and h: hi + lo, each output row's own two f32 dots added in
+            # that order before it joins the accumulator; the count is its
+            # hi dot alone (its lo dot was +0.0 on a non-negative sum)
+            out_ref[0, :2 * L, sl] += prod[:2 * L] + prod[3 * L:5 * L]
+            out_ref[0, 2 * L:3 * L, sl] += prod[2 * L:3 * L]
+        else:
+            out_ref[0, :, sl] += prod[:out_rows]
 
 
 def pack4bit(binned: np.ndarray) -> np.ndarray:
@@ -382,6 +416,21 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
     return HistBins(binned, tuple(blocks), tile_cols)
 
 
+def _count_pass_rows(slots: int, precision: str) -> None:
+    """Trace time: the rows of a pass's MXU left operand, padded and live,
+    in ``hist_pass_mxu_rows`` / ``hist_pass_live_rows{slots,precision}``."""
+    from ..obs.metrics import default_registry
+
+    m_pad, m_live, _ = pass_rows(slots, precision)
+    for name, rows, what in (
+            ("hist_pass_mxu_rows", m_pad, "as padded to the row granule"),
+            ("hist_pass_live_rows", m_live, "that carry data")):
+        default_registry().gauge(
+            name, "Rows of the histogram kernel's MXU left operand " + what,
+            label_names=("slots", "precision")).labels(
+                slots=str(slots), precision=precision).set(float(rows))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("num_leaves", "num_bins", "precision", "row_tile",
@@ -390,8 +439,10 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
 def hist_leaves_pallas(
     binned,                 # HistBins, or the raw (F, N) uint8 matrix
                             # (packed: (ceil(F/2), N)) laid out in the pass
-    g3: jax.Array,          # (N, 3) f32
-    leaf_id: jax.Array,     # (N,) int32
+    g3: jax.Array,          # (N, 3) f32: grad, hess, count — the count a
+                            # 0/1 row mask (exact in bfloat16: bf16x2
+                            # gives it no lo term)
+    leaf_id: jax.Array,     # (N,) int32; outside [0, num_leaves): dropped
     num_leaves: int,
     num_bins: int,
     precision: str = "int8",
@@ -405,9 +456,9 @@ def hist_leaves_pallas(
     F = (num_features or 2 * stored) if packed else stored
     fblk, tile_cols, nfb = _feature_blocks(stored, B, packed)
     f_pad = nfb * fblk
-    lpad = -(-L // 8) * 8
-    m_pad = 3 * lpad
-    T = row_tile if row_tile > 0 else _row_tile_for(m_pad, fblk * B, B)
+    out_rows = pass_rows(L, precision)[2]
+    _count_pass_rows(L, precision)
+    T = row_tile if row_tile > 0 else _row_tile_for(out_rows, fblk * B, B)
 
     if not isinstance(binned, HistBins):
         binned = prepare_hist_bins(binned, B, packed, row_tile=T,
@@ -422,16 +473,20 @@ def hist_leaves_pallas(
             f"of {T}): prepare them with the pass's num_bins / packed")
     nrt = n_pad // T
 
-    # padded rows carry zero g3 => no effect
+    # padded rows carry zero g3 => no effect; they and every row labelled
+    # outside [0, L) (a wave's dead rows) take the one id, L, that no row of
+    # the left operand has, whatever its padding
     g3t = jnp.pad(g3.astype(jnp.float32), ((0, n_pad - N), (0, 0))).T  # (3, n_pad)
-    leaf_p = jnp.pad(leaf_id.astype(jnp.int32), (0, n_pad - N),
-                     constant_values=lpad)[None, :]      # (1, n_pad)
+    leaf_id = leaf_id.astype(jnp.int32)
+    leaf_p = jnp.pad(
+        jnp.where((leaf_id >= 0) & (leaf_id < L), leaf_id, L),
+        (0, n_pad - N), constant_values=L)[None, :]      # (1, n_pad)
 
     iota_bins = (jnp.arange(B * fblk, dtype=jnp.int32)
                  // fblk).astype(jnp.float32)[None, :]      # (1, B*fblk)
 
     kernel = functools.partial(
-        _kernel, lpad=lpad, num_bins=B, fblk=fblk, precision=precision,
+        _kernel, num_leaves=L, num_bins=B, fblk=fblk, precision=precision,
         interpret=interpret, packed=packed,
     )
 
@@ -448,18 +503,19 @@ def hist_leaves_pallas(
                 pl.BlockSpec((3, T), lambda fb, rt: (0, rt)),
                 pl.BlockSpec((1, T), lambda fb, rt: (0, rt)),
             ],
-            out_specs=pl.BlockSpec((1, m_pad, fblk * B),
+            out_specs=pl.BlockSpec((1, out_rows, fblk * B),
                                    lambda fb, rt: (0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, m_pad, fblk * B), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((1, out_rows, fblk * B),
+                                           jnp.float32),
             interpret=interpret,
         )(iota_bins, bins_block, g3t, leaf_p)
 
     blocks = [one_block(b[:, :tile_cols]) for b in binned.blocks]
     out = jnp.concatenate(blocks, axis=0) if nfb > 1 else blocks[0]
 
-    # (nfb, 3*Lpad, B*fblk) -> (L, F, B, 3)
-    h = out.reshape(nfb, lpad, 3, B, fblk)
-    h = h.transpose(1, 0, 4, 3, 2).reshape(lpad, f_pad, B, 3)
+    # (nfb, out_rows, B*fblk) -> (L, F, B, 3)
+    h = out[:, :3 * L].reshape(nfb, 3, L, B, fblk)
+    h = h.transpose(2, 0, 4, 3, 1).reshape(L, f_pad, B, 3)
     if packed:
         # per block the unpacked feature order is [lo nibbles | hi nibbles]
         # = [2p0, 2p0+2, ... | 2p0+1, 2p0+3, ...]; invert it
@@ -471,4 +527,4 @@ def hist_leaves_pallas(
             pos += fblk
         inv = np.argsort(perm)
         h = h[:, inv]
-    return h[:L, :F]
+    return h[:, :F]

@@ -323,9 +323,12 @@ def hist_wave(
 ) -> jax.Array:             # (nslots, F, B, 3)
     """Histograms of the rows labeled ``0..nslots-1`` in one pass; rows
     labeled ``nslots`` (not part of the current wave) contribute nothing.
-    Used by the wave-batched leaf-wise grower (models/grower_wave.py): one
-    sacrificial slot absorbs the dead rows, then is sliced away."""
-    return hist_frontier(binned, g3, label, nslots + 1, num_bins,
+    Used by the wave-batched leaf-wise grower (models/grower_wave.py).  The
+    Pallas kernel drops a row whose label is no slot's; ``scatter`` and
+    ``onehot`` index by the label, so there one sacrificial slot absorbs
+    the dead rows, then is sliced away."""
+    dead = 0 if method == "pallas" else 1
+    return hist_frontier(binned, g3, label, nslots + dead, num_bins,
                          method=method, precision=precision,
                          packed=packed, num_features=num_features,
                          interpret=interpret)[:nslots]

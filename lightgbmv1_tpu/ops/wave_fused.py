@@ -108,7 +108,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..io.binning import MISSING_NAN, MISSING_ZERO
 from .hist_pallas import (MAX_LANES, _kernel as _hist_tile, _row_tile_for,
-                          packed_bins_of_rows)
+                          packed_bins_of_rows, pass_rows)
 from .split import (
     NEG_INF,
     NO_CONSTRAINT,
@@ -235,7 +235,7 @@ def child_scan_residue(hc, mask_c, csum_c, constr_c, depth_c, pout_c,
          sel.astype(jnp.float32)[:, None], lsel], axis=1)
 
 
-def _fused_kernel(*refs, nrt, lpad, num_bins, fblk, precision, interpret,
+def _fused_kernel(*refs, nrt, num_bins, fblk, precision, interpret,
                   params, use_mc, monotone_penalty, has_contri, sub,
                   apply_scale, child_scale, nslots, nchildren,
                   route_blk=False, fpb=0):
@@ -292,7 +292,7 @@ def _fused_kernel(*refs, nrt, lpad, num_bins, fblk, precision, interpret,
         leaf_ref = r["leaf"]
 
     _hist_tile(r["iota"], r["bins"], r["g3"], leaf_ref, r["acc"],
-               lpad=lpad, num_bins=num_bins, fblk=fblk,
+               num_leaves=nslots, num_bins=num_bins, fblk=fblk,
                precision=precision, interpret=interpret, packed=fpb > 0)
 
     rt = pl.program_id(1)
@@ -300,17 +300,17 @@ def _fused_kernel(*refs, nrt, lpad, num_bins, fblk, precision, interpret,
 
     @pl.when(rt == nrt - 1)
     def _scan():
-        # accumulator rows are (slot-major, channel-minor), lanes are
+        # accumulator rows are (channel-major, slot-minor), lanes are
         # (bin-major, feature-minor) — the same unscramble
         # hist_leaves_pallas applies outside, here on VMEM values
-        acc = r["acc"][0]                               # (3*lpad, B*fblk)
-        h = acc.reshape(lpad, 3, B, fblk).transpose(0, 3, 2, 1)
+        acc = r["acc"][0, :3 * nslots]                  # (3*S, B*fblk)
+        h = acc.reshape(3, nslots, B, fblk).transpose(1, 3, 2, 0)
         if fpb:
             # packed accumulator order is [lo nibbles | hi nibbles]; the
             # tie-band pick is feature-ORDER-sensitive (first in band =
             # min feature), so restore natural order BEFORE any scan
             h = jnp.stack([h[:, :fpb], h[:, fpb:]], axis=2) \
-                .reshape(lpad, fblk, B, 3)
+                .reshape(nslots, fblk, B, 3)
         meta_blk = FeatureMeta(
             num_bins=r["nb"][...][0],
             missing_type=r["mt"][...][0],
@@ -325,7 +325,7 @@ def _fused_kernel(*refs, nrt, lpad, num_bins, fblk, precision, interpret,
             # smaller-child + parent subtraction IN VMEM — the exact op
             # order of subtract_child_hists (dequant multiply first, then
             # the smaller/larger select), so values are bit-identical
-            hsm = h[:nslots]                            # (S, fblk, B, 3)
+            hsm = h                                     # (S, fblk, B, 3)
             r["hsmall"][...] = hsm                      # raw (int on quant)
             if apply_scale:
                 # power-of-two scales (ops/quantize.py) make this exact,
@@ -368,8 +368,10 @@ def fused_wave_scan(binned, g3, label, *, nslots, nchildren, num_bins,
     """One fused wave round over all feature blocks.
 
     ``nslots`` counts the ACCUMULATED slots (smaller children in
-    subtraction mode, all 2S children pool-free); slot ``nslots`` is the
-    sacrificial dead-row slot, as in ``hist_wave``.  ``parent`` non-None
+    subtraction mode, all 2S children pool-free); a row labelled
+    ``nslots`` is dead and adds to nothing, as in ``hist_wave``'s Pallas
+    pass, whose operand shapes and row tile this round shares
+    (``pass_rows``).  ``parent`` non-None
     selects the subtraction-composed mode.  ``route`` non-None (dict
     ``dbin (N,) / oleaf (N,) / rmeta (S, RMETA_COLS)``) folds the
     partition in: ``label`` is ignored (pass None) — feature block 0
@@ -402,15 +404,13 @@ def fused_wave_scan(binned, g3, label, *, nslots, nchildren, num_bins,
         fblk = max(1, min(F, MAX_LANES // B))
         nfb = -(-F // fblk)
     f_pad = nfb * fblk
-    L = nslots + 1
-    lpad = -(-L // 8) * 8
-    m_pad = 3 * lpad
+    out_rows = pass_rows(nslots, precision)[2]
     # the row tile is priced on the UNPACKED lane count either way: the
     # same T means the same row partition, so every (leaf, bin, feature)
     # accumulator cell sums the same per-tile dots in the same order —
     # the packed round's f32 histograms are bit-identical to unpacked
     T = row_tile if row_tile > 0 else _row_tile_for(
-        m_pad, max(1, min(F, MAX_LANES // B)) * B, B)
+        out_rows, max(1, min(F, MAX_LANES // B)) * B, B)
     nrt = -(-N // T)
     n_pad = nrt * T
 
@@ -437,7 +437,7 @@ def fused_wave_scan(binned, g3, label, *, nslots, nchildren, num_bins,
         leaf_p = None
     else:
         leaf_p = jnp.pad(label.astype(jnp.int32), (0, n_pad - N),
-                         constant_values=lpad)[None, :]
+                         constant_values=nslots)[None, :]
     iota_bins = (jnp.arange(B * fblk, dtype=jnp.int32)
                  // fblk).astype(jnp.float32)[None, :]
 
@@ -465,7 +465,7 @@ def fused_wave_scan(binned, g3, label, *, nslots, nchildren, num_bins,
     child_scale = cscale is not None
 
     kern = functools.partial(
-        _fused_kernel, nrt=nrt, lpad=lpad, num_bins=B, fblk=fblk,
+        _fused_kernel, nrt=nrt, num_bins=B, fblk=fblk,
         precision=precision, interpret=interpret, params=params,
         use_mc=use_mc, monotone_penalty=monotone_penalty,
         has_contri=has_contri, sub=sub, apply_scale=apply_scale,
@@ -537,7 +537,8 @@ def fused_wave_scan(binned, g3, label, *, nslots, nchildren, num_bins,
             in_specs=specs,
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((1, m_pad, fblk * B), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((1, out_rows, fblk * B),
+                                       jnp.float32)],
             interpret=interpret,
         )(*ins)
         res_blocks.append(out[0])
@@ -841,19 +842,22 @@ def plan_wave_loop(*, rounds, N, F, num_bins, K, L, use_sub, slot_buckets,
     Fk = 2 * -(-F // 2) if packed else F    # kernel feature width
     Fb = -(-F // 2) if packed else F        # stored bins columns
 
-    def lanes_pad(S):
-        nsl = S if use_sub else 2 * S
-        return 3 * (-(-(nsl + 1) // 8) * 8)
+    def staged_tile(S):
+        """The row tile of the staged pass of bucket ``S`` (a quantized
+        bucket's is the int8sr kernel's)."""
+        rows = pass_rows(S if use_sub else 2 * S,
+                         "int8sr" if S in quant_buckets else precision)[2]
+        return _row_tile_for(rows, F * B, B)
 
-    m_pad = lanes_pad(K)
-    T = _row_tile_for(m_pad, F * B, B)
+    out_rows = pass_rows(K if use_sub else 2 * K, precision)[2]
+    T = _row_tile_for(out_rows, F * B, B)
     nrt = -(-max(N, 1) // T)
     n_pad = nrt * T
-    acc_bytes = m_pad * Fk * B * 4
+    acc_bytes = out_rows * Fk * B * 4
     # the one-hot working set _row_tile_for budgets for, per row tile,
     # plus the resident bins row tile (packed bytes when packed — the
     # layout's VMEM dividend)
-    stream_bytes = T * (14 * min(Fk * B, 512) + 16 * m_pad) + T * Fb
+    stream_bytes = T * (14 * min(Fk * B, 512) + 16 * out_rows) + T * Fb
     state_bytes = (L * 12 * 4 + n_pad * 4
                    + (L * Fk * B * 3 * 4 if use_sub else 0))
     total_bytes = acc_bytes + stream_bytes + state_bytes
@@ -886,8 +890,7 @@ def plan_wave_loop(*, rounds, N, F, num_bins, K, L, use_sub, slot_buckets,
         plan["reason"] = ("deep-precision drop would change the "
                           "accumulate dtype mid-loop")
         return plan
-    tiles = {_row_tile_for(lanes_pad(S), F * B, B) for S in slot_buckets}
-    if len(tiles) > 1:
+    if {staged_tile(S) for S in slot_buckets} != {T}:
         plan["reason"] = ("slot-bucket ladder changes the row tile "
                           "(accumulation order would differ)")
         return plan
@@ -901,7 +904,7 @@ def plan_wave_loop(*, rounds, N, F, num_bins, K, L, use_sub, slot_buckets,
     return plan
 
 
-def _loop_kernel(*refs, R, nrt, T, lpad, num_bins, fblk, N, K, L,
+def _loop_kernel(*refs, R, nrt, T, num_bins, fblk, N, K, L,
                  precision, interpret, params, monotone_penalty,
                  has_contri, sub, scaled, ladder, quant_ladder, max_depth,
                  topk_fn, qmax, packed=False):
@@ -1074,19 +1077,19 @@ def _loop_kernel(*refs, R, nrt, T, lpad, num_bins, fblk, N, K, L,
     else:
         val3 = g3v
     _hist_tile(r["iota"], r["bins"], _ValRef(val3), _ValRef(label),
-               r["acc"], lpad=lpad, num_bins=B, fblk=fblk,
+               r["acc"], num_leaves=nsl, num_bins=B, fblk=fblk,
                precision=precision, interpret=interpret, packed=packed)
 
     @pl.when(rt == nrt - 1)
     def _commit():
-        acc = r["acc"][0]
-        h = acc.reshape(lpad, 3, B, fblk).transpose(0, 3, 2, 1)
+        acc = r["acc"][0, :3 * nsl]
+        h = acc.reshape(3, nsl, B, fblk).transpose(1, 3, 2, 0)
         if packed:
             # [lo nibbles | hi nibbles] -> natural feature order BEFORE
             # the order-sensitive tie-band pick (and the pool commit,
             # which the host replay reads in natural order)
             h = jnp.stack([h[:, :fblk // 2], h[:, fblk // 2:]], axis=2) \
-                .reshape(lpad, fblk, B, 3)
+                .reshape(nsl, fblk, B, 3)
         ones3 = jnp.ones((1, 3), jnp.float32)
         scale3 = (jnp.where(quant_r, r["qscale"][...], ones3)
                   if quant else ones3)                  # (1, 3)
@@ -1232,11 +1235,10 @@ def make_fused_wave_loop(*, meta, params, num_bins, precision,
         L = ft12.shape[0]
         C = 2 * K
         nsl = K if sub else C
-        lpad = -(-(nsl + 1) // 8) * 8
-        m_pad = 3 * lpad
+        out_rows = pass_rows(nsl, precision)[2]
         # row tile from the UNPACKED lane count (plan_wave_loop's rule):
         # same T => same row partition => bit-identical f32 accumulation
-        T = _row_tile_for(m_pad, F0 * B, B)
+        T = _row_tile_for(out_rows, F0 * B, B)
         nrt = -(-N // T)
         n_pad = nrt * T
         R = rounds
@@ -1319,7 +1321,7 @@ def make_fused_wave_loop(*, meta, params, num_bins, precision,
             out_specs.append(full_spec(pool_in.shape))
 
         scratch = [
-            pltpu.VMEM((1, m_pad, F * B), jnp.float32),   # acc
+            pltpu.VMEM((1, out_rows, F * B), jnp.float32),   # acc
             pltpu.VMEM((L, 12), jnp.float32),             # ft_scr
             pltpu.VMEM((1, 1), jnp.int32),                # nl_scr
             pltpu.VMEM((1, n_pad), jnp.int32),            # leaf_scr
@@ -1328,7 +1330,7 @@ def make_fused_wave_loop(*, meta, params, num_bins, precision,
             scratch.append(pltpu.VMEM(pool_in.shape, jnp.float32))
 
         kern = functools.partial(
-            _loop_kernel, R=R, nrt=nrt, T=T, lpad=lpad, num_bins=B,
+            _loop_kernel, R=R, nrt=nrt, T=T, num_bins=B,
             fblk=F, N=N, K=K, L=L, precision=precision,
             interpret=interpret, params=params,
             monotone_penalty=monotone_penalty, has_contri=has_contri,

@@ -161,6 +161,9 @@ def test_pallas_single_leaf_masks_rows(rng):
     """hist_one_leaf through the pallas method (the leafwise smaller-child
     pass) must equal the scatter slice."""
     binned, g3, leaf_id = make_inputs(rng, N=700, F=4, B=16, L=3)
+    # the count channel is a 0/1 row mask in every caller; hist_one_leaf's
+    # own precision (bf16x2) gives it no lo term
+    g3 = g3.at[:, 2].set((rng.rand(700) < 0.8).astype(np.float32))
     full = np.asarray(hist_leaves_scatter(binned, g3, leaf_id, 3, 16))
     import lightgbmv1_tpu.ops.hist_pallas as hp
     import functools
@@ -305,3 +308,167 @@ def test_prepared_bins_train_same_model(monkeypatch, grower, params):
     b, text_raw = train()
     assert not isinstance(b._gbdt._grow_binned, HistBins)
     assert text_prepared == text_raw
+
+
+# ---------------------------------------------------------------------------
+# the wave pass's MXU left operand: only rows that carry information
+# ---------------------------------------------------------------------------
+
+_WAVE_PRECISIONS = ["bf16", "bf16x2", "f32", "int8", "int8sr"]
+
+
+def _wave_inputs(layout, slots, precision, N=1500, seed=0):
+    """``(binned, bins, g3, label, num_bins, kw)`` of a wave pass: labels
+    ``0..slots-1`` are live, ``slots`` is the wave's dead label, and a few
+    rows carry a label far above it.  ``N`` is no multiple of a row tile."""
+    from lightgbmv1_tpu.ops.hist_pallas import pack4bit, prepare_hist_bins
+
+    rng = np.random.RandomState(1000 * seed + slots)
+    packed = layout == "packed4"
+    B, F = (16, 11) if packed else (64, 37)      # 64 bins: two blocks of 32
+    bins = rng.randint(0, B, size=(F, N)).astype(np.uint8)
+    binned = jnp.asarray(pack4bit(bins) if packed else bins)
+    if layout == "prepared":
+        binned = prepare_hist_bins(binned, B)
+    if precision == "int8sr":        # rows arrive pre-quantized
+        g3 = rng.randint(-127, 128, size=(N, 3)).astype(np.float32)
+    elif precision == "f32":
+        # on a 2^-6 grid every partial sum is exact: XLA:CPU's f32 matmul
+        # (the interpreter's) blocks by the operands' shapes, the MXU does not
+        g3 = (rng.randint(-256, 257, size=(N, 3)) / 64.0).astype(np.float32)
+    else:
+        g3 = rng.randn(N, 3).astype(np.float32)
+    g3[:, 2] = rng.rand(N) < 0.9                 # the count: a 0/1 row mask
+    label = rng.randint(0, slots + 1, N).astype(np.int32)
+    label[::13] = slots + 1000
+    kw = dict(precision=precision, packed=packed, num_features=F,
+              interpret=_PALLAS_INTERPRET)
+    return binned, bins, jnp.asarray(g3), label, B, kw
+
+
+@pytest.mark.parametrize("precision", _WAVE_PRECISIONS)
+@pytest.mark.parametrize("slots", [1, 4, 16, 63])
+@pytest.mark.parametrize("layout", ["prepared", "raw", "packed4"])
+def test_wave_pass_bit_equal_to_dead_slot_form(layout, slots, precision):
+    """``hist_wave`` on the Pallas method asks the kernel for the live
+    slots alone.  The parent's form, written out here — one slot more for
+    the dead rows, sliced away — gives the same bits on the same row tile:
+    an output row is its own f32 dot products, whatever other rows share
+    the product; bf16x2's count row is its hi product alone.  Against the
+    scatter oracle: each precision's bound, counts exact."""
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.hist_pallas import (_feature_blocks,
+                                                _row_tile_for, bin_matrix,
+                                                hist_leaves_pallas,
+                                                pass_rows)
+
+    binned, bins, g3, label, B, kw = _wave_inputs(layout, slots, precision)
+    got = hist_wave(binned, g3, jnp.asarray(label), slots, B,
+                    method="pallas", **kw)
+    assert got.shape == (slots, bins.shape[0], B, 3)
+    fblk = _feature_blocks(bin_matrix(binned).shape[0], B, kw["packed"])[0]
+    tile = _row_tile_for(pass_rows(slots, precision)[2], fblk * B, B)
+    parent = hist_leaves_pallas(
+        binned, g3, jnp.asarray(np.minimum(label, slots)), slots + 1, B,
+        row_tile=tile, **kw)[:slots]
+    got, parent = np.asarray(got), np.asarray(parent)
+    np.testing.assert_array_equal(got, parent)
+    np.testing.assert_array_equal(np.signbit(got), np.signbit(parent))
+
+    live = label < slots
+    ref = np.asarray(hist_leaves_scatter(
+        jnp.asarray(bins[:, live]), g3[live], jnp.asarray(label[live]),
+        slots, B))
+    np.testing.assert_array_equal(got[..., 2], ref[..., 2])
+    if precision in ("f32", "int8sr"):           # exact sums by construction
+        np.testing.assert_array_equal(got, ref)
+    elif precision == "bf16x2":
+        np.testing.assert_allclose(got, ref, rtol=1e-2, atol=1e-2)
+    else:       # single-pass bf16 / quantized int8: coarse but bounded
+        assert np.abs(got - ref).max() < 0.5
+        np.testing.assert_allclose(got.sum((0, 2)), ref.sum((0, 2)),
+                                   rtol=5e-2, atol=5e-1)
+
+
+@pytest.mark.parametrize("precision", ["bf16x2", "bf16", "int8sr"])
+@pytest.mark.parametrize("slots", [8, 16, 64])
+def test_wave_dead_rows_add_nothing(slots, precision):
+    """Where the live slots fill the operand to its last row (a multiple
+    of 8), rows labelled ``slots``, rows labelled far above it and the row
+    padding still land nowhere: whatever values they carry, no bit of any
+    live slot's histogram moves, and the sums are the live rows' alone."""
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+
+    binned, bins, g3, label, B, kw = _wave_inputs("raw", slots, precision)
+    dead = jnp.asarray(label >= slots)[:, None]
+    lab = jnp.asarray(label)
+    loud = hist_wave(binned, jnp.where(dead, 100.0, g3), lab, slots, B,
+                     method="pallas", **kw)
+    quiet = hist_wave(binned, jnp.where(dead, 0.0, g3), lab, slots, B,
+                      method="pallas", **kw)
+    np.testing.assert_array_equal(np.asarray(loud), np.asarray(quiet))
+    live = label < slots
+    ref = np.asarray(hist_leaves_scatter(
+        jnp.asarray(bins[:, live]), g3[live], jnp.asarray(label[live]),
+        slots, B))
+    np.testing.assert_array_equal(np.asarray(loud)[..., 2], ref[..., 2])
+
+
+@pytest.mark.parametrize("slots,precision,mxu_rows,live_rows", [
+    (1, "bf16x2", 16, 5), (4, "bf16x2", 32, 20), (16, "bf16x2", 80, 80),
+    (63, "bf16", 192, 189), (63, "int8sr", 192, 189), (1, "f32", 8, 3)])
+def test_pass_rows_gauges(slots, precision, mxu_rows, live_rows):
+    """``hist_pass_mxu_rows`` / ``hist_pass_live_rows``: the left operand's
+    rows as padded and as they carry data, set when a pass is traced."""
+    from lightgbmv1_tpu.obs.metrics import default_registry
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+
+    default_registry().reset(["hist_pass_mxu_rows", "hist_pass_live_rows"])
+    binned, _, g3, label, B, kw = _wave_inputs("raw", slots, precision, N=64)
+    jax.jit(lambda b, g, l: hist_wave(b, g, l, slots, B, method="pallas",
+                                      **kw)).lower(
+        binned, g3, jnp.asarray(label))
+    snap = default_registry().snapshot()
+    labels = '{slots="%d",precision="%s"}' % (slots, precision)
+    assert snap["hist_pass_mxu_rows" + labels] == mxu_rows
+    assert snap["hist_pass_live_rows" + labels] == live_rows
+
+
+# sha256 of the model text the PARENT of PR 29 (commit 8bc3106: the kernel
+# asked for one dead slot more, slots padded to 8, six rows a slot under
+# bf16x2) dumps for the problem below, from a run of that commit on this
+# installation's CPU interpreter
+_PARENT_MODEL_TEXT = {
+    "wave": "9a63b072d933c77a99ba11bae85fc187be519f2a9305484c60e18a6f2db1c06a",
+    "levelwise":
+        "8f4db26cee56705cd29575f686d56998164b8211d07061fa78a60ea5d58eaa92",
+    "leafwise":
+        "fa2595c8529043c815fdae89b70c248c7dec1d03ce1a40d0fc3f13d6c1ac1005",
+}
+
+
+@pytest.mark.parametrize("grower,params", [
+    ("wave", {"num_leaves": 63}),
+    ("levelwise", {"num_leaves": 15, "tree_growth": "levelwise"}),
+    ("leafwise", {"num_leaves": 7}),      # auto wave size 1: sequential
+])
+def test_growers_dump_the_parents_model_text(monkeypatch, grower, params):
+    """No result bit changed: on 2,600 rows (three row tiles of 1024) and
+    the 4 / 16 / K slot ladder the three growers dump, byte for byte, the
+    model text of the parent commit."""
+    import hashlib
+
+    import lightgbmv1_tpu as lgb
+    from lightgbmv1_tpu.models import grower_wave as gw
+
+    monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
+    rng = np.random.RandomState(5)
+    X = rng.randn(2600, 9)
+    y = (X[:, 0] - 0.7 * X[:, 1] + 0.4 * X[:, 2] * X[:, 3]
+         + 0.3 * rng.randn(2600) > 0).astype(float)
+    p = {"objective": "binary", "verbosity": -1, "seed": 11, "max_bin": 63,
+         "hist_method": "pallas", "min_data_in_leaf": 5, **params}
+    text = lgb.train(p, lgb.Dataset(X, label=y),
+                     num_boost_round=3).model_to_string()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _PARENT_MODEL_TEXT[grower]

@@ -56,11 +56,12 @@ def rows():
 @pytest.mark.parametrize("num_bins", [16, 64, 256])
 def test_hist_kernel_lowers(rows, num_bins, precision):
     """The default-on-TPU histogram kernel at every kernel-width rung,
-    every precision, and the slot counts of the wave ladder (1 = root
-    pass, 16 and 64 = ramp and sustained buckets incl. the dead slot)."""
+    every precision, and the live slots the trainer asks it for: 1 = root
+    pass, 4 / 16 / 63 = the wave ladder's buckets (no dead slot: the
+    kernel drops a row whose label is no slot's), 64 = a full frontier."""
     rng, N, g3 = rows
     binned = jnp.asarray(rng.randint(0, num_bins, (28, N)).astype(np.uint8))
-    for slots in (1, 16, 64):
+    for slots in (1, 4, 16, 63, 64):
         leaf = jnp.asarray(rng.randint(0, slots, N).astype(np.int32))
         lower_for_tpu(
             lambda b, g, l: hist_leaves_pallas(b, g, l, slots, num_bins,
@@ -73,12 +74,13 @@ def test_hist_kernel_packed4_lowers(rows, precision):
     rng, N, g3 = rows
     packed = jnp.asarray(pack4bit(
         rng.randint(0, 16, (27, N)).astype(np.uint8)))     # odd F tail
-    leaf = jnp.asarray(rng.randint(0, 64, N).astype(np.int32))
-    lower_for_tpu(
-        lambda b, g, l: hist_leaves_pallas(b, g, l, 64, 16,
-                                           precision=precision, packed=True,
-                                           num_features=27),
-        packed, g3, leaf)
+    for slots in (1, 4, 16, 63):
+        leaf = jnp.asarray(rng.randint(0, slots + 1, N).astype(np.int32))
+        lower_for_tpu(
+            lambda b, g, l: hist_leaves_pallas(
+                b, g, l, slots, 16, precision=precision, packed=True,
+                num_features=27),
+            packed, g3, leaf)
 
 
 def _big_u8_relayouts(txt, min_elems):
@@ -216,6 +218,106 @@ def test_prepared_pass_compiles_without_relayout(one_chip):
     assert len(re.findall(rf"= {operand}\S* bitcast\(", txt)) == 2
     assert not re.search(rf"= {operand}\S* (copy|slice|fusion)\(", txt)
     assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,2048\]", txt)
+
+
+def _kernel_result_rows(txt):
+    """Second dimension of every ``tpu_custom_call``'s f32 result."""
+    return [int(m) for m in re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call.*-> tensor<[0-9]+x([0-9]+)"
+        r"x[0-9]+xf32>", txt)]
+
+
+def test_wave_grow_calls_hold_live_slots_only(rows, monkeypatch):
+    """A 255-leaf wave grower's lowered ``grow`` (root pass and the 4 / 16
+    / 63 ladder): every histogram call's result block is 3 rows a LIVE
+    slot rounded up to 8 — 8 / 16 / 48 / 192 — and none has the parent's
+    72 rows (17 slots stored as 24) or 24 (2 and 5 slots stored as 8)."""
+    from lightgbmv1_tpu.models import grower_wave as gw
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+
+    rng, N, g3 = rows
+    F, B = 37, 64
+    monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
+    grow = gw.make_wave_grower(
+        num_leaves=255, num_bins=B, meta=_probe_meta(F, B),
+        params=SplitParams(min_data_in_leaf=2.0), wave_size=63,
+        hist_wave_fn=lambda b, g, l, n, deep=False: hist_wave(
+            b, g, l, n, B, method="pallas",
+            precision="bf16" if deep else "bf16x2"))
+    prepared = prepare_hist_bins(
+        jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8)), B)
+    txt = lower_for_tpu(
+        lambda b: grow(b, g3, jnp.ones(F, bool), jax.random.PRNGKey(0)),
+        prepared)
+    assert set(_kernel_result_rows(txt)) == {8, 16, 48, 192}
+
+
+# benchmarks/roofline.py's pattern and reading of a call's HLO text, copied:
+# the benchmark is not this PR's to import or edit, and its reader must keep
+# finding ``f32[n, 3 x stored slots, block_features x bins]`` in the result
+_ROOFLINE_SHAPE = re.compile(r"(u8|s8|s32|f32|bf16)\[([0-9,]+)\]")
+
+
+def _roofline_call_shapes(long_name, bins):
+    head, _, tail = long_name.partition("custom-call(")
+    res = _ROOFLINE_SHAPE.search(head.split(" = ", 1)[-1])
+    if not res:
+        return None
+    out = [int(x) for x in res.group(2).split(",")]
+    if len(out) != 3:
+        return None
+    shapes = {"features": out[2] // bins, "slots": out[1] // 3}
+    u8 = [m for m in _ROOFLINE_SHAPE.finditer(tail)
+          if m.group(1) in ("u8", "s8")]
+    if u8:
+        shapes["rows"] = int(u8[0].group(2).split(",")[0])
+    return shapes
+
+
+@pytest.mark.parametrize("slots,precision,credited", [
+    (1, "bf16x2", 2), (4, "bf16x2", 5), (16, "bf16x2", 16),
+    (63, "bf16", 64), (63, "int8sr", 64)])
+def test_ladder_pass_compiles_and_the_roofline_reader_parses_it(
+        one_chip, slots, precision, credited):
+    """Compiled for the chip at the live slots the trainer runs, over
+    prepared bins: the call keeps the name ``hist_leaves_pallas`` and a
+    three-dimensional f32 result with the lanes last, from which the
+    roofline reader's pattern credits at least the live slots (3 rows a
+    live slot rounded up to 8, over 3)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+
+    N, F, B = 200_000, 37, 64
+    n_pad = 200_704
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    prepared = jax.tree_util.tree_map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda b: prepare_hist_bins(b, B),
+                       shape((F, N), jnp.uint8)))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        txt = jax.jit(lambda b, g, l: hist_wave(
+            b, g, l, slots, B, method="pallas", precision=precision)).lower(
+                prepared, shape((N, 3), jnp.float32),
+                shape((N,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    calls = [ln.strip() for ln in txt.splitlines()
+             if re.match(r"\s*%hist_leaves_pallas[.0-9]* = ", ln)
+             and "custom-call(" in ln]
+    assert len(calls) == 2, calls                 # two blocks of 32 features
+    for ln in calls:
+        assert _roofline_call_shapes(ln, B) == {
+            "features": 32, "slots": credited, "rows": n_pad}
+    assert credited >= slots
 
 
 def _probe_meta(F, B):
