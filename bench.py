@@ -434,220 +434,14 @@ def measure_phases(ds, N, gb_lw, schedule, hist_fields, n_valid,
     }
 
 
-def measure_fused(ds, N, backend, n_iters):
-    """``hist_method=fused`` A/B (ISSUE 13 — ops/wave_fused.py), every
-    backend:
-
-    * **parity** — trees of the fused run must byte-compare to the
-      staged ``hist_method=pallas`` run's model text at the bench
-      config (the same histogram arithmetic, fused vs staged
-      scheduling; on CPU both ride the Pallas interpreter — the lane
-      tests/test_wave_fused.py pins).
-    * **throughput** — the fused run's M row-trees/s next to the
-      headline.
-    * **HBM accounting** — the compiled executables' own
-      ``cost_analysis()`` bytes for ONE sustained-bucket wave round:
-      staged (hist pass → subtraction → vmapped split scan) minus fused
-      (one kernel, residue out).  ``fused_hbm_bytes_saved_per_round``
-      is that difference — the measured form of the "the (F, B, 3)
-      histogram stack never materializes off-chip" claim, with the
-      analytic stack size recorded beside it for scale.
-
-    ``fused_ok`` itself is joined in main(): parity AND (on device) the
-    measured fused round <= staged ``phase_hist_ms + phase_split_ms``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbmv1_tpu.basic import _objective_string
-    from lightgbmv1_tpu.config import Config
-    from lightgbmv1_tpu.io.model_text import model_to_string
-    from lightgbmv1_tpu.models.gbdt import create_boosting
-
-    fields = {}
-    base = {
-        "objective": "binary", "num_leaves": 255, "max_bin": 63,
-        "learning_rate": 0.1, "min_data_in_leaf": 20, "verbosity": -1,
-        "tree_growth": "leafwise",
-    }
-
-    def run(hist_method):
-        cfg = Config.from_dict({**base, "hist_method": hist_method})
-        gb = create_boosting(cfg, ds)
-        gb.train_iters(n_iters)
-        jax.block_until_ready(gb._train_scores.score)
-        dt = 1e30
-        for _ in range(2):
-            t0 = time.time()
-            gb.train_iters(n_iters)
-            jax.block_until_ready(gb._train_scores.score)
-            dt = min(dt, time.time() - t0)
-        text = model_to_string(
-            gb.materialize_host_trees(),
-            objective_string=_objective_string(cfg), num_class=1,
-            num_tree_per_iteration=1,
-            feature_names=list(ds.feature_names),
-            feature_infos=ds.feature_infos())
-        return gb, dt, text
-
-    gb_fu, fu_dt, fu_text = run("fused")
-    _, st_dt, st_text = run("pallas")
-    fields["fused_parity_ok"] = bool(fu_text == st_text)
-    fields["fused_M_row_trees_per_s"] = round(N * n_iters / fu_dt / 1e6, 3)
-    fields["fused_staged_pallas_M_row_trees_per_s"] = round(
-        N * n_iters / st_dt / 1e6, 3)
-
-    # analytic single-read contract (ISSUE 15) — pure shape arithmetic,
-    # recorded even when the compile leg below cannot run: the routed
-    # round touches the binned matrix once (F*N kernel sweep + N
-    # decision bins) vs the staged partition's K-row gather + hist read
-    from lightgbmv1_tpu.models.grower_wave import auto_wave_size
-
-    F_b = ds.train_matrix.shape[0]
-    K_b = auto_wave_size(255)
-    fields["staged_round_binned_bytes_analytic"] = int(F_b * N + K_b * N)
-    fields["fused_round_binned_bytes_analytic"] = int(F_b * N + N)
-    fields["fused_round_single_read_ok"] = bool(
-        fields["fused_round_binned_bytes_analytic"]
-        < fields["staged_round_binned_bytes_analytic"])
-
-    # ---- compiled-executable HBM accounting (cost_analysis bytes) ------
-    # own guard region: a backend that cannot lower (or cost-analyze)
-    # the round executables must not take the parity fields down with it
-    try:
-        fields.update(_fused_round_bytes(ds, N, backend, gb_fu))
-    except Exception as e:  # noqa: BLE001
-        fields["fused_bytes_error"] = f"{type(e).__name__}: {e}"[:200]
-    return fields
-
-
-def measure_fused_waveloop(ds, N, backend, n_iters):
-    """Persistent multi-round wave loop A/B (ISSUE 17 —
-    ``wave_loop_rounds`` on the ``hist_method=fused`` path), every
-    backend:
-
-    * **parity** — the looped run's trees must byte-compare to the
-      single-round fused run's model text (which measure_fused pins
-      against staged): the R-rounds-per-launch kernel replays the same
-      round boundary, so this is the whole-loop bit contract.
-    * **launch accounting** — the VMEM plan (recorded verbatim: why this
-      shape looped or fell back) and the analytic launch/state-traffic
-      deltas: each R-round segment saves R-1 kernel launches and R-1
-      round-trips of the resident state (frontier table + leaf ids +
-      hist pool — ``2 * state_bytes`` per avoided boundary).
-    * **measured bytes** — the compiled ``grow.fused_loop`` vs
-      ``grow.fused_round`` executables' own cost_analysis bytes
-      (obs/xla compile telemetry), the measured form of "state never
-      spills", recorded beside the analytic figure.
-    * **``phase_wave_loop_ms``** — the looped run's per-iteration round
-      dispatch ms by the differential method: the single-round run's
-      per-iter wall minus the looped run's per-iter wall is the
-      boundary saving; applied to the single-round wall it prices the
-      loop dispatch as a phase row (bench_trend watches it at the 10%
-      bar on device captures).
-
-    ``fused_loop_ok`` is joined in main(): parity everywhere AND, on
-    device, loop per-iter <= single-round per-iter.
-    """
-    import jax
-
-    from lightgbmv1_tpu.basic import _objective_string
-    from lightgbmv1_tpu.config import Config
-    from lightgbmv1_tpu.io.model_text import model_to_string
-    from lightgbmv1_tpu.models.gbdt import create_boosting
-    from lightgbmv1_tpu.models.grower_wave import (_SUB_STATE_CAP_BYTES,
-                                                   auto_wave_size,
-                                                   slot_buckets_for)
-    from lightgbmv1_tpu.obs import xla as obs_xla
-    from lightgbmv1_tpu.ops.wave_fused import plan_wave_loop
-
-    fields = {}
-    R_REQ = 4
-    base = {
-        "objective": "binary", "num_leaves": 255, "max_bin": 63,
-        "learning_rate": 0.1, "min_data_in_leaf": 20, "verbosity": -1,
-        "tree_growth": "leafwise", "hist_method": "fused",
-    }
-
-    def run(over):
-        cfg = Config.from_dict({**base, **over})
-        gb = create_boosting(cfg, ds)
-        gb.train_iters(n_iters)
-        jax.block_until_ready(gb._train_scores.score)
-        dt = 1e30
-        for _ in range(2):
-            t0 = time.time()
-            gb.train_iters(n_iters)
-            jax.block_until_ready(gb._train_scores.score)
-            dt = min(dt, time.time() - t0)
-        text = model_to_string(
-            gb.materialize_host_trees(),
-            objective_string=_objective_string(cfg), num_class=1,
-            num_tree_per_iteration=1,
-            feature_names=list(ds.feature_names),
-            feature_infos=ds.feature_infos())
-        return gb, dt, text
-
-    gb_lp, lp_dt, lp_text = run({"wave_loop_rounds": R_REQ})
-    _, sr_dt, sr_text = run({})
-    fields["fused_loop_parity_ok"] = bool(lp_text == sr_text)
-    fields["wave_loop_M_row_trees_per_s"] = round(
-        N * n_iters / lp_dt / 1e6, 3)
-    fields["wave_loop_single_round_M_row_trees_per_s"] = round(
-        N * n_iters / sr_dt / 1e6, 3)
-
-    # the static plan, recorded verbatim (why this shape looped or fell
-    # back) + the analytic launch / state-traffic deltas it implies
-    F_b = int(ds.train_matrix.shape[0])
-    K_b = auto_wave_size(255)
-    L_b = 255
-    B_b = 64
-    use_sub_b = L_b * F_b * B_b * 3 * 4 <= _SUB_STATE_CAP_BYTES
-    plan = plan_wave_loop(
-        rounds=R_REQ, N=N, F=F_b, num_bins=B_b, K=K_b, L=L_b,
-        use_sub=use_sub_b, slot_buckets=slot_buckets_for(K_b, N))
-    fields["fused_loop_plan"] = {k: (list(v) if isinstance(v, tuple)
-                                     else v) for k, v in plan.items()}
-    R_eff = plan["rounds"] if plan["eligible"] else 1
-    fields["fused_loop_rounds"] = int(R_eff)
-    fields["fused_loop_launches_saved_per_segment"] = int(R_eff - 1)
-    fields["fused_loop_state_bytes_saved_per_segment_analytic"] = int(
-        (R_eff - 1) * 2 * plan["state_bytes"])
-
-    # measured executable bytes (obs/xla compile telemetry): the looped
-    # vs single-round grow executables' own cost_analysis
-    st = obs_xla.compile_stats()
-    for label, key in (("grow.fused_loop", "fused_loop_bytes_accessed"),
-                       ("grow.fused_round",
-                        "fused_round_bytes_accessed")):
-        b = (st.get(label) or {}).get("bytes_accessed")
-        fields[key] = int(b) if b is not None else None
-
-    # phase_wave_loop_ms by the differential method (device sessions:
-    # the watched phase row; the CPU interpreter's wall is
-    # unrepresentative, so the CPU record carries the raw per-iter ms
-    # pair only, like fused_ok's perf leg)
-    lp_it = lp_dt / n_iters * 1e3
-    sr_it = sr_dt / n_iters * 1e3
-    fields["wave_loop_ms_per_iter"] = round(lp_it, 3)
-    fields["wave_loop_single_round_ms_per_iter"] = round(sr_it, 3)
-    if backend != "cpu" and fields["fused_loop_rounds"] > 1:
-        # joined into phase_wave_loop_ms in main(), where the
-        # single-round dispatch ms (partition_fused_ms_per_iter) lives
-        fields["wave_loop_boundary_saving_ms_per_iter"] = round(
-            sr_it - lp_it, 3)
-    return fields
-
-
 def measure_packed(X, y, backend, n_iters):
     """``bin_layout=packed4`` A/B (ISSUE 18 — sub-byte bin residency),
     every backend, at its own ``max_bin=15`` config (the nibble regime):
 
-    * **parity** — trees of the packed fused run must byte-compare to
-      the unpacked fused AND staged runs' model text: the kernels
-      unpack nibbles in VMEM onto the identical arithmetic, so packing
-      is a pure storage-layout change (the lane
-      tests/test_wave_fused.py pins across the golden matrix).
+    * **parity** — trees of the packed run must byte-compare to the
+      unpacked run's model text: the kernel unpacks nibbles in VMEM
+      onto the identical arithmetic, so packing is a pure
+      storage-layout change (the lane tests/test_packed_bins.py pins).
     * **analytic bytes** — the per-round binned HBM read halves:
       ``ceil(F/2) * N`` packed bytes vs ``F * N`` unpacked
       (``packed_binned_bytes``, watched by bench_trend on device
@@ -655,8 +449,7 @@ def measure_packed(X, y, backend, n_iters):
     * **measured bytes** — the compiled histogram executables' own
       ``cost_analysis()`` bytes, packed vs unpacked input, recorded
       beside the analytic figure (CPU interpret-mode accounting is
-      unrepresentative — ``packed_bytes_interpret_mode`` — like the
-      fused round's byte leg).
+      unrepresentative — ``packed_bytes_interpret_mode``).
 
     ``packed_ok`` is joined in main(): parity AND the analytic >= 1.9x
     reduction AND, on device, a measured hist-bytes reduction >= 1.5x.
@@ -701,14 +494,11 @@ def measure_packed(X, y, backend, n_iters):
             feature_infos=ds.feature_infos())
         return ds, dt, text
 
-    ds_u, _, st_text = run({"hist_method": "pallas"})
-    _, u8_dt, u8_text = run({"hist_method": "fused"})
-    _, pk_dt, pk_text = run({"hist_method": "fused",
+    ds_u, u8_dt, u8_text = run({"hist_method": "pallas",
+                                "bin_layout": "u8"})
+    _, pk_dt, pk_text = run({"hist_method": "pallas",
                              "bin_layout": "packed4"})
-    _, _, sp_text = run({"hist_method": "pallas",
-                         "bin_layout": "packed4"})
-    fields["packed_parity_ok"] = bool(
-        pk_text == u8_text == st_text == sp_text)
+    fields["packed_parity_ok"] = bool(pk_text == u8_text)
     fields["packed_M_row_trees_per_s"] = round(N * n_iters / pk_dt / 1e6,
                                                3)
     fields["packed_u8_M_row_trees_per_s"] = round(
@@ -751,237 +541,6 @@ def measure_packed(X, y, backend, n_iters):
     except Exception as e:  # noqa: BLE001 — the parity legs stand alone
         fields["packed_bytes_error"] = f"{type(e).__name__}: {e}"[:200]
     return fields
-
-
-def _fused_round_bytes(ds, N, backend, gb_fu):
-    """Compiled-executable byte accounting of ONE sustained wave round,
-    BOTH legs starting from the same (leaf ids + committed splits)
-    state (ISSUE 15): staged = the (S, N) partition decision pass +
-    histogram pass + subtraction + vmapped split scan; fused = the
-    routed single-pass kernel (partition + histogram + scan in one
-    sweep of the binned rows) + the same per-leaf state update.  The
-    analytic binned-traffic bound is recorded beside the measured
-    figures: the fused round touches the binned matrix ONCE (F*N for
-    the kernel sweep + N decision bins) where the staged round pays the
-    hist read AND the partition's K-row gather + (K, N) HBM mask
-    intermediates."""
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbmv1_tpu.models.grower_wave import (auto_wave_size,
-                                                   subtract_child_hists)
-    from lightgbmv1_tpu.obs.xla import _extract_cost
-    from lightgbmv1_tpu.ops.histogram import hist_wave
-    from lightgbmv1_tpu.ops.split import (NO_CONSTRAINT, find_best_split,
-                                          go_left_rule)
-    from lightgbmv1_tpu.ops.wave_fused import make_fused_round
-
-    fields = {}
-    interp = backend == "cpu"
-    K = auto_wave_size(255)
-    B = 64
-    binned = jnp.asarray(ds.train_matrix)
-    F = binned.shape[0]
-    meta, params = gb_fu.meta, gb_fu.split_params
-    rng = np.random.RandomState(13)
-    L = 255
-    g3 = jnp.asarray(rng.randn(N, 3).astype(np.float32))
-    lids = jnp.asarray(rng.randint(0, K, N).astype(np.int32))
-    feats = jnp.asarray(rng.randint(0, F, K).astype(np.int32))
-    thrs = jnp.asarray(rng.randint(0, B, K).astype(np.int32))
-    dls = jnp.asarray(rng.rand(K) < 0.5)
-    leafs = jnp.arange(K, dtype=jnp.int32)
-    nls = jnp.arange(K, dtype=jnp.int32) + K
-    parent = jnp.asarray(
-        np.abs(rng.randn(K, F, B, 3)).astype(np.float32)) * 4.0
-    sml = jnp.asarray(rng.rand(K) < 0.5)
-    csums = jnp.asarray(np.abs(rng.randn(2 * K, 3)).astype(np.float32))
-    mask = jnp.ones((2 * K, F), bool)
-    nc = jnp.asarray(NO_CONSTRAINT, jnp.float32)
-    ar = jnp.arange(K, dtype=jnp.int32)
-    siota = jnp.arange(K, dtype=jnp.int32)
-
-    def staged_round(g3_, parent_, sml_):
-        # the staged (S, N) partition decision pass (grower_wave
-        # go_left_s): per-split bin gather + HBM mask intermediates
-        bk = jax.vmap(lambda f: binned[f])(feats).astype(jnp.int32)
-        gl = go_left_rule(bk, thrs[:, None], dls[:, None],
-                          meta.missing_type[feats][:, None],
-                          meta.nan_bin[feats][:, None],
-                          meta.zero_bin[feats][:, None])
-        mine = lids[None, :] == leafs[:, None]
-        leaf_id = lids + jnp.sum(
-            jnp.where(mine & (~gl), nls[:, None] - lids[None, :], 0),
-            axis=0)
-        label = jnp.sum(
-            jnp.where(mine & (gl == sml_[:, None]),
-                      siota[:, None] - K, 0), axis=0) + K
-        h = hist_wave(binned, g3_, label, K, B, method="pallas",
-                      precision="bf16x2", interpret=interp)
-        hist, _, _ = subtract_child_hists(h, parent_, ar, ar, sml_,
-                                          h_parent=parent_)
-        res = jax.vmap(lambda hh, ps: find_best_split(
-            hh, ps, meta, mask[0], params, nc, 1, 0.0, 0.0, None, None)
-        )(hist, csums)
-        return res.gain, res.feature, hist, leaf_id
-
-    fn = make_fused_round(meta=meta, params=params, num_bins=B,
-                          precision="bf16x2", deep_precision="bf16",
-                          interpret=interp)
-    route = dict(leaf_id=lids, feats=feats, thrs=thrs, dls=dls,
-                 leafs=leafs, nls=nls, num_leaves=L)
-
-    def fused_round(g3_, parent_, sml_):
-        packed, hsm, _, leaf_id = fn(
-            binned, g3_, None, K, mask=mask,
-            csums=csums, constr=jnp.tile(nc, (2 * K, 1)),
-            depth=jnp.ones(2 * K, jnp.int32),
-            pout=jnp.zeros(2 * K, jnp.float32),
-            sml=sml_, parent=parent_, route=route)
-        # the per-leaf table update the grower still performs (the K
-        # smaller-child stack IS emitted); keep it in the accounting so
-        # the comparison prices the whole round fairly
-        hist, _, _ = subtract_child_hists(hsm, parent_, ar, ar, sml_,
-                                          h_parent=parent_)
-        return packed, hist, leaf_id
-
-    st_c = jax.jit(staged_round).lower(g3, parent, sml).compile()
-    fu_c = jax.jit(fused_round).lower(g3, parent, sml).compile()
-    _, st_bytes = _extract_cost(st_c)
-    _, fu_bytes = _extract_cost(fu_c)
-    # analytic binned-matrix traffic per round (uint8 bytes): the
-    # single-read contract the acceptance criteria pin, recorded beside
-    # whatever the compiled executables measure
-    fields["staged_round_binned_bytes_analytic"] = int(F * N + K * N)
-    fields["fused_round_binned_bytes_analytic"] = int(F * N + N)
-    fields["fused_round_single_read_ok"] = bool(
-        fields["fused_round_binned_bytes_analytic"]
-        < fields["staged_round_binned_bytes_analytic"])
-    if st_bytes and fu_bytes:
-        fields["staged_round_bytes_accessed"] = int(st_bytes)
-        fields["fused_round_bytes_accessed"] = int(fu_bytes)
-        fields["fused_hbm_bytes_saved_per_round"] = int(
-            st_bytes - fu_bytes)
-        fields["fused_round_bytes_reduction"] = round(
-            st_bytes / max(fu_bytes, 1), 3)
-        # the analytic scan-stack size the fused path keeps on-chip
-        fields["fused_hbm_stack_bytes_analytic"] = int(
-            2 * K * F * B * 3 * 4)
-        # CPU smoke caveat: in interpret mode the kernel lowers to plain
-        # XLA ops with per-grid-step block copies, so the byte
-        # comparison does NOT reflect device behavior (it typically
-        # reads NEGATIVE there); the honest number is the device
-        # capture's, where the kernel is one custom call and the VMEM
-        # accumulator never appears in the byte accounting
-        if interp:
-            fields["fused_bytes_interpret_mode"] = True
-    return fields
-
-
-def measure_fused_round_ms(ds, N, gb_lw, schedule, hist_fields, backend):
-    """The fused wave round timed per slot bucket with the two-length
-    scan differential and priced over the REPLAYED round schedule —
-    ``hist_split_fused_ms_per_iter``, directly comparable to
-    ``phase_hist_ms + phase_split_ms`` (the staged root pass is added on
-    both sides of that comparison: the fused path keeps the staged root
-    histogram, so its cost rides this field via
-    ``hist_ms_per_pass_root``).
-
-    ISSUE 15: the ROUTED single-pass round (partition + valid-metadata
-    decisions folded into the kernel, leaf ids in and out) is priced
-    the same way as ``partition_fused_ms_per_iter`` — directly
-    comparable to ``phase_hist_ms + phase_split_ms +
-    phase_partition_ms``, the three staged traversals it collapses;
-    bench_trend watches it at the 10% bar."""
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbmv1_tpu.models.grower_wave import (auto_wave_size,
-                                                   slot_buckets_for)
-    from lightgbmv1_tpu.ops.split import NO_CONSTRAINT
-    from lightgbmv1_tpu.ops.wave_fused import make_fused_round
-
-    B = 64
-    K = auto_wave_size(255)
-    BUCKETS = tuple(slot_buckets_for(K, N))
-    binned = jnp.asarray(ds.train_matrix)
-    F = binned.shape[0]
-    rng = np.random.RandomState(14)
-    g3 = jnp.asarray(rng.randn(N, 3).astype(np.float32))
-    nc = jnp.asarray(NO_CONSTRAINT, jnp.float32)
-    fn = make_fused_round(meta=gb_lw.meta, params=gb_lw.split_params,
-                          num_bins=B, precision="bf16x2",
-                          deep_precision="bf16",
-                          interpret=backend == "cpu")
-
-    def make_for(S, routed=False):
-        label = jnp.asarray(rng.randint(0, S + 1, N).astype(np.int32))
-        parent = jnp.asarray(
-            np.abs(rng.randn(S, F, B, 3)).astype(np.float32)) * 4.0
-        sml = jnp.asarray(rng.rand(S) < 0.5)
-        csums = jnp.asarray(
-            np.abs(rng.randn(2 * S, 3)).astype(np.float32))
-        mask = jnp.ones((2 * S, F), bool)
-        deep = S == K and K >= 32 and len(BUCKETS) > 1
-        route = None
-        if routed:
-            route = dict(
-                leaf_id=jnp.asarray(
-                    rng.randint(0, S, N).astype(np.int32)),
-                feats=jnp.asarray(
-                    rng.randint(0, F, S).astype(np.int32)),
-                thrs=jnp.asarray(rng.randint(0, B, S).astype(np.int32)),
-                dls=jnp.asarray(rng.rand(S) < 0.5),
-                leafs=jnp.arange(S, dtype=jnp.int32),
-                nls=jnp.arange(S, dtype=jnp.int32) + S,
-                num_leaves=255)
-
-        def make(r):
-            @jax.jit
-            def reps():
-                def body(c, i):
-                    g = g3 * (1.0 + 1e-6 * i.astype(jnp.float32))
-                    out = fn(
-                        binned, g, None if routed else label, S,
-                        deep=deep, mask=mask,
-                        csums=csums, constr=jnp.tile(nc, (2 * S, 1)),
-                        depth=jnp.ones(2 * S, jnp.int32),
-                        pout=jnp.zeros(2 * S, jnp.float32),
-                        sml=sml, parent=parent, route=route)
-                    acc = out[0].sum() + out[1].sum()
-                    if routed:   # the emitted leaf ids are a live output
-                        acc = acc + out[3].sum().astype(jnp.float32)
-                    return c + acc, None
-                s, _ = jax.lax.scan(body, jnp.float32(0), jnp.arange(r))
-                return s
-            return reps
-        return make
-
-    pass_ms = {S: timed_per_rep(make_for(S), 4, 16) * 1e3
-               for S in BUCKETS}
-    routed_ms = {S: timed_per_rep(make_for(S, routed=True), 4, 16) * 1e3
-                 for S in BUCKETS}
-
-    def bucket_of(k):
-        for s in BUCKETS:
-            if k <= s:
-                return s
-        return K
-
-    rounds = schedule["schedule"]
-    iters = max(1, round(len(rounds) / schedule["rounds_per_tree"]))
-    root_ms = hist_fields.get("hist_ms_per_pass_root", 0.0)
-    per_iter = (sum(pass_ms[bucket_of(k)] for k in rounds) / iters
-                + root_ms)
-    routed_iter = (sum(routed_ms[bucket_of(k)] for k in rounds) / iters
-                   + root_ms)
-    out = {"hist_split_fused_ms_per_iter": round(per_iter, 2),
-           "fused_ms_per_pass": round(pass_ms[K], 2),
-           "partition_fused_ms_per_iter": round(routed_iter, 2),
-           "partition_fused_ms_per_pass": round(routed_ms[K], 2)}
-    for s in BUCKETS[:-1]:
-        out[f"fused_ms_per_pass_s{s}"] = round(pass_ms[s], 2)
-    return out
 
 
 def measure_predict(gb_lw, X):
@@ -2215,19 +1774,13 @@ def measure_obs(X, y, backend: str, phase_fields=None):
         # roofline join: measured phase ms x cost-analysis flops/bytes
         # against the same-session matmul peak (device captures only —
         # the CPU smoke has neither phase fields nor a peak)
-        if phase_fields and (
-                phase_fields.get("phase_hist_ms") is not None
-                or phase_fields.get("phase_round_fused_ms") is not None
-                or phase_fields.get("phase_hist_split_fused_ms")
-                is not None) \
+        if phase_fields and phase_fields.get("phase_hist_ms") is not None \
                 and phase_fields.get("device_matmul_peak_tf_s"):
             from tools.phase_attrib import (phase_ms_from_fields,
                                             roofline_attribution,
                                             split_cost_by_ms)
 
-            # canonical phase list (tools/phase_attrib.py): a fused
-            # capture's single merged hist+split phase gets its own
-            # labeled roofline row instead of pooling into phase_other
+            # canonical phase list (tools/phase_attrib.py)
             pms = phase_ms_from_fields(phase_fields)
             pms.pop("valid_route", None)   # valid routing is not part of
                                            # the compiled train step's
@@ -2640,28 +2193,6 @@ def main():
     except Exception as e:  # noqa: BLE001
         extra["precision_expt_error"] = f"{type(e).__name__}: {e}"[:200]
 
-    # ---- fused wave-round megakernel A/B (hist_method=fused, ISSUE 13) --
-    # Parity + throughput + compiled-executable HBM accounting on every
-    # backend (CPU rides the interpreter lane); the perf leg of fused_ok
-    # joins the device phase fields below.
-    try:
-        extra.update(measure_fused(ds, N, backend,
-                                   n_iters=min(lw_trees, 3)))
-    except Exception as e:  # noqa: BLE001 — partial records beat none
-        extra["fused_error"] = f"{type(e).__name__}: {e}"[:200]
-        extra["fused_parity_ok"] = False
-
-    # ---- persistent multi-round wave loop A/B (wave_loop_rounds,
-    # ISSUE 17): loop-vs-single-round parity + the VMEM plan + launch /
-    # state-traffic accounting on every backend; the perf leg of
-    # fused_loop_ok joins below.
-    try:
-        extra.update(measure_fused_waveloop(ds, N, backend,
-                                            n_iters=min(lw_trees, 3)))
-    except Exception as e:  # noqa: BLE001 — partial records beat none
-        extra["fused_loop_error"] = f"{type(e).__name__}: {e}"[:200]
-        extra["fused_loop_parity_ok"] = False
-
     # ---- 4-bit packed bins A/B (bin_layout=packed4, ISSUE 18): layout
     # parity at max_bin=15 + the binned-bytes halving, analytic and
     # measured; the packed_ok join lives below with the other guards.
@@ -2747,22 +2278,6 @@ def main():
                     extra["phase_split_ms"] - sbd.total_attributed(), 3)
         except Exception as e:  # noqa: BLE001
             extra["split_attrib_error"] = f"{type(e).__name__}: {e}"[:200]
-
-        # ---- fused wave round, measured (ISSUE 13 + 15): the merged
-        # pass per bucket priced over the replayed schedule — the
-        # label-input kernel (hist_split_fused_ms_per_iter, the
-        # fused_ok perf leg) AND the routed single-pass round with
-        # partition folded in (partition_fused_ms_per_iter, the
-        # fused_round_ok leg bench_trend watches).  A capture training
-        # with hist_method=fused would carry the routed number as its
-        # phase row (phase_round_fused_ms,
-        # tools/phase_attrib.PHASE_MS_KEYS).
-        try:
-            if schedule:
-                extra.update(measure_fused_round_ms(
-                    ds, N, gb_lw, schedule, hist_fields, backend))
-        except Exception as e:  # noqa: BLE001
-            extra["fused_round_error"] = f"{type(e).__name__}: {e}"[:200]
 
         # DART per-iteration cost (fused single-dispatch iteration):
         # VERDICT r3 #7 asks this within ~2x of the scanned GBDT path
@@ -2960,66 +2475,13 @@ def main():
         except Exception as e:  # noqa: BLE001
             extra["northstar_error"] = f"{type(e).__name__}: {e}"[:200]
 
-    # ---- fused_ok (ISSUE 13): parity AND, on device, the measured
-    # fused round at or under the staged hist+split it replaces.  The
-    # staged path stays the default until a device capture lands this
-    # guard True with the ms comparison actually evaluated (a CPU
-    # capture proves parity only — the perf leg is trivially true
-    # there, like pipeline_ok).
-    fused_ms = extra.get("hist_split_fused_ms_per_iter")
-    staged_ms = ((extra.get("phase_hist_ms") or 0)
-                 + (extra.get("phase_split_ms") or 0))
-    extra["fused_ok"] = bool(
-        extra.get("fused_parity_ok")
-        and (backend == "cpu"
-             or (fused_ms is not None and staged_ms > 0
-                 and fused_ms <= staged_ms)))
-
-    # ---- fused_round_ok (ISSUE 15): the single-pass wave round —
-    # routed parity (the measure_fused A/B trains through the in-kernel
-    # partition + valid routing + top-k dispatch) AND the single-read
-    # bytes contract: analytically the binned matrix is touched once
-    # per round, and on device the compiled round executables must show
-    # >= 1.8x fewer bytes than the staged partition+hist they replace
-    # (the CPU interpreter's block-copy accounting is unrepresentative
-    # — fused_bytes_interpret_mode — so the CPU record carries the
-    # parity + analytic legs only, like fused_ok's perf leg).
-    fr_red = extra.get("fused_round_bytes_reduction")
-    extra["fused_round_ok"] = bool(
-        extra.get("fused_parity_ok")
-        and extra.get("fused_round_single_read_ok")
-        and (backend == "cpu"
-             or (fr_red is not None and fr_red >= 1.8
-                 and extra.get("partition_fused_ms_per_iter")
-                 is not None)))
-
-    # ---- fused_loop_ok (ISSUE 17): the persistent multi-round wave
-    # loop — loop-vs-single-round model-text parity everywhere AND, on
-    # device, the looped per-iteration wall at or under the single-round
-    # fused wall it replaces (the boundary saving must not be negative;
-    # a CPU capture proves parity only — the interpreter serializes the
-    # grid, so its wall is unrepresentative, like fused_ok's perf leg).
-    # The staged path stays the default until a device capture lands
-    # this guard True with the ms leg actually evaluated.
-    lp_save = extra.get("wave_loop_boundary_saving_ms_per_iter")
-    extra["fused_loop_ok"] = bool(
-        extra.get("fused_loop_parity_ok")
-        and (backend == "cpu"
-             or (lp_save is not None and lp_save >= 0)))
-    # the watched phase row: the loop dispatch priced by the
-    # differential method — the measured single-round dispatch ms minus
-    # the boundary saving the loop run demonstrated
-    pfm = extra.get("partition_fused_ms_per_iter")
-    if pfm is not None and lp_save is not None:
-        extra["phase_wave_loop_ms"] = round(max(pfm - lp_save, 0.0), 3)
-
-    # ---- packed_ok (ISSUE 18): 4-bit packed bins — four-way layout
-    # parity (packed/unpacked x fused/staged, model text byte-compared)
-    # AND the analytic >= 1.9x binned-read reduction AND, on device, the
+    # ---- packed_ok (ISSUE 18): 4-bit packed bins — layout parity
+    # (packed against unpacked, model text byte-compared) AND the
+    # analytic >= 1.9x binned-read reduction AND, on device, the
     # compiled hist executables showing >= 1.5x fewer bytes on packed
     # input (the CPU interpreter's block-copy accounting is
     # unrepresentative — packed_bytes_interpret_mode — so the CPU record
-    # carries the parity + analytic legs only, like fused_round_ok).
+    # carries the parity + analytic legs only).
     pk_red = extra.get("packed_hist_bytes_reduction")
     extra["packed_ok"] = bool(
         extra.get("packed_parity_ok")

@@ -32,7 +32,6 @@ the verdict the driver reads, exactly
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import sys
 import time
@@ -348,7 +347,7 @@ class Smoke:
         assert engaged == (self.device["platform"] == "tpu"), engaged
         assert not twin._gbdt._packed
         # same row tiles, independent output cells: bit-identical trees
-        # (tests/test_wave_fused.py packed contract)
+        # (tests/test_packed_bins.py)
         assert_trees_agree(packed, twin, "packed4 vs u8", exact=True)
         packed_hist = None
         if engaged:
@@ -385,34 +384,13 @@ class Smoke:
     # -- kernels behind a knob ---------------------------------------------
     def optin(self):
         """One attempt per opt-in kernel.  Passing outcomes: the kernel
-        RAN (engagement read from the program: the grower's compile label,
-        the predictor's method and plan) and matched the staged result —
-        or it RAISED.  A result that came back by another path fails."""
+        RAN (engagement read from the program: the predictor's method and
+        plan) and matched the host walk — or it RAISED.  A result that came
+        back by another path fails."""
         Xs = self.Xv[:4096]
-
-        @functools.cache
-        def staged_twin():
-            # the staged Pallas path, named so that the CPU rehearsal
-            # compares interpret lane with interpret lane; trained only
-            # once a fused grower has actually produced trees
-            return lgb.train({**PARAMS, "hist_method": "pallas"},
-                             self.dtrain, num_boost_round=2,
-                             verbose_eval=False)
 
         leaf_host = self.oracle.predict(Xs, pred_leaf=True,
                                         predict_method="host")
-
-        def grow(label, **knobs):
-            b = lgb.train({**PARAMS, **knobs}, self.dtrain,
-                          num_boost_round=2, verbose_eval=False)
-            got = b._gbdt._grow.label
-            if got != label:
-                # a planner refusal with a logged reason (VMEM plan,
-                # eligibility) is a decision, not a fallback: report it
-                return {"outcome": f"planner routed to {got}"}
-            return {"outcome": "ran and matched staged",
-                    "max_leaf_value_delta": assert_trees_agree(
-                        b, staged_twin(), label)}
 
         def walk(method, label):
             n0 = obs_xla.compile_counts().get(label, 0)
@@ -428,10 +406,6 @@ class Smoke:
             return {"outcome": "ran and matched staged"}
 
         attempts = {
-            "hist_method=fused": lambda: grow(
-                "grow.fused_round", hist_method="fused"),
-            "wave_loop_rounds=4": lambda: grow(
-                "grow.fused_loop", hist_method="fused", wave_loop_rounds=4),
             "predict_method=pallas": lambda: walk("pallas", "predict.leaf"),
             "predict_method=fused": lambda: walk("fused", "predict.fused"),
         }

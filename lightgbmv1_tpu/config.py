@@ -318,30 +318,19 @@ class Config:
     # auto: static pick, measured only for ambiguous shapes; bench: ALWAYS
     # time the applicable implementations at init and pick the winner
     # (reference Dataset::GetShareStates, src/io/dataset.cpp:590-684).
-    # fused (OPT-IN until a device capture lands the `fused_ok` guard):
-    # wave rounds run the fused histogram+split Pallas megakernel
-    # (ops/wave_fused.py) — per-slot histograms accumulate in VMEM and
-    # the split scan runs in the SAME kernel invocation, so the
-    # (F, B, 3) histogram stack never round-trips HBM; trees are
-    # bit-identical to hist_method=pallas (interpret-mode pin,
-    # tests/test_wave_fused.py).  Ineligible configs (categorical,
-    # extra_trees, EFB/packed/int16 bins, row-sharded learners,
-    # non-wave growth) run the staged path with a logged reason (the
-    # fallback taxonomy, BASELINE.md); a kernel the backend cannot
-    # lower or compile raises.
-    hist_method: str = "auto"  # auto | bench | scatter | onehot | pallas | fused
+    hist_method: str = "auto"  # auto | bench | scatter | onehot | pallas
     # device bin-matrix layout (the reference's DenseBin<VAL_T, IS_4BIT>
     # choice, bin.h): "packed4" stores two 4-bit bins per byte —
     # (ceil(F/2), N) instead of (F, N) — so the per-round HBM binned
     # read, the streaming block cache's disk/H2D bytes, and the kernels'
-    # VMEM row-tile footprint all halve; the hist/fused kernels unpack
+    # VMEM row-tile footprint all halve; the histogram kernel unpacks
     # nibbles in VMEM (ops/hist_pallas.pack4bit layout: lo nibble =
     # feature 2p, hi = 2p+1).  Needs num_total_bin <= 16 (max_bin <= 15
-    # plus the missing bin), uint8 bins, no EFB bundling, a pallas-family
+    # plus the missing bin), uint8 bins, no EFB bundling, the pallas
     # hist method, and not gpu_use_dp / feature-parallel.  "auto" packs
     # exactly when eligible (silent); an explicit "packed4" on an
     # ineligible config falls back to "u8" with the staged warning.
-    # Trees are bit-identical across layouts (tests/test_wave_fused.py).
+    # Trees are bit-identical across layouts (`tests/test_packed_bins.py`).
     bin_layout: str = "auto"   # auto | u8 | packed4
     hist_dtype: str = "bf16x2"     # bf16 | bf16x2 | f32 | int8 (quantized) precision
     # histogram precision for the wave grower's SUSTAINED rounds (the
@@ -386,21 +375,6 @@ class Config:
     # fully-serialized round body, kept as the bit-parity pin;
     # tests/test_wave_pipeline.py).
     async_wave_pipeline: bool = True
-    # persistent multi-round wave loop (ROADMAP item 1, ops/wave_fused.
-    # make_fused_wave_loop): with hist_method=fused, R>1 runs R
-    # consecutive wave rounds in ONE Pallas launch — the frontier table,
-    # histogram pool, row->leaf labels and top-k state stay resident in
-    # VMEM scratch across rounds, eliminating R-1 kernel launches plus
-    # their leaf-id/pool/split-table HBM round-trips per loop.  A static
-    # VMEM budget planner (plan_wave_loop) may refuse the loop (multi-
-    # round state over budget, monotone constraints, quantized deep
-    # rounds off the f32 lane, non-uniform row tiling across the slot
-    # ladder) — refusals fall back to single-round fused dispatch with a
-    # logged reason (the fallback taxonomy, BASELINE.md).  1 = the
-    # PR-15 single-round kernel (default; the loop is opt-in until a
-    # device capture lands the `fused_loop_ok` guard).  Trees are
-    # bit-identical at any R (tests/test_wave_fused.py parity matrix).
-    wave_loop_rounds: int = 1
     # donate the score caches (train + valid) into the fused per-iteration
     # step (jax donate_argnums): the iteration's score update runs in
     # place instead of allocating a second (N, K) buffer per cache —
@@ -799,10 +773,10 @@ class Config:
             elif self.force_row_wise:
                 self.hist_method = "onehot"
         if self.hist_method not in (
-                "auto", "bench", "scatter", "onehot", "pallas", "fused"):
+                "auto", "bench", "scatter", "onehot", "pallas"):
             raise ValueError(
                 f"hist_method={self.hist_method!r}: expected auto | bench "
-                "| scatter | onehot | pallas | fused")
+                "| scatter | onehot | pallas")
         if self.bin_layout not in ("auto", "u8", "packed4"):
             raise ValueError(
                 f"bin_layout={self.bin_layout!r}: expected auto | u8 "
@@ -815,9 +789,6 @@ class Config:
                 "reduce_scatter | allreduce | hierarchical")
         if self.num_hosts < 0:
             raise ValueError("num_hosts must be >= 0 (0 = auto-detect)")
-        if self.wave_loop_rounds < 1:
-            raise ValueError("wave_loop_rounds must be >= 1 (1 = the "
-                             "single-round fused kernel)")
         if self.hier_ici_gbps <= 0 or self.hier_dcn_gbps <= 0:
             raise ValueError("hier_ici_gbps / hier_dcn_gbps must be > 0 "
                              "(modeled link bandwidths of the "

@@ -128,8 +128,8 @@ class GBDT:
                                             train_set.num_bins)
         # 4-bit packing (reference DenseBin<..,IS_4BIT>, dense_bin.hpp:52):
         # two bins per byte when every feature fits 4 bits — halves the
-        # binned matrix in HBM and the hist pass's dominant read stream,
-        # including the fused wave round/loop (in-VMEM nibble unpack).
+        # binned matrix in HBM and the hist pass's dominant read stream
+        # (in-VMEM nibble unpack).
         # Layout resolution + once-per-build logging:
         # parallel/trainer.select_bin_layout (config.bin_layout).
         self._packed = False

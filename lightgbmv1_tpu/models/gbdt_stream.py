@@ -174,14 +174,6 @@ class StreamingGBDT(GBDT):
         cfg = self.config
         method = default_hist_method(cfg.hist_method,
                                      self._source.block_dtype)
-        if cfg.hist_method == "fused":
-            # the fused wave-round kernel needs the resident wave grower;
-            # streaming runs the sequential schedule on the staged AUTO
-            # method (the documented fallback taxonomy, ops/wave_fused.py)
-            method = default_hist_method("auto", self._source.block_dtype)
-            log_warning("hist_method=fused: streaming training runs the "
-                        "sequential schedule; using the staged "
-                        f"'{method}' histogram path")
         if method == "pallas":
             log_warning("hist_method=pallas streams as per-block partial "
                         "sums: deterministic at fixed block order, but "

@@ -91,7 +91,6 @@ the exact-fp32 histogram path (tests/test_phase_attrib.py).
 
 from __future__ import annotations
 
-import contextlib
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -104,7 +103,6 @@ from ..ops.split import (
     NO_CONSTRAINT,
     FeatureMeta,
     SplitParams,
-    child_leaf_output,
     find_best_split,
     go_left_rule,
     leaf_output,
@@ -312,9 +310,8 @@ def subtract_child_hists(h_slot, leaf_hist, leafs, order_c, sm_left,
         # exact multiply: every dequantization scale is a power of two
         # (ops/quantize.sr_prequantize_g3), so the subtraction below
         # rounds identically whether or not the compiler contracts this
-        # product into it (fma) — the bit-parity contract between this
-        # site, the fused kernel's scan, and the wave-loop commit
-        # depends on that exactness, not on fusion heuristics.
+        # product into it (fma): reproducible trees do not depend on
+        # fusion heuristics.
         h_small = h_small * slot_scale[order_c][:, None, None, :]
     if h_parent is None:
         h_parent = leaf_hist[leafs]
@@ -685,8 +682,6 @@ def make_wave_grower(
     split_fn: Callable = None,
     sums_fn: Callable = None,
     bins_of_fn: Callable = None,
-    fused_round_fn: Callable = None,
-    fused_loop_fn: Callable = None,
 ):
     """Build the jittable ``grow(binned, g3, base_mask, key)`` function.
 
@@ -720,42 +715,6 @@ def make_wave_grower(
     tables with one coalesced scatter each (_PackedStore, default) or the
     legacy per-field scatters (_FieldStore); trees are bit-identical
     either way on the exact-fp32 histogram path.
-    ``fused_round_fn`` (ops/wave_fused.make_fused_round, wired by
-    parallel/trainer.py under ``hist_method=fused``): the wave rounds'
-    histogram pass + smaller-child subtraction + split scan collapse
-    into ONE fused kernel call per slot bucket — the kernel accumulates
-    the slot histograms in VMEM, subtracts the parent stack it reads as
-    an input, runs the staged scan's own stage functions on the VMEM
-    values and returns only the packed per-child SplitInfo (plus, in
-    subtraction mode, the smaller-child histograms the per-leaf state
-    scatter needs).  The staged ``hist_wave_fn`` still runs the root
-    pass, and ``hist_wave_quant_fn``'s PRESENCE still gates the int8sr
-    buckets — the fused path quantizes through the same
-    ``sr_quantize_g3`` stream, so the (iteration, round) determinism
-    contract and the root/ramp never-quantize rule are shared, not
-    re-implemented.  A ROUTING-CAPABLE ``fused_round_fn``
-    (``supports_route`` + the ``route_rows`` valid-set router, ISSUE
-    15) additionally folds the round's PARTITION into the kernel: the
-    staged (S, N) decision pass is skipped, the kernel returns the
-    updated per-row leaf ids from the same sweep that accumulates the
-    histograms, the O(L) top-k and the dispatch run under one
-    ``lgbm.fused_round`` label, and the valid sets (in-round or the
-    pipelined drain) ride the kernel's decision stage instead of the
-    staged gather chain — the round reads the binned rows ONCE.
-    Trees are bit-identical to the staged path on the
-    same histogram arithmetic (tests/test_wave_fused.py pins this in
-    interpret mode).
-    ``fused_loop_fn`` (ops/wave_fused.make_fused_wave_loop, wired by
-    parallel/trainer.py under ``wave_loop_rounds > 1``): each while-loop
-    body becomes a SEGMENT of R consecutive rounds run by ONE persistent
-    kernel launch — frontier table, histogram pool and row→leaf labels
-    resident in VMEM between rounds — followed by a host REPLAY of the R
-    rounds' bookkeeping (store writes, valid routing, done flag) from
-    the kernel's per-round packed SplitInfo.  Engagement is static
-    (``fused_loop_fn.plan``, the VMEM budget planner) and falls back to
-    the single-round body when ineligible; trees, stores and routings
-    are bit-identical to both the single-round fused and the staged
-    paths (tests/test_wave_fused.py's loop parity matrix).
     ``async_wave_pipeline`` (default on) software-pipelines the round
     loop: the per-leaf histogram-state scatter and the valid-row routing
     of round r are DEFERRED into a pending carry and applied at the
@@ -786,20 +745,6 @@ def make_wave_grower(
               if interaction_groups is not None else None)
     store = (_PackedStore if fused_bookkeeping else _FieldStore)(
         L, L1, W, use_mc, use_cat)
-    use_fused = fused_round_fn is not None
-    # single-pass wave round (ISSUE 15): a routing-capable fused_round_fn
-    # (ops/wave_fused.make_fused_round — supports_route + the route_rows
-    # valid-set router) folds the (S, N) partition into the kernel: the
-    # binned rows are swept ONCE per round, the kernel emits the updated
-    # leaf ids, and the valid sets ride the same decision stage.  The
-    # feature-parallel trainer wrapper deliberately lacks the capability
-    # (its shard sees only a feature slice), so it keeps the staged
-    # partition below.
-    use_fused_route = use_fused and getattr(fused_round_fn,
-                                            "supports_route", False)
-    if use_fused:
-        from ..ops.wave_fused import unpack_children as _unpack_children
-
     # the default split accepts a per-child hist_scale (dequantize-aware
     # scan, ops/split.py), as do custom split_fns that declare
     # ``accepts_hist_scale = True`` (the sharded data-/voting-parallel
@@ -833,10 +778,12 @@ def make_wave_grower(
         return allowed_features_for(groups, used)
 
     def clamp_out(sums, constr, parent_out):
-        # shared with the persistent wave-loop kernel (ops/split.py) —
-        # both paths must run the same ops for the loop parity contract
-        return child_leaf_output(sums, constr, parent_out, params,
-                                 use_mc=use_mc)
+        out = leaf_output(sums[0], sums[1], params)
+        if params.path_smooth > 0:
+            out = smooth_output(out, sums[2], parent_out, params)
+        if not use_mc:
+            return out
+        return jnp.clip(out, constr[0], constr[1])
 
     def grow(binned, g3, base_mask, key, cegb_used=None, valids=()):
         # ``binned`` may arrive prepared for the histogram kernel
@@ -877,32 +824,12 @@ def make_wave_grower(
         # the larger child from the per-leaf histogram state.  Skipped
         # when that state would exceed 512 MB (wide-F configs).
         use_sub = (L * int(np.prod(hist0.shape)) * 4) <= _SUB_STATE_CAP_BYTES
-        # persistent multi-round wave loop (ROADMAP item 1): engage only
-        # when the static plan says the whole frontier state fits VMEM
-        # and every staged leg the loop cannot replicate in-kernel is
-        # off.  The decision is trace-time — shapes and knobs only — so
-        # the ineligible fallback is the unchanged single-round body.
-        use_loop = False
-        loop_plan = None
-        if (fused_loop_fn is not None and use_fused_route
-                and not (use_cat or use_mc or use_inter or use_groups)
-                and feature_fraction_bynode >= 1.0):
-            loop_plan = fused_loop_fn.plan(
-                N=N, F=F, K=K, L=L, use_sub=use_sub,
-                slot_buckets=slot_buckets, quant_buckets=quant_buckets)
-            use_loop = bool(loop_plan["eligible"])
         # async wave pipelining: active whenever there is deferred work to
         # overlap — the per-leaf histogram-state scatter (use_sub) and/or
         # the valid-row routing.  With neither, the sequential body IS the
         # pipelined one (nothing to defer), so the pending carry is
-        # skipped entirely and the paths are the same trace.  Loop mode
-        # runs serialized (nothing defers across a kernel launch — the
-        # in-loop rounds ARE the overlap); the pipelined staged path is
-        # observably identical to the serialized one (value-forwarded
-        # design, tests/test_wave_pipeline.py), so loop-vs-pipelined
-        # parity follows transitively and is pinned under both flags.
-        pipeline = (async_wave_pipeline and (use_sub or bool(valids))
-                    and not use_loop)
+        # skipped entirely and the paths are the same trace.
+        pipeline = async_wave_pipeline and (use_sub or bool(valids))
         with jax.named_scope("lgbm.select"):
             root_sum = sums_fn(g3)
             mask0 = _node_feature_mask(key, 0, base_mask, feature_fraction_bynode)
@@ -953,18 +880,9 @@ def make_wave_grower(
             valid routing, evaluated over the rank-order (K,) split
             metadata (dead slots carry leaf id L and match no row).  The
             per-row update terms are int32 — exact and summation-order
-            free — so deferral is bit-identical to in-round routing.
-            Under the routed fused kernel the drain rides the SAME
-            decision stage as the train rows (``route_rows`` — the
-            ISSUE 15 valid-set lane) instead of the staged gather
-            chain; ``route_tile`` shares ``go_left_rule`` with the
-            staged path, so the routing cannot diverge."""
+            free — so deferral is bit-identical to in-round routing."""
             feats_k, thrs_k, dls_k = p["feats"], p["thrs"], p["dls"]
             leafs_k, nls_k = p["leafs"], p["nls"]
-            if use_fused_route:   # fused gate excludes categorical sets
-                return fused_round_fn.route_rows(
-                    vb, vl, feats=feats_k, thrs=thrs_k, dls=dls_k,
-                    leafs=leafs_k, nls=nls_k, num_leaves=L)
             mt_k = meta.missing_type[feats_k][:, None]
             bk = jax.vmap(lambda f: bins_of_fn(vb, f))(feats_k)
             bk = bk.astype(jnp.int32)
@@ -1041,17 +959,7 @@ def make_wave_grower(
                 vlids_in = st.valid_lids
             with jax.named_scope("lgbm.select"):
                 budget = L - st.num_leaves
-                # routed fused rounds label the WHOLE round — the O(L) top-k
-                # slot ranking, the in-kernel routing + histogram + scan and
-                # the residue pick — as one `lgbm.fused_round` region, so
-                # compile/cost/roofline telemetry (and the trace phase
-                # profile's merged `phase_round_fused_ms` row) see a single
-                # labeled executable instead of a partition/top-k residue
-                fr_scope = (jax.named_scope("lgbm.fused_round") if use_fused
-                            else contextlib.nullcontext())
-                with fr_scope:
-                    vals, leafs = _topk_by_rank(store.gains(st.store),
-                                                K)             # (K,)
+                vals, leafs = _topk_by_rank(store.gains(st.store), K)  # (K,)
                 valid = (vals > 0) & (kiota < budget)
                 if use_inter and K > 1:
                     # soundness: two leaves ADJACENT along a monotone feature
@@ -1097,9 +1005,7 @@ def make_wave_grower(
                         if quant_buckets else None)
 
                 # value-forwarded parent histogram rows, hoisted ahead of the
-                # slot-bucket switch: the staged subtraction and the fused
-                # kernel (which streams the parent stack as a kernel input)
-                # must read the SAME forwarded values
+                # slot-bucket switch
                 h_parent = None
                 if use_sub and pipeline:
                     # value forwarding: gather the parents from the ONE-
@@ -1114,15 +1020,10 @@ def make_wave_grower(
                     src = jnp.argmax(match, axis=1)
                     h_parent = jnp.where(hit[:, None, None, None],
                                          p_hist[src], h_parent)
-                elif use_fused and use_sub:
-                    h_parent = leaf_hist_in[leafs]
 
                 # ---- children metadata --------------------------------------
-                # Hoisted ahead of the histogram dispatch (it depends only on
-                # the store read): the fused kernel consumes the per-child
-                # masks/constraints/outputs INSIDE its scan, so they must
-                # exist before the slot-bucket switch; the staged split reads
-                # the identical values after it.
+                # (depends only on the store read; the split reads it after
+                # the slot-bucket switch)
                 cleafs = jnp.stack([leafs, nls], axis=1).reshape(2 * K)
                 csums = jnp.stack([lsums, rsums], axis=1).reshape(2 * K, 3)
                 if use_inter:
@@ -1250,8 +1151,7 @@ def make_wave_grower(
                 def go_left_s(matrix):
                     """(S, rows) left-decision of this round's splits —
                     shared by the train partition and valid routing
-                    (``go_left_rule`` is the single decision source,
-                    shared with the fused kernel's routing stage)."""
+                    (``go_left_rule`` is the single decision source)."""
                     mt_k = meta.missing_type[feats_s][:, None]
                     bk = jax.vmap(lambda f: bins_of_fn(matrix, f))(feats_s)
                     bk = bk.astype(jnp.int32)
@@ -1268,58 +1168,42 @@ def make_wave_grower(
                         g = jnp.where(iscats_s[:, None], in_set, g)
                     return g
 
-                if use_fused_route:
-                    # ---- single-pass round (ISSUE 15): NO staged
-                    # partition — the fused kernel evaluates the go-left
-                    # decisions while sweeping the rows for the
-                    # histograms and returns the updated leaf ids; valid
-                    # sets ride the same decision stage (in-round here,
-                    # via the drain above when pipelined)
-                    label = leaf_id = None
+                with jax.named_scope("lgbm.partition"):
+                    gl = go_left_s(bins)                  # (S, N)
+                    mine = st.leaf_id[None, :] == leafs_s[:, None]
+                    go_r = mine & (~gl)                   # disjoint rows
+                    leaf_id = st.leaf_id + jnp.sum(
+                        jnp.where(go_r,
+                                  nls_s[:, None] - st.leaf_id[None, :],
+                                  0), axis=0)
                     vl_new = []
                     if not pipeline:
-                        vl_new = [fused_round_fn.route_rows(
-                            vb, vl, feats=feats_s, thrs=thrs_s,
-                            dls=dls_s, leafs=leafs_s, nls=nls_s,
-                            num_leaves=L)
-                            for vb, vl in zip(valids, st.valid_lids)]
-                else:
-                    with jax.named_scope("lgbm.partition"):
-                        gl = go_left_s(bins)                  # (S, N)
-                        mine = st.leaf_id[None, :] == leafs_s[:, None]
-                        go_r = mine & (~gl)                   # disjoint rows
-                        leaf_id = st.leaf_id + jnp.sum(
-                            jnp.where(go_r,
-                                      nls_s[:, None] - st.leaf_id[None, :],
-                                      0), axis=0)
-                        vl_new = []
-                        if not pipeline:
-                            # pipelined rounds defer valid routing to the
-                            # next body's drain (route_pending) — off this
-                            # round's critical path, bit-identical updates
-                            for vb, vl in zip(valids, st.valid_lids):
-                                gv = go_left_s(vb)
-                                mine_v = vl[None, :] == leafs_s[:, None]
-                                go_rv = mine_v & (~gv)
-                                vl_new.append(vl + jnp.sum(
-                                    jnp.where(go_rv,
-                                              nls_s[:, None] - vl[None, :],
-                                              0),
-                                    axis=0))
-                        if use_sub:
-                            # label only the SMALLER child of each split
-                            # (known up front from the recorded counts)
-                            in_small = gl == sml_s[:, None]
-                            label = jnp.sum(
-                                jnp.where(mine & in_small,
-                                          siota[:, None] - S, 0),
-                                axis=0) + S
-                        else:
-                            slot2 = 2 * siota[:, None] \
-                                + (~gl).astype(jnp.int32)
-                            label = jnp.sum(
-                                jnp.where(mine, slot2 - 2 * S, 0),
-                                axis=0) + 2 * S
+                        # pipelined rounds defer valid routing to the
+                        # next body's drain (route_pending) — off this
+                        # round's critical path, bit-identical updates
+                        for vb, vl in zip(valids, st.valid_lids):
+                            gv = go_left_s(vb)
+                            mine_v = vl[None, :] == leafs_s[:, None]
+                            go_rv = mine_v & (~gv)
+                            vl_new.append(vl + jnp.sum(
+                                jnp.where(go_rv,
+                                          nls_s[:, None] - vl[None, :],
+                                          0),
+                                axis=0))
+                    if use_sub:
+                        # label only the SMALLER child of each split
+                        # (known up front from the recorded counts)
+                        in_small = gl == sml_s[:, None]
+                        label = jnp.sum(
+                            jnp.where(mine & in_small,
+                                      siota[:, None] - S, 0),
+                            axis=0) + S
+                    else:
+                        slot2 = 2 * siota[:, None] \
+                            + (~gl).astype(jnp.int32)
+                        label = jnp.sum(
+                            jnp.where(mine, slot2 - 2 * S, 0),
+                            axis=0) + 2 * S
 
                 # sustained rounds (the LARGEST bucket of a big wave) may
                 # run the configured cheaper deep precision; ramp rounds
@@ -1328,59 +1212,6 @@ def make_wave_grower(
                 # variants — everything stays full precision
                 deep = S == K and K >= 32 and len(slot_buckets) > 1
                 nsl = S if use_sub else 2 * S
-                if use_fused:
-                    # ---- fused megakernel round: histogram + subtraction
-                    # + split scan in ONE Pallas pass (ops/wave_fused.py).
-                    # The per-child scan parameters are slot-compacted
-                    # exactly like the slot arrays above (child 2s+lr of
-                    # rank k with order_c[k] == s); dead ranks drop.
-                    csidx = (2 * sidx[:, None]
-                             + jnp.arange(2, dtype=jnp.int32)[None, :]
-                             ).reshape(2 * K)
-
-                    def to_cslot(v, fill):
-                        base = jnp.full((2 * S,) + v.shape[1:], fill,
-                                        v.dtype)
-                        return base.at[csidx].set(v, mode="drop")
-
-                    pr = None
-                    if use_sub:
-                        pr = jnp.zeros((S,) + h_parent.shape[1:],
-                                       jnp.float32) \
-                            .at[sidx].set(h_parent, mode="drop")
-                    route = None
-                    if use_fused_route:
-                        route = dict(leaf_id=st.leaf_id, feats=feats_s,
-                                     thrs=thrs_s, dls=dls_s,
-                                     leafs=leafs_s, nls=nls_s,
-                                     num_leaves=L)
-                    fr_out = fused_round_fn(
-                        bins, g3, label, S, deep=deep,
-                        quant_key=rkey if S in quant_buckets else None,
-                        scaled=bool(quant_buckets),
-                        mask=to_cslot(cmask, False),
-                        csums=to_cslot(csums, 1.0),
-                        constr=to_cslot(cconstr, 0.0),
-                        depth=to_cslot(cdepth, 1),
-                        pout=to_cslot(couts, 0.0),
-                        sml=sml_s if use_sub else None,
-                        parent=pr, route=route)
-                    if use_fused_route:
-                        packed, h_sm, hsc, leaf_id = fr_out
-                    else:
-                        packed, h_sm, hsc = fr_out
-                    if S < K:   # pad to the bucket-invariant width
-                        packed = jnp.pad(packed,
-                                         ((0, 2 * (K - S)), (0, 0)))
-                    if not use_sub:
-                        return (packed, leaf_id) + tuple(vl_new)
-                    if S < K:
-                        h_sm = jnp.pad(
-                            h_sm, ((0, K - S),) + ((0, 0),) * 3)
-                        hsc = jnp.concatenate(
-                            [hsc, jnp.ones((K - S, 3), hsc.dtype)],
-                            axis=0)
-                    return (packed, h_sm, hsc, leaf_id) + tuple(vl_new)
                 if S in quant_buckets:
                     # stochastic-rounded int8 pass: integer histogram +
                     # per-slot dequant scales, rounding stream keyed per
@@ -1402,48 +1233,22 @@ def make_wave_grower(
                                            hsc.dtype)], axis=0)
                 return (h, hsc, leaf_id) + tuple(vl_new)
 
-            with (jax.named_scope("lgbm.fused_round") if use_fused
-                  else contextlib.nullcontext()):
-                if len(slot_buckets) > 1:
-                    with jax.named_scope("lgbm.select"):
-                        s_idx = jnp.zeros((), jnp.int32)
-                        for S in slot_buckets[:-1]:
-                            s_idx = s_idx + (n_split > S).astype(jnp.int32)
-                    outs = lax.switch(
-                        s_idx,
-                        [lambda S=S: round_pass(S) for S in slot_buckets])
-                else:
-                    outs = round_pass(slot_buckets[0])
-            if use_fused:
-                if use_sub:
-                    packed, h_slot, hscale, leaf_id = outs[:4]
-                    tail = outs[4:]
-                else:
-                    packed, leaf_id = outs[:2]
-                    h_slot = hscale = None
-                    tail = outs[2:]
-                new_vlids = vlids_in if pipeline else tuple(tail)
+            if len(slot_buckets) > 1:
+                with jax.named_scope("lgbm.select"):
+                    s_idx = jnp.zeros((), jnp.int32)
+                    for S in slot_buckets[:-1]:
+                        s_idx = s_idx + (n_split > S).astype(jnp.int32)
+                outs = lax.switch(
+                    s_idx,
+                    [lambda S=S: round_pass(S) for S in slot_buckets])
             else:
-                h_slot, hscale, leaf_id = outs[0], outs[1], outs[2]
-                new_vlids = vlids_in if pipeline else tuple(outs[3:])
+                outs = round_pass(slot_buckets[0])
+            h_slot, hscale, leaf_id = outs[0], outs[1], outs[2]
+            new_vlids = vlids_in if pipeline else tuple(outs[3:])
 
             cscale = None                   # per-child dequant (quant rounds)
             with jax.named_scope("lgbm.select"):
-                if use_fused:
-                    # the kernel already scanned the children in VMEM; what
-                    # remains is the per-leaf table bookkeeping (subtraction
-                    # mode: the SAME subtract the kernel ran, recomputed on
-                    # the emitted smaller-child stack for the state scatter)
-                    # and the slot->rank gather of the packed SplitInfo
-                    if use_sub:
-                        hist, h_left, h_right = subtract_child_hists(
-                            h_slot, leaf_hist_in, leafs, order_c, sm_left,
-                            slot_scale=hscale if quant_buckets else None,
-                            h_parent=h_parent)
-                    ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
-                                       axis=1).reshape(2 * K)
-                    res = _unpack_children(packed[ch_idx], B)
-                elif use_sub:
+                if use_sub:
                     # ---- smaller-child histograms + subtraction --------------
                     # quant rounds fold the per-slot dequantization into the
                     # subtraction pass (slot_scale); non-quant rounds carry
@@ -1467,11 +1272,7 @@ def make_wave_grower(
                             cscale = None
 
             # ---- batched split finding over the 2K children ---------------
-            # (fused rounds already hold `res` — the kernel's packed
-            # SplitInfo — and never route through split_fn)
-            if use_fused:
-                pass
-            elif cscale is not None:
+            if cscale is not None:
                 # dequantize-aware scan: integer histograms + per-child
                 # scales go straight into the gain cumsum (ops/split.py)
                 res = jax.vmap(
@@ -1561,122 +1362,8 @@ def make_wave_grower(
                     pending=new_pending,
                 )
 
-        R_loop = loop_plan["rounds"] if use_loop else 0
-
-        def body_loop(st: WaveState) -> WaveState:
-            # ---- persistent multi-round segment (ROADMAP item 1) ----
-            # ONE kernel launch runs R_loop consecutive rounds with the
-            # frontier table, histogram pool and row→leaf labels resident
-            # in VMEM (ops/wave_fused.make_fused_wave_loop); the staged
-            # bookkeeping below REPLAYS the rounds from the emitted
-            # per-round packed SplitInfo — the same store.write/
-            # route_rows code path as the single-round body, so trees,
-            # stores and valid routings are bit-identical.  Rounds past
-            # an exhausted frontier are bit-exact no-ops (every scatter
-            # drops, the leaf count stays put) both in-kernel and here.
-            rows_all = store.read(st.store,
-                                  jnp.arange(L, dtype=jnp.int32))
-            ft12 = jnp.concatenate([
-                store.gains(st.store)[:, None],
-                rows_all["feats"].astype(jnp.float32)[:, None],
-                rows_all["thrs"].astype(jnp.float32)[:, None],
-                rows_all["dls"].astype(jnp.float32)[:, None],
-                rows_all["lsums"], rows_all["rsums"],
-                rows_all["pout"][:, None],
-                rows_all["pdepth"].astype(jnp.float32)[:, None]], axis=1)
-            with jax.named_scope("lgbm.fused_loop"):
-                packed_R, leaf_id_new, pool_new = fused_loop_fn(
-                    bins, g3, st.leaf_id, ft12, st.num_leaves, key,
-                    K=K, slot_buckets=slot_buckets,
-                    quant_buckets=quant_buckets, max_depth=max_depth,
-                    base_mask=base_mask,
-                    pool=(st.leaf_hist if use_sub else None))
-            store_s = st.store
-            nl_s = st.num_leaves
-            vlids_s = st.valid_lids
-            done_s = st.done
-            for rr in range(R_loop):
-                vals, leafs = _topk_by_rank(store.gains(store_s), K)
-                budget = L - nl_s
-                valid = (vals > 0) & (kiota < budget)
-                n_split = valid.sum()
-                if _ROUND_PROBE is not None:   # bench round-schedule probe
-                    jax.debug.callback(_ROUND_PROBE, n_split)
-                order = jnp.cumsum(valid.astype(jnp.int32)) - 1
-                nodes = nl_s - 1 + order
-                nls = nl_s + order
-                rd = store.read(store_s, leafs)
-                feats, thrs, dls = rd["feats"], rd["thrs"], rd["dls"]
-                iscats, bitsets = rd["iscats"], rd["bitsets"]
-                lsums, rsums = rd["lsums"], rd["rsums"]
-                order_c = jnp.clip(order, 0, K - 1)
-                cleafs = jnp.stack([leafs, nls], axis=1).reshape(2 * K)
-                csums = jnp.stack([lsums, rsums],
-                                  axis=1).reshape(2 * K, 3)
-                pout = rd["pout"]
-                out_l = jax.vmap(clamp_out)(lsums, pconstr_const, pout)
-                out_r = jax.vmap(clamp_out)(rsums, pconstr_const, pout)
-                couts = jnp.stack([out_l, out_r], axis=1).reshape(2 * K)
-                d = rd["pdepth"] + 1
-                cdepth = jnp.stack([d, d], axis=1).reshape(2 * K)
-                depth_ok = (max_depth <= 0) | (cdepth < max_depth)
-                ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
-                                   axis=1).reshape(2 * K)
-                res = _unpack_children(packed_R[rr][ch_idx], B)
-                cgain = jnp.where(depth_ok, res.gain, -jnp.inf)
-                cvalid = jnp.stack([valid, valid], axis=1).reshape(2 * K)
-                cidx = jnp.where(cvalid, cleafs, L + 1)
-                nidx = jnp.where(valid, nodes, L1 + 1)
-                lidx = jnp.where(valid, leafs, L + 1)
-                nlidx = jnp.where(valid, nls, L + 1)
-                p = rd["parent"]
-                was_left = rd["was_left"]
-                fix_l = jnp.where(valid & (p >= 0) & was_left,
-                                  jnp.maximum(p, 0), L1 + 1)
-                fix_r = jnp.where(valid & (p >= 0) & (~was_left),
-                                  jnp.maximum(p, 0), L1 + 1)
-                psum_k = lsums + rsums
-                store_s = store.write(store_s, dict(
-                    res=res, cgain=cgain, cidx=cidx, nidx=nidx,
-                    lidx=lidx, nlidx=nlidx, fix_l=fix_l, fix_r=fix_r,
-                    leafs=leafs, nls=nls,
-                    feats=feats, thrs=thrs, dls=dls,
-                    iscats=iscats, bitsets=bitsets,
-                    mtypes=meta.missing_type[feats],
-                    vals=vals, pout=pout, psum=psum_k,
-                    lsums=lsums, rsums=rsums, csums=csums,
-                    out_l=out_l, out_r=out_r, couts=couts,
-                    cdepth=cdepth, cconstr=cconstr_const,
-                    num_leaves_new=nl_s + n_split,
-                ))
-                if valids:
-                    # per-replayed-round valid routing over the rank
-                    # arrays (dead ranks carry leaf id L, matching no
-                    # row) — the same route_rows decision stage as
-                    # route_pending's fused leg, bit-identical to the
-                    # in-round slot routing
-                    vlids_s = tuple(fused_round_fn.route_rows(
-                        vb, vl, feats=feats, thrs=thrs, dls=dls,
-                        leafs=jnp.where(valid, leafs, L), nls=nls,
-                        num_leaves=L)
-                        for vb, vl in zip(valids, vlids_s))
-                done_s = done_s | (n_split == 0)
-                nl_s = nl_s + n_split
-
-            return WaveState(
-                leaf_id=leaf_id_new,
-                valid_lids=vlids_s,
-                leaf_hist=(pool_new if use_sub else st.leaf_hist),
-                store=store_s,
-                leaf_box=st.leaf_box,
-                leaf_used=st.leaf_used,
-                num_leaves=nl_s,
-                done=done_s,
-                pending=st.pending,
-            )
-
         if L > 1:
-            st = lax.while_loop(cond, body_loop if use_loop else body, st)
+            st = lax.while_loop(cond, body, st)
         with jax.named_scope("lgbm.select"):
             tree = store.finalize(st.store, st.num_leaves)
         vlids_out = st.valid_lids

@@ -284,8 +284,7 @@ def hist_frontier(
     """All-leaves histogram in a single pass (level-wise grower).
 
     ``interpret`` reaches the Pallas kernel only: the CPU backend runs
-    ``hist_method=pallas`` through the interpreter — the bit-parity lane
-    the fused wave-round kernel (ops/wave_fused.py) is pinned against.
+    ``hist_method=pallas`` through the interpreter.
 
     Wrapped in ``jax.named_scope`` so device traces attribute histogram
     time the way the reference's USE_TIMETAG FunctionTimer tags host time
@@ -389,19 +388,7 @@ def default_hist_method(config_method: str = "auto",
     debug comparator, gpu_tree_learner.cpp:71-98).  int16-binned data
     (num_bins > 256) routes to the XLA one-hot path — the Pallas kernel is
     uint8-only (see hist_pallas.hist_leaves_pallas).
-
-    ``"fused"`` (the wave-round megakernel, ops/wave_fused.py) resolves to
-    its BASE method here — the implementation every non-fused pass (root
-    pass, sequential/level-wise growers, streaming) runs: the same
-    ``pallas`` arithmetic the fused kernel reuses, which is what makes
-    ``hist_method=fused`` trees bit-comparable to ``hist_method=pallas``
-    trees; int16 bins exclude the whole kernel family.  The fused
-    wave-round dispatch itself lives in parallel/trainer.py.
     """
-    if config_method == "fused":
-        if bin_dtype is not None and jnp.dtype(bin_dtype).itemsize > 1:
-            return "onehot"
-        return "pallas"
     if config_method not in ("auto", "bench"):
         return config_method
     platform = jax.default_backend()

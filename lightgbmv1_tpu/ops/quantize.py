@@ -37,9 +37,8 @@ min_data_in_leaf gating relies on (ops/histogram.py module docstring).
 
 ALL scales are powers of two — grad/hess too, snapped down from
 amax/127 (sr_prequantize_g3).  Exact dequantization multiplies make the
-parent-subtraction arithmetic rounding-order independent, which is what
-lets the persistent wave loop's in-kernel commit stay bit-identical to
-the host grower's subtraction (see the comment at the snap site).
+parent-subtraction arithmetic rounding-order independent (see the
+comment at the snap site).
 """
 
 from __future__ import annotations
@@ -92,15 +91,7 @@ def sr_quantize_g3(g3: jax.Array, label: jax.Array, nslots: int,
 def sr_prequantize_g3(g3: jax.Array, nslots: int, axis_name=None):
     """The key-INDEPENDENT half of :func:`sr_quantize_g3`: scaled
     grad/hess rows ``zg = g * inv`` (N, 2), the exactly-rounded count
-    channel ``qc`` (N,), and the (nslots, 3) dequantization scales.
-
-    Factored out so the persistent wave-loop kernel
-    (ops/wave_fused.make_fused_wave_loop) can host-precompute everything
-    but the per-round uniform draw — the rounding stream stays
-    ``clip(floor(zg + U), -127, 127)`` with U drawn per (iteration,
-    round) key inside the loop, reproducing sr_quantize_g3's exact
-    per-round bits.  The ops here are the literal ones sr_quantize_g3
-    ran inline before the factoring (bit-parity contract)."""
+    channel ``qc`` (N,), and the (nslots, 3) dequantization scales."""
     from jax import lax as _lax
 
     g = g3[:, :2].astype(jnp.float32)
@@ -112,13 +103,10 @@ def sr_prequantize_g3(g3: jax.Array, nslots: int, axis_name=None):
     # 127/amax)), scale = 1/inv): a power-of-two dequantization multiply
     # is EXACT in f32, so `parent - q*scale` rounds identically whether a
     # compiler contracts the multiply into the subtraction (fma, one
-    # rounding) or not (two roundings).  The three places that compute
-    # subtracted children from the same quantized histogram — the host
-    # grower (XLA), the fused kernel's scan, and the persistent wave
-    # loop's commit (both Pallas) — sit in different fusion contexts, and
-    # their bit-parity contract must not hang on a contraction heuristic
-    # (optimization_barrier does not stop it).  Costs at most one bit of
-    # int8 range; SR unbiasedness holds for any scale (module docstring).
+    # rounding) or not (two roundings): trees must not hang on a
+    # contraction heuristic (optimization_barrier does not stop it).
+    # Costs at most one bit of int8 range; SR unbiasedness holds for any
+    # scale (module docstring).
     e2 = jnp.floor(jnp.log2(INT8_QMAX / amax))
     inv = jnp.where(amax > 0, jnp.exp2(e2), 0.0)
     scale = jnp.where(amax > 0, jnp.exp2(-e2), 0.0)

@@ -70,13 +70,10 @@ def go_left_rule(bins, thr, dl, mt, nan_bin, zero_bin):
     All inputs broadcast (``bins`` is int32 bin ids, the rest per-split
     scalars or column vectors; ``dl`` bool, ``mt``/``nan_bin``/
     ``zero_bin`` int32).  Pure integer/bool ops — exact everywhere, so
-    the staged (S, N) partition pass (models/grower_wave.py
-    ``go_left_s``), the deferred valid-routing drain (``route_pending``)
-    and the fused megakernel's in-VMEM routing stage
-    (ops/wave_fused.py ``route_tile``) all evaluate the SAME code
-    object: the decision cannot drift between the paths.  Categorical
-    bitset membership stays with the callers that support it (the fused
-    gate excludes categorical datasets)."""
+    the (S, N) partition pass (models/grower_wave.py ``go_left_s``) and
+    the deferred valid-routing drain (``route_pending``) evaluate the
+    SAME code object: the decision cannot drift between them.
+    Categorical bitset membership stays with the callers."""
     na = ((mt == MISSING_NAN) & (bins == nan_bin)) | (
         (mt == MISSING_ZERO) & (bins == zero_bin))
     return jnp.where(na, dl, bins <= thr)
@@ -211,21 +208,6 @@ def smooth_output(raw_out, count, parent_output, p: SplitParams):
     ``out*(n/a)/(n/a+1) + parent/(n/a+1)`` with a = path_smooth."""
     w = count / p.path_smooth
     return raw_out * w / (w + 1.0) + parent_output / (w + 1.0)
-
-
-def child_leaf_output(sums, constr, parent_out, p: SplitParams,
-                      use_mc: bool = False):
-    """One frontier child's (possibly smoothed / clamped) leaf output from
-    its (g, h, c) sums — the wave grower's per-round ``clamp_out`` math,
-    factored here so the grower bookkeeping and the persistent wave-loop
-    kernel (ops/wave_fused.make_fused_wave_loop) run the SAME op sequence;
-    the loop's bit-parity contract rides on sharing this code object."""
-    out = leaf_output(sums[0], sums[1], p)
-    if p.path_smooth > 0:
-        out = smooth_output(out, sums[2], parent_out, p)
-    if not use_mc:
-        return out
-    return jnp.clip(out, constr[0], constr[1])
 
 
 def monotone_penalty_factor(depth, penalization):
@@ -515,9 +497,7 @@ def scan_left_sums(hist, meta, hist_scale=None):
 def gain_shift(parent_sum, parent_output, params):
     """The gain baseline every candidate is differenced against: parent
     gain (at the smoothed current output when path smoothing is on) plus
-    ``min_gain_to_split``.  One function so the staged scan
-    (:func:`scan_direction_gains`) and the fused wave-round kernel's
-    outside-the-kernel tie band (ops/wave_fused.py) cannot drift."""
+    ``min_gain_to_split``."""
     total_g, total_h = parent_sum[0], parent_sum[1]
     if params.path_smooth > 0:
         # reference: with smoothing the gain shift is the leaf's gain AT
@@ -532,7 +512,7 @@ def gain_shift(parent_sum, parent_output, params):
 def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
                          constraint=None, depth=0, monotone_penalty=0.0,
                          parent_output=0.0, rand_key=None,
-                         cegb_penalty=None, use_mc=None):
+                         cegb_penalty=None):
     """Phase 2 of the fused split scan: gains of every (direction,
     feature, bin) candidate in ONE stacked evaluation over the
     ``(2, F, B, 3)`` left sums from :func:`scan_left_sums` — the gain
@@ -540,19 +520,12 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
     doubled tensor instead of once per direction, so the whole
     cumsum → gain chain lowers as a single fused pass.
 
-    ``use_mc`` overrides the monotone-constraint probe for callers whose
-    ``meta`` arrays are traced values (the fused wave-round kernel reads
-    its per-feature-block meta slices from kernel refs, where the
-    ``np.asarray`` probe below cannot run); ``None`` derives it from the
-    concrete meta as before.
-
     Returns ``(gains (2, F, B), shift)`` with gains RELATIVE (shift =
     parent gain + min_gain_to_split already subtracted) and every
     penalty applied.  Module-level for tools/phase_attrib.py."""
     _, F, B, _ = left2.shape
     total_g, total_h, total_c = parent_sum[0], parent_sum[1], parent_sum[2]
-    if use_mc is None:
-        use_mc = bool(np.asarray(meta.monotone_type).any())
+    use_mc = bool(np.asarray(meta.monotone_type).any())
     use_smooth = params.path_smooth > 0
     if constraint is None:
         constraint = jnp.asarray(NO_CONSTRAINT, jnp.float32)
@@ -638,13 +611,7 @@ def scan_pick_feature(gains, shift, meta):
     feature's best candidate gain over its ``2B`` (direction, bin) slots
     plus the preferred in-band candidate index.  Returns
     ``(fbest (F,), sel_f (F,))`` with ``sel_f`` encoding
-    ``direction * B + threshold``.
-
-    Split out of :func:`scan_pick` so the fused wave-round kernel
-    (ops/wave_fused.py) can run EXACTLY this reduction per feature block
-    in VMEM and emit only the O(F) residue — the cross-feature band
-    needs the global best, so that half stays outside the kernel — while
-    the staged path composes the same code object."""
+    ``direction * B + threshold``."""
     _, F, B = gains.shape
     t_idx = lax.broadcasted_iota(jnp.int32, (F, B), 1)
     rev_like_a = ((meta.missing_type == MISSING_NONE)

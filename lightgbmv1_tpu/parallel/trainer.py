@@ -307,14 +307,13 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
                       bundled: bool) -> str:
     """Resolve ``config.bin_layout`` to the device layout actually built
     (``"u8"`` or ``"packed4"``) — ONE call per GBDT build, which also
-    owns the once-per-build engagement/refusal logging (the wave-loop
-    logging precedent).
+    owns the once-per-build engagement/refusal logging.
 
     Eligibility for ``packed4`` (the reference ``DenseBin<.., IS_4BIT>``
     gate, dense_bin.hpp:52): every feature fits 4 bits
     (``num_total_bin <= 16``), uint8 bins (int16-binned data exceeds the
-    nibble), no EFB bundling (bundle offsets address byte bins), a
-    pallas-family hist method (scatter/onehot gathers address unpacked
+    nibble), no EFB bundling (bundle offsets address byte bins), the
+    pallas hist method (scatter/onehot gathers address unpacked
     bins), ``tree_learner != "feature"`` (feature shards split the byte
     pairing), and not ``gpu_use_dp`` (an explicit request for the widest
     histogram datapath; packing narrows the read stream — dp wins, the
@@ -334,7 +333,7 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
         reason = "EFB bundle offsets address unpacked byte bins"
     elif method != "pallas":
         reason = (f"hist method {method!r} gathers unpacked bins "
-                  "(pallas-family kernels unpack nibbles in VMEM)")
+                  "(the pallas kernel unpacks nibbles in VMEM)")
     elif config.tree_learner == "feature":
         reason = ("tree_learner=feature shards features, not byte "
                   "pairs")
@@ -481,9 +480,8 @@ def build_trainer(
     levelwise = config.tree_growth == "levelwise"
 
     # hist_method=pallas on the CPU backend runs the kernels through the
-    # Pallas interpreter — the bit-parity lane the fused wave-round
-    # kernel is pinned against (ops/wave_fused.py; the BatchPredictor
-    # precedent for interpret-on-CPU)
+    # Pallas interpreter (the BatchPredictor precedent for
+    # interpret-on-CPU)
     pallas_interpret = (method == "pallas"
                         and jax.default_backend() == "cpu")
 
@@ -701,45 +699,7 @@ def build_trainer(
         grow_fn._renew_policy = renew_policy
         return obs_xla.instrument_jit(grow_fn, label)
 
-    # ---- hist_method=fused: the wave-round megakernel dispatch ----------
-    # (ops/wave_fused.py — histogram + smaller-child subtraction + split
-    # scan in one Pallas invocation, histograms resident in VMEM).  The
-    # static gates below are planner DECISIONS with a stated reason
-    # (logged once; the staged path runs).  There is no compile probe: an
-    # eligible config runs the kernel, and a kernel the backend cannot
-    # lower or compile raises with the compiler's message.
-    fused_builder = None
-    if config.hist_method == "fused":
-        from ..ops import wave_fused
-
-        fused_reason = wave_fused.fused_ineligible_reason(
-            meta=meta, params=params, bin_dtype=binned_np.dtype,
-            num_bins=B, packed=packed, bundled=bundle is not None)
-        if not fused_reason and (levelwise or not use_wave
-                                 or forced is not None):
-            fused_reason = ("the fused kernel is a wave-round kernel; "
-                            "this config routes to the "
-                            + ("level-wise" if levelwise else "sequential")
-                            + " grower")
-        if not fused_reason and learner in ("data", "voting"):
-            fused_reason = (f"tree_learner={learner} reduces histograms "
-                            "across row shards (the collective needs the "
-                            "explicit histogram)")
-        if fused_reason:
-            log_warning(f"hist_method=fused: {fused_reason}; running the "
-                        "staged histogram+split path")
-        else:
-            fused_builder = wave_fused.make_fused_round
-            log_info("hist_method=fused: wave rounds run the fused "
-                     "histogram+split kernel with partition, valid "
-                     "routing and top-k folded into the same dispatch "
-                     "(ops/wave_fused.py, single-pass wave round"
-                     + (", 4-bit packed bins" if packed else "")
-                     + (", interpret mode"
-                        if jax.default_backend() == "cpu" else "") + ")")
-
     if learner in ("serial", ""):
-        fused_loop = None   # set by the wave branch when the loop engages
         if levelwise:
             grow = make_levelwise_grower(
                 hist_frontier_fn=local_frontier, split_fn=split_local,
@@ -748,95 +708,12 @@ def build_trainer(
         elif use_wave and forced is None:
             # wave-batched best-first: the leaf-wise default schedule
             # (models/grower_wave.py)
-            fused_fn = None
-            if fused_builder is not None:
-                fused_fn = fused_builder(
-                    meta=meta, params=params, num_bins=B,
-                    precision=precision, deep_precision=deep_precision,
-                    monotone_penalty=config.monotone_penalty,
-                    interpret=jax.default_backend() == "cpu",
-                    packed=packed)
-            # ---- persistent multi-round wave loop (ROADMAP item 1) ----
-            # wave_loop_rounds > 1 on the fused path: ONE Pallas launch
-            # runs R consecutive rounds with the frontier state resident
-            # in VMEM (ops/wave_fused.make_fused_wave_loop).  The gates
-            # below are the loop's own planner refusals — every staged
-            # leg the kernel cannot replicate in-loop (per-node feature
-            # re-masking, monotone constraint propagation), each running
-            # SINGLE-ROUND fused dispatch with a logged reason.  The VMEM
-            # planner runs at trace time inside the grower
-            # (shape-dependent).
-            fused_loop = None
-            if fused_fn is not None and config.wave_loop_rounds > 1:
-                from ..models import grower_wave as _gw
-
-                loop_reason = None
-                if common["interaction_groups"] is not None:
-                    loop_reason = ("interaction constraints re-mask "
-                                   "features per split; the loop kernel "
-                                   "freezes the round-0 mask")
-                elif config.feature_fraction_bynode < 1.0:
-                    loop_reason = ("feature_fraction_bynode draws a "
-                                   "fresh per-node mask every round")
-                elif has_mono:
-                    loop_reason = ("monotone constraints propagate "
-                                   "child bounds between rounds outside "
-                                   "the kernel")
-                if loop_reason:
-                    log_warning(f"wave_loop_rounds="
-                                f"{config.wave_loop_rounds}: "
-                                f"{loop_reason}; running single-round "
-                                "fused dispatch")
-                else:
-                    fused_loop = wave_fused.make_fused_wave_loop(
-                        meta=meta, params=params, num_bins=B,
-                        precision=precision,
-                        deep_precision=deep_precision,
-                        rounds=config.wave_loop_rounds,
-                        monotone_penalty=config.monotone_penalty,
-                        interpret=jax.default_backend() == "cpu",
-                        packed=packed)
-                    # replicate the grower's trace-time plan for the
-                    # dispatch label / log line (shape statics only)
-                    K_eff = max(1, min(wave_size,
-                                       max(config.num_leaves - 1, 1)))
-                    sb = _gw.slot_buckets_for(K_eff, N)
-                    qb = ()
-                    if use_int8sr and len(sb) > 1:
-                        qb = tuple(S for S in sb
-                                   if (S == K_eff and K_eff >= 32)
-                                   or (S == 16 and S < K_eff))
-                    use_sub_t = (config.num_leaves * F * B * 3 * 4
-                                 <= _gw._SUB_STATE_CAP_BYTES)
-                    plan = fused_loop.plan(
-                        N=N, F=F, K=K_eff, L=config.num_leaves,
-                        use_sub=use_sub_t, slot_buckets=sb,
-                        quant_buckets=qb)
-                    if not plan["eligible"]:
-                        log_warning(f"wave_loop_rounds="
-                                    f"{config.wave_loop_rounds}: "
-                                    f"{plan['reason']}; running "
-                                    "single-round fused dispatch")
-                        fused_loop = None
-                    else:
-                        log_info("wave_loop_rounds="
-                                 f"{plan['rounds']}: persistent "
-                                 "multi-round wave loop engaged — "
-                                 "frontier state resident in VMEM "
-                                 f"({plan['total_bytes'] >> 10} KiB of "
-                                 f"{plan['vmem_budget'] >> 20} MiB "
-                                 "budget, ops/wave_fused.py"
-                                 + (", interpret mode"
-                                    if jax.default_backend() == "cpu"
-                                    else "") + ")")
             grow = make_wave_grower(hist_wave_fn=local_wave,
                                     hist_wave_quant_fn=(
                                         local_wave_quant if use_int8sr
                                         else None),
                                     split_fn=split_local,
                                     bins_of_fn=bins_feat_fn,
-                                    fused_round_fn=fused_fn,
-                                    fused_loop_fn=fused_loop,
                                     **wave_common)
         else:
             # sequential best-first (the reference's exact split order):
@@ -855,22 +732,12 @@ def build_trainer(
         # _supports_valids capability flag — valid rows routed through
         # each round's splits instead of per-tree walks — rides the
         # wrapped callable automatically; compile telemetry (obs/xla.py)
-        # labels this dispatch per learner — `grow.fused_round` when the
-        # fused megakernel is engaged, so compile counters, cost
-        # analysis (flops / bytes accessed) and the roofline join track
-        # the fused executable as its own watched row
-        label = ("grow.fused_loop" if fused_loop is not None
-                 else "grow.fused_round" if fused_builder is not None
-                 else "grow.serial")   # gates above null the builder
-                                       # whenever a non-wave grower runs
+        # labels this dispatch per learner
         binned_dev = jnp.asarray(binned_np)
-        # the wave rounds of an engaged fused kernel lay the rows out
-        # themselves (ops/wave_fused.py): only the staged passes read
-        # prepared bins
-        if method == "pallas" and fused_builder is None:
+        if method == "pallas":
             binned_dev = _place_hist_bins(binned_dev, Bh, packed)
         grow = renewing(grow, N, wave_grows)
-        return finished(grow, label), binned_dev, N
+        return finished(grow, "grow.serial"), binned_dev, N
 
     if learner == "voting" and levelwise:
         log_warning("tree_learner=voting requires the leaf-wise grower; "
@@ -1427,87 +1294,6 @@ def build_trainer(
             # the level-wise grower is basic-only (warned above)
             fp_kwargs["monotone_mode"] = mono_mode
             fp_kwargs["async_wave_pipeline"] = config.async_wave_pipeline
-        # hist_method=fused per feature slice (ISSUE 13): each shard runs
-        # the fused kernel over its OWN feature block — histograms stay
-        # in that shard's VMEM, nothing crosses chips but the packed
-        # SplitInfo the existing _sync_best_split election already moves
-        fused_fp = None
-        if fused_builder is not None and use_wave and not levelwise:
-            from ..ops.wave_fused import pack_children, unpack_children
-
-            # partition-specific fallback (the ISSUE 15 taxonomy leg):
-            # the in-kernel routing stage decides with the committed
-            # split feature's GLOBAL column, but each shard's kernel
-            # sees only its own feature slice — so the feature-parallel
-            # learner keeps the staged (S, N) partition + valid routing
-            # (the wrapper below deliberately lacks supports_route)
-            # while still fusing histogram + scan per slice
-            log_info("hist_method=fused: feature-parallel keeps the "
-                     "staged partition (in-kernel routing needs the "
-                     "split feature's global column; each shard holds a "
-                     "feature slice) — histogram+split stay fused per "
-                     "slice through the SplitInfo election")
-            base_fused = fused_builder(
-                meta=meta_p, params=params, num_bins=B,
-                precision=precision, deep_precision=deep_precision,
-                monotone_penalty=config.monotone_penalty,
-                interpret=jax.default_backend() == "cpu")
-
-            def _slice_meta(lo):
-                def sl(a, wide=F_loc):
-                    return lax.dynamic_slice(a, (lo,), (wide,))
-                return FeatureMeta(
-                    num_bins=sl(meta_p.num_bins),
-                    missing_type=sl(meta_p.missing_type),
-                    nan_bin=sl(meta_p.nan_bin),
-                    zero_bin=sl(meta_p.zero_bin),
-                    is_categorical=sl(meta_p.is_categorical),
-                    usable=sl(meta_p.usable),
-                    monotone_type=sl(meta_p.monotone_type),
-                    contri=(sl(meta_p.contri)
-                            if meta_p.contri is not None else None),
-                )
-
-            def fused_fp(binned, g3, label, S, *, deep=False,
-                         quant_key=None, scaled=False, mask=None,
-                         csums=None, constr=None, depth=None, pout=None,
-                         sml=None, parent=None, meta_override=None,
-                         route=None):
-                del meta_override
-                assert route is None, (
-                    "feature-parallel fused rounds keep the staged "
-                    "partition (no supports_route); the grower must not "
-                    "request in-kernel routing here")
-                lo = lax.axis_index("feature") * F_loc
-                block = lax.dynamic_slice(binned, (lo, 0), (F_loc, N))
-                mask_loc = lax.dynamic_slice(
-                    mask, (0, lo), (2 * S, F_loc))
-                par_loc = (lax.dynamic_slice(
-                    parent, (0, lo, 0, 0), (S, F_loc, B, 3))
-                    if parent is not None else None)
-                packed, hsm, sc = base_fused(
-                    block, g3, label, S, deep=deep, quant_key=quant_key,
-                    scaled=scaled, mask=mask_loc, csums=csums,
-                    constr=constr, depth=depth, pout=pout, sml=sml,
-                    parent=par_loc, meta_override=_slice_meta(lo))
-                # shard-local feature ids -> global, then the SplitInfo
-                # election (reference SyncUpGlobalBestSplit) per child
-                local = unpack_children(packed, B)
-                local = local._replace(feature=local.feature + lo)
-                synced = jax.vmap(
-                    lambda lc, ps: _sync_best_split(lc, ps, params,
-                                                    "feature")
-                )(local, csums)
-                packed_g = pack_children(synced)
-                if hsm is not None:
-                    # re-embed the shard's smaller-child block at its
-                    # offset of the full-width (zeros elsewhere) state —
-                    # the hist_wave_fp layout the subtraction table uses
-                    full = jnp.zeros((S, F_pad, B, 3), jnp.float32)
-                    hsm = lax.dynamic_update_slice(full, hsm,
-                                                   (0, lo, 0, 0))
-                return packed_g, hsm, sc
-
         if levelwise:
             # feature-sharded frontier histograms + vmapped all_gather
             # argmax per leaf — the level-wise grower composes with the
@@ -1530,7 +1316,6 @@ def build_trainer(
                 hist_wave_quant_fn=(hist_wave_quant_fp if use_int8sr
                                     else None),
                 split_fn=split_fn,
-                fused_round_fn=fused_fp,
                 wave_size=wave_size, **fp_kwargs)
         else:
             grow = make_leafwise_grower(
@@ -1557,8 +1342,6 @@ def build_trainer(
             return sharded(binned, g3, maskp, key,
                            jnp.pad(cegb_used, (0, pad_f)))
 
-        return finished(
-            grow_fn, ("grow.fused_round" if fused_fp is not None
-                      else f"grow.{learner}")), binned_dev, N
+        return finished(grow_fn, f"grow.{learner}"), binned_dev, N
 
     log_fatal(f"Unknown tree_learner: {learner}")

@@ -319,70 +319,6 @@ def test_split_breakdown_and_pipeline_render():
     assert "AUC-parity experiment" not in txt0
 
 
-def test_fused_section_renders_fused_fields():
-    """The Fused-wave-round section (ISSUE 13) is generated from the
-    BENCH fused_* fields (bench.py measure_fused /
-    measure_fused_round_ms): parity, the merged hist+split row inside
-    the phase table, the cost-analysis HBM accounting and the fused_ok
-    guard all grep to record fields; records without them render
-    nothing (older records stay stable)."""
-    import perf_report
-
-    rec = {
-        "phase_hist_ms": 66.78, "phase_partition_ms": 9.7,
-        "phase_valid_route_ms": 2.1, "phase_split_ms": 22.8,
-        "phase_other_ms": 50.48, "phase_total_measured_ms": 151.9,
-        "hist_split_fused_ms_per_iter": 41.25,
-        "partition_fused_ms_per_iter": 43.75,
-        "fused_parity_ok": True, "fused_ok": True,
-        "fused_round_ok": True,
-        "fused_M_row_trees_per_s": 11.5,
-        "fused_staged_pallas_M_row_trees_per_s": 9.875,
-        "staged_round_bytes_accessed": 500_000_000,
-        "fused_round_bytes_accessed": 180_000_000,
-        "fused_hbm_bytes_saved_per_round": 320_000_000,
-        "fused_round_bytes_reduction": 2.778,
-        "fused_hbm_stack_bytes_analytic": 170_698_752,
-        "staged_round_binned_bytes_analytic": 346_500_000,
-        "fused_round_binned_bytes_analytic": 299_000_000,
-        "fused_loop_parity_ok": True, "fused_loop_ok": True,
-        "fused_loop_rounds": 4,
-        "fused_loop_launches_saved_per_segment": 3,
-        "fused_loop_state_bytes_saved_per_segment_analytic": 9_437_184,
-        "wave_loop_ms_per_iter": 39.5,
-        "wave_loop_single_round_ms_per_iter": 43.75,
-        "wave_loop_boundary_saving_ms_per_iter": 4.25,
-    }
-    txt = perf_report.generate(rec, "BENCH_rTEST.json")
-    for needle in ("## Fused wave round", "41.25", "fused_ok=True",
-                   "fused_parity_ok=True", "320000000", "hist+split fused",
-                   "ops/wave_fused.py",
-                   # ISSUE 15: the routed single-pass round renders its
-                   # merged column + the bytes contract + the guard
-                   "43.75", "round fused", "fused_round_ok=True",
-                   "2.778", "299000000", "read once per round",
-                   # ISSUE 17: the persistent wave loop renders parity,
-                   # the looped-vs-single ms pair, the per-segment launch
-                   # and state savings, and its guard
-                   "wave_loop_rounds=4", "fused_loop_parity_ok=True",
-                   "39.5", "3 launches", "9437184",
-                   "4.25 ms/iter", "fused_loop_ok=True"):
-        assert needle in txt, needle
-    # absent fields: no fused section, legacy phase-table header — the
-    # on-disk PERF.md (generated from an r05-era record) stays stable
-    txt0 = perf_report.generate({"auc": 0.9}, "BENCH_rTEST.json")
-    assert "## Fused wave round" not in txt0
-    # an ISSUE-13-era record (no partition_fused field) keeps its
-    # seven-column phase table
-    txt13 = perf_report.generate(
-        {k: v for k, v in rec.items()
-         if k not in ("partition_fused_ms_per_iter",)},
-        "BENCH_rTEST.json")
-    assert "| hist+split fused |\n" in txt13 or \
-        "| hist+split fused |" in txt13
-    assert "round fused" not in txt13
-
-
 def test_observability_section_renders_obs_fields():
     """The Observability section (ISSUE 9) is generated from the BENCH
     obs_* fields (bench.py measure_obs): overhead vs the 2% contract,

@@ -197,42 +197,23 @@ def test_assembly_measures_real_store_codecs():
         assert np.isfinite(ms) and ms >= 0
 
 
-def test_fused_merged_phase_recognized():
-    """ISSUE 13/15: a record training with hist_method=fused carries
-    the merged round phase (`phase_round_fused_ms` — partition, valid
-    routing, top-k, histogram and scan all folded in) — the canonical
-    phase list must route it into the cost split and the roofline join
-    as its own labeled row, never into phase_other.  A fused run has NO
-    staged partition row: the partition rides the fused dispatch."""
+def test_phase_ms_from_fields_takes_the_canonical_phases():
+    """Every positive canonical phase of a record lands as its own row of
+    the cost split and the roofline join; absent rows and fields that are
+    not phases do not."""
     from tools.phase_attrib import (PHASE_MS_KEYS, phase_ms_from_fields,
                                     roofline_attribution,
                                     split_cost_by_ms)
 
-    assert "phase_round_fused_ms" in PHASE_MS_KEYS
-    assert "phase_hist_split_fused_ms" not in PHASE_MS_KEYS  # renamed
-    fields = {"phase_round_fused_ms": 45.0,
+    assert PHASE_MS_KEYS[0] == "phase_hist_ms"
+    fields = {"phase_hist_ms": 45.0, "phase_split_ms": 22.8,
               "phase_other_ms": 50.0,
-              "phase_hist_ms": None,          # fused run: no staged rows
-              "phase_partition_ms": None,     # folded into the round
+              "phase_valid_route_ms": None,   # no valid set attached
+              "phase_partition_ms": 0.0,
               "not_a_phase_ms": 3.0}
     pms = phase_ms_from_fields(fields)
-    assert pms == {"round_fused": 45.0, "other": 50.0}
+    assert pms == {"hist": 45.0, "split": 22.8, "other": 50.0}
     cost = split_cost_by_ms(1e12, 1e9, pms)
     assert set(cost) == set(pms)
     rl = roofline_attribution(pms, cost, 1e12, peak_bytes_per_s=1e11)
-    assert "round_fused" in rl and rl["round_fused"]["ms"] == 45.0
-
-
-def test_fused_merged_phase_legacy_alias():
-    """Pre-ISSUE-15 records carried the merged fused row as
-    `phase_hist_split_fused_ms` (no partition folded); it must land on
-    the canonical `round_fused` row so old captures keep rendering."""
-    from tools.phase_attrib import phase_ms_from_fields
-
-    pms = phase_ms_from_fields({"phase_hist_split_fused_ms": 40.0,
-                                "phase_partition_ms": 9.7})
-    assert pms == {"round_fused": 40.0, "partition": 9.7}
-    # canonical key wins when both are present
-    pms = phase_ms_from_fields({"phase_hist_split_fused_ms": 40.0,
-                                "phase_round_fused_ms": 45.0})
-    assert pms == {"round_fused": 45.0}
+    assert rl["hist"]["ms"] == 45.0

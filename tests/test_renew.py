@@ -229,7 +229,9 @@ def test_new_cell_rehearses_and_its_metrics_are_declared():
     p = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
          "--workload", "criteo-dp4-train", "--seed", "2147483659",
-         "--seconds", "4", "--trace", "0", "--rehearse-cpu"],
+         # 6 trees have to finish in the window while five other workers
+         # load the box
+         "--seconds", "12", "--trace", "0", "--rehearse-cpu"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-2000:]
     result = json.loads(p.stdout.strip().splitlines()[-1])

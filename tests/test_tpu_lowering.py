@@ -4,7 +4,7 @@ the CPU suite, no chip needed.
 ``jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))`` runs the
 Pallas -> Mosaic lowering only (the Mosaic compiler proper runs on the
 chip; chip_smoke.py covers that).  Lowering is therefore necessary, not
-sufficient — but it is where four of the five kernels stop on the
+sufficient — but it is where the two serving kernels stop on the
 installed JAX, and nothing else in tier-1 would notice: on CPU they all
 run in interpret mode.
 
@@ -22,17 +22,11 @@ import pytest
 
 import lightgbmv1_tpu as lgb
 from lightgbmv1_tpu.models.predict import BatchPredictor
-from lightgbmv1_tpu.ops import wave_fused as wf
 from lightgbmv1_tpu.ops.hist_pallas import hist_leaves_pallas, pack4bit
 from lightgbmv1_tpu.ops.predict_pallas import (serving_fused_pallas,
                                                serving_leaf_pallas)
 from lightgbmv1_tpu.ops.split import FeatureMeta, SplitParams
 
-CUMSUM = ("Unimplemented primitive in Pallas TPU lowering for "
-          "KernelType.TC: cumsum (ops/split.scan_left_sums in the kernel "
-          "body; with the cumsum written as a triangular matmul the next "
-          "stop is 'Only 2D gather is supported', the (F, B, 3) "
-          "take_along_axis point reads of the same function)")
 GATHER = ("Only 2D gather is supported (jnp.take of the flattened 1-D "
           "node table, ops/predict_pallas.py walk body)")
 
@@ -332,27 +326,6 @@ def _probe_meta(F, B):
     )
 
 
-def _round_args(F=4, B=8, N=64, S=2):
-    rng = np.random.RandomState(0)
-    binned = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
-    g3 = jnp.asarray(rng.randn(N, 3).astype(np.float32))
-    lids = jnp.asarray(rng.randint(0, 2 * S, N).astype(np.int32))
-    kw = dict(mask=jnp.ones((2 * S, F), bool),
-              csums=jnp.abs(jnp.asarray(
-                  rng.randn(2 * S, 3).astype(np.float32))),
-              constr=jnp.tile(jnp.asarray([-3e38, 3e38], jnp.float32),
-                              (2 * S, 1)),
-              depth=jnp.ones(2 * S, jnp.int32),
-              pout=jnp.zeros(2 * S, jnp.float32))
-    route = dict(feats=jnp.arange(S, dtype=jnp.int32),
-                 thrs=jnp.full(S, B // 2, jnp.int32),
-                 dls=jnp.zeros(S, bool),
-                 leafs=jnp.arange(S, dtype=jnp.int32),
-                 nls=jnp.arange(S, dtype=jnp.int32) + S,
-                 num_leaves=2 * S)
-    return binned, g3, lids, kw, route
-
-
 @pytest.mark.parametrize("precision", ["bf16x2", "bf16", "f32", "int8"])
 @pytest.mark.parametrize("leaves", [31, 255, 2048])
 def test_leaf_sums_kernel_lowers(rows, leaves, precision):
@@ -389,49 +362,6 @@ def test_leaf_sums_kernel_compiles_at_the_cells_sizes(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
-
-
-def test_fused_route_rows_lowers():
-    """The valid-set router of the fused round — the one piece of
-    ops/wave_fused.py the installed JAX can put on a TPU."""
-    F, B, S = 4, 8, 2
-    binned, _, lids, _, route = _round_args(F, B, S=S)
-    fn = wf.make_fused_round(meta=_probe_meta(F, B), params=SplitParams(),
-                             num_bins=B, precision="bf16x2",
-                             deep_precision="bf16")
-    lower_for_tpu(lambda b, l: fn.route_rows(b, l, **route), binned, lids)
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=CUMSUM)
-def test_fused_round_lowers():
-    """hist_method=fused: the routed single-round megakernel."""
-    F, B, S = 4, 8, 2
-    binned, g3, lids, kw, route = _round_args(F, B, S=S)
-    fn = wf.make_fused_round(meta=_probe_meta(F, B), params=SplitParams(),
-                             num_bins=B, precision="bf16x2",
-                             deep_precision="bf16")
-    lower_for_tpu(
-        lambda b, g, l: fn(b, g, None, S, **kw,
-                           route=dict(leaf_id=l, **route)),
-        binned, g3, lids)
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=CUMSUM)
-def test_fused_wave_loop_lowers():
-    """wave_loop_rounds > 1: the persistent multi-round kernel."""
-    F, B, N, K, L = 4, 8, 64, 2, 8
-    binned, g3, _, _, _ = _round_args(F, B, N)
-    fn = wf.make_fused_wave_loop(
-        meta=_probe_meta(F, B), params=SplitParams(), num_bins=B,
-        precision="f32", deep_precision="f32", rounds=2)
-    ft = jnp.zeros((L, 12), jnp.float32).at[0, 0].set(1.0)
-    pool = jnp.zeros((L, F, B, 3), jnp.float32)
-    lower_for_tpu(
-        lambda b, g, l, f, p, k: fn(
-            b, g, l, f, 1, k, K=K, slot_buckets=(K,), quant_buckets=(),
-            max_depth=0, base_mask=jnp.ones(F, bool), pool=p),
-        binned, g3, jnp.zeros(N, jnp.int32), ft, pool,
-        jnp.zeros(2, jnp.uint32))
 
 
 @pytest.fixture(scope="module")

@@ -273,59 +273,6 @@ def test_tier1_budget_tool_jsonl(tmp_path):
     assert tb.main([str(p), "--budget", "30"]) == 1
 
 
-def test_tier1_budget_suggest_promote(tmp_path):
-    """--suggest-promote (ISSUE 17): from a full-suite durations log the
-    tool parses conftest's _T1_REMARK_SLOW table from SOURCE, projects
-    the tier-1 base without the re-marked entries, and greedily names
-    the cheapest re-marked tests that fit back under the bar."""
-    import json
-
-    tb = _tb()
-    # a stand-in conftest carrying a tiny re-mark table (the real one is
-    # parsed the same way — pinned below)
-    cft = tmp_path / "conftest.py"
-    cft.write_text(
-        "_T1_REMARK_SLOW = frozenset((\n"
-        "    'test_a.py::cheap',\n"
-        "    'test_a.py::mid',\n"
-        "    'test_a.py::'\n"
-        "    'huge',\n"          # implicit concatenation, as in the real table
-        "))\n")
-    assert tb.load_remark_table(str(cft)) == frozenset(
-        ("test_a.py::cheap", "test_a.py::mid", "test_a.py::huge"))
-    rows = [("tests/test_b.py::base1", 40.0),
-            ("tests/test_b.py::base2", 20.0),
-            ("tests/test_a.py::cheap", 4.0),
-            ("tests/test_a.py::mid", 10.0),
-            ("tests/test_a.py::huge", 300.0)]
-    p = tmp_path / "full.jsonl"
-    p.write_text("\n".join(json.dumps(
-        {"nodeid": n, "when": "call", "duration": d, "outcome": "passed"})
-        for n, d in rows) + "\n")
-    per_test, _ = tb.load(str(p))
-    out = []
-    # bar = 0.95*100 = 95; base 60 x1.0 -> headroom 35: cheap (4) and
-    # mid (10) fit, huge (300) does not
-    picks = tb.suggest_promote(per_test, budget=100.0, frac=0.95,
-                               inflate=1.0, conftest_path=str(cft),
-                               out=out.append)
-    assert [k for k, _ in picks] == ["test_a.py::cheap", "test_a.py::mid"]
-    assert any("huge" not in line and "cheap" in line for line in out)
-    # inflation shrinks the headroom: x2.0 -> headroom -25, nothing fits
-    assert tb.suggest_promote(per_test, budget=100.0, frac=0.95,
-                              inflate=2.0, conftest_path=str(cft),
-                              out=out.append) == []
-    # the REAL conftest table parses from source (no jax import) and
-    # holds the known re-marks
-    real = tb.load_remark_table()
-    assert "test_api.py::test_cv" in real
-    # advisory mode always exits 0 even though the full-suite wall is
-    # over the tier-1 bar
-    assert tb.main([str(p), "--budget", "100", "--suggest-promote",
-                    "--conftest", str(cft)]) == 0
-    assert tb.main([str(p), "--budget", "100"]) == 1
-
-
 def test_tier1_budget_tool_pytest_log(tmp_path):
     """The same tool on a tee'd pytest console log: the trailing summary
     wall and any --durations lines drive the projection."""

@@ -103,23 +103,6 @@ WATCHED: Tuple[Tuple[str, str, float], ...] = (
     # work of ROADMAP item 2 lands against a baseline
     ("compile_ms_total", "down", 0.50),
     ("hbm_peak_bytes", "down", 0.10),
-    # fused wave-round megakernel (ISSUE 13): the merged hist+split
-    # round priced over the replayed schedule gets the standard 10%
-    # clock bar; fused_ok / fused_parity_ok are booleans the guard
-    # sweep flags automatically
-    ("hist_split_fused_ms_per_iter", "down", 0.10),
-    # single-pass wave round (ISSUE 15): the routed round — partition +
-    # valid routing + top-k folded into the fused dispatch — gets the
-    # same 10% clock bar; fused_round_ok is the boolean guard the sweep
-    # flags automatically
-    ("partition_fused_ms_per_iter", "down", 0.10),
-    # persistent multi-round wave loop (ISSUE 17): the looped dispatch
-    # priced by the differential method (single-round dispatch ms minus
-    # the measured boundary saving) at the standard 10% bar — a
-    # regression here means the loop stopped paying for its resident
-    # state; fused_loop_ok / fused_loop_parity_ok are booleans the
-    # guard sweep flags automatically
-    ("phase_wave_loop_ms", "down", 0.10),
     # sub-byte bin residency (ISSUE 18): the per-round packed binned
     # read in bytes — analytic ceil(F/2) * N, so ANY upward move means
     # the packed layout stopped engaging at the bench config; packed_ok

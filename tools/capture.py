@@ -39,10 +39,6 @@ Usage::
                                              # isolation (no priors), repo
                                              # records untouched
     python tools/capture.py --out DIR        # keep artifacts in DIR
-    python tools/capture.py --flip-defaults  # rehearse ROADMAP item 1's
-                                             # default flip (fused + auto
-                                             # deep dtype): parity battery
-                                             # + required-guards gate
 
 Exit 0 only when every stage ran AND the gate passed.  Prints one JSON
 summary line last.  ``run_capture`` is the library entry (tests drive it
@@ -268,109 +264,6 @@ def run_capture(records_dir: str = ROOT, out_dir: str = None,
     return summary
 
 
-# ROADMAP item 1's endgame knobs: what the device capture flips
-# default-on once the fused guards land green.  One place, so the
-# rehearsal and the real flip can never drift apart.
-FLIP_DEFAULTS = {"hist_method": "fused", "hist_dtype_deep": "auto"}
-
-
-def run_flip_rehearsal(records_dir: str = ROOT, iters: int = 3,
-                       out=print) -> dict:
-    """``--flip-defaults``: the ROADMAP item 1 default-flip rehearsal as
-    ONE flag instead of a hand-assembled session.
-
-    Trains the parity battery — binary / multiclass / DART, plus the
-    ``wave_loop_rounds>1`` persistent-loop leg (ISSUE 17) — UNDER the
-    flipped defaults (``FLIP_DEFAULTS``: ``hist_method=fused`` +
-    ``hist_dtype_deep=auto``), each case byte-compared against its
-    staged ``hist_method=pallas`` twin at the SAME dtype policy: the
-    flip's bit contract is that fused-vs-staged stays a pure scheduling
-    change whatever the deep-dtype policy resolves to (the dtype leg
-    itself is gated by the device AUC-parity capture, not bit parity —
-    tools/precision_expt.py).  Then runs the required-guards gate
-    (``ci_gate --require-guards``) over ``records_dir``'s newest BENCH
-    record, so the flip cannot be declared rehearsed against a capture
-    whose guards are not already green.  Returns the summary dict;
-    ``ok`` is parity AND gate."""
-    import ci_gate  # noqa: E402 — sibling tool, path set above
-    import numpy as np
-
-    import lightgbmv1_tpu as lgb
-
-    rng = np.random.RandomState(11)
-    X = rng.randn(900, 7)
-    y_bin = (X[:, 0] - X[:, 1] + 0.4 * X[:, 2] > 0).astype(float)
-    y_mc = np.clip((np.abs(X[:, 0]) + X[:, 1] > 1).astype(float)
-                   + (X[:, 2] > 0.3), 0, 2)
-    battery = {
-        "binary": {"objective": "binary"},
-        "multiclass": {"objective": "multiclass", "num_class": 3},
-        "dart": {"objective": "binary", "boosting": "dart",
-                 "drop_rate": 0.5},
-        "wave_loop": {"objective": "binary", "wave_loop_rounds": 4},
-        # sub-byte residency (ISSUE 18): the packed fused run's twin is
-        # the staged UNPACKED run — the flip must hold across the layout
-        # change, not just the scheduling change
-        "packed4": {"objective": "binary", "max_bin": 15,
-                    "bin_layout": "packed4"},
-    }
-    base = {"num_leaves": 31, "max_bin": 63, "min_data_in_leaf": 5,
-            "verbosity": -1, "seed": 5, "tree_growth": "leafwise",
-            "leafwise_wave_size": 8}
-
-    def text(params, label):
-        ds = lgb.Dataset(X, label=label, params=dict(params))
-        booster = lgb.train(dict(params), ds, num_boost_round=int(iters),
-                            verbose_eval=False)
-        return booster.model_to_string()
-
-    summary = {"flip": dict(FLIP_DEFAULTS), "parity": {}, "ok": False}
-    parity_ok = True
-    for name, over in battery.items():
-        label = y_mc if name == "multiclass" else y_bin
-        flip = text({**base, **over, **FLIP_DEFAULTS}, label)
-        twin = {"hist_method": "pallas"}
-        if name == "packed4":
-            twin["bin_layout"] = "u8"
-        staged = text({**base, **over, **FLIP_DEFAULTS, **twin}, label)
-        same = bool(flip == staged)
-        summary["parity"][name] = same
-        parity_ok &= same
-        out(f"flip-defaults: {name} parity "
-            f"{'OK' if same else 'DIVERGED'}")
-
-    # serving megakernel (ISSUE 19): the fused walk+accumulate predictor
-    # over a packed-eligible model (max_bin 10 → every feature fits the
-    # 16 nibble values), packed + unpacked twins both node-exact against
-    # the HostTree oracle — so the next driver capture lands the device
-    # legs with the parity half already rehearsed
-    from lightgbmv1_tpu.models.predict import BatchPredictor
-
-    pk_params = {**base, "objective": "binary", "max_bin": 10}
-    ds_pk = lgb.Dataset(X, label=y_bin, params=dict(pk_params))
-    bst_pk = lgb.train(dict(pk_params), ds_pk, num_boost_round=int(iters),
-                       verbose_eval=False)
-    trees_pk = bst_pk._all_trees()
-    leaf_host = np.stack([t.predict_leaf_index(X) for t in trees_pk],
-                         axis=1)
-    for layout in ("packed4", "u8"):
-        bp = BatchPredictor(trees_pk, 1, X.shape[1], method="fused",
-                            code_layout=layout)
-        same = bool(bp._fused_engaged()
-                    and np.array_equal(bp.predict_leaf(X), leaf_host))
-        summary["parity"][f"predict_fused_{layout}"] = same
-        parity_ok &= same
-        out(f"flip-defaults: predict_fused_{layout} parity "
-            f"{'OK' if same else 'DIVERGED'}")
-    summary["parity_ok"] = parity_ok
-
-    gate_ok = ci_gate.check_required_guards(
-        records_dir, ci_gate.REQUIRED_GUARDS, out=out)
-    summary["guards_ok"] = bool(gate_ok)
-    summary["ok"] = bool(parity_ok and gate_ok)
-    return summary
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--records-dir", default=ROOT,
@@ -393,20 +286,11 @@ def main(argv=None) -> int:
                          "window into OUT_DIR and print its summary as "
                          "the last line (run_capture spawns this)")
     ap.add_argument("--stage-timeout-s", type=float, default=7200.0)
-    ap.add_argument("--flip-defaults", action="store_true",
-                    help="rehearse ROADMAP item 1's default flip "
-                         "(hist_method=fused + hist_dtype_deep=auto): "
-                         "parity battery under the flipped defaults + "
-                         "the required-guards gate; no records written")
     args = ap.parse_args(argv)
     if args.window:
         print(json.dumps(profiled_window(args.window,
                                          rows=args.window_rows)))
         return 0
-    if args.flip_defaults:
-        summary = run_flip_rehearsal(records_dir=args.records_dir)
-        print(json.dumps(summary, default=str))
-        return 0 if summary["ok"] else 1
     summary = run_capture(
         records_dir=args.records_dir, out_dir=args.out,
         round_no=args.round, dry_run=args.dry_run,

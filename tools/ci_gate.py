@@ -47,25 +47,13 @@ import tier1_budget  # noqa: E402
 # are added (a new *_ok lands here in the same PR that records it);
 # obs_device_ok is the device-truth telemetry guard (compile counters,
 # serving zero-retrace, HBM/ledger reconciliation — bench.py
-# measure_obs); fused_ok is the fused wave-round megakernel guard
-# (bit parity with the staged path AND, on device, the merged
-# hist+split round at or under the staged phases — bench.py
-# measure_fused / measure_fused_round_ms); drift_ok is the
-# model-quality guard (skew-injection probe detected + zero clean
+# measure_obs); drift_ok is the model-quality guard (skew-injection probe detected + zero clean
 # false alarms + streamed-vs-resident reference byte parity + armed
 # sampling within the <= 2% serving contract — bench.py measure_drift);
-# fused_round_ok is the single-pass wave-round guard (ISSUE 15: routed
-# parity with partition + valid routing + top-k folded into the fused
-# dispatch AND the binned-matrix-read-once bytes contract — >= 1.8x
-# bytes_accessed reduction vs staged partition+hist on device);
 # hier_comm_ok is the pod-scale two-level collective guard (ISSUE 16:
 # DCN histogram bytes <= flat reduce-scatter wire / num_hosts, and the
 # voting learner's DCN payload <= its top-2k analytic bound —
-# parallel/cluster.py hier_comm_table_per_round); fused_loop_ok is the
-# persistent multi-round wave-loop guard (ISSUE 17: wave_loop_rounds>1
-# model-text parity with the single-round fused path everywhere AND, on
-# device, the looped per-iteration wall at or under the single-round
-# wall it replaces — bench.py measure_fused_waveloop);
+# parallel/cluster.py hier_comm_table_per_round);
 # predict_fused_ok is the serving-megakernel guard (ISSUE 19: fused
 # walk+accumulate node/bit parity with the host oracle, zero retraces
 # within a bucket, and on device >= 1.5x the scan walk's compute rate
@@ -78,8 +66,7 @@ import tier1_budget  # noqa: E402
 # SLO-driven placement-move drill — bench.py measure_tenants)
 REQUIRED_GUARDS = ("obs_ok", "slo_ok", "forensics_ok", "chaos_ok",
                    "fleet_ok", "chaos_fleet_ok", "obs_device_ok",
-                   "fused_ok", "drift_ok", "fused_round_ok",
-                   "hier_comm_ok", "fused_loop_ok", "packed_ok",
+                   "drift_ok", "hier_comm_ok", "packed_ok",
                    "predict_fused_ok", "tenant_ok")
 
 

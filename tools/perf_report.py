@@ -209,83 +209,6 @@ def pod_comm_section(w, mc_name, mc):
     w("")
 
 
-def fused_section(w, rec):
-    """Fused wave-round megakernel (ISSUE 13 — ops/wave_fused.py,
-    bench.py measure_fused / measure_fused_round_ms): parity, the merged
-    hist+split round vs the staged phases it replaces, and the
-    compiled-executable HBM accounting.  Placeholder until the first
-    capture that carries the fields."""
-    if rec.get("fused_parity_ok") is None and rec.get("fused_ok") is None:
-        return
-    w("## Fused wave round (hist_method=fused, ops/wave_fused.py)")
-    w("")
-    w(f"Tree parity vs the staged pallas path: "
-      f"`fused_parity_ok={rec.get('fused_parity_ok')}`; throughput "
-      f"{get(rec, 'fused_M_row_trees_per_s')} M row-trees/s vs staged "
-      f"{get(rec, 'fused_staged_pallas_M_row_trees_per_s')}.")
-    w("")
-    if rec.get("hist_split_fused_ms_per_iter") is not None:
-        w(f"Merged hist+split round: "
-          f"**{get(rec, 'hist_split_fused_ms_per_iter')} ms/iter** "
-          f"(replayed schedule, staged root pass included) vs staged "
-          f"`phase_hist_ms + phase_split_ms` = "
-          f"{get(rec, 'phase_hist_ms')} + {get(rec, 'phase_split_ms')} "
-          "ms/iter.")
-        w("")
-    if rec.get("partition_fused_ms_per_iter") is not None:
-        w(f"Single-pass round (ISSUE 15 — partition + valid routing + "
-          f"top-k folded into the dispatch): "
-          f"**{get(rec, 'partition_fused_ms_per_iter')} ms/iter** "
-          f"(replayed schedule, staged root pass included) vs staged "
-          f"`phase_hist_ms + phase_split_ms + phase_partition_ms` = "
-          f"{get(rec, 'phase_hist_ms')} + {get(rec, 'phase_split_ms')} "
-          f"+ {get(rec, 'phase_partition_ms')} ms/iter.")
-        w("")
-    if rec.get("fused_loop_parity_ok") is not None:
-        w(f"Persistent multi-round wave loop (ISSUE 17 — "
-          f"`wave_loop_rounds={get(rec, 'fused_loop_rounds')}`, frontier "
-          f"state resident in VMEM across rounds): parity "
-          f"`fused_loop_parity_ok={rec.get('fused_loop_parity_ok')}`; "
-          f"{get(rec, 'wave_loop_ms_per_iter')} ms/iter looped vs "
-          f"{get(rec, 'wave_loop_single_round_ms_per_iter')} single-round "
-          f"({get(rec, 'fused_loop_launches_saved_per_segment')} launches "
-          f"and {get(rec, 'fused_loop_state_bytes_saved_per_segment_analytic')} "
-          f"state bytes saved per segment, analytic"
-          + (f"; measured boundary saving "
-             f"{get(rec, 'wave_loop_boundary_saving_ms_per_iter')} ms/iter"
-             if rec.get("wave_loop_boundary_saving_ms_per_iter")
-             is not None else "") + ").")
-        w("")
-    if rec.get("fused_hbm_bytes_saved_per_round") is not None:
-        w(f"Compiled-executable HBM accounting (cost_analysis bytes, one "
-          f"sustained-bucket round incl. the staged partition pass): "
-          f"staged {get(rec, 'staged_round_bytes_accessed')} vs fused "
-          f"{get(rec, 'fused_round_bytes_accessed')} — "
-          f"**{get(rec, 'fused_hbm_bytes_saved_per_round')} bytes/round "
-          f"saved** ({get(rec, 'fused_round_bytes_reduction', 3)}x; "
-          f"analytic scan-stack size "
-          f"{get(rec, 'fused_hbm_stack_bytes_analytic')}): the "
-          "(F, B, 3) histogram stack stays in VMEM and the binned "
-          f"matrix is read once per round (analytic binned traffic "
-          f"{get(rec, 'fused_round_binned_bytes_analytic')} vs staged "
-          f"{get(rec, 'staged_round_binned_bytes_analytic')} bytes).")
-        w("")
-    w(f"Guard `fused_ok={rec.get('fused_ok')}`: parity AND (on device) "
-      "fused round <= staged hist+split.  Guard "
-      f"`fused_round_ok={rec.get('fused_round_ok')}` (ISSUE 15): routed "
-      "parity AND the binned-read-once bytes contract (>= 1.8x "
-      "cost_analysis reduction vs staged partition+hist on device).  "
-      f"Guard `fused_loop_ok={rec.get('fused_loop_ok')}` (ISSUE 17): "
-      "loop-vs-single-round parity AND (on device) a non-negative "
-      "boundary saving.  "
-      "The staged path stays the default until a device capture lands "
-      "these guards True "
-      "(BASELINE.md \"Fused wave round\" / \"Persistent multi-round "
-      "wave loop\" — dispatch rules, fallback taxonomy, parity "
-      "contract).")
-    w("")
-
-
 def prediction_section(w, rec):
     """Prediction: the serving-engine table (native C++ / depth-stepped
     device walk / legacy scan pin) plus the component split of the device
@@ -939,45 +862,15 @@ def generate(rec, name, prev=None, prev_name=None):
     if rec.get("phase_hist_ms") is not None:
         w("## Per-phase breakdown (ms per leaf-wise iteration)")
         w("")
-        if rec.get("partition_fused_ms_per_iter") is not None:
-            # single-pass wave round (ISSUE 15): the routed round —
-            # partition + valid routing + top-k folded into the fused
-            # dispatch — next to the merged hist+split kernel and the
-            # staged phases they replace
-            w("| hist | partition | valid-route | split | other | "
-              "measured total | hist+split fused | round fused |")
-            w("|---|---|---|---|---|---|---|---|")
-            w(f"| {get(rec, 'phase_hist_ms')} | "
-              f"{get(rec, 'phase_partition_ms')} | "
-              f"{get(rec, 'phase_valid_route_ms')} | "
-              f"{get(rec, 'phase_split_ms')} | "
-              f"{get(rec, 'phase_other_ms')} | "
-              f"{get(rec, 'phase_total_measured_ms')} | "
-              f"{get(rec, 'hist_split_fused_ms_per_iter')} | "
-              f"**{get(rec, 'partition_fused_ms_per_iter')}** |")
-        elif rec.get("hist_split_fused_ms_per_iter") is not None:
-            # fused wave-round row (ISSUE 13): the merged hist+split
-            # kernel next to the staged phases it replaces
-            w("| hist | partition | valid-route | split | other | "
-              "measured total | hist+split fused |")
-            w("|---|---|---|---|---|---|---|")
-            w(f"| {get(rec, 'phase_hist_ms')} | "
-              f"{get(rec, 'phase_partition_ms')} | "
-              f"{get(rec, 'phase_valid_route_ms')} | "
-              f"{get(rec, 'phase_split_ms')} | "
-              f"{get(rec, 'phase_other_ms')} | "
-              f"{get(rec, 'phase_total_measured_ms')} | "
-              f"**{get(rec, 'hist_split_fused_ms_per_iter')}** |")
-        else:
-            w("| hist | partition | valid-route | split | other | "
-              "measured total |")
-            w("|---|---|---|---|---|---|")
-            w(f"| {get(rec, 'phase_hist_ms')} | "
-              f"{get(rec, 'phase_partition_ms')} | "
-              f"{get(rec, 'phase_valid_route_ms')} | "
-              f"{get(rec, 'phase_split_ms')} | "
-              f"{get(rec, 'phase_other_ms')} | "
-              f"{get(rec, 'phase_total_measured_ms')} |")
+        w("| hist | partition | valid-route | split | other | "
+          "measured total |")
+        w("|---|---|---|---|---|---|")
+        w(f"| {get(rec, 'phase_hist_ms')} | "
+          f"{get(rec, 'phase_partition_ms')} | "
+          f"{get(rec, 'phase_valid_route_ms')} | "
+          f"{get(rec, 'phase_split_ms')} | "
+          f"{get(rec, 'phase_other_ms')} | "
+          f"{get(rec, 'phase_total_measured_ms')} |")
         w("")
         tot = rec.get("phase_total_measured_ms") or 0
         hist = rec.get("phase_hist_ms") or 0
@@ -1111,8 +1004,6 @@ def generate(rec, name, prev=None, prev_name=None):
           "grower's trees on the multiclass smoke shape — see "
           "BASELINE.md).")
         w("")
-
-    fused_section(w, rec)
 
     prediction_section(w, rec)
 

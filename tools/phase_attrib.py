@@ -513,48 +513,24 @@ def measure_other_breakdown(*, N, F, B, L, K, rounds_per_iter,
 # Canonical per-iteration phase fields (BENCH record keys).  The single
 # source of truth for "what counts as a phase" — bench.py's phase
 # profile and the roofline join both build their {phase: ms} dicts from
-# this list, so a NEW phase (the fused wave-round kernel's single merged
-# hist+split row, ISSUE 13) lands as its own labeled row everywhere
+# this list, so a new phase lands as its own labeled row everywhere
 # instead of silently pooling into phase_other.  Order is render order.
 PHASE_MS_KEYS = (
     "phase_hist_ms",
     "phase_partition_ms",
     "phase_valid_route_ms",
     "phase_split_ms",
-    # hist_method=fused (ISSUE 15, the single-pass wave round):
-    # partition + valid routing + histogram + smaller-child subtraction
-    # + split scan + top-k are ONE labeled dispatch — one merged phase,
-    # mutually exclusive with the staged hist/partition/valid_route/
-    # split rows for the run that produced it
-    "phase_round_fused_ms",
-    # wave_loop_rounds>1 (ISSUE 17, the persistent multi-round wave
-    # loop): R consecutive rounds — frontier state resident in VMEM —
-    # are ONE labeled dispatch; mutually exclusive with BOTH the staged
-    # rows and the single-round fused row for the run that produced it
-    "phase_wave_loop_ms",
     "phase_other_ms",
 )
-
-# pre-ISSUE-15 records carried the merged fused row WITHOUT partition
-# folded in under this name; renders as the same row so old captures
-# keep their phase profile
-_LEGACY_PHASE_ALIASES = {
-    "phase_hist_split_fused_ms": "phase_round_fused_ms",
-}
 
 
 def phase_ms_from_fields(fields):
     """``{phase: ms}`` from a BENCH record's phase fields, stripping the
-    ``phase_``/``_ms`` wrapping — every positive canonical phase,
-    including the fused merged row.  Consumers (bench.py's trace phase
-    profile and the roofline join) go through here so the phase list
-    cannot drift per call site.  Legacy field names
-    (``_LEGACY_PHASE_ALIASES``) land on their canonical row."""
+    ``phase_``/``_ms`` wrapping — every positive canonical phase.
+    Consumers (bench.py's trace phase profile and the roofline join) go
+    through here so the phase list cannot drift per call site."""
     out = {}
-    fields = dict(fields or {})
-    for legacy, canon in _LEGACY_PHASE_ALIASES.items():
-        if fields.get(canon) is None and fields.get(legacy) is not None:
-            fields[canon] = fields[legacy]
+    fields = fields or {}
     for k in PHASE_MS_KEYS:
         v = fields.get(k)
         if isinstance(v, (int, float)) and v > 0:

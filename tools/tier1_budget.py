@@ -22,23 +22,12 @@ loudly BEFORE the driver's timeout starts eating tests.  The fix for a
 failing guard is the PR-6 discipline: mark the listed offenders ``slow``
 (they still run in the full suite / bench / driver captures) or shrink
 documented-arbitrary scales at constant structure.
-
-The demotion is meant to be REVERSIBLE: tests/conftest.py centralizes
-the re-marks in ``_T1_REMARK_SLOW`` precisely so a faster box can bring
-tests back by deleting entries.  ``--suggest-promote`` closes that loop:
-given a FULL-suite durations log (``LGBMV1_T1_DURATIONS=... pytest
-tests/ -q -m ''`` — a tier-1 log never executes the re-marked tests, so
-it carries no durations for them), it projects the tier-1 wall without
-the re-marked entries and greedily names the cheapest ones that fit
-back under the bar, inflation-adjusted (in-suite wall historically runs
-~15% over summed call durations; ``--inflate`` tunes the factor).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import re
 import sys
 from collections import defaultdict
@@ -122,74 +111,6 @@ def report(per_test, wall, budget=DEFAULT_BUDGET_S, frac=DEFAULT_FRAC,
     return ok
 
 
-def load_remark_table(conftest_path=None):
-    """The ``_T1_REMARK_SLOW`` entries from tests/conftest.py, parsed
-    out of the SOURCE (importing conftest would set JAX env vars and
-    drag the whole runtime into a bookkeeping tool)."""
-    import ast
-
-    if conftest_path is None:
-        conftest_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tests", "conftest.py")
-    with open(conftest_path) as fh:
-        tree = ast.parse(fh.read())
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and getattr(node.targets[0], "id", "") == "_T1_REMARK_SLOW"):
-            # the value is ``frozenset((<string literals>))`` — the call
-            # itself is not a literal, its tuple argument is
-            return frozenset(ast.literal_eval(node.value.args[0]))
-    raise ValueError(f"_T1_REMARK_SLOW not found in {conftest_path}")
-
-
-DEFAULT_INFLATE = 1.15   # conftest-measured in-suite wall over summed calls
-
-
-def suggest_promote(per_test, budget=DEFAULT_BUDGET_S, frac=DEFAULT_FRAC,
-                    inflate=DEFAULT_INFLATE, conftest_path=None, out=print):
-    """Name the ``_T1_REMARK_SLOW`` entries that fit back under the bar.
-
-    Wants a FULL-suite durations log (``-m ''``): the tier-1 base is the
-    sum over tests NOT in the re-mark table, and candidates are packed
-    cheapest-first into ``bar - inflate * base``.  Returns the list of
-    ``(nodeid, duration_s)`` picks."""
-    bar = frac * budget
-    table = load_remark_table(conftest_path)
-    durs = defaultdict(float)
-    for nodeid, d in per_test.items():
-        key = nodeid[len("tests/"):] if nodeid.startswith("tests/") else nodeid
-        durs[key] += d
-    marked = {k: durs[k] for k in table if k in durs}
-    unknown = sorted(k for k in table if k not in durs)
-    base = sum(d for k, d in durs.items() if k not in table)
-    headroom = bar - inflate * base
-    out(f"tier-1 base projection without the {len(table)} re-marked slow "
-        f"entries: {base:.1f} s (x{inflate:.2f} in-suite inflation = "
-        f"{base * inflate:.1f} s) vs bar {bar:.1f} s -> headroom "
-        f"{headroom:.1f} s")
-    picks = []
-    for k, d in sorted(marked.items(), key=lambda kv: (kv[1], kv[0])):
-        cost = d * inflate
-        if cost <= headroom:
-            picks.append((k, d))
-            headroom -= cost
-    if picks:
-        out(f"promote candidates — {len(picks)} of {len(marked)} measured "
-            "entries fit; DELETE these from tests/conftest.py "
-            "_T1_REMARK_SLOW to re-promote:")
-        for k, d in picks:
-            out(f"  {d:8.2f}s  {k}")
-    else:
-        out("no measured re-marked entry fits back under the bar")
-    if unknown:
-        out(f"{len(unknown)} re-marked entries carry no duration in this "
-            "log (a tier-1 `-m 'not slow'` run never executes them) — "
-            "measure with the full suite: LGBMV1_T1_DURATIONS=... "
-            "python -m pytest tests/ -q -m ''")
-    return picks
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("path", nargs="?", default="/tmp/_t1.log",
@@ -197,22 +118,8 @@ def main(argv=None):
     ap.add_argument("--budget", type=float, default=DEFAULT_BUDGET_S)
     ap.add_argument("--frac", type=float, default=DEFAULT_FRAC)
     ap.add_argument("--top", type=int, default=15)
-    ap.add_argument("--suggest-promote", action="store_true",
-                    help="advisory mode: from a FULL-suite durations log, "
-                         "name _T1_REMARK_SLOW entries that fit back under "
-                         "the bar (exit 0 regardless of the budget check)")
-    ap.add_argument("--inflate", type=float, default=DEFAULT_INFLATE,
-                    help="wall-over-summed-durations safety factor applied "
-                         "to the base projection and each candidate")
-    ap.add_argument("--conftest", default=None,
-                    help="override the tests/conftest.py to read the "
-                         "re-mark table from")
     args = ap.parse_args(argv)
     per_test, wall = load(args.path)
-    if args.suggest_promote:
-        suggest_promote(per_test, budget=args.budget, frac=args.frac,
-                        inflate=args.inflate, conftest_path=args.conftest)
-        return 0
     ok = report(per_test, wall, budget=args.budget, frac=args.frac,
                 top=args.top)
     return 0 if ok else 1
