@@ -229,7 +229,8 @@ class BundleArrays:
         self.num_bins = jnp.asarray(np.asarray(num_bins), jnp.int32)
 
 
-def expand_bundle_hist(hist_b, parent_sum, ba: BundleArrays, num_bins: int):
+def expand_bundle_hist(hist_b, parent_sum, ba: BundleArrays, num_bins: int,
+                       columns=None, first_column=0):
     """(BF, Bb, 3) bundle histogram -> (F, B, 3) per-original-feature view.
 
     Each feature's non-zero bins are a slice of its bundle's histogram; the
@@ -237,21 +238,35 @@ def expand_bundle_hist(hist_b, parent_sum, ba: BundleArrays, num_bins: int):
     (the analog of the reference's most-freq-bin recovery ``FixHistogram``,
     src/io/dataset.cpp:1410).  Singleton bundles are identity slices, so
     unbundled features see exactly the histograms they would without EFB.
+
+    ``columns`` (W,) expands those features only (traced ids; past the end
+    = no feature, a row nobody reads) out of a ``hist_b`` that holds the
+    bundle columns from ``first_column`` on: the slice a device keeps
+    after the data-parallel reduce-scatter.
     """
     import jax.numpy as jnp
 
+    def of(x, fill):
+        return x if columns is None else jnp.take(
+            x, columns, mode="fill", fill_value=fill)
+
     Bb = hist_b.shape[1]
     B = num_bins
-    F = ba.bundle_of.shape[0]
+    bundle_of = ba.bundle_of
+    if columns is not None:     # a padding id reads whatever column 0 holds
+        bundle_of = jnp.clip(of(bundle_of, 0) - first_column,
+                             0, hist_b.shape[0] - 1)
+    offset, zero_bin = of(ba.offset, 0), of(ba.zero_bin, 0)
+    F = bundle_of.shape[0]
     bins_iota = jnp.arange(B, dtype=jnp.int32)
-    idx = ba.offset[:, None] + bins_iota[None, :]                # (F, B)
-    v = hist_b[ba.bundle_of[:, None], jnp.clip(idx, 0, Bb - 1)]  # (F, B, 3)
-    valid = (bins_iota[None, :] < ba.num_bins[:, None]) & (idx < Bb)
+    idx = offset[:, None] + bins_iota[None, :]                   # (F, B)
+    v = hist_b[bundle_of[:, None], jnp.clip(idx, 0, Bb - 1)]     # (F, B, 3)
+    valid = (bins_iota[None, :] < of(ba.num_bins, 0)[:, None]) & (idx < Bb)
     v = jnp.where(valid[..., None], v, 0.0)
     zfix = parent_sum[None, :] - v.sum(axis=1)                   # (F, 3)
-    zb = jnp.clip(ba.zero_bin, 0, B - 1)
+    zb = jnp.clip(zero_bin, 0, B - 1)
     cur = v[jnp.arange(F), zb]                                   # (F, 3)
-    newz = jnp.where(ba.is_bundled[:, None], zfix, cur)
+    newz = jnp.where(of(ba.is_bundled, False)[:, None], zfix, cur)
     return v.at[jnp.arange(F), zb].set(newz)
 
 

@@ -165,6 +165,64 @@ class FeatureMeta(NamedTuple):
     contri: Optional[jax.Array] = None  # (F,) f32 feature_contri gain
                               # multipliers (reference FeatureMetainfo::penalty,
                               # feature_histogram.hpp:32,94,1139) or None
+    window: Optional["FeatureWindow"] = None  # set by narrow_meta only
+
+
+class FeatureWindow(NamedTuple):
+    """Marks a :class:`FeatureMeta` as some columns of a wider one (the
+    features a chip owns after the data-parallel reduce-scatter).  Its
+    arrays are traced gathers, so what the scan decides at trace time and
+    what it draws per GLOBAL feature come from the whole, kept here."""
+
+    columns: jax.Array        # (F,) int32 ids in the whole; past its end:
+                              # padding
+    whole: FeatureMeta
+
+
+def _whole(meta: FeatureMeta) -> FeatureMeta:
+    return meta if meta.window is None else meta.window.whole
+
+
+def _any_monotone(meta: FeatureMeta) -> bool:
+    return bool(np.asarray(_whole(meta).monotone_type).any())
+
+
+def _any_categorical(meta: FeatureMeta) -> bool:
+    return bool(np.asarray(_whole(meta).is_categorical).any())
+
+
+def take_columns(x: jax.Array, columns: jax.Array, fill) -> jax.Array:
+    """``x[..., columns]``, ``fill`` where an id lies past the end."""
+    return jnp.take(x, columns, axis=-1, mode="fill", fill_value=fill)
+
+
+def narrow_meta(meta: FeatureMeta, columns: jax.Array) -> FeatureMeta:
+    """``meta`` of the features ``columns`` (traced ids; past the end =
+    padding: unusable, one bin).  ``find_best_split`` on a histogram of
+    those columns ranks the candidates the whole scan ranks for them and
+    names its winner by its id in the whole."""
+    take = functools.partial(take_columns, columns=columns)
+    return FeatureMeta(
+        num_bins=take(meta.num_bins, fill=1),
+        missing_type=take(meta.missing_type, fill=MISSING_NONE),
+        nan_bin=take(meta.nan_bin, fill=-1),
+        zero_bin=take(meta.zero_bin, fill=0),
+        is_categorical=take(meta.is_categorical, fill=False),
+        usable=take(meta.usable, fill=False),
+        monotone_type=take(meta.monotone_type, fill=0),
+        contri=(None if meta.contri is None
+                else take(meta.contri, fill=1.0)),
+        window=FeatureWindow(columns, meta))
+
+
+def _feature_uniform(key, meta: FeatureMeta, lead=()) -> jax.Array:
+    """``lead + (F,)`` uniforms, one per GLOBAL feature: a window cuts its
+    columns out of the whole problem's draw, so a node's random
+    thresholds do not depend on how the features are spread over chips."""
+    u = jax.random.uniform(key, lead + (_whole(meta).num_bins.shape[0],))
+    if meta.window is None:
+        return u
+    return take_columns(u, meta.window.columns, 0.0)
 
 
 def make_feature_meta(dataset, monotone_constraints=None,
@@ -296,7 +354,7 @@ def _best_categorical(hist, parent_sum, meta, feature_mask, params,
     use_onehot = (nb <= params.max_cat_to_onehot)
     use_rand = params.extra_trees and rand_key is not None
     if use_rand:
-        ku = jax.random.uniform(jax.random.fold_in(rand_key, 7), (2, F))
+        ku = _feature_uniform(jax.random.fold_in(rand_key, 7), meta, (2,))
 
     # ---- one-vs-rest (reference :316-369) --------------------------------
     oth_g, oth_h, oth_c = total_g - g, total_h - h, total_c - c
@@ -525,7 +583,7 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
     penalty applied.  Module-level for tools/phase_attrib.py."""
     _, F, B, _ = left2.shape
     total_g, total_h, total_c = parent_sum[0], parent_sum[1], parent_sum[2]
-    use_mc = bool(np.asarray(meta.monotone_type).any())
+    use_mc = _any_monotone(meta)
     use_smooth = params.path_smooth > 0
     if constraint is None:
         constraint = jnp.asarray(NO_CONSTRAINT, jnp.float32)
@@ -575,7 +633,7 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
     if params.extra_trees and rand_key is not None:
         # extremely-randomized trees (reference USE_RAND: one random
         # threshold per feature per node, feature_histogram.hpp:919-930)
-        u = jax.random.uniform(rand_key, (F,))
+        u = _feature_uniform(rand_key, meta)
         rand_bin = (u * jnp.maximum(meta.num_bins - 1, 1)).astype(jnp.int32)
         base_valid = base_valid & (t_idx == rand_bin[:, None])
     # both directions masked and evaluated in one shot: direction 1 only
@@ -673,7 +731,7 @@ def _find_best_split(
     # objects this search runs; candidate values are bit-identical to the
     # historical per-direction evaluation (same formulas, elementwise).
     F, B, _ = hist.shape
-    use_mc = bool(np.asarray(meta.monotone_type).any())
+    use_mc = _any_monotone(meta)
     if constraint is None:
         constraint = jnp.asarray(NO_CONSTRAINT, jnp.float32)
 
@@ -687,7 +745,7 @@ def _find_best_split(
 
     # categorical candidates (compiled in only when the dataset has any —
     # meta arrays are trace-time constants via the grower closure)
-    has_cat = bool(np.asarray(meta.is_categorical).any())
+    has_cat = _any_categorical(meta)
     W = -(-B // 32)
     if has_cat:
         cgain, cfeat, cleft, cbitset = _best_categorical(
@@ -718,6 +776,11 @@ def _find_best_split(
 
     # best_gain is already relative (shift subtracted before the argmax)
     rel_gain = jnp.where(jnp.isfinite(best_gain), best_gain, NEG_INF)
+    if meta.window is not None:
+        # the winner by its id in the whole; no candidate names feature 0,
+        # as the whole scan's argmax does (a padding id never leaves)
+        feature = jnp.where(rel_gain > NEG_INF,
+                            meta.window.columns[feature], 0)
 
     return SplitResult(
         gain=rel_gain.astype(jnp.float32),

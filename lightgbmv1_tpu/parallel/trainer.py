@@ -72,7 +72,8 @@ from ..obs import xla as obs_xla
 from ..ops.histogram import (default_hist_method, hist_one_leaf, hist_wave,
                              hist_wave_quant)
 from ..ops.split import (FeatureMeta, SplitParams, SplitResult,
-                         find_best_split, leaf_gain, tie_tol)
+                         find_best_split, leaf_gain, narrow_meta,
+                         take_columns, tie_tol)
 from ..utils.log import log_fatal, log_info, log_warning
 from .cluster import (comm_table_per_round, hier_comm_table_per_round,
                       make_hier_mesh, make_mesh, publish_comm_metrics,
@@ -143,6 +144,20 @@ def _reduce_bytes(what: str, x, times: int = 1) -> None:
         "Bytes a device hands one round's cross-chip op, by what it carries",
         label_names=("what",)).labels(what=what).set_max(
             float(x.size * x.dtype.itemsize * times))
+
+
+def _scan_columns(owned: int, scanned: int) -> None:
+    """Trace time: ``dp_scan_columns{what}`` holds the histogram columns a
+    device owns after the reduce-scatter and the columns of the array its
+    split scan is handed, from the static shapes."""
+    from ..obs.metrics import default_registry
+
+    gauge = default_registry().gauge(
+        "dp_scan_columns",
+        "Histogram columns a device owns after the reduce-scatter, and "
+        "columns its split scan reads", label_names=("what",))
+    gauge.labels(what="owned").set(float(owned))
+    gauge.labels(what="scanned").set(float(scanned))
 
 
 def _psum(what: str, x, axes):
@@ -669,10 +684,12 @@ def build_trainer(
                                method=method, precision=precision,
                                interpret=pallas_interpret)
 
-    def renewing(grow, rows, is_wave, leaf_sums_fn=local_leaf_sums):
+    def renewing(grow, rows, is_wave, leaf_sums_fn=local_leaf_sums,
+                 cols=binned_np.shape[0] if bundle is not None else F):
         """``grow`` with its marked sums renewed; ``rows`` are what one
         device's passes sum, ``leaf_sums_fn`` the learner's direct sums (a
-        row-sharded learner adds its shards up inside)."""
+        row-sharded learner adds its shards up inside), ``cols`` the
+        columns of the histograms the grower keeps."""
         nonlocal renew_policy
         if (has_mono or params.path_smooth > 0 or config.num_leaves < 2
                 or config.num_leaves > renew.MAX_LEAVES):
@@ -685,7 +702,6 @@ def build_trainer(
                     later.append(deep_precision)
                 if use_int8sr:
                     later.append("int8sr")
-        cols = binned_np.shape[0] if bundle is not None else F
         renew_policy = renew.RenewPolicy(
             eps_root=renew.pass_rounding(method, precision, rows),
             eps_rest=max(renew.pass_rounding(method, p, rows)
@@ -1029,14 +1045,13 @@ def build_trainer(
             """The reference's ReduceScatter of histogram blocks
             (data_parallel_tree_learner.cpp:155-173): reduce over the
             row shards, each device KEEPING only its FH_loc-column
-            feature slice.  The slice is placed at its offset of a
-            zeros-elsewhere full-width array so every downstream shape
-            (leaf_hist state, subtraction, split scan) is unchanged; the
+            feature slice, compact as it arrives: ``(slots, FH_loc, B,
+            3)``.  The grower's histogram state, the sibling subtraction
+            and the scan (_split_sharded) all run at that width; the
             allgather the old psum implied is replaced by the SplitInfo
-            sync in _split_sharded.  ``int_domain``: quantized rounds
-            cross the wire as raw int32 (exact, order-invariant sums;
-            ops/quantize.py global scales make shard partials
-            commensurable)."""
+            sync there.  ``int_domain``: quantized rounds cross the wire
+            as raw int32 (exact, order-invariant sums; ops/quantize.py
+            global scales make shard partials commensurable)."""
             nb = h.ndim - 3                   # leading slot axes (0 or 1)
             hp = jnp.pad(h, [(0, 0)] * nb
                          + [(0, FH_pad - FH), (0, 0), (0, 0)])
@@ -1051,11 +1066,7 @@ def build_trainer(
                 sl = _psum_scatter(sl, "host", nb)
             else:
                 sl = _psum_scatter(hp, "data", nb)
-            lo = _shard_lo()
-            full = jnp.zeros(hp.shape, jnp.float32)
-            full = lax.dynamic_update_slice(
-                full, sl.astype(jnp.float32), (0,) * nb + (lo, 0, 0))
-            return full[..., :FH, :, :] if FH_pad > FH else full
+            return sl.astype(jnp.float32)
 
         def _shard_lo():
             """First histogram column this device owns after the
@@ -1067,32 +1078,42 @@ def build_trainer(
                         + lax.axis_index("host") * FH_loc)
             return lax.axis_index("data") * FH_loc
 
-        if bundle is not None:
-            _shard_col = bundle.bundle_of            # (F,) hist column
-        else:
-            _shard_col = jnp.arange(F, dtype=jnp.int32)
+        # the features each column slice owns, ascending, padded with F
+        # (no feature) to the largest count: a plain range of FH_loc ids
+        # but under EFB, where a slice owns the features of its bundles
+        col_of = (np.asarray(bundle.bundle_of) if bundle is not None
+                  else np.arange(F))
+        owned = [np.flatnonzero(col_of // FH_loc == s) for s in range(ndev)]
+        own_tbl = np.full((ndev, max(map(len, owned))), F, np.int32)
+        for s, ids in enumerate(owned):
+            own_tbl[s, :len(ids)] = ids
+        own_tbl = jnp.asarray(own_tbl)
 
         def _split_sharded(hist, parent, mask, key, uid, constraint, depth,
                            parent_output, cegb_pen=None, hist_scale=None):
-            """Local best split over this shard's feature slice + the
-            SplitInfo sync — FindBestSplitsFromHistograms restricted to
-            OWN features, as the reference data-parallel learner does
-            after its ReduceScatter (data_parallel_tree_learner.cpp:
-            175-199)."""
+            """Best split over the ``(FH_loc, B, 3)`` slice this device
+            kept, then the SplitInfo sync —
+            FindBestSplitsFromHistograms restricted to OWN features, as
+            the reference data-parallel learner does after its
+            ReduceScatter (data_parallel_tree_learner.cpp:175-199).  The
+            per-feature inputs are cut to the owned ids (ops/split.py
+            narrow_meta) and the winner comes back under its global id."""
             lo = _shard_lo()
-            in_shard = (_shard_col >= lo) & (_shard_col < lo + FH_loc)
+            own = own_tbl[lo // FH_loc]
             if bundle is not None:
                 from ..io.bundle import expand_bundle_hist
 
-                # zeroed out-of-shard bundle columns expand to garbage
-                # zero-bin fixes — masked out by in_shard below
-                hist = expand_bundle_hist(hist, parent, bundle, B)
+                hist = expand_bundle_hist(hist, parent, bundle, B,
+                                          columns=own, first_column=lo)
+            _scan_columns(owned=FH_loc, scanned=hist.shape[0])
             rk = jax.random.fold_in(key, uid + 1_000_003 + params.extra_seed) \
                 if params.extra_trees else None
-            local = find_best_split(hist, parent, meta, mask & in_shard,
-                                    params, constraint, depth,
-                                    config.monotone_penalty, parent_output,
-                                    rk, cegb_pen, hist_scale=hist_scale)
+            local = find_best_split(
+                hist, parent, narrow_meta(meta, own),
+                take_columns(mask, own, False), params, constraint, depth,
+                config.monotone_penalty, parent_output, rk,
+                None if cegb_pen is None else take_columns(cegb_pen, own, 0.0),
+                hist_scale=hist_scale)
             return _sync_best_split(local, parent, params, row_axes,
                                     round_children)
 
@@ -1161,15 +1182,18 @@ def build_trainer(
                                     split_fn=split_dp,
                                     bins_of_fn=bins_feat_fn, **wave_common)
         else:
+            # the pool holds what hist_fn returns: the kept slice
+            pool = dict(lw_pool, num_features=FH_loc) if use_rs else lw_pool
             grow = make_leafwise_grower(hist_fn=hist_fn, sums_fn=sums_fn,
                                         split_fn=split_dp,
                                         bins_of_fn=bins_feat_fn,
                                         forced_splits=forced,
-                                        **lw_pool, **common)
+                                        **pool, **common)
         grow = renewing(
             grow, N_pad // ndev, wave_grows,
             lambda lid, g3: _psum("renew", local_leaf_sums(lid, g3),
-                                  row_axes))
+                                  row_axes),
+            cols=FH_loc if use_rs else FH)
         sharded = jax.shard_map(
             grow,
             mesh=mesh,

@@ -291,6 +291,141 @@ def test_reduce_scatter_feature_count_not_divisible():
         atol=1e-5)
 
 
+def _owned_slice_problem(case):
+    """13 columns (not a multiple of 2, 4 or 8): a categorical one, one
+    with NaNs, one half zeros; ``efb`` appends 24 one-hot-ish columns that
+    bundle, so a chip's owned FEATURES are those of its bundle columns."""
+    rng = np.random.RandomState(11)
+    n = 1200
+    X = rng.randn(n, 13)
+    X[:, 2] = rng.randint(0, 6, n)
+    X[rng.rand(n) < 0.2, 3] = np.nan
+    X[rng.rand(n) < 0.5, 4] = 0.0
+    logit = (X[:, 0] + 0.6 * X[:, 1] * (X[:, 2] % 2) + X[:, 4]
+             + 0.8 * np.nan_to_num(X[:, 3]) - 0.7 * np.isnan(X[:, 3]))
+    cat = 2
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 63,
+              "feature_fraction_bynode": 0.7, "seed": 5}
+    if case == "zero_as_missing":
+        params["zero_as_missing"] = True
+    elif case == "extra_trees":
+        params["extra_trees"] = True
+    elif case == "efb":
+        which = rng.randint(0, 4, (6, n))
+        hot = np.zeros((n, 24))
+        for b in range(6):
+            hot[np.arange(n), 4 * b + which[b]] = rng.rand(n) + 0.5
+            logit += 0.5 * (which[b] - 1.5) * (b % 3 - 1)
+        X = np.concatenate([hot, X], axis=1)
+        cat += 24
+    y = (logit + 0.4 * rng.randn(n) > 0).astype(np.float64)
+
+    def train(over):
+        cfg = Config.from_dict({"verbosity": -1, "min_data_in_leaf": 5,
+                                **params, **over})
+        g = create_boosting(cfg, BinnedDataset.from_numpy(
+            X, label=y, config=cfg, categorical_features=[cat]))
+        for _ in range(3):
+            g.train_one_iter(check_stop=False)
+        return g
+
+    return train
+
+
+def _split_signature(g):
+    return [(t.num_leaves, tuple(t.split_feature), tuple(t.threshold_bin),
+             tuple(t.default_left)) for t in g.materialize_host_trees()]
+
+
+_OWNED_SERIAL = {}
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+@pytest.mark.parametrize("case", ["nan_missing", "zero_as_missing",
+                                  "extra_trees", "efb"])
+def test_owned_slice_scan_grows_the_serial_trees(case, shards):
+    """After the reduce-scatter a chip scans only the columns it kept
+    (ops/split.py narrow_meta): categorical, NaN- and zero-missing
+    columns, per-node feature sampling, a feature count the shards do not
+    divide, one extra_trees draw per GLOBAL feature, and EFB (owned
+    features = those of the owned bundle columns, a count that differs by
+    chip) grow the trees of the full-width scans, node for node."""
+    train = _owned_slice_problem(case)
+    if case not in _OWNED_SERIAL:
+        g = train({})
+        meta = g.meta
+        assert bool(np.asarray(meta.is_categorical).any())
+        kinds = set(np.asarray(meta.missing_type).tolist())
+        assert (1 if case == "zero_as_missing" else 2) in kinds, kinds
+        assert (g._bundle is not None) == (case == "efb")
+        sig = _split_signature(g)
+        assert all(s[0] > 4 for s in sig)
+        _OWNED_SERIAL[case] = sig
+    serial = _OWNED_SERIAL[case]
+    data = {"tree_learner": "data", "num_shards": shards}
+    assert _split_signature(train(data)) == serial
+    assert _split_signature(train(
+        {**data, "data_parallel_collective": "allreduce"})) == serial
+
+
+def _lowered_grow_data(features, shards, leaves=255):
+    rng = np.random.RandomState(0)
+    n = 2048
+    X = rng.randn(n, features)
+    cfg = Config.from_dict({
+        "objective": "binary", "verbosity": -1, "num_leaves": leaves,
+        "max_bin": 63, "tree_learner": "data", "num_shards": shards})
+    ds = BinnedDataset.from_numpy(X, label=(X[:, 0] > 0).astype(np.float64),
+                                  config=cfg)
+    gb = create_boosting(cfg, ds)
+    return gb._grow.lower(
+        gb._grow_binned, jnp.zeros((n, 3), jnp.float32),
+        jnp.ones(features, bool), jax.random.PRNGKey(0),
+        jnp.zeros(features, bool)).as_text()
+
+
+def _scan_columns_gauge():
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    snap = default_registry().snapshot()
+    return tuple(int(snap['dp_scan_columns{what="%s"}' % w])
+                 for w in ("owned", "scanned"))
+
+
+def test_no_full_width_histogram_after_the_reduce_scatter():
+    """grow.data at the four-chip cell's shapes (4 shards, 67 features, 64
+    bins): the exchange is the parent's (63 x 68 x 64 x 3 f32 in, 17
+    columns out) and nothing behind it is 67 or 68 columns wide: the
+    loop's histogram state is (255, 17, 64, 3), and the only ops that
+    touch a full-width histogram build it, pad it and hand it over."""
+    import re
+
+    txt = _lowered_grow_data(67, 4)
+    full = [ln for ln in txt.split("\n")
+            if re.search(r"x6[78]x64x3xf32>", ln)]
+    exchanged = [ln for ln in full if "x17x64x3xf32>" in ln]
+    assert exchanged and all(re.search(
+        r"\(tensor<(\d+)x68x64x3xf32>\) -> tensor<\1x17x64x3xf32>", ln)
+        for ln in exchanged), exchanged
+    assert txt.count('"stablehlo.reduce_scatter"') == len(exchanged)
+    assert any("tensor<63x68x64x3xf32>" in ln for ln in exchanged)
+    behind = ("stablehlo.while", "dynamic_update_slice", "dynamic_slice",
+              "stablehlo.subtract", "stablehlo.select", "stablehlo.gather",
+              "stablehlo.concatenate", "@cumsum", "reduce_window")
+    assert not [ln for ln in full if any(op in ln for op in behind)]
+    carried = [re.findall(r"tensor<([0-9x]+)x64x3xf32>", ln)
+               for ln in txt.split("\n") if "stablehlo.while" in ln]
+    assert ["255x17", "126x17"] in carried, carried
+    assert _scan_columns_gauge() == (17, 17)
+
+
+def test_scan_columns_gauge_when_shards_outnumber_columns():
+    """11 features on 8 shards: 2 columns a chip, the last two chips
+    padding only; the scan reads what the chip owns."""
+    _lowered_grow_data(11, 8, leaves=15)
+    assert _scan_columns_gauge() == (2, 2)
+
+
 # tier-1 wall budget (tools/tier1_budget.py): slow-marked — still run by the full
 # suite and driver captures
 @pytest.mark.slow
@@ -366,6 +501,9 @@ def test_int8sr_collective_moves_int32(monkeypatch):
         dtypes.update(re.findall(r"tensor<[0-9x]*([a-z][0-9]+)>",
                                  txt[m.start():m.start() + 400]))
     assert "i32" in dtypes, dtypes
+    # the exchange itself is untouched: 6 features padded to the 8 shards
+    assert re.search(r"\(tensor<\d+x8x\d+x3xi32>\) -> "
+                     r"tensor<\d+x1x\d+x3xi32>", txt)
 
 
 @pytest.mark.slow

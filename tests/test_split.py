@@ -227,3 +227,70 @@ def test_genuinely_distinct_gains_not_tied():
     res = find_best_split(jnp.asarray(hist), jnp.asarray(parent), meta,
                           jnp.ones(2, bool), params)
     assert int(res.feature) == 1
+
+
+def _window_problem(seed):
+    """11 features x 16 bins: NaN- and zero-missing columns, a categorical
+    one, an unusable one, a monotone pair, a contri penalty."""
+    from lightgbmv1_tpu.io.binning import MISSING_ZERO
+
+    rs = np.random.RandomState(seed)
+    F, B = 11, 16
+    nb = rs.randint(4, B + 1, F)
+    missing = [MISSING_NONE] * F
+    missing[3], missing[4], missing[9] = MISSING_NAN, MISSING_ZERO, MISSING_NAN
+    cnt = rs.randint(0, 40, (F, B)).astype(np.float64)
+    cnt *= np.arange(B)[None, :] < nb[:, None]
+    total = 400.0
+    cnt = np.round(cnt / cnt.sum(axis=1, keepdims=True) * total)
+    cnt[:, 0] += total - cnt.sum(axis=1)
+    hist = np.stack([rs.randn(F, B) * cnt, cnt * 0.25, cnt], axis=-1)
+    parent = hist[0].sum(axis=0)
+    hist[:, 0, 0] += parent[0] - hist[:, :, 0].sum(axis=1)
+    meta = make_meta(nb.tolist(), missing)._replace(
+        zero_bin=jnp.asarray(rs.randint(0, 3, F), jnp.int32),
+        is_categorical=jnp.zeros(F, bool).at[2].set(True),
+        usable=jnp.ones(F, bool).at[7].set(False),
+        monotone_type=jnp.zeros(F, jnp.int32).at[0].set(1).at[5].set(-1),
+        contri=jnp.asarray(rs.uniform(0.5, 1.0, F), jnp.float32))
+    mask = jnp.asarray(rs.rand(F) < 0.8)
+    return (jnp.asarray(hist, jnp.float32), jnp.asarray(parent, jnp.float32),
+            meta, mask)
+
+
+@pytest.mark.parametrize("extra_trees", [False, True])
+@pytest.mark.parametrize("lo,width", [(0, 3), (3, 3), (6, 3), (9, 3),
+                                      (12, 3), (0, 6), (6, 6), (0, 11)])
+def test_window_scan_is_the_whole_scan_of_its_columns(lo, width, extra_trees):
+    """``narrow_meta``: the scan of a window's columns elects what the
+    whole scan elects with every other column masked out: same gain,
+    same global feature id, threshold, direction, sums and bitset; ids
+    past the end are padding that never wins; one extra_trees draw per
+    global feature."""
+    import jax
+
+    from lightgbmv1_tpu.ops.split import narrow_meta, take_columns
+
+    hist, parent, meta, mask = _window_problem(lo + width)
+    F = hist.shape[0]
+    params = SplitParams(min_data_in_leaf=5.0, extra_trees=extra_trees,
+                         max_cat_to_onehot=2, min_data_per_group=5.0,
+                         cat_smooth=1.0)
+    key = jax.random.PRNGKey(3) if extra_trees else None
+    bounds = jnp.asarray([-0.8, 0.9], jnp.float32)
+    pen = jnp.linspace(0.0, 0.02, F)
+    cols = jnp.arange(lo, lo + width, dtype=jnp.int32)
+    inside = (jnp.arange(F) >= lo) & (jnp.arange(F) < lo + width)
+    whole = jax.jit(lambda m: find_best_split(
+        hist, parent, meta, m, params, bounds, 2, 0.5, 0.1, key, pen))(
+            mask & inside)
+    rows = jnp.take(hist, jnp.clip(cols, 0, F - 1), axis=0) \
+        * (cols < F)[:, None, None]          # padding columns arrive zero
+    part = jax.jit(lambda c: find_best_split(
+        rows, parent, narrow_meta(meta, c), take_columns(mask, c, False),
+        params, bounds, 2, 0.5, 0.1, key, take_columns(pen, c, 0.0)))(cols)
+    if lo >= F:         # nothing but padding: no candidate, feature 0
+        assert not np.isfinite(float(part.gain)) and int(part.feature) == 0
+        whole, part = whole[:2], part[:2]
+    for a, b in zip(whole, part):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
