@@ -15,6 +15,9 @@ Legs, in order (every leg runs unguarded — a failure is a traceback and a
 non-zero exit; the only ``except`` is the one that DEFINES the opt-in leg,
 where "the kernel raised" is one of the two passing outcomes):
 
+  wide       one 255-leaf tree at 2,000 columns x 4,096 rows: the split
+             scan's widest compiled shape (126 children x 2,000 x 64), first,
+             so that a scan the chip's compiler refuses shows in seconds
   train      lightgbmv1_tpu.train, 20 iterations, 100k held-out rows
   hist       hist_leaves_pallas vs numpy.bincount at the train shape
   predict    Booster.predict depthwise (device) vs host
@@ -141,10 +144,38 @@ class Smoke:
         self.out[leg] = {k: v for k, v in fields.items()
                          if k not in ("leg", "platform")}
 
+    # -- the wide shape -----------------------------------------------------
+    def wide(self):
+        """One tree over 2,000 dense columns (the width of
+        ``epsilon-2000f-63b``): a round's scan covers 126 children x 2,000
+        columns x 64 bins, which the channel-minor scan could not compile
+        for the chip at all (PERF.md, PR 32)."""
+        from lightgbmv1_tpu.obs.metrics import default_registry
+
+        columns, rows = 2_000, 512 if self.rehearse else 4_096
+        rng = np.random.RandomState(7)
+        X = rng.randn(rows, columns).astype(np.float32)
+        y = (X[:, 0] - X[:, 1_999] + 0.5 * X[:, 1_000]
+             + 0.3 * rng.randn(rows) > 0).astype(np.float64)
+        params = {**PARAMS, "min_data_in_leaf": 1}
+        t = time.perf_counter()
+        booster = lgb.train(
+            params, lgb.Dataset(X, label=y, params=dict(params)),
+            num_boost_round=1, verbose_eval=False)
+        leaves = int(booster._all_trees()[0].num_leaves)
+        assert leaves > 8, f"the wide tree grew {leaves} leaves"
+        scanned = default_registry().get("split_scan_columns").labels(
+            what="scanned").get()
+        assert scanned == columns, scanned
+        self.say("wide", rows=rows, columns=columns, leaves=leaves,
+                 scan_columns=int(scanned),
+                 tree_s_with_compile=round(time.perf_counter() - t, 2))
+
     # -- train ------------------------------------------------------------
     def train(self):
         self.X, self.y = make_data(self.n, 0)
         self.Xv, self.yv = make_data(self.n_valid, 1)
+        compiled_before_ms = obs_xla.compile_ms_total()   # the wide leg's
         t = time.perf_counter()
         self.dtrain = lgb.Dataset(self.X, label=self.y,
                                   params=dict(PARAMS)).construct()
@@ -187,7 +218,8 @@ class Smoke:
                  first_iter_s=round(float(per_iter[0]), 2),
                  iter_s_after_warmup_smoke_figure=round(
                      float(np.median(per_iter[2:])), 4),
-                 compile_s=round(obs_xla.compile_ms_total() / 1e3, 2),
+                 compile_s=round((obs_xla.compile_ms_total()
+                                  - compiled_before_ms) / 1e3, 2),
                  auc_device=auc_dev, auc_host_oracle=auc_host,
                  auc_delta=abs(auc_host - auc_dev),
                  compile_labels=sorted(stats), fallbacks=0)
@@ -510,8 +542,8 @@ def main(argv=None) -> int:
         return 1
     print(json.dumps({"leg": "device", **smoke.device}), flush=True)
 
-    for leg in (smoke.train, smoke.hist, smoke.predict, smoke.serve,
-                smoke.variants, smoke.optin, smoke.multichip):
+    for leg in (smoke.wide, smoke.train, smoke.hist, smoke.predict,
+                smoke.serve, smoke.variants, smoke.optin, smoke.multichip):
         leg()
 
     tr = smoke.out["train"]

@@ -25,6 +25,7 @@ bin), categorical binning by descending frequency, trivial-feature detection.
 
 from __future__ import annotations
 
+import bisect
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
@@ -40,6 +41,18 @@ MISSING_NAN = 2
 
 BIN_NUMERICAL = 0
 BIN_CATEGORICAL = 1
+
+
+def _count_column(path: str) -> None:
+    """``find_bin_columns_total{path}``: columns whose boundaries were
+    found by bisection on the running counts (``array``) and by a walk in
+    the interpreter (``loop``: categorical columns and forced bounds)."""
+    from ..obs.metrics import default_registry
+
+    default_registry().counter(
+        "find_bin_columns_total",
+        "Columns binned, by the path that found their boundaries",
+        label_names=("path",)).labels(path=path).inc()
 
 
 def _need_filter(cnt_in_bin: np.ndarray, total_cnt: int, filter_cnt: int,
@@ -108,11 +121,10 @@ def _greedy_find_bin(
         max_bin = max(1, min(max_bin, int(total_cnt) // min_data_in_bin))
     mean_bin_size = total_cnt / max_bin
 
-    rest_bin_cnt = max_bin
-    rest_sample_cnt = int(total_cnt)
-    is_big = np.asarray(counts, np.int64) >= mean_bin_size
-    rest_bin_cnt -= int(is_big.sum())
-    rest_sample_cnt -= int(counts[is_big].sum())
+    counts = np.asarray(counts, np.int64)
+    is_big = counts >= mean_bin_size
+    rest_bin_cnt = max_bin - int(is_big.sum())
+    rest_sample_cnt = int(total_cnt) - int(counts[is_big].sum())
 
     def _mean(cnt, bins):
         if bins != 0:
@@ -120,28 +132,46 @@ def _greedy_find_bin(
         return math.inf if cnt > 0 else math.nan
 
     mean_bin_size = _mean(rest_sample_cnt, rest_bin_cnt)
-    upper = [math.inf] * max_bin
-    lower = [math.inf] * max_bin
-    bin_cnt = 0
-    lower[0] = float(distinct_values[0])
-    cur = 0
-    for i in range(nd - 1):
-        if not is_big[i]:
-            rest_sample_cnt -= int(counts[i])
-        cur += int(counts[i])
-        if (is_big[i] or cur >= mean_bin_size
-                or (is_big[i + 1] and cur >= max(1.0, mean_bin_size * 0.5))):
-            upper[bin_cnt] = float(distinct_values[i])
-            bin_cnt += 1
-            lower[bin_cnt] = float(distinct_values[i + 1])
-            if bin_cnt >= max_bin - 1:
-                break
-            cur = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = _mean(rest_sample_cnt, rest_bin_cnt)
-    bin_cnt += 1
-    for i in range(bin_cnt - 1):
+    # The reference walks the distinct values one by one, closing a bin at
+    # value i when it is big, when the bin holds ``mean_bin_size`` samples,
+    # or when the next value is big and the bin is half full.  Between two
+    # closes nothing it compares against changes, so each close is found
+    # by bisection on the running counts: at most ``max_bin`` steps a
+    # column, not one step a distinct value.  (``bisect``, not
+    # ``searchsorted``: a lookup of one number that gives the GIL up and
+    # takes it back stalls every other column's thread.)
+    cum = np.cumsum(counts)                           # samples through i
+    cum_small = np.cumsum(np.where(is_big, 0, counts))
+    big_at = np.flatnonzero(is_big).tolist()
+    last = nd - 2                                     # the walk's last i
+    upper: List[float] = []
+    lower = [float(distinct_values[0])]
+    start, base = 0, 0
+    while start <= last:
+        close = nd
+        k = bisect.bisect_left(big_at, start)
+        if k < len(big_at):
+            close = big_at[k]                         # a big value closes
+            # ... and so does the value before it, on a half-full bin
+            # (Python's max(1.0, nan) is 1.0, as the walk's was)
+            if close > start and cum[close - 1] - base >= max(
+                    1.0, mean_bin_size * 0.5):
+                close -= 1
+        if not (math.isinf(mean_bin_size) or math.isnan(mean_bin_size)):
+            close = min(close, bisect.bisect_left(
+                cum, base + math.ceil(mean_bin_size), lo=start))
+        if close > last:
+            break
+        upper.append(float(distinct_values[close]))
+        lower.append(float(distinct_values[close + 1]))
+        if len(upper) >= max_bin - 1:
+            break
+        if not is_big[close]:
+            rest_bin_cnt -= 1
+            mean_bin_size = _mean(rest_sample_cnt - int(cum_small[close]),
+                                  rest_bin_cnt)
+        start, base = close + 1, int(cum[close])
+    for i in range(len(upper)):
         val = _upper_bound_1ulp((upper[i] + lower[i + 1]) / 2.0)
         if not bounds or not _eq_ordered(bounds[-1], val):
             bounds.append(val)
@@ -212,29 +242,26 @@ def _distinct_with_zero(values_sorted: np.ndarray, zero_cnt: int):
     new_grp = np.empty(n, bool)
     new_grp[0] = True
     new_grp[1:] = v[1:] > np.nextafter(v[:-1], np.inf)
-    gid = np.cumsum(new_grp) - 1
-    counts = np.bincount(gid).astype(np.int64)
-    ends = np.cumsum(counts) - 1
-    distinct = v[ends]                 # reference keeps the LARGE value
-    starts = ends - counts + 1
-
-    out_v: List[float] = []
-    out_c: List[int] = []
-    if v[0] > 0.0 and zero_cnt > 0:
-        out_v.append(0.0)
-        out_c.append(zero_cnt)
-    for g in range(len(distinct)):
-        if g > 0 and v[starts[g] - 1] < 0.0 and v[starts[g]] > 0.0:
-            # sign change between consecutive sample values: splice zero
-            # (reference pushes it with zero_cnt even when that is 0)
-            out_v.append(0.0)
-            out_c.append(zero_cnt)
-        out_v.append(float(distinct[g]))
-        out_c.append(int(counts[g]))
-    if v[-1] < 0.0 and zero_cnt > 0:
-        out_v.append(0.0)
-        out_c.append(zero_cnt)
-    return np.asarray(out_v, np.float64), np.asarray(out_c, np.int64)
+    starts = np.flatnonzero(new_grp)
+    counts = np.diff(np.append(starts, n)).astype(np.int64)
+    distinct = np.asarray(v[starts + counts - 1], np.float64)  # reference
+                                                     # keeps the LARGE value
+    # where zero sorts: before all, after all, or at the one sign change
+    # between consecutive sample values (there the reference pushes it
+    # with zero_cnt even when that is 0)
+    at = None
+    if v[0] > 0.0:
+        at = 0 if zero_cnt > 0 else None
+    elif v[-1] < 0.0:
+        at = len(distinct) if zero_cnt > 0 else None
+    else:
+        flip = np.flatnonzero((v[starts[1:] - 1] < 0.0)
+                              & (v[starts[1:]] > 0.0))
+        at = int(flip[0]) + 1 if len(flip) else None
+    if at is not None:
+        distinct = np.insert(distinct, at, 0.0)
+        counts = np.insert(counts, at, zero_cnt)
+    return distinct, counts
 
 
 def _find_bin_with_predefined(
@@ -415,6 +442,8 @@ class BinMapper:
         """
         m = cls()
         m.bin_type = bin_type
+        _count_column("loop" if bin_type == BIN_CATEGORICAL or forced_bounds
+                      else "array")
         vals = np.asarray(sample_values, dtype=np.float64)
         na_cnt = int(np.isnan(vals).sum())
         vals = vals[~np.isnan(vals)]

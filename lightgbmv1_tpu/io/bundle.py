@@ -82,9 +82,10 @@ def find_bundles(
     num_bins = np.asarray(num_bins, dtype=np.int64)
     budget = int(max_conflict_rate * S)
 
-    order = np.argsort(-nonzero_masks.sum(axis=1, dtype=np.int64),
-                       kind="stable")
+    nnz = nonzero_masks.sum(axis=1, dtype=np.int64)
+    order = np.argsort(-nnz, kind="stable")
     group_masks: List[np.ndarray] = []       # aggregated nonzero per bundle
+    group_nnz: List[int] = []                # rows set in that mask
     group_conflicts: List[int] = []          # conflicts spent per bundle
     group_bins: List[int] = []               # bins used (incl. shared bin 0)
     group_members: List[List[int]] = []
@@ -109,9 +110,14 @@ def find_bundles(
             # the bundle and the candidate are non-zero
             if group_bins[g] + nb > max_bundle_bins:
                 continue
+            # two sets of a and b of the S rows share at least a + b - S:
+            # dense columns are told apart without looking at their rows
+            if group_conflicts[g] + group_nnz[g] + int(nnz[f]) - S > budget:
+                continue
             cnt = int(np.count_nonzero(group_masks[g] & fm))
             if group_conflicts[g] + cnt <= budget:
                 group_masks[g] |= fm
+                group_nnz[g] += int(nnz[f]) - cnt
                 group_conflicts[g] += cnt
                 group_bins[g] += nb
                 group_members[g].append(int(f))
@@ -119,6 +125,7 @@ def find_bundles(
                 break
         if not placed:
             group_masks.append(fm.copy())
+            group_nnz.append(int(nnz[f]))
             group_conflicts.append(0)
             # +1: bundle bin 0 is the shared all-zero slot
             group_bins.append(1 + nb)

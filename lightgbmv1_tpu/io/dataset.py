@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import contextlib
 import math
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -41,6 +43,42 @@ from .binning import (
 
 def _next_pow2(x: int) -> int:
     return 1 << max(0, (x - 1)).bit_length()
+
+
+# Columns are found and binned one by one in NumPy calls that release the
+# GIL (sort, searchsorted, the dtype copies), so a pool of threads takes
+# them side by side.  Below this many rows a column the interpreter's share
+# of a column (the walk over at most ``max_bin`` boundaries) is the larger
+# one, and threads only queue for the GIL.
+_POOL_MIN_ROWS = 1 << 15
+_POOL_THREADS = 8
+
+
+def _map_columns(fn, columns: int, rows: int) -> list:
+    """``[fn(j) for j in range(columns)]``, in threads where a column
+    (``rows`` values) is long enough for a pool to pay."""
+    workers = min(_POOL_THREADS, os.cpu_count() or 1, columns)
+    if rows < _POOL_MIN_ROWS or workers < 2:
+        return [fn(j) for j in range(columns)]
+    with ThreadPoolExecutor(max_workers=workers,
+                            thread_name_prefix="bin") as pool:
+        return list(pool.map(fn, range(columns)))
+
+
+def _apply_bins(mappers, X: np.ndarray, dtype=None) -> np.ndarray:
+    """The (features, rows) bins of ``X`` under ``mappers``; ``dtype``
+    None: uint8 where every mapper's bins fit."""
+    if dtype is None:
+        max_nb = max(m.num_bin for m in mappers) if mappers else 2
+        dtype = np.uint8 if max_nb <= 256 else np.int16
+    num_data = X.shape[0]
+    binned = np.empty((len(mappers), num_data), dtype=dtype)
+
+    def apply(j):
+        binned[j] = mappers[j].value_to_bin(X[:, j])
+
+    _map_columns(apply, len(mappers), num_data)
+    return binned
 
 
 @contextlib.contextmanager
@@ -220,7 +258,15 @@ class BinnedDataset:
                 max_bins = list(config.max_bin_by_feature) or [config.max_bin] * num_features
                 if len(max_bins) != num_features:
                     log_fatal("max_bin_by_feature length must equal number of features")
-                samples = [np.asarray(X[sample_idx, j], dtype=np.float64) for j in range(num_features)]
+
+                def sample_of(j):
+                    return np.asarray(X[sample_idx, j], dtype=np.float64)
+
+                # a finder that syncs mappers takes every column's sample
+                # at once; otherwise a column's is drawn where it is
+                # binned, and 8 B x sample x columns are never held
+                samples = ([sample_of(j) for j in range(num_features)]
+                           if bin_finder is not None else None)
             with construct_phase("find_bins"):
                 if bin_finder is not None:
                     mappers = bin_finder(samples, sample_cnt, max_bins, categorical,
@@ -230,9 +276,10 @@ class BinnedDataset:
 
                     forced = get_forced_bins(config.forcedbins_filename,
                                              num_features, categorical)
-                    mappers = [
-                        BinMapper.find_bin(
-                            samples[j],
+
+                    def find(j):
+                        return BinMapper.find_bin(
+                            sample_of(j),
                             total_sample_cnt=sample_cnt,
                             max_bin=max_bins[j],
                             min_data_in_bin=config.min_data_in_bin,
@@ -244,15 +291,11 @@ class BinnedDataset:
                             filter_cnt=int(config.min_data_in_leaf * sample_cnt
                                            / max(num_data, 1)),
                         )
-                        for j in range(num_features)
-                    ]
+
+                    mappers = _map_columns(find, num_features, sample_cnt)
 
         with construct_phase("apply_bins"):
-            max_nb = max(m.num_bin for m in mappers) if mappers else 2
-            dtype = np.uint8 if max_nb <= 256 else np.int16
-            binned = np.empty((num_features, num_data), dtype=dtype)
-            for j, m in enumerate(mappers):
-                binned[j] = m.value_to_bin(X[:, j]).astype(dtype)
+            binned = _apply_bins(mappers, X)
 
         meta = Metadata()
         if label is not None:
@@ -642,13 +685,10 @@ class BinnedDataset:
     # ------------------------------------------------------------------
     def bin_raw_features(self, X: np.ndarray) -> np.ndarray:
         """Bin new raw data with this dataset's mappers → (F, N) bins."""
-        X = np.asarray(X)
-        dtype = (self.binned.dtype if self.binned is not None
-                 else (np.uint8 if self.num_total_bin <= 256 else np.int16))
-        out = np.empty((self.num_features, X.shape[0]), dtype=dtype)
-        for j, m in enumerate(self.bin_mappers):
-            out[j] = m.value_to_bin(X[:, j]).astype(dtype)
-        return out
+        return _apply_bins(
+            self.bin_mappers, np.asarray(X),
+            self.binned.dtype if self.binned is not None
+            else (np.uint8 if self.num_total_bin <= 256 else np.int16))
 
     def feature_infos(self) -> List[str]:
         return [m.feature_info_str() for m in self.bin_mappers]

@@ -124,6 +124,14 @@ _BUCKET_MIN_N = 1 << 16
 # collective only exists there, tests/test_parallel.py).
 _SUB_STATE_CAP_BYTES = 512 * (1 << 20)
 
+# ``jax.named_scope`` of what reads or writes that state, always inside an
+# ``lgbm.select`` block: the state's first fill, a round's parent read
+# (value-forwarded or not), the sibling subtraction, the scatter of the
+# children's histograms (drained a round late when pipelined), and the pass
+# result's padding to the width the subtraction takes.  Its traffic grows
+# with the columns; the rest of ``lgbm.select`` does not.
+POOL_SCOPE = "lgbm.pool"
+
 
 def replay_wave_schedule(trees, K: int):
     """Per-round split counts of the wave policy, replayed EXACTLY from
@@ -868,8 +876,9 @@ def make_wave_grower(
                     nls=jnp.zeros(K, jnp.int32),
                 )
                 if use_sub:
-                    pend0["hist"] = jnp.zeros((2 * K,) + hist0.shape,
-                                              jnp.float32)
+                    with jax.named_scope(POOL_SCOPE):
+                        pend0["hist"] = jnp.zeros((2 * K,) + hist0.shape,
+                                                  jnp.float32)
                 if use_cat:
                     pend0["iscats"] = jnp.zeros(K, bool)
                     pend0["bitsets"] = jnp.zeros((K, W), jnp.uint32)
@@ -902,14 +911,16 @@ def make_wave_grower(
                 jnp.where(go_rv, nls_k[:, None] - vl[None, :], 0), axis=0)
 
         with jax.named_scope("lgbm.select"):
+            with jax.named_scope(POOL_SCOPE):
+                leaf_hist0 = (jnp.zeros((L,) + hist0.shape,
+                                        jnp.float32).at[0].set(hist0)
+                              if use_sub
+                              else jnp.zeros((1,) + hist0.shape, jnp.float32))
             st = WaveState(
                 leaf_id=leaf_id0,
                 valid_lids=tuple(jnp.zeros(v.shape[1], jnp.int32)
                                  for v in valids),
-                leaf_hist=(jnp.zeros((L,) + hist0.shape,
-                                     jnp.float32).at[0].set(hist0)
-                           if use_sub
-                           else jnp.zeros((1,) + hist0.shape, jnp.float32)),
+                leaf_hist=leaf_hist0,
                 store=store.init(res0, out0),
                 leaf_box=(jnp.zeros((L, F, 2), jnp.int32)
                           .at[0, :, 1].set(meta.num_bins)
@@ -947,7 +958,8 @@ def make_wave_grower(
             # parent rows are value-forwarded from the pending commit.
             if pipeline:
                 p_hist = st.pending.get("hist")
-                with jax.named_scope("lgbm.select"):
+                with jax.named_scope("lgbm.select"), \
+                        jax.named_scope(POOL_SCOPE):
                     leaf_hist_in = (st.leaf_hist.at[st.pending["cidx"]]
                                     .set(p_hist, mode="drop")
                                     if use_sub else st.leaf_hist)
@@ -1014,12 +1026,13 @@ def make_wave_grower(
                     # values to a post-scatter gather, but the subtracted
                     # sibling's split scan starts without waiting for the
                     # drained scatter (or the partition) to complete
-                    h_parent = st.leaf_hist[leafs]
-                    match = leafs[:, None] == st.pending["cidx"][None, :]
-                    hit = jnp.any(match, axis=1)
-                    src = jnp.argmax(match, axis=1)
-                    h_parent = jnp.where(hit[:, None, None, None],
-                                         p_hist[src], h_parent)
+                    with jax.named_scope(POOL_SCOPE):
+                        h_parent = st.leaf_hist[leafs]
+                        match = leafs[:, None] == st.pending["cidx"][None, :]
+                        hit = jnp.any(match, axis=1)
+                        src = jnp.argmax(match, axis=1)
+                        h_parent = jnp.where(hit[:, None, None, None],
+                                             p_hist[src], h_parent)
 
                 # ---- children metadata --------------------------------------
                 # (depends only on the store read; the split reads it after
@@ -1223,7 +1236,8 @@ def make_wave_grower(
                     hsc = jnp.ones((nsl, 3), jnp.float32)
                 full = 2 * K if not use_sub else K
                 if h.shape[0] < full:   # pad to the bucket-invariant width
-                    with jax.named_scope("lgbm.select"):
+                    with jax.named_scope("lgbm.select"), \
+                            jax.named_scope(POOL_SCOPE):
                         h = jnp.concatenate(
                             [h, jnp.zeros((full - h.shape[0],) + h.shape[1:],
                                           h.dtype)], axis=0)
@@ -1253,10 +1267,11 @@ def make_wave_grower(
                     # quant rounds fold the per-slot dequantization into the
                     # subtraction pass (slot_scale); non-quant rounds carry
                     # all-ones scales and skip the multiply entirely
-                    hist, h_left, h_right = subtract_child_hists(
-                        h_slot, leaf_hist_in, leafs, order_c, sm_left,
-                        slot_scale=hscale if quant_buckets else None,
-                        h_parent=h_parent)
+                    with jax.named_scope(POOL_SCOPE):
+                        hist, h_left, h_right = subtract_child_hists(
+                            h_slot, leaf_hist_in, leafs, order_c, sm_left,
+                            slot_scale=hscale if quant_buckets else None,
+                            h_parent=h_parent)
                 else:
                     ch_idx = jnp.stack([2 * order_c, 2 * order_c + 1],
                                        axis=1).reshape(2 * K)
@@ -1337,11 +1352,12 @@ def make_wave_grower(
                     # packed: ONE interleaved scatter at cidx (hist is already
                     # the rank-interleaved (2K, ...) child stack); legacy: the
                     # historical two half-scatters
-                    leaf_hist = (
-                        st.leaf_hist.at[cidx].set(hist, mode="drop")
-                        if store.fused else
-                        st.leaf_hist.at[lidx].set(h_left, mode="drop")
-                        .at[nlidx].set(h_right, mode="drop"))
+                    with jax.named_scope(POOL_SCOPE):
+                        leaf_hist = (
+                            st.leaf_hist.at[cidx].set(hist, mode="drop")
+                            if store.fused else
+                            st.leaf_hist.at[lidx].set(h_left, mode="drop")
+                            .at[nlidx].set(h_right, mode="drop"))
                     new_pending = st.pending
                 else:
                     leaf_hist = st.leaf_hist

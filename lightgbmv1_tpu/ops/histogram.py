@@ -476,8 +476,12 @@ def benchmark_hist_methods(binned_np, num_bins: int, precision: str,
     times = {}
     for m in candidates:
         def reps_for(r, m=m):
+            # the sample is an argument, not a constant of the program: a
+            # constant is folded through the kernel's bin layout on the
+            # host and stored in the executable (0.6 GB at 2,000 columns,
+            # too large for the compile cache), once per scan length
             @jax.jit
-            def reps():
+            def reps(binned, g3, label):
                 def body(c, i):
                     g = g3 * (1.0 + 1e-6 * i.astype(jnp.float32))
                     h = hist_wave(binned, g, label, nslots, num_bins,
@@ -487,7 +491,7 @@ def benchmark_hist_methods(binned_np, num_bins: int, precision: str,
                     return c + h.sum(), None
                 s, _ = _lax.scan(body, jnp.float32(0), jnp.arange(r))
                 return s
-            return reps
+            return functools.partial(reps, binned, g3, label)
 
         # the shared two-length-scan differential (utils/timer.py), in
         # seconds; its first calls are where a candidate compiles

@@ -338,13 +338,13 @@ def _best_categorical(hist, parent_sum, meta, feature_mask, params,
     raw-category bitset in the v3 model format (unseen categories at
     prediction time go right, like the reference's FindInBitset miss).
     """
-    F, B, _ = hist.shape
+    _, F, B = hist.shape                              # channel planes
     eps = 1e-15
     use_mc = constraint is not None
     use_smooth = params.path_smooth > 0
     if constraint is None:
         constraint = jnp.asarray(NO_CONSTRAINT, jnp.float32)
-    g, h, c = hist[..., 0], hist[..., 1], hist[..., 2]
+    g, h, c = hist[0], hist[1], hist[2]
     total_g, total_h, total_c = parent_sum[0], parent_sum[1], parent_sum[2]
     t_idx = lax.broadcasted_iota(jnp.int32, (F, B), 1)
     nb = meta.num_bins[:, None]
@@ -450,7 +450,7 @@ def _best_categorical(hist, parent_sum, meta, feature_mask, params,
     feat = jnp.where(from_onehot, (best // B) % F, (idx2 // B) % F).astype(jnp.int32)
     pos = jnp.where(from_onehot, best % B, idx2 % B).astype(jnp.int32)
 
-    left1 = hist[feat, pos] + jnp.array([0.0, eps, 0.0])
+    left1 = hist[:, feat, pos] + jnp.array([0.0, eps, 0.0])
     left2 = jnp.stack([clg[direction, feat, pos],
                        clh[direction, feat, pos],
                        clc[direction, feat, pos]])
@@ -496,11 +496,32 @@ def find_best_split(
                                 hist_scale)
 
 
+def _count_scan_columns(scanned: int, block: int) -> None:
+    """Trace time: ``split_scan_columns{what}`` holds the histogram columns
+    one scan covers and the columns one scanned block holds, from the
+    static shapes."""
+    from ..obs.metrics import default_registry
+
+    gauge = default_registry().gauge(
+        "split_scan_columns",
+        "Histogram columns a split scan covers, and columns of one "
+        "scanned block", label_names=("what",))
+    gauge.labels(what="scanned").set(float(scanned))
+    gauge.labels(what="block").set(float(block))
+
+
 def scan_left_sums(hist, meta, hist_scale=None):
     """Phase 1 of the fused split scan: ONE cumulative-sum pass over the
     bin axis plus the missing-mass adjustments, both scan directions
-    stacked into a single ``(2, F, B, 3)`` tensor (direction 0 =
-    missing/default right, direction 1 = missing joins the left side).
+    stacked into a single channel-major ``(3, 2, F, B)`` tensor (plane 0 /
+    1 / 2 = grad / hess / count; direction 0 = missing/default right,
+    direction 1 = missing joins the left side).
+
+    Channel-major on purpose: no array of the scan has the 3 channels as
+    its minor dimension, which a TPU tiles to 128 lanes (42.7x the bytes;
+    at 2,000 columns x 126 children one such array alone passes the
+    chip's memory).  The values are those of the channel-minor scan,
+    element for element.
 
     Dequantize-aware (stochastic-rounded int8 histograms,
     ops/quantize.py): ``hist`` holds exact integer counts and
@@ -511,22 +532,23 @@ def scan_left_sums(hist, meta, hist_scale=None):
     consumed straight from HBM in quantized form: no separate
     dequantization pass ever writes a real-valued copy back.
 
-    Returns ``(left2, hist)`` where ``hist`` is the (dequantized) input
-    for the point reads the categorical search and the missing-direction
-    bookkeeping still need.  Module-level so tools/phase_attrib.py can
-    time exactly this sub-phase of the scan the grower runs."""
+    Returns ``(left2, planes)`` where ``planes`` is the (dequantized)
+    input as ``(3, F, B)`` for the point reads the categorical search
+    still needs.  Module-level so tools/phase_attrib.py can time exactly
+    this sub-phase of the scan the grower runs."""
     F, B, _ = hist.shape
-    cum = jnp.cumsum(hist, axis=1)                    # (F, B, 3) inclusive
+    planes = jnp.moveaxis(hist, 2, 0)                 # (3, F, B)
+    cum = jnp.cumsum(planes, axis=2)                  # inclusive
     if hist_scale is not None:
-        cum = cum * hist_scale[None, None, :]
-        hist = hist * hist_scale[None, None, :]       # point reads below
+        cum = cum * hist_scale[:, None, None]
+        planes = planes * hist_scale[:, None, None]   # point reads below
     t_idx = lax.broadcasted_iota(jnp.int32, (F, B), 1)
 
-    nan_contrib = jnp.take_along_axis(
-        hist,
-        jnp.maximum(meta.nan_bin, 0)[:, None, None].repeat(3, axis=2),
-        axis=1,
-    )[:, 0, :]                                        # (F, 3)
+    def bin_of_each_feature(b):                       # (F,) -> (3, F, 1)
+        return jnp.take_along_axis(
+            planes, jnp.broadcast_to(b[None, :, None], (3, F, 1)), axis=2)
+
+    nan_contrib = bin_of_each_feature(jnp.maximum(meta.nan_bin, 0))
     is_nan_f = (meta.missing_type == MISSING_NAN)[:, None]     # (F, 1)
     is_zero_f = (meta.missing_type == MISSING_ZERO)[:, None]   # (F, 1)
 
@@ -536,20 +558,16 @@ def scan_left_sums(hist, meta, hist_scale=None):
     # with the missing direction — left in the reverse scan, right in the
     # forward scan — INDEPENDENT of where the threshold falls relative to
     # the zero bin.
-    zero_contrib = jnp.take_along_axis(
-        hist, meta.zero_bin[:, None, None].repeat(3, axis=2),
-        axis=1)[:, 0, :]                              # (F, 3)
+    zero_contrib = bin_of_each_feature(meta.zero_bin)
     zb = meta.zero_bin[:, None]                       # (F, 1)
 
     # direction 0: missing/default right (forward scan)
-    left_a = cum - jnp.where(
-        (is_zero_f & (t_idx >= zb))[..., None], zero_contrib[:, None, :], 0.0)
+    left_a = cum - jnp.where(is_zero_f & (t_idx >= zb), zero_contrib, 0.0)
     # direction 1: missing joins the left side (reverse scan equivalent)
     left_b = cum + jnp.where(
-        is_nan_f[..., None], nan_contrib[:, None, :],
-        jnp.where((is_zero_f & (t_idx < zb))[..., None],
-                  zero_contrib[:, None, :], 0.0))
-    return jnp.stack([left_a, left_b]), hist          # (2, F, B, 3)
+        is_nan_f, nan_contrib,
+        jnp.where(is_zero_f & (t_idx < zb), zero_contrib, 0.0))
+    return jnp.stack([left_a, left_b], axis=1), planes   # (3, 2, F, B)
 
 
 def gain_shift(parent_sum, parent_output, params):
@@ -573,7 +591,7 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
                          cegb_penalty=None):
     """Phase 2 of the fused split scan: gains of every (direction,
     feature, bin) candidate in ONE stacked evaluation over the
-    ``(2, F, B, 3)`` left sums from :func:`scan_left_sums` — the gain
+    ``(3, 2, F, B)`` left sums from :func:`scan_left_sums` — the gain
     math (leaf_gain / smoothing / monotone clamps) is traced once on the
     doubled tensor instead of once per direction, so the whole
     cumsum → gain chain lowers as a single fused pass.
@@ -581,7 +599,7 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
     Returns ``(gains (2, F, B), shift)`` with gains RELATIVE (shift =
     parent gain + min_gain_to_split already subtracted) and every
     penalty applied.  Module-level for tools/phase_attrib.py."""
-    _, F, B, _ = left2.shape
+    _, _, F, B = left2.shape
     total_g, total_h, total_c = parent_sum[0], parent_sum[1], parent_sum[2]
     use_mc = _any_monotone(meta)
     use_smooth = params.path_smooth > 0
@@ -594,7 +612,7 @@ def scan_direction_gains(left2, parent_sum, meta, feature_mask, params,
     has_miss_dir = is_nan_f | is_zero_f
 
     def eval_direction(left):
-        lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
+        lg, lh, lc = left[0], left[1], left[2]
         rg, rh, rc = total_g - lg, total_h - lh, total_c - lc
         ok = (
             (lc >= params.min_data_in_leaf)
@@ -705,17 +723,34 @@ def scan_pick(gains, shift, meta):
 
     Returns ``(best_gain, feature, threshold, direction)``.  Module-level
     for tools/phase_attrib.py."""
-    _, F, B = gains.shape
+    B = gains.shape[-1]
+    # the gains are ranked and the winner's is read back: both from one
+    # stored array, so that the compiler does not evaluate the gain math
+    # once for each reader (and, where it contracts multiply-adds by
+    # context, round the two differently)
+    gains = lax.optimization_barrier(gains)
     fbest, sel_f = scan_pick_feature(gains, shift, meta)
-    gains_f = jnp.concatenate([gains[0], gains[1]], axis=1)   # (F, 2B)
     gbest = jnp.max(fbest)
     feature = jnp.argmax(fbest >= gbest - tie_tol(gbest, shift)) \
         .astype(jnp.int32)                   # first in band = min feature
     sel = sel_f[feature]
-    best_gain = gains_f[feature, sel]
     direction = (sel // B).astype(jnp.int32)
     threshold = (sel % B).astype(jnp.int32)
+    best_gain = read_candidate(gains, direction, feature, threshold)
     return best_gain, feature, threshold, direction
+
+
+def read_candidate(x, direction, feature, threshold):
+    """``x[..., direction, feature, threshold]`` of a ``(..., 2, F, B)``
+    array as a masked maximum: it reads ``x`` in whatever layout the scan
+    left it, where a gather of single numbers has the operand copied to a
+    layout of its own.  One position passes the mask, so the value comes
+    out as stored (a maximum, not a sum: -0.0 stays -0.0)."""
+    D, F, B = x.shape[-3:]
+    hit = ((lax.broadcasted_iota(jnp.int32, (D, F, B), 0) == direction)
+           & (lax.broadcasted_iota(jnp.int32, (D, F, B), 1) == feature)
+           & (lax.broadcasted_iota(jnp.int32, (D, F, B), 2) == threshold))
+    return jnp.max(jnp.where(hit, x, NEG_INF), axis=(-3, -2, -1))
 
 
 def _find_best_split(
@@ -731,6 +766,7 @@ def _find_best_split(
     # objects this search runs; candidate values are bit-identical to the
     # historical per-direction evaluation (same formulas, elementwise).
     F, B, _ = hist.shape
+    _count_scan_columns(F, F)
     use_mc = _any_monotone(meta)
     if constraint is None:
         constraint = jnp.asarray(NO_CONSTRAINT, jnp.float32)
@@ -741,7 +777,7 @@ def _find_best_split(
         monotone_penalty, parent_output, rand_key, cegb_penalty)
     best_gain, feature, threshold, direction = scan_pick(gains, shift, meta)
 
-    left = left2[direction, feature, threshold]
+    left = read_candidate(left2, direction, feature, threshold)   # (3,)
 
     # categorical candidates (compiled in only when the dataset has any —
     # meta arrays are trace-time constants via the grower closure)
@@ -807,42 +843,26 @@ def per_feature_best_gain(
     feature before voting, voting_parallel_tree_learner.cpp:300-310)."""
     F, B, _ = hist.shape
     total_g, total_h, total_c = parent_sum[0], parent_sum[1], parent_sum[2]
-    cum = jnp.cumsum(hist, axis=1)
     t_idx = lax.broadcasted_iota(jnp.int32, (F, B), 1)
     nb = meta.num_bins[:, None]
     is_nan_f = (meta.missing_type == MISSING_NAN)[:, None]
     is_zero_f = (meta.missing_type == MISSING_ZERO)[:, None]
-    nan_contrib = jnp.take_along_axis(
-        hist, jnp.maximum(meta.nan_bin, 0)[:, None, None].repeat(3, axis=2),
-        axis=1)[:, 0, :]
-    zero_contrib = jnp.take_along_axis(
-        hist, meta.zero_bin[:, None, None].repeat(3, axis=2),
-        axis=1)[:, 0, :]
-    zb = meta.zero_bin[:, None]
-
-    def gains_for(left):
-        lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
-        rg, rh, rc = total_g - lg, total_h - lh, total_c - lc
-        ok = ((lc >= params.min_data_in_leaf)
-              & (rc >= params.min_data_in_leaf)
-              & (lh >= params.min_sum_hessian_in_leaf)
-              & (rh >= params.min_sum_hessian_in_leaf))
-        gain = leaf_gain(lg, lh, params) + leaf_gain(rg, rh, params)
-        return jnp.where(ok, gain, NEG_INF)
+    # missing-direction accounting is find_best_split's own (zero-as-missing
+    # mass rides the scan direction, SKIP_DEFAULT_BIN semantics)
+    left2, _ = scan_left_sums(hist, meta)             # (3, 2, F, B)
+    lg, lh, lc = left2[0], left2[1], left2[2]
+    rg, rh, rc = total_g - lg, total_h - lh, total_c - lc
+    ok = ((lc >= params.min_data_in_leaf)
+          & (rc >= params.min_data_in_leaf)
+          & (lh >= params.min_sum_hessian_in_leaf)
+          & (rh >= params.min_sum_hessian_in_leaf))
+    gain = jnp.where(ok, leaf_gain(lg, lh, params)
+                     + leaf_gain(rg, rh, params), NEG_INF)
 
     valid = (t_idx <= nb - 2) & feature_mask[:, None] & meta.usable[:, None] \
         & (~meta.is_categorical[:, None])
-    # missing-direction accounting mirrors find_best_split (zero-as-missing
-    # mass rides the scan direction, SKIP_DEFAULT_BIN semantics)
-    left_a = cum - jnp.where(
-        (is_zero_f & (t_idx >= zb))[..., None], zero_contrib[:, None, :], 0.0)
-    left_b = cum + jnp.where(
-        is_nan_f[..., None], nan_contrib[:, None, :],
-        jnp.where((is_zero_f & (t_idx < zb))[..., None],
-                  zero_contrib[:, None, :], 0.0))
-    ga = jnp.where(valid, gains_for(left_a), NEG_INF)
-    gb = jnp.where(valid & (is_nan_f | is_zero_f),
-                   gains_for(left_b), NEG_INF)
+    ga = jnp.where(valid, gain[0], NEG_INF)
+    gb = jnp.where(valid & (is_nan_f | is_zero_f), gain[1], NEG_INF)
     best = jnp.maximum(ga.max(axis=1), gb.max(axis=1))
     # votes rank RELATIVE gains with the feature_contri penalty applied,
     # like the full search (the constant shift is rank-neutral without

@@ -172,13 +172,28 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def compile_for_chip(fn, *shapes):
+    """``fn`` compiled for the described chip, the compile cache off
+    around it: an entry compiled for a described chip cannot be read back
+    (it warns)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
 def test_prepared_pass_compiles_without_relayout(one_chip):
     """Compiled for the chip, the pass over prepared bins holds no
     temporary as large as one stored block: the blocks' default device
     layout is the row-major one the kernel's call takes and the cut of
     their lane padding is a bitcast.  The raw matrix's pass holds the
     padded transposition and every block (what ``bytes_probe.py`` reads)."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
     from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
 
     N, F, B = 1_000_000, 37, 64         # too large for on-chip memory
@@ -195,16 +210,8 @@ def test_prepared_pass_compiles_without_relayout(one_chip):
         lambda x: shape(x.shape, x.dtype),
         jax.eval_shape(lambda b: prepare_hist_bins(b, B), raw))
     rest = (shape((N, 3), jnp.float32), shape((N,), jnp.int32))
-    # an entry compiled for a described chip cannot be read back (it warns)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        got = jax.jit(fn).lower(prepared, *rest).compile()
-        base = jax.jit(fn).lower(raw, *rest).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
+    got = compile_for_chip(fn, prepared, *rest)
+    base = compile_for_chip(fn, raw, *rest)
     assert got.memory_analysis().temp_size_in_bytes < block_bytes
     assert base.memory_analysis().temp_size_in_bytes >= 2 * block_bytes
     txt = got.as_text()
@@ -212,6 +219,40 @@ def test_prepared_pass_compiles_without_relayout(one_chip):
     assert len(re.findall(rf"= {operand}\S* bitcast\(", txt)) == 2
     assert not re.search(rf"= {operand}\S* (copy|slice|fusion)\(", txt)
     assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,2048\]", txt)
+
+
+@pytest.mark.parametrize("columns", [137, 2000])
+def test_split_scan_temporaries_at_126_children(one_chip, columns):
+    """A wave round's scan (``find_best_split`` under ``vmap`` over 126
+    children x ``columns`` x 64 bins), compiled for the chip: bytes only.
+    On channel-minor arrays the chip tiled the 3 channels to 128 lanes:
+    1.13 GB of temporaries at 137 columns and, at 2,000, one array of
+    15.4 GB (``RESOURCE_EXHAUSTED``, PERF.md PR 32).  On channel planes
+    the 2,000-column scan stays under 2 GB, and no array of it has a
+    minor dimension of 3."""
+    from lightgbmv1_tpu.ops.split import find_best_split
+
+    K, B = 126, 64
+    meta = _probe_meta(columns, B)
+    params = SplitParams(min_data_in_leaf=1.0, min_sum_hessian_in_leaf=100.0)
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def scan(hist, parents, masks):
+        return jax.vmap(lambda h, p, m: find_best_split(
+            h, p, meta, m, params))(hist, parents, masks)
+
+    got = compile_for_chip(
+        scan, shape((K, columns, B, 3), jnp.float32),
+        shape((K, 3), jnp.float32), shape((K, columns), jnp.bool_))
+    hist_bytes = K * columns * B * 3 * 4
+    assert got.memory_analysis().temp_size_in_bytes < min(
+        2 * 1024 ** 3, 4 * hist_bytes)
+    channel_minor = re.findall(
+        rf"f32\[{K},(?:[12],)?{columns},{B},3\]\{{4,3,2,1,0|"
+        rf"f32\[{K},{columns},{B},3\]\{{3,2,1,0", got.as_text())
+    assert not channel_minor
 
 
 def _kernel_result_rows(txt):
