@@ -255,6 +255,40 @@ def test_split_scan_temporaries_at_126_children(one_chip, columns):
     assert not channel_minor
 
 
+@pytest.mark.parametrize("queries,width", [(273, 1536), (3276, 128)])
+def test_lambdarank_chunk_compiles_heads_by_documents(one_chip, queries,
+                                                      width):
+    """``LambdarankNDCG._chunk_grads`` compiled for the chip at
+    ``mslr-train``'s widest window (12 rows of 128 documents, as many
+    queries as the chunk budget admits) and at its commonest (one row, a
+    full chunk), 20 heads: the temporaries stay under the budget's bytes
+    (8 f32 arrays of ``_PAIRWISE_CHUNK_ELEMS``) and no array is documents
+    x documents."""
+    from lightgbmv1_tpu import objectives
+    from lightgbmv1_tpu.config import Config
+    from lightgbmv1_tpu.io.dataset import Metadata
+
+    heads = 20
+    assert queries == objectives._PAIRWISE_CHUNK_ELEMS // (width * heads)
+    obj = objectives.LambdarankNDCG(Config.from_dict(
+        {"objective": "lambdarank", "verbosity": -1,
+         "lambdarank_truncation_level": heads}))
+    meta = Metadata(label=np.arange(12, dtype=np.float32) % 3)
+    meta.set_group(np.array([5, 7]))
+    obj.init(meta, 12)
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    got = compile_for_chip(
+        obj._chunk_grads, shape((queries, width), jnp.float32),
+        shape((queries,), jnp.int32), shape((queries,), jnp.int32),
+        shape((queries, width), jnp.float32), shape((queries,), jnp.float32))
+    assert got.memory_analysis().temp_size_in_bytes < (
+        8 * 4 * objectives._PAIRWISE_CHUNK_ELEMS)
+    assert not re.search(rf"\[{queries},{width},{width}\]", got.as_text())
+
+
 def _kernel_result_rows(txt):
     """Second dimension of every ``tpu_custom_call``'s f32 result."""
     return [int(m) for m in re.findall(
