@@ -20,6 +20,9 @@ where "the kernel raised" is one of the two passing outcomes):
              so that a scan the chip's compiler refuses shows in seconds
   train      lightgbmv1_tpu.train, 20 iterations, 100k held-out rows
   hist       hist_leaves_pallas vs numpy.bincount at the train shape
+  hist256    the same at max_bin=255, the library's default: two trees
+             through lightgbmv1_tpu.train, then the kernel's 256-bin rung
+             on the lane-dense operand the trainer placed, 1-64 slots
   predict    Booster.predict depthwise (device) vs host
   serve      build_server + ServeHTTP on port 0 in this process
   variants   kernels that engage on their own: packed4, int8sr
@@ -51,7 +54,8 @@ from lightgbmv1_tpu.config import Config
 from lightgbmv1_tpu.metrics import AUCMetric
 from lightgbmv1_tpu.models.grower_wave import auto_wave_size, slot_buckets_for
 from lightgbmv1_tpu.obs import xla as obs_xla
-from lightgbmv1_tpu.ops.hist_pallas import hist_leaves_pallas
+from lightgbmv1_tpu.ops.hist_pallas import (HistBins, hist_leaves_pallas,
+                                            kernel_width)
 from lightgbmv1_tpu.parallel.trainer import resolve_deep_dtype
 from lightgbmv1_tpu.serve import ServeHTTP
 from lightgbmv1_tpu.serve.server import build_server
@@ -285,6 +289,10 @@ class Smoke:
                 f"{precision} L={L}: worst {worst[precision]} > {tol}"
         return worst
 
+    def ladder(self):
+        """The slot buckets of the wave ladder the 255-leaf grower runs."""
+        return slot_buckets_for(auto_wave_size(PARAMS["num_leaves"]), self.n)
+
     def hist(self):
         gb = self.booster._gbdt
         B = int(gb.num_bins)
@@ -293,8 +301,7 @@ class Smoke:
         # 255-leaf grower runs, once with a slot more for its dead rows
         # (the scatter / onehot methods' form, every slot live) and once as
         # the trainer asks the kernel: the live slots, dead rows dropped
-        ladder = slot_buckets_for(auto_wave_size(PARAMS["num_leaves"]),
-                                  self.n)
+        ladder = self.ladder()
         worst, worst_live = {}, {}
         for L in [1] + [S + 1 for S in ladder]:
             precisions = ["bf16x2", "bf16"] + (["int8sr"] if L > 5 else [])
@@ -311,6 +318,36 @@ class Smoke:
                  tolerance={k: float(v) for k, v in HIST_TOL.items()})
 
     # -- device predict vs host -------------------------------------------
+    def hist256(self):
+        p255 = {**PARAMS, "max_bin": 255}
+        d255 = lgb.Dataset(self.X, label=self.y,
+                           params=dict(p255)).construct()
+        booster = lgb.train(dict(p255), d255, num_boost_round=2,
+                            verbose_eval=False)
+        gb = booster._gbdt
+        B = int(gb.num_bins)
+        assert kernel_width(B) == 256 and B > 64, B
+        # on the chip the serial learner hands the kernel the operand it
+        # laid out at placement: the 28 columns side by side in one array
+        operand = gb._grow_binned
+        prepared = isinstance(operand, HistBins)
+        assert prepared == (self.device["platform"] == "tpu"), type(operand)
+        if prepared:
+            assert (operand.tile_cols, operand.windows,
+                    [b.shape[1] for b in operand.blocks]) == (8, 16, [128])
+        bins_np = np.asarray(gb.binned)
+        ladder = self.ladder()
+        worst = {f"slots{L}": self.check_hist(operand, bins_np, B, L,
+                                              ["bf16x2", "bf16"])
+                 for L in (1, 64)}
+        worst_live = {f"live{S}": self.check_hist(
+            operand, bins_np, B, S, ["bf16x2", "bf16"], dead=True)
+            for S in ladder}
+        self.say("hist256", shape=[F, self.n], num_bins=B, ladder=ladder,
+                 prepared_operand=prepared, counts="exact",
+                 worst_error_over_sum_abs=worst,
+                 live_slots_worst_error_over_sum_abs=worst_live)
+
     def predict(self):
         before = obs_xla.compile_counts().get("predict.leaf", 0)
         leaf_dev = self.booster.predict(self.Xv, pred_leaf=True,
@@ -542,7 +579,8 @@ def main(argv=None) -> int:
         return 1
     print(json.dumps({"leg": "device", **smoke.device}), flush=True)
 
-    for leg in (smoke.wide, smoke.train, smoke.hist, smoke.predict,
+    for leg in (smoke.wide, smoke.train, smoke.hist, smoke.hist256,
+                smoke.predict,
                 smoke.serve, smoke.variants, smoke.optin, smoke.multichip):
         leg()
 
@@ -568,6 +606,8 @@ def main(argv=None) -> int:
             "hist_worst_error_over_sum_abs":
                 smoke.out["hist"]["worst_error_over_sum_abs"],
             "hist_counts": "exact",
+            "hist256_worst_error_over_sum_abs":
+                smoke.out["hist256"]["worst_error_over_sum_abs"],
             "predict_leaf_indices": "equal",
             "predict_raw_score_max_delta":
                 smoke.out["predict"]["raw_score_max_delta"],

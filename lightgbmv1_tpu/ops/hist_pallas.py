@@ -42,20 +42,39 @@ TPUs have no atomics; the design maps the OpenCL structure onto the MXU:
     - ``bf16x2``— hi/lo-split bf16, ~fp32 accuracy at 2 MXU passes.
     - ``f32``   — exact; used by tests/CPU.
 
-The bin operand — per feature block a row-major ``u8[n_pad, tile_cols]``
-array — is made by ONE function, ``prepare_hist_bins``.  A learner calls it
-once at placement and hands ``hist_leaves_pallas`` the result (``HistBins``);
-a caller that hands over the raw ``(F, N)`` matrix gets the same layout made
-inside the pass, every pass (pad + transposition + one slice per block: 7-9x
-the bins in temporaries).  With a prepared operand the HBM traffic of a pass
-is the blocks + g3 + leaf_id and nothing else.  A TPU tiles a ``u8`` array
-``T(8,128)(4,1)``, so a block narrower than 128 byte columns occupies 128
-lanes a row whatever its shape says: the 32-column blocks of a 64-bin pass
-read 4x the bins' own bytes.  ``HistBins`` stores each block AT that width
-(pad columns included) so that the array's default device layout is the
-row-major one the kernel's call takes — a tall ``u8[n, 32]`` array would be
-stored column-major and copied in every pass — and the pass's column slice
-back to ``tile_cols`` is a bitcast there.
+The bin operand is made by ONE function, ``prepare_hist_bins``.  A learner
+calls it once at placement and hands ``hist_leaves_pallas`` the result
+(``HistBins``); a caller that hands over the raw ``(F, N)`` matrix gets the
+same layout made inside the pass, every pass (pad + transposition, and on
+the 16 and 64 rungs one slice per block: 7-9x the bins in temporaries).
+With a prepared operand the HBM traffic of a pass is the stored arrays + g3
++ leaf_id and nothing else.  A TPU tiles a ``u8`` array ``T(8,128)(4,1)``,
+so a row of a stored array occupies 128 byte lanes whatever its shape says,
+and the operand costs ``arrays x n_pad x 128`` bytes
+(``prepared_bins_bytes``; the learner prepares it where that is at most a
+quarter of the device's memory, ``trainer._place_hist_bins``):
+
+* the 16 and 64 rungs store each feature block as its own row-major
+  ``u8[n_pad, 128]`` array, ``tile_cols`` live columns and lane padding: the
+  32-column blocks of a 64-bin pass read 4x the bins' own bytes
+  (``mslr-train``: 5 blocks x 2,271,232 rows = 1,453,588,480 B;
+  ``epsilon-train``: 63 x 400,384 x 128 = 3,228,696,576 B).  Stored AT lane
+  width, the array's default device layout is the row-major one the
+  kernel's call takes — a tall ``u8[n, 32]`` array would be stored
+  column-major and copied in every pass — and the pass's column slice back
+  to ``tile_cols`` is a bitcast there.  (The same storage would serve the 64
+  rung on the lane-dense form below at a quarter of these bytes: not done,
+  ROADMAP S1 (h).)
+* the 256 rung's blocks are 8 columns wide (16 at 128 bins), so one array a
+  block would store 16x the bins (``higgs-255b-train``, 10,500,000 x 28: 4
+  blocks = 5,376,049,152 B, over the quarter).  Its operand is lane-dense:
+  the matrix's columns side by side, 128 a ``u8[n_pad, 128]`` array (all 28
+  of that cell in ONE: 1,344,012,288 B), and feature block ``fb`` of a pass
+  is the static window of ``tile_cols`` columns ``(fb % windows) *
+  tile_cols`` columns into array ``fb // windows``.  Each call takes the
+  whole array a (T, 128) tile at a time and the kernel picks its window
+  with the MXU (``_kernel``: a 128 x 128 selection, which also repeats the
+  window across the lanes).
 """
 
 from __future__ import annotations
@@ -122,23 +141,32 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     budget.  ``m_pad`` is the result block's rows (``pass_rows``' third):
     the left operand's own rows, up to 5/3 of them, ride in the estimate's
     16 bytes a row.  The estimate is deliberately conservative: per-chunk f32
-    temporaries (repeat buffer, compare, select, cast) can coexist, and
-    narrow feature blocks pay lane-padding amplification (observed OOM at
-    B=256 with 3 features and T=1024).  No ``compiler_params`` is passed,
-    so the chip's default scoped limit applies: on TPU v5 lite every
-    shape chip_smoke.py runs (28 features, 16/64 bins, 1-64 slots, all
-    default-policy precisions, packed4) compiles under it."""
+    temporaries (repeat buffer, compare, select, cast) can coexist.  No
+    ``compiler_params`` is passed, so the chip's default scoped limit
+    applies: on TPU v5 lite every shape chip_smoke.py runs (28 features,
+    16/64/256 bins, 1-64 slots, all default-policy precisions, packed4)
+    compiles under it.
+
+    The 16 and 64 rungs hold the estimate to 8 MB (1024 rows up to 16
+    slots, 512 at 63).  The 256 rung holds it to 12 MB, which admits 1024
+    rows up to 64 slots: what the v5e's compiler accepted and the chip ran
+    on 2026-10-03 at 10,500,096 rows x 28 columns x 256 bins, 1 / 4 / 16 /
+    63 slots (M 16 / 32 / 80 / 192), ``bf16x2`` and ``bf16``, where 1024
+    rows took 51.6 ms a 63-slot call against 56.6 at 512 and 27.9 against
+    31.5 at 16 slots; the compiler alone also accepted 1-255 slots of all
+    five precisions at 256 and 128 bins.  The rung's blocks are always
+    whole windows of a 128-lane tile, whatever the matrix's width."""
     out_bytes = m_pad * num_lanes * 4
     per_row = 14 * min(num_lanes, 512) + 16 * m_pad
-    t0 = MAX_ROW_TILE if kernel_width(num_bins) <= 64 else 512
+    budget = (12 if kernel_width(num_bins) == 256 else 8) * 2**20
     for t in (MAX_ROW_TILE, 512, 256, 128):
-        if t <= t0 and out_bytes + t * per_row <= 8 * 2**20:
+        if out_bytes + t * per_row <= budget:
             return t
     return 128
 
 
 def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
-            num_bins, fblk, precision, interpret, packed=False):
+            num_bins, fblk, precision, interpret, packed=False, window=None):
     """Grid: (feature_blocks, row_tiles); out revisited across row tiles.
 
     iota_ref: (1, FBLK*B) f32          — precomputed ``lane // FBLK`` pattern
@@ -150,7 +178,11 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
                                          reference DenseBin<.., IS_4BIT=true>
                                          src/io/dense_bin.hpp:52) and the
                                          effective feature block is 2*FBLK
-                                         wide, ordered [lo nibbles | hi]
+                                         wide, ordered [lo nibbles | hi];
+                                         with ``window`` a (T, 128) tile
+                                         of a lane-dense array, of which
+                                         the block is the FBLK columns
+                                         from column ``window`` on
     g3_ref:   (3, T) f32               — grad / hess / count (pre-transposed)
     leaf_ref: (1, T) int32             — leaf id per row, never negative;
                                          a row whose id is num_leaves or
@@ -236,11 +268,34 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
             .astype(jnp.float32)
     else:
         bins_f = bins_ref[...].astype(jnp.int32).astype(jnp.float32)
+    pattern = None
+    if window is not None:
+        # the block's FBLK columns, which start ``window`` columns into the
+        # lane-dense tile, repeated across 128 lanes by the MXU: lane l of
+        # the product is column window + l % FBLK (one 1.0 a column of the
+        # selection, bin ids < 256 are exact in bfloat16, so the product is
+        # the id).  A lane repeat of 8-lane pieces cost 2.5x the whole
+        # 32-feature build of the 64 rung (46.0 against 18.7 ms a root call
+        # at 10.5 M rows, chip run of 2026-10-03).
+        k = lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+        # (the interpreter's XLA:CPU dot has no bf16 x bf16 -> f32 at every
+        # shape: it selects in f32, as exactly)
+        dt = jnp.float32 if interpret else jnp.bfloat16
+        sel = (k == window + (lane & (fblk - 1))).astype(dt)
+        pattern = lax.dot_general(
+            bins_f.astype(dt), sel, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=lax.Precision.HIGHEST if interpret else None)
 
     for c in range(n_chunks):
         cb_c = min(cb, B - c * cb)
         sl = slice(c * cb * fblk, (c * cb + cb_c) * fblk)
-        bw = rep(bins_f, cb_c, 1)                            # (T, cb_c*FBLK)
+        if pattern is not None:
+            bw = rep(pattern, -(-cb_c * fblk // _LANES), 1)  # whole vregs
+            bw = bw[:, :cb_c * fblk]
+        else:
+            bw = rep(bins_f, cb_c, 1)                        # (T, cb_c*FBLK)
         oh_cmp = bw == iota_ref[0:1, sl]
         # bool -> numeric cast IS the one-hot (exactly 1.0/0.0): a direct
         # convert, not a select pass — the one-hot build is the
@@ -321,22 +376,27 @@ def packed_bins_of_rows(binned, f_row):
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["matrix", "blocks"],
-                   meta_fields=["tile_cols"])
+                   meta_fields=["tile_cols", "windows"])
 @dataclasses.dataclass(frozen=True)
 class HistBins:
     """The kernel's bin operand, made once for a dataset by
-    ``prepare_hist_bins``: ``blocks[fb][:, :tile_cols]`` is feature block
-    ``fb`` as a row-major ``u8[n_pad, tile_cols]`` array, the operand of that
-    block's ``pallas_call``; the columns beyond, up to a multiple of 128,
-    are the lane padding the device would add anyway (module docstring).
-    ``matrix`` is the untouched ``(F, N)`` matrix (packed:
-    ``(ceil(F/2), N)``) the blocks were cut from, which everything but the
-    histogram pass (partition decisions, tree walks) keeps reading.  A
-    pytree of arrays; ``tile_cols`` is static."""
+    ``prepare_hist_bins``: row-major ``u8[n_pad, 128k]`` arrays, each
+    holding ``windows`` feature blocks of ``tile_cols`` byte columns side by
+    side (module docstring).  With ``windows`` 1 (the 16 and 64 rungs)
+    ``blocks[fb][:, :tile_cols]`` is feature block ``fb``, the operand of
+    that block's ``pallas_call``, and the columns beyond are the lane
+    padding the device would add anyway; on the 256 rung block ``fb`` is
+    columns ``(fb % windows) * tile_cols ...`` of ``blocks[fb // windows]``,
+    which the call takes whole.  ``matrix`` is the untouched ``(F, N)``
+    matrix (packed: ``(ceil(F/2), N)``) the blocks were cut from, which
+    everything but the histogram pass (partition decisions, tree walks)
+    keeps reading.  A pytree of arrays; ``tile_cols`` and ``windows`` are
+    static."""
 
     matrix: jax.Array
     blocks: Tuple[jax.Array, ...]
     tile_cols: int
+    windows: int = 1
 
 
 def bin_matrix(binned) -> jax.Array:
@@ -353,18 +413,54 @@ def _feature_blocks(stored_rows: int, num_bins: int, packed: bool):
     if packed:
         fblk = max(2, min(2 * stored_rows, MAX_LANES // num_bins) & ~1)
         tile_cols = fblk // 2
+    elif kernel_width(num_bins) == 256:
+        # a window of a lane-dense array: always whole, whatever the matrix
+        # holds (columns beyond it are padding, sliced away by the pass),
+        # and a power of two, so that windows tile the 128 lanes (8 columns
+        # at 256 bins, 16 at 128)
+        fblk = tile_cols = 1 << ((MAX_LANES // num_bins).bit_length() - 1)
     else:
         fblk = max(1, min(stored_rows, MAX_LANES // num_bins))
         tile_cols = fblk
     return fblk, tile_cols, -(-stored_rows // tile_cols)
 
 
+def _block_windows(tile_cols: int, num_bins: int) -> int:
+    """Feature blocks one stored array holds side by side.  The 256 rung's
+    blocks are 8 byte columns wide: one array a block would store 16x the
+    bins, so 16 of them share an array's 128 lanes.  The 16 and 64 rungs
+    keep one array a block."""
+    return _LANES // tile_cols if kernel_width(num_bins) == 256 else 1
+
+
 def prepared_bins_bytes(stored_rows: int, num_rows: int, num_bins: int,
                         packed: bool = False) -> int:
     """Bytes of the blocks ``prepare_hist_bins`` makes of such a matrix."""
     _, tile_cols, nfb = _feature_blocks(stored_rows, num_bins, packed)
+    windows = _block_windows(tile_cols, num_bins)
     n_pad = -(-num_rows // MAX_ROW_TILE) * MAX_ROW_TILE
-    return nfb * n_pad * (-(-tile_cols // _LANES) * _LANES)
+    return -(-nfb // windows) * n_pad * (-(-tile_cols // _LANES) * _LANES)
+
+
+def _count_operand(stored_rows: int, tile_cols: int, windows: int, nfb: int,
+                   num_bins: int) -> None:
+    """Trace time: ``hist_operand_lanes{what}``, the byte columns a row of
+    the fullest stored array occupies and those of them that carry a
+    feature, and ``hist_pass_blocks{rung}``, the kernel calls of a pass."""
+    from ..obs.metrics import default_registry
+
+    lanes = default_registry().gauge(
+        "hist_operand_lanes",
+        "Byte columns of a row of the histogram kernel's stored bin "
+        "operand: as stored, and carrying a feature",
+        label_names=("what",))
+    lanes.labels(what="stored").set(float(-(-tile_cols // _LANES) * _LANES))
+    lanes.labels(what="live").set(float(min(stored_rows,
+                                            windows * tile_cols)))
+    default_registry().gauge(
+        "hist_pass_blocks", "Kernel calls of one histogram pass",
+        label_names=("rung",)).labels(
+            rung=str(kernel_width(num_bins))).set(float(nfb))
 
 
 def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
@@ -386,8 +482,9 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
     lane width (module docstring; the padding columns are never read).
     ``hist_leaves_pallas`` passes False where it was handed the raw matrix
     and makes the layout inside the pass, consumed at once at its own
-    width.  Traceable; each trace counts in ``hist_bins_layout_total``
-    under ``site="placement"`` (resident) or ``"pass"``."""
+    width; the 256 rung's lane-dense arrays are the same either way.
+    Traceable; each trace counts in ``hist_bins_layout_total`` under
+    ``site="placement"`` (resident) or ``"pass"``."""
     if binned.dtype not in (jnp.uint8, np.uint8):
         raise ValueError(
             "hist_leaves_pallas requires uint8 bins (num_bins <= 256); "
@@ -403,17 +500,22 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
             site="placement" if resident else "pass").inc()
     stored, N = binned.shape
     _, tile_cols, nfb = _feature_blocks(stored, num_bins, packed)
+    windows = _block_windows(tile_cols, num_bins)
+    _count_operand(stored, tile_cols, windows, nfb, num_bins)
+    # byte columns of one stored array, and how many arrays
+    width = _LANES if windows > 1 else tile_cols
+    n_arrays = -(-nfb // windows)
     n_pad = -(-N // row_tile) * row_tile
     fill = 0 if packed else 255
     binned_rm = jnp.pad(
-        binned, ((0, nfb * tile_cols - stored), (0, n_pad - N)),
-        constant_values=fill).T                     # (n_pad, nfb*tile_cols)
-    blocks = [binned_rm[:, fb * tile_cols:(fb + 1) * tile_cols]
-              for fb in range(nfb)]
-    if resident and tile_cols % _LANES:
-        blocks = [jnp.pad(b, ((0, 0), (0, -tile_cols % _LANES)),
+        binned, ((0, n_arrays * width - stored), (0, n_pad - N)),
+        constant_values=fill).T                     # (n_pad, arrays*width)
+    blocks = [binned_rm[:, a * width:(a + 1) * width]
+              for a in range(n_arrays)]
+    if resident and width % _LANES:
+        blocks = [jnp.pad(b, ((0, 0), (0, -width % _LANES)),
                           constant_values=fill) for b in blocks]
-    return HistBins(binned, tuple(blocks), tile_cols)
+    return HistBins(binned, tuple(blocks), tile_cols, windows)
 
 
 def _count_pass_rows(slots: int, precision: str) -> None:
@@ -455,21 +557,25 @@ def hist_leaves_pallas(
     stored, N = bin_matrix(binned).shape
     F = (num_features or 2 * stored) if packed else stored
     fblk, tile_cols, nfb = _feature_blocks(stored, B, packed)
+    windows = _block_windows(tile_cols, B)
     f_pad = nfb * fblk
     out_rows = pass_rows(L, precision)[2]
     _count_pass_rows(L, precision)
     T = row_tile if row_tile > 0 else _row_tile_for(out_rows, fblk * B, B)
 
-    if not isinstance(binned, HistBins):
+    if isinstance(binned, HistBins):
+        _count_operand(stored, tile_cols, windows, nfb, B)
+    else:       # counted there
         binned = prepare_hist_bins(binned, B, packed, row_tile=T,
                                    resident=False)
     n_pad = binned.blocks[0].shape[0]
-    if (n_pad < N or n_pad % T or len(binned.blocks) != nfb
-            or binned.tile_cols != tile_cols):
+    if (n_pad < N or n_pad % T or len(binned.blocks) != -(-nfb // windows)
+            or (binned.tile_cols, binned.windows) != (tile_cols, windows)):
         raise ValueError(
-            f"prepared bins ({len(binned.blocks)} blocks of "
-            f"{binned.tile_cols} columns, {n_pad} rows) do not fit this "
-            f"pass ({nfb} blocks of {tile_cols} columns, {N} rows in tiles "
+            f"prepared bins ({len(binned.blocks)} arrays, blocks of "
+            f"{binned.tile_cols} columns, {binned.windows} an array, "
+            f"{n_pad} rows) do not fit this pass ({nfb} blocks of "
+            f"{tile_cols} columns, {windows} an array, {N} rows in tiles "
             f"of {T}): prepare them with the pass's num_bins / packed")
     nrt = n_pad // T
 
@@ -485,21 +591,23 @@ def hist_leaves_pallas(
     iota_bins = (jnp.arange(B * fblk, dtype=jnp.int32)
                  // fblk).astype(jnp.float32)[None, :]      # (1, B*fblk)
 
-    kernel = functools.partial(
-        _kernel, num_leaves=L, num_bins=B, fblk=fblk, precision=precision,
-        interpret=interpret, packed=packed,
-    )
-
-    def one_block(bins_block):
+    def one_block(bins_block, window=None):
         # Mosaic requires the bins block's lane dim to equal the array dim
         # (or be 128-divisible), so each feature block is its own call; the
-        # row-tile grid dimension does the accumulation.
+        # row-tile grid dimension does the accumulation.  A lane-dense
+        # array is taken 128 columns at a time and the kernel cuts the
+        # block's static ``window`` of them.
+        kernel = functools.partial(
+            _kernel, num_leaves=L, num_bins=B, fblk=fblk,
+            precision=precision, interpret=interpret, packed=packed,
+            window=window)
         return pl.pallas_call(
             kernel,
             grid=(1, nrt),
             in_specs=[
                 pl.BlockSpec((1, fblk * B), lambda fb, rt: (0, 0)),
-                pl.BlockSpec((T, tile_cols), lambda fb, rt: (rt, 0)),
+                pl.BlockSpec((T, bins_block.shape[1]),
+                             lambda fb, rt: (rt, 0)),
                 pl.BlockSpec((3, T), lambda fb, rt: (0, rt)),
                 pl.BlockSpec((1, T), lambda fb, rt: (0, rt)),
             ],
@@ -510,7 +618,12 @@ def hist_leaves_pallas(
             interpret=interpret,
         )(iota_bins, bins_block, g3t, leaf_p)
 
-    blocks = [one_block(b[:, :tile_cols]) for b in binned.blocks]
+    if windows > 1:
+        blocks = [one_block(binned.blocks[fb // windows],
+                            (fb % windows) * tile_cols)
+                  for fb in range(nfb)]
+    else:
+        blocks = [one_block(b[:, :tile_cols]) for b in binned.blocks]
     out = jnp.concatenate(blocks, axis=0) if nfb > 1 else blocks[0]
 
     # (nfb, out_rows, B*fblk) -> (L, F, B, 3)

@@ -365,8 +365,15 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
     return "packed4"
 
 
-# share of the device's memory (``bytes_limit``) the prepared histogram
-# operands may hold resident; over it the passes lay the bins out on the fly
+# The bytes rule: share of the device's memory (``bytes_limit``) the prepared
+# histogram operands may hold resident; over it the passes lay the bins out
+# on the fly.  The operands cost ``hist_pallas.prepared_bins_bytes``: stored
+# arrays x padded rows x 128 lanes, one array a feature block on the 16 and
+# 64 rungs, one a 128 columns on the 256 rung (module docstring there).  On
+# the v5e (16,909,336,064 B, a quarter 4,227,334,016) the benchmark's cells
+# read: mslr-train 1,453,588,480 B (5 blocks), epsilon-train 3,228,696,576
+# (63), higgs-255b-train 1,344,012,288 (one array; 5,376,049,152 and raw as
+# one array a block); the row-sharded learners keep the raw shard.
 _HIST_BINS_SHARE = 0.25
 
 

@@ -344,6 +344,13 @@ def _roofline_call_shapes(long_name, bins):
     return shapes
 
 
+def _hist_calls(txt):
+    """The ``hist_leaves_pallas`` custom calls of a compiled module."""
+    return [ln.strip() for ln in txt.splitlines()
+            if re.match(r"\s*%hist_leaves_pallas[.0-9]* = ", ln)
+            and "custom-call(" in ln]
+
+
 @pytest.mark.parametrize("slots,precision,credited", [
     (1, "bf16x2", 2), (4, "bf16x2", 5), (16, "bf16x2", 16),
     (63, "bf16", 64), (63, "int8sr", 64)])
@@ -379,14 +386,69 @@ def test_ladder_pass_compiles_and_the_roofline_reader_parses_it(
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
-    calls = [ln.strip() for ln in txt.splitlines()
-             if re.match(r"\s*%hist_leaves_pallas[.0-9]* = ", ln)
-             and "custom-call(" in ln]
+    calls = _hist_calls(txt)
     assert len(calls) == 2, calls                 # two blocks of 32 features
     for ln in calls:
         assert _roofline_call_shapes(ln, B) == {
             "features": 32, "slots": credited, "rows": n_pad}
     assert credited >= slots
+
+
+@pytest.mark.parametrize("precision", ["bf16x2", "bf16"])
+@pytest.mark.parametrize("slots,credited", [(1, 2), (4, 5), (16, 16),
+                                            (63, 64)])
+def test_dense_256_rung_compiles_at_higgs_size(one_chip, slots, credited,
+                                               precision):
+    """The 256-bin rung over its lane-dense prepared operand, compiled for
+    the chip at ``higgs-255b-train``'s own shapes (10,500,000 x 28 ->
+    one ``u8[10500096,128]`` array, four calls of 8 x 256 lanes): every
+    call takes the stored array whole (no copy, slice or fusion makes a
+    ``u8`` operand), keeps the name and the result shape the roofline
+    reader parses, and the pass's temporaries are the transposed g3 and
+    leaf ids alone."""
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+
+    N, F, B, n_pad = 10_500_000, 28, 256, 10_500_096
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    prepared = jax.tree_util.tree_map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda b: prepare_hist_bins(b, B),
+                       shape((F, N), jnp.uint8)))
+    assert [b.shape for b in prepared.blocks] == [(n_pad, 128)]
+    got = compile_for_chip(
+        lambda b, g, l: hist_leaves_pallas(b, g, l, slots, B,
+                                           precision=precision),
+        prepared, shape((N, 3), jnp.float32), shape((N,), jnp.int32))
+    assert got.memory_analysis().temp_size_in_bytes < n_pad * 20
+    txt = got.as_text()
+    calls = _hist_calls(txt)
+    assert len(calls) == 4, calls
+    for ln in calls:
+        assert _roofline_call_shapes(ln, B) == {
+            "features": 8, "slots": credited, "rows": n_pad}
+    assert not re.search(rf"= u8\[{n_pad},\d+\]\S* "
+                         r"(copy|slice|fusion|transpose)\(", txt)
+
+
+def test_prepared_bytes_of_the_cells():
+    """The bytes rule's arithmetic (``trainer._place_hist_bins``): the 256
+    rung stores 128 byte columns an array; the 64 rung one array a block,
+    **unchanged**: ``mslr-train`` 5 blocks, ``epsilon-train`` 63."""
+    from lightgbmv1_tpu.ops.hist_pallas import (_feature_blocks,
+                                                prepared_bins_bytes)
+
+    assert prepared_bins_bytes(28, 10_500_000, 256) == 1_344_012_288
+    assert prepared_bins_bytes(137, 2_270_296, 64) == 1_453_588_480
+    assert prepared_bins_bytes(2000, 400_000, 64) == 63 * 400_384 * 128
+    # one array a block, the 256 rung's form before: 16x the bins, over a
+    # quarter of the v5e's 16.9 GB
+    _, tile_cols, nfb = _feature_blocks(28, 256, False)
+    assert nfb * 10_500_096 * 128 == 5_376_049_152 > 16_909_336_064 // 4
+    # a matrix wider than one array's 128 columns takes two
+    assert prepared_bins_bytes(137, 10_500_000, 256) == 2 * 1_344_012_288
 
 
 def _probe_meta(F, B):
