@@ -23,6 +23,10 @@ where "the kernel raised" is one of the two passing outcomes):
   hist256    the same at max_bin=255, the library's default: two trees
              through lightgbmv1_tpu.train, then the kernel's 256-bin rung
              on the lane-dense operand the trainer placed, 1-64 slots
+  partition  the row-tiled partition kernel vs the gather form it stands
+             for, 1-64 slots on 28 and 137 columns: integers equal, and the
+             ms a round of both (what ``partition_path``'s rule is fitted
+             from, at this leg's rows)
   predict    Booster.predict depthwise (device) vs host
   serve      build_server + ServeHTTP on port 0 in this process
   variants   kernels that engage on their own: packed4, int8sr
@@ -38,6 +42,7 @@ the verdict the driver reads, exactly
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -56,6 +61,9 @@ from lightgbmv1_tpu.models.grower_wave import auto_wave_size, slot_buckets_for
 from lightgbmv1_tpu.obs import xla as obs_xla
 from lightgbmv1_tpu.ops.hist_pallas import (HistBins, hist_leaves_pallas,
                                             kernel_width)
+from lightgbmv1_tpu.ops.partition_pallas import (partition_gather,
+                                                 partition_pallas,
+                                                 partition_path)
 from lightgbmv1_tpu.parallel.trainer import resolve_deep_dtype
 from lightgbmv1_tpu.serve import ServeHTTP
 from lightgbmv1_tpu.serve.server import build_server
@@ -348,6 +356,81 @@ class Smoke:
                  worst_error_over_sum_abs=worst,
                  live_slots_worst_error_over_sum_abs=worst_live)
 
+    # -- partition kernel vs the gather form --------------------------------
+    def partition(self):
+        """One round's partition of the train leg's rows in both memory
+        forms (ops/partition_pallas.py): the new leaf ids and the labels
+        must be the same integers at every slot count and both labelings;
+        the ms a round are a smoke figure, printed for the width rule."""
+        import jax.numpy as jnp
+
+        interpret = self.device["platform"] == "cpu"
+        rounds = 2 if self.rehearse else 10
+        rng = np.random.RandomState(35)
+        B = int(self.booster._gbdt.num_bins)
+        matrices = {
+            F: jnp.asarray(np.asarray(self.booster._gbdt.binned)),
+            137: jnp.asarray(rng.randint(0, B, (137, self.n), np.uint8))}
+
+        def ms(fn, *args):
+            """Median ms a round over three runs of ``rounds`` chained
+            rounds in one program (no dispatch in the figure)."""
+            @jax.jit
+            def chain(bins, leaf_id, cols):
+                def body(_, carry):
+                    new, label = fn(bins, carry[0], cols)
+                    return new % 8, carry[1] + label    # ids the slots split
+                return jax.lax.fori_loop(
+                    0, rounds, body, (leaf_id, jnp.zeros_like(leaf_id)))
+
+            jax.block_until_ready(chain(*args))
+            ticks = []
+            for _ in range(3):
+                t = time.perf_counter()
+                jax.block_until_ready(chain(*args))
+                ticks.append((time.perf_counter() - t) * 1e3 / rounds)
+            return round(float(np.median(ticks)), 4)
+
+        table = {}
+        for columns, bins in matrices.items():
+            assert bins.shape == (columns, self.n) and bins.dtype == jnp.uint8
+            for S in (1, 4, 16, 63, 64):
+                live = max(1, S - 2)
+                leaf_id = jnp.asarray(
+                    rng.randint(0, live + 3, self.n).astype(np.int32))
+                cols = dict(
+                    feats=rng.randint(0, columns, S),
+                    thrs=rng.randint(0, B, S), dls=rng.rand(S) < 0.5,
+                    leafs=np.where(np.arange(S) < live, np.arange(S), 255),
+                    nls=100 + np.arange(S), sml=rng.rand(S) < 0.5,
+                    mt=rng.randint(0, 3, S), nan=np.full(S, B - 1),
+                    zero=rng.randint(0, B, S))
+                cols = {k: jnp.asarray(v if v.dtype == bool
+                                       else v.astype(np.int32))
+                        for k, v in cols.items()}
+                row = {"rule": partition_path(columns, S, self.n,
+                                              pallas=True, plain_u8=True,
+                                              use_cat=False)}
+                for use_sub in (True, False):
+                    gather = functools.partial(partition_gather,
+                                               use_sub=use_sub)
+                    kernel = functools.partial(partition_pallas,
+                                               use_sub=use_sub,
+                                               interpret=interpret)
+                    want = gather(bins, leaf_id, cols)
+                    got = kernel(bins, leaf_id, cols)
+                    for g, w in zip(got, want):
+                        assert np.array_equal(np.asarray(g), np.asarray(w)), \
+                            f"partition kernel differs: {columns} columns, " \
+                            f"{S} slots, use_sub={use_sub}"
+                    assert int(jnp.max(got[1])) == (S if use_sub else 2 * S)
+                row["gather_ms"] = ms(gather, bins, leaf_id, cols)
+                row["kernel_ms"] = ms(kernel, bins, leaf_id, cols)
+                table[f"{columns}x{S}"] = row
+        self.say("partition", rows=self.n, integers="equal",
+                 labelings=["smaller_child", "pool_free"],
+                 ms_a_round_smoke_figure=table)
+
     def predict(self):
         before = obs_xla.compile_counts().get("predict.leaf", 0)
         leaf_dev = self.booster.predict(self.Xv, pred_leaf=True,
@@ -580,7 +663,7 @@ def main(argv=None) -> int:
     print(json.dumps({"leg": "device", **smoke.device}), flush=True)
 
     for leg in (smoke.wide, smoke.train, smoke.hist, smoke.hist256,
-                smoke.predict,
+                smoke.partition, smoke.predict,
                 smoke.serve, smoke.variants, smoke.optin, smoke.multichip):
         leg()
 
@@ -608,6 +691,7 @@ def main(argv=None) -> int:
             "hist_counts": "exact",
             "hist256_worst_error_over_sum_abs":
                 smoke.out["hist256"]["worst_error_over_sum_abs"],
+            "partition_kernel_vs_gather": "equal",
             "predict_leaf_indices": "equal",
             "predict_raw_score_max_delta":
                 smoke.out["predict"]["raw_score_max_delta"],

@@ -99,6 +99,10 @@ import numpy as np
 from jax import lax
 
 from ..ops.hist_pallas import bin_matrix
+from ..ops.partition_pallas import (MAX_LEAF_IDS, assign_rows,
+                                    count_partition_bytes,
+                                    count_partition_round, partition_pallas,
+                                    partition_path)
 from ..ops.split import (
     NO_CONSTRAINT,
     FeatureMeta,
@@ -690,6 +694,8 @@ def make_wave_grower(
     split_fn: Callable = None,
     sums_fn: Callable = None,
     bins_of_fn: Callable = None,
+    hist_method: str = "",
+    pallas_interpret: bool = False,
 ):
     """Build the jittable ``grow(binned, g3, base_mask, key)`` function.
 
@@ -723,6 +729,11 @@ def make_wave_grower(
     tables with one coalesced scatter each (_PackedStore, default) or the
     legacy per-field scatters (_FieldStore); trees are bit-identical
     either way on the exact-fp32 histogram path.
+    ``hist_method`` is the trainer's resolved histogram method and
+    ``pallas_interpret`` whether its kernels run under the Pallas
+    interpreter: where the method is ``"pallas"`` a round's partition of
+    the training rows may take the row-tiled kernel
+    (ops/partition_pallas.py ``partition_path``: a rule on shapes).
     ``async_wave_pipeline`` (default on) software-pipelines the round
     loop: the per-leaf histogram-state scatter and the valid-row routing
     of round r are DEFERRED into a pending carry and applied at the
@@ -737,12 +748,16 @@ def make_wave_grower(
     sequential path is the pin, config ``async_wave_pipeline=false``).
     """
     L = num_leaves
+    if L >= MAX_LEAF_IDS:
+        raise ValueError(f"num_leaves={L}: a round's partition packs a leaf "
+                         f"id into {MAX_LEAF_IDS.bit_length() - 1} bits")
     L1 = max(L - 1, 1)
     K = max(1, min(wave_size, L1))
     B = num_bins
     W = -(-B // 32)
     use_mc = bool(np.asarray(meta.monotone_type).any())
     use_cat = bool(np.asarray(meta.is_categorical).any())
+    has_missing = bool(np.asarray(meta.missing_type).any())
     use_inter = use_mc and monotone_mode == "intermediate"
     use_groups = interaction_groups is not None
     if use_inter:
@@ -778,6 +793,7 @@ def make_wave_grower(
         def sums_fn(g3):
             return g3.sum(axis=0)
 
+    plain_bins = bins_of_fn is None    # a feature's bins are a matrix row
     if bins_of_fn is None:
         def bins_of_fn(binned, feat):
             return binned[feat]
@@ -813,6 +829,16 @@ def make_wave_grower(
         # is the slot-count-independent in-VMEM one-hot build).  Selection
         # is by the replicated n_split, so row shards stay in lockstep.
         slot_buckets = slot_buckets_for(K, N)
+
+        def partition_path_of(S):
+            return partition_path(
+                bins.shape[0], S, N, pallas=hist_method == "pallas",
+                plain_u8=(plain_bins and bins.dtype == jnp.uint8
+                          and bins.shape[0] == F),
+                use_cat=use_cat)
+
+        count_partition_bytes(partition_path_of(slot_buckets[-1]),
+                              bins.shape[0], slot_buckets[-1], N)
         # Quantized-pass eligibility (hist_dtype_deep="int8sr"): the
         # sustained largest bucket (the depth-adaptive deep gate) and the
         # 16-slot ramp bucket of a K>16 wave.  Root (the nslots=1 call
@@ -1159,18 +1185,20 @@ def make_wave_grower(
                     sml_s = to_slot(sm_left, False)
                     iscats_s = to_slot(iscats, False) if use_cat else None
                     bitsets_s = to_slot(bitsets, 0) if use_cat else None
-                    siota = jnp.arange(S, dtype=jnp.int32)
+
+                    mt_s = meta.missing_type[feats_s]
+                    nan_s = meta.nan_bin[feats_s]
+                    zero_s = meta.zero_bin[feats_s]
 
                 def go_left_s(matrix):
                     """(S, rows) left-decision of this round's splits —
                     shared by the train partition and valid routing
                     (``go_left_rule`` is the single decision source)."""
-                    mt_k = meta.missing_type[feats_s][:, None]
                     bk = jax.vmap(lambda f: bins_of_fn(matrix, f))(feats_s)
                     bk = bk.astype(jnp.int32)
                     g = go_left_rule(bk, thrs_s[:, None], dls_s[:, None],
-                                     mt_k, meta.nan_bin[feats_s][:, None],
-                                     meta.zero_bin[feats_s][:, None])
+                                     mt_s[:, None], nan_s[:, None],
+                                     zero_s[:, None])
                     if use_cat:  # categorical bitset membership (bin-space)
                         word = jnp.zeros(bk.shape, jnp.uint32)
                         for wv in range(W):
@@ -1181,14 +1209,25 @@ def make_wave_grower(
                         g = jnp.where(iscats_s[:, None], in_set, g)
                     return g
 
+                # the train rows' partition: one algorithm (go_left_rule,
+                # then assign_rows) in two memory forms, chosen from the
+                # shapes (ops/partition_pallas.py)
+                path = partition_path_of(S)
+                count_partition_round(path, S)
                 with jax.named_scope("lgbm.partition"):
-                    gl = go_left_s(bins)                  # (S, N)
-                    mine = st.leaf_id[None, :] == leafs_s[:, None]
-                    go_r = mine & (~gl)                   # disjoint rows
-                    leaf_id = st.leaf_id + jnp.sum(
-                        jnp.where(go_r,
-                                  nls_s[:, None] - st.leaf_id[None, :],
-                                  0), axis=0)
+                    if path == "kernel":
+                        leaf_id, label = partition_pallas(
+                            bins, st.leaf_id,
+                            dict(feats=feats_s, thrs=thrs_s, dls=dls_s,
+                                 leafs=leafs_s, nls=nls_s, sml=sml_s,
+                                 mt=mt_s, nan=nan_s, zero=zero_s),
+                            use_sub=use_sub, missing=has_missing,
+                            interpret=pallas_interpret)
+                    else:
+                        leaf_id, label = (v[0] for v in assign_rows(
+                            go_left_s(bins), st.leaf_id[None, :],
+                            leafs_s[:, None], nls_s[:, None],
+                            sml_s[:, None], S, use_sub))
                     vl_new = []
                     if not pipeline:
                         # pipelined rounds defer valid routing to the
@@ -1203,20 +1242,6 @@ def make_wave_grower(
                                           nls_s[:, None] - vl[None, :],
                                           0),
                                 axis=0))
-                    if use_sub:
-                        # label only the SMALLER child of each split
-                        # (known up front from the recorded counts)
-                        in_small = gl == sml_s[:, None]
-                        label = jnp.sum(
-                            jnp.where(mine & in_small,
-                                      siota[:, None] - S, 0),
-                            axis=0) + S
-                    else:
-                        slot2 = 2 * siota[:, None] \
-                            + (~gl).astype(jnp.int32)
-                        label = jnp.sum(
-                            jnp.where(mine, slot2 - 2 * S, 0),
-                            axis=0) + 2 * S
 
                 # sustained rounds (the LARGEST bucket of a big wave) may
                 # run the configured cheaper deep precision; ramp rounds

@@ -73,10 +73,15 @@ def go_left_rule(bins, thr, dl, mt, nan_bin, zero_bin):
     the (S, N) partition pass (models/grower_wave.py ``go_left_s``) and
     the deferred valid-routing drain (``route_pending``) evaluate the
     SAME code object: the decision cannot drift between them.
-    Categorical bitset membership stays with the callers."""
+    Categorical bitset membership stays with the callers.
+
+    ``where(na, dl, bins <= thr)`` written as and / or / not: the row-tiled
+    partition kernel (ops/partition_pallas.py) evaluates this same code
+    object inside its body, and Mosaic has no select between two vectors
+    of booleans."""
     na = ((mt == MISSING_NAN) & (bins == nan_bin)) | (
         (mt == MISSING_ZERO) & (bins == zero_bin))
-    return jnp.where(na, dl, bins <= thr)
+    return (na & dl) | (~na & (bins <= thr))
 
 
 class SplitParams(NamedTuple):

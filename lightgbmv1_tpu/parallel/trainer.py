@@ -662,6 +662,8 @@ def build_trainer(
     wave_common["monotone_mode"] = mono_mode
     wave_common["fused_bookkeeping"] = config.fused_bookkeeping
     wave_common["async_wave_pipeline"] = config.async_wave_pipeline
+    wave_common["hist_method"] = method
+    wave_common["pallas_interpret"] = pallas_interpret
     # sequential-grower histogram pool cap (reference histogram_pool_size;
     # the wave/level growers use frontier-sized buffers and need no cap)
     lw_pool = dict(hist_pool_mb=config.histogram_pool_size, num_features=F)

@@ -691,3 +691,46 @@ def test_data_parallel_matches_serial_with_renewed_sums():
     np.testing.assert_allclose(
         serial.raw_train_scores(), par.raw_train_scores(), rtol=1e-3,
         atol=1e-5)
+
+
+def test_data_parallel_partition_kernel_grows_the_gather_form_s_trees(
+        monkeypatch):
+    """PR 35: the row-sharded learner calls the row-tiled partition kernel
+    on its own shard inside the ``shard_map`` (the interpreter here). Four
+    shards, a row count they do not divide, NaN in the data, two slot
+    buckets: with the kernel in every round the trees, the leaf
+    values and the training scores are those of the gather form in every
+    round, and of the serial learner with the kernel."""
+    from lightgbmv1_tpu.models import grower_wave as gw
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    rng = np.random.RandomState(35)
+    X = rng.randn(1203, 9)
+    X[rng.rand(*X.shape) < 0.05] = np.nan
+    y = (np.nan_to_num(X[:, 0]) - np.nan_to_num(X[:, 1])
+         + 0.5 * rng.randn(len(X)) > 0).astype(np.float64)
+    monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
+    common = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "hist_method": "pallas", "hist_dtype": "f32"}
+    data = {**common, "tree_learner": "data", "num_shards": 4}
+
+    def grown(cfg, path):
+        monkeypatch.setattr(gw, "partition_path", lambda *a, **k: path)
+        g = _train(cfg, X, y, 2)
+        return (_split_signature(g),
+                [t.leaf_value.tolist() for t in g.materialize_host_trees()],
+                g.raw_train_scores())
+
+    def kernel_rounds():
+        return {k: v for k, v in default_registry().snapshot().items()
+                if k.startswith('partition_rounds_traced_total{path="kernel"')}
+
+    before = kernel_rounds()
+    sig_k, values_k, scores_k = grown(data, "kernel")
+    after = kernel_rounds()
+    assert sum(after[k] - before.get(k, 0) for k in after) == 2, after
+    sig_g, values_g, scores_g = grown(data, "gather")
+    assert sig_k == sig_g and all(s[0] > 8 for s in sig_k)
+    assert values_k == values_g
+    np.testing.assert_array_equal(scores_k, scores_g)
+    assert grown(common, "kernel")[0] == sig_k

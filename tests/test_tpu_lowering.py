@@ -501,6 +501,87 @@ def test_leaf_sums_kernel_compiles_at_the_cells_sizes(one_chip):
         cc.reset_cache()
 
 
+@pytest.mark.parametrize("use_sub", [True, False], ids=["sub", "pool_free"])
+@pytest.mark.parametrize("slots", [1, 4, 16, 63])
+def test_partition_kernel_lowers(rows, slots, use_sub):
+    """PR 35's row-tiled partition kernel (ops/partition_pallas.py) at the
+    wave ladder's buckets, both labelings, a width that is no multiple of
+    a ``u8`` tile's 32 rows and a row count that leaves an edge block."""
+    from lightgbmv1_tpu.ops.partition_pallas import _COLS, partition_pallas
+
+    F, N = 137, rows[1] - 100
+    lower_for_tpu(
+        lambda b, l, c: partition_pallas(b, l, c, use_sub=use_sub),
+        jnp.zeros((F, N), jnp.uint8), jnp.zeros(N, jnp.int32),
+        {name: jnp.zeros(slots, bool if name in ("dls", "sml") else jnp.int32)
+         for name in _COLS})
+
+
+@pytest.mark.parametrize("columns,rows_,bins,prepared", [
+    (28, 10_500_000, 256, True),      # higgs-255b-train
+    (67, 4_000_000, 64, False),       # criteo-dp4-train, a chip's shard
+    (137, 2_270_296, 64, True),       # mslr-train
+])
+def test_partition_kernel_compiles_in_the_step_at_the_cells_sizes(
+        one_chip, columns, rows_, bins, prepared):
+    """A 255-leaf wave grower's ``grow`` compiled for the described v5e at
+    the three cells' sizes, each bucket's partition in the form
+    ``partition_path`` gives it: a bucket that takes the kernel leaves no
+    op under ``lgbm.partition`` with a ``(slots, rows)`` result (the
+    gather form's ``bitcast_dynamic-update-slice_fusion``, ``convert`` and
+    ``select_reduce_fusion`` were three such), and where every bucket takes
+    it, on prepared bins (a raw matrix is laid out in every histogram
+    pass), the whole program's temporaries are smaller than one 63 x rows
+    array of bytes."""
+    from lightgbmv1_tpu.models import grower_wave as gw
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+    from lightgbmv1_tpu.ops.partition_pallas import KERNEL_NAME, partition_path
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    grow = gw.make_wave_grower(
+        num_leaves=255, num_bins=bins, meta=_probe_meta(columns, bins),
+        params=SplitParams(min_data_in_leaf=1.0,
+                           min_sum_hessian_in_leaf=100.0), wave_size=63,
+        hist_wave_fn=lambda b, g, l, n, deep=False: hist_wave(
+            b, g, l, n, bins, method="pallas",
+            precision="bf16" if deep else "bf16x2"),
+        hist_method="pallas")
+    matrix = shape((columns, rows_), jnp.uint8)
+    if prepared:
+        matrix = jax.tree_util.tree_map(
+            lambda x: shape(x.shape, x.dtype),
+            jax.eval_shape(lambda b: prepare_hist_bins(b, bins), matrix))
+    got = compile_for_chip(
+        lambda b, g: grow(b, g, jnp.ones(columns, bool),
+                          jax.random.PRNGKey(0)),
+        matrix, shape((rows_, 3), jnp.float32))
+    txt = got.as_text()
+    paths = {S: partition_path(columns, S, rows_, pallas=True,
+                               plain_u8=True, use_cat=False)
+             for S in (4, 16, 63)}
+    assert paths[63] == "kernel"
+    kernels = sum(p == "kernel" for p in paths.values())
+    assert len(re.findall(
+        rf"%{KERNEL_NAME}[.0-9]* = .*custom_call_target=\"tpu_custom_call\"",
+        txt)) == kernels
+    tall = {}       # first dimension -> ops under lgbm.partition of rows_ wide
+    for line in txt.splitlines():
+        m = re.search(rf"= \w+\[(\d+),{rows_}\]", line)
+        if m and "lgbm.partition/" in line:
+            tall.setdefault(int(m.group(1)), []).append(line.split(" = ")[0])
+    for S, path in paths.items():
+        pad8 = -(-S // 8) * 8
+        if path == "kernel":
+            assert S not in tall and pad8 not in tall, (S, tall.get(S))
+        else:
+            assert S in tall
+    if kernels == 3 and prepared:
+        assert got.memory_analysis().temp_size_in_bytes < 63 * rows_
+
+
 @pytest.fixture(scope="module")
 def predictor():
     rng = np.random.RandomState(4)
