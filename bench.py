@@ -140,7 +140,8 @@ def probe_round_schedule(model, n_trees=5, K=None):
     structure + gains determine the executed round grouping
     (grower_wave.replay_wave_schedule: no host callback inside the timed
     program and no device round-trip at all).  A CPU test pins replay ==
-    the live _ROUND_PROBE counts."""
+    the grower's own per-bucket round counts (``WaveState.rounds``, the
+    ``rounds`` field of the per-tree record: tests/test_wave_bucket.py)."""
     from lightgbmv1_tpu.models.grower_wave import (auto_wave_size,
                                                     replay_wave_schedule)
 

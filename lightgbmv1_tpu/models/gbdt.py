@@ -224,6 +224,10 @@ class GBDT:
 
         self.models: List[Optional[HostTree]] = []  # flat: iter-major, class-minor
         self._device_trees: List[TreeArrays] = []
+        # the last iteration's rounds by slot bucket, as the wave grower
+        # counted them on the device: arrays to add up, read only when the
+        # iteration's record is (rounds_later); None where no grower said
+        self._rounds = None
         self._model_shrink: List[float] = []
         self._model_bias: List[float] = []
         # Host trees are materialized lazily (one batched device_get at the
@@ -370,13 +374,16 @@ class GBDT:
             # binned/valid_binned ride as arguments (NOT closure constants):
             # closed-over process-spanning global arrays cannot be baked into
             # the jaxpr on multi-host meshes
-            s = train_score[:, 0] if K == 1 else train_score
+            with jax.named_scope("lgbm.objective"):
+                s = train_score[:, 0] if K == 1 else train_score
             grad, hess = self._objective_grads(s, iteration)
             if grad.ndim == 1:
-                grad, hess = grad[:, None], hess[:, None]
+                with jax.named_scope("lgbm.objective"):
+                    grad, hess = grad[:, None], hess[:, None]
             bag = self._bag_fraction_mask(None, iteration)
             trees = []
             leaf_ids = []
+            rounds = []
             train_preds = []
             valid_preds = [[] for _ in valid_binned]
             grow_valids = getattr(self._grow, "_supports_valids", False)
@@ -387,14 +394,17 @@ class GBDT:
                     # the wave grower routes valid rows through each
                     # round's splits: valid predictions become a
                     # leaf_value gather (no per-tree device walk)
-                    tree_dev, leaf_id, _, vlids = self._grow(
+                    tree_dev, leaf_id, third, vlids = self._grow(
                         binned, g3, feat_masks[k], key, cegb_used,
                         valids=tuple(valid_binned))
                 else:
-                    tree_dev, leaf_id, _ = self._grow(
+                    tree_dev, leaf_id, third = self._grow(
                         binned, g3, feat_masks[k], key, cegb_used
                     )
                     vlids = None
+                # the wave grower counts its rounds by slot bucket
+                # (grower_wave.RootAndRounds); the others hand back sums
+                rounds.append(getattr(third, "rounds", None))
                 if self._cegb_enabled:
                     cegb_used = self._update_cegb_state(
                         cegb_used, tree_dev, leaf_id)
@@ -434,8 +444,11 @@ class GBDT:
                         for vs, vp in zip(valid_scores, valid_preds))
                 stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
                 leaf_ids = jnp.stack(leaf_ids)
+            # beside the stacked trees, not a field of them: ``bookkeep``
+            # slices those field by field, and keeps this as it is
+            rounds = None if None in rounds else sum(rounds)
             return (train_score, valid_scores, stacked, leaf_ids,
-                    cegb_used)
+                    cegb_used, rounds)
 
         self._step_fn = step
         # args 2/3 are the train/valid score caches — the buffers the
@@ -495,6 +508,21 @@ class GBDT:
         if policy is None or not trees:
             return 0
         return lambda: sum(count_marked(t, policy) for t in trees)
+
+    def rounds_later(self):
+        """For the iteration record (obs/trace.py): a callable giving the
+        rounds the last iteration's trees ran in each slot bucket of the
+        wave grower, smallest bucket first (``(b4, b16, bK)``, ``(bK,)``
+        without a ladder: grower_wave.slot_buckets_for), or None where the
+        grower has no rounds.  The device counted them (``WaveState.rounds``)
+        and they stay there until the record is read: no host operation
+        and no wait here.  Taken once: an iteration that counts none
+        (DART's own step) does not hand on the one before it."""
+        counted, self._rounds = self._rounds, None
+        if counted is None:
+            return None
+        return lambda: tuple(
+            int(n) for n in np.sum([np.asarray(c) for c in counted], axis=0))
 
     def check_finite_boundary(self) -> None:
         """Iteration-boundary finite check (``finite_guard=warn|raise``).
@@ -569,8 +597,8 @@ class GBDT:
                         start_iter, feat_masks_all, cegb_used):
                 def body(carry, fm):
                     ts, vs, it, cu = carry
-                    ts, vs, stacked, _, cu = step_fn(binned, valid_binned,
-                                                     ts, vs, it, fm, cu)
+                    ts, vs, stacked, _, cu, _ = step_fn(
+                        binned, valid_binned, ts, vs, it, fm, cu)
                     return (ts, vs, it + 1, cu), stacked
 
                 (ts, vs, _, cu), trees = jax.lax.scan(
@@ -643,8 +671,9 @@ class GBDT:
                     self._cegb_used)
         with obs_trace.phase_span("dispatch"):
             (new_train, new_valid, stacked, leaf_ids,
-             self._cegb_used) = self._step(*args)
+             self._cegb_used, rounds) = self._step(*args)
         with obs_trace.phase_span("bookkeep"):
+            self._rounds = None if rounds is None else [rounds]
             self._train_scores.score = new_train
             for vs, s in zip(self._valid_scores, new_valid):
                 vs.score = s
@@ -754,15 +783,16 @@ class GBDT:
         update is exact: a row 'used' precisely the features on its final
         leaf's root path (the union over the tree of the reference's
         per-split row marking, cost_effective_gradient_boosting.hpp:110)."""
-        if isinstance(state, tuple):
-            used, marks = state
-            used = used | tree_used_features(tree_dev, used.shape[0])
-            from .tree import leaf_path_features
+        with jax.named_scope("lgbm.select"):
+            if isinstance(state, tuple):
+                used, marks = state
+                used = used | tree_used_features(tree_dev, used.shape[0])
+                from .tree import leaf_path_features
 
-            pf = leaf_path_features(tree_dev, marks.shape[1])
-            marks = marks | pf[leaf_id]
-            return (used, marks)
-        return state | tree_used_features(tree_dev, state.shape[0])
+                pf = leaf_path_features(tree_dev, marks.shape[1])
+                marks = marks | pf[leaf_id]
+                return (used, marks)
+            return state | tree_used_features(tree_dev, state.shape[0])
 
     def _sample_g3(self, grad_k, hess_k, bag, iteration):
         """Assemble the (N, 3) [grad, hess, count] channels with bagging.
@@ -807,17 +837,19 @@ class GBDT:
             if custom_grad is None:
                 grad, hess = self._gradients()
             bag = self._bagging_mask(self.iter)
-            new_trees = []
+            new_trees, rounds = [], []
             for k in range(self.num_class):
                 g3 = self._sample_g3(grad[:, k], hess[:, k], bag, self.iter)
                 key = jax.random.fold_in(self._rng_key, self.iter * self.num_class + k)
                 base_mask = jnp.asarray(self._tree_feature_mask())
-                tree_dev, leaf_id, root_sum = self._grow(
+                tree_dev, leaf_id, third = self._grow(
                     self._grow_binned, g3, base_mask, key, self._cegb_used)
+                rounds.append(getattr(third, "rounds", None))
                 if self._cegb_enabled:
                     self._cegb_used = self._update_cegb_state(
                         self._cegb_used, tree_dev, leaf_id)
                 new_trees.append(self._finish_tree(tree_dev, leaf_id, k))
+            self._rounds = None if None in rounds else rounds
         self.iter += 1
         return check_stop and self._stopped(new_trees)
 

@@ -147,9 +147,9 @@ def replay_wave_schedule(trees, K: int):
     IS an internal node of the final tree — so replaying the ranked
     commit order over internal nodes reproduces the executed round
     grouping without any device round-trip or host callback in the
-    timed program (_ROUND_PROBE, a jax.debug callback, is the live
-    counter; the parity test ties the two together,
-    tests/test_wave_bucket.py).
+    timed program (the live count is the grower's own: ``WaveState.rounds``,
+    handed back as ``RootAndRounds.rounds``; the parity test ties the two
+    together, tests/test_wave_bucket.py).
     Caveats: fp-equal gain ties replay by node index (the device breaks
     ties by leaf index), and the intermediate-monotone same-round
     deferral is not modeled — neither occurs in the bench configs."""
@@ -172,6 +172,16 @@ def replay_wave_schedule(trees, K: int):
     return out
 
 
+def rounds_by_bucket(schedule, slot_buckets):
+    """One replayed tree's rounds counted by the slot bucket each ran in,
+    in ``WaveState.rounds``' order: the grower's own rule (``s_idx``), a
+    round of ``n`` splits takes the smallest bucket that holds them."""
+    counts = [0] * len(slot_buckets)
+    for n in schedule:
+        counts[sum(n > S for S in slot_buckets[:-1])] += 1
+    return tuple(counts)
+
+
 def auto_wave_size(num_leaves: int) -> int:
     """The auto (leafwise_wave_size=0) wave size policy — num_leaves // 4
     (measured optimum with the smaller-child subtraction pass, PERF.md).
@@ -190,14 +200,29 @@ def slot_buckets_for(K: int, N: int):
         return sorted({4, min(16, K), K})
     return [K]
 
-# Optional host callback fired once per EXECUTED wave round with the
-# round's realized split count (jax.debug.callback in the while-loop
-# body).  bench.py sets this on a probe model to record the ACTUAL
-# rounds-per-tree schedule behind `wave_rounds_per_tree` and the per-iter
-# histogram cost — the counting role of the reference's USE_TIMETAG global
-# timers (include/LightGBM/utils/common.h:1054-1138).  None (the default)
-# adds nothing to the traced program.
-_ROUND_PROBE = None
+
+# ``jax.named_scope`` of the root's histogram pass, once a tree
+ROOT_ROUND_SCOPE = "lgbm.round.root"
+
+
+def round_scope(S: int, slot_buckets) -> str:
+    """``jax.named_scope`` of a round taken in slot bucket ``S`` of the
+    ladder ``slot_buckets`` (``slot_buckets_for``): ``lgbm.round.b4`` /
+    ``lgbm.round.b16``, and ``lgbm.round.bK`` for the largest bucket
+    whatever its K (the only one where there is no ladder).  It goes round
+    the whole of ``round_pass``, outside the scopes that has, which stay
+    components of the ops' paths: a bucket's device time is read beside
+    the rounds the tree ran in it (``WaveState.rounds``)."""
+    return "lgbm.round." + ("bK" if S == slot_buckets[-1] else f"b{S}")
+
+
+class RootAndRounds(NamedTuple):
+    """What the wave grower hands back third, where the other growers hand
+    back the root's sums alone."""
+    root_sum: jax.Array       # (3,) grad / hess / count over the rows
+    rounds: jax.Array         # (len(slot_buckets_for(K, N)),) int32: the
+                              # rounds this tree ran in each slot bucket,
+                              # smallest bucket first
 
 
 def _box_adjacency_per_feature(lo, hi, feats):
@@ -284,6 +309,9 @@ class WaveState(NamedTuple):
                               # unless interaction constraints are on
     num_leaves: jax.Array     # () int32
     done: jax.Array           # () bool
+    rounds: jax.Array         # (buckets,) int32 — rounds run so far in each
+                              # slot bucket (the per-tree record's count
+                              # beside ``lgbm.round.*``'s device time)
     pending: dict = {}        # async_wave_pipeline: the previous round's
                               # DEFERRED commits — the (2K, F, B, 3) child
                               # histograms + their scatter indices and the
@@ -849,8 +877,10 @@ def make_wave_grower(
                 S for S in slot_buckets
                 if (S == K and K >= 32) or (S == 16 and S < K))
 
-        leaf_id0 = jnp.zeros(N, jnp.int32)
-        hist0 = hist_wave_fn(binned, g3, leaf_id0, 1, deep=False)[0]
+        with jax.named_scope("lgbm.select"):
+            leaf_id0 = jnp.zeros(N, jnp.int32)
+        with jax.named_scope(ROOT_ROUND_SCOPE):
+            hist0 = hist_wave_fn(binned, g3, leaf_id0, 1, deep=False)[0]
         # smaller-child + subtraction mode: build K child histograms per
         # round instead of 2K (halves the one-hot MXU pass and, in
         # data-parallel mode, the psum volume — the reference's
@@ -916,25 +946,26 @@ def make_wave_grower(
             metadata (dead slots carry leaf id L and match no row).  The
             per-row update terms are int32 — exact and summation-order
             free — so deferral is bit-identical to in-round routing."""
-            feats_k, thrs_k, dls_k = p["feats"], p["thrs"], p["dls"]
-            leafs_k, nls_k = p["leafs"], p["nls"]
-            mt_k = meta.missing_type[feats_k][:, None]
-            bk = jax.vmap(lambda f: bins_of_fn(vb, f))(feats_k)
-            bk = bk.astype(jnp.int32)
-            g = go_left_rule(bk, thrs_k[:, None], dls_k[:, None], mt_k,
-                             meta.nan_bin[feats_k][:, None],
-                             meta.zero_bin[feats_k][:, None])
-            if use_cat:
-                word = jnp.zeros(bk.shape, jnp.uint32)
-                for wv in range(W):
-                    word = jnp.where((bk >> 5) == wv,
-                                     p["bitsets"][:, wv][:, None], word)
-                in_set = ((word >> (bk.astype(jnp.uint32) & 31)) & 1) == 1
-                g = jnp.where(p["iscats"][:, None], in_set, g)
-            mine = vl[None, :] == leafs_k[:, None]
-            go_rv = mine & (~g)
-            return vl + jnp.sum(
-                jnp.where(go_rv, nls_k[:, None] - vl[None, :], 0), axis=0)
+            with jax.named_scope("lgbm.partition"):
+                feats_k, thrs_k, dls_k = p["feats"], p["thrs"], p["dls"]
+                leafs_k, nls_k = p["leafs"], p["nls"]
+                mt_k = meta.missing_type[feats_k][:, None]
+                bk = jax.vmap(lambda f: bins_of_fn(vb, f))(feats_k)
+                bk = bk.astype(jnp.int32)
+                g = go_left_rule(bk, thrs_k[:, None], dls_k[:, None], mt_k,
+                                 meta.nan_bin[feats_k][:, None],
+                                 meta.zero_bin[feats_k][:, None])
+                if use_cat:
+                    word = jnp.zeros(bk.shape, jnp.uint32)
+                    for wv in range(W):
+                        word = jnp.where((bk >> 5) == wv,
+                                         p["bitsets"][:, wv][:, None], word)
+                    in_set = ((word >> (bk.astype(jnp.uint32) & 31)) & 1) == 1
+                    g = jnp.where(p["iscats"][:, None], in_set, g)
+                mine = vl[None, :] == leafs_k[:, None]
+                go_rv = mine & (~g)
+                return vl + jnp.sum(
+                    jnp.where(go_rv, nls_k[:, None] - vl[None, :], 0), axis=0)
 
         with jax.named_scope("lgbm.select"):
             with jax.named_scope(POOL_SCOPE):
@@ -955,6 +986,7 @@ def make_wave_grower(
                            else jnp.zeros((1, 1), bool)),
                 num_leaves=jnp.asarray(1, jnp.int32),
                 done=jnp.asarray(L <= 1),
+                rounds=jnp.zeros(len(slot_buckets), jnp.int32),
                 pending=pend0,
             )
 
@@ -1018,8 +1050,6 @@ def make_wave_grower(
                         kept = kept.at[j].set(kept[j] & (~clash))
                     valid = kept
                 n_split = valid.sum()
-                if _ROUND_PROBE is not None:   # bench round-schedule probe
-                    jax.debug.callback(_ROUND_PROBE, n_split)
                 order = jnp.cumsum(valid.astype(jnp.int32)) - 1
                 nodes = st.num_leaves - 1 + order                 # (K,) int32
                 nls = st.num_leaves + order                       # new right leaves
@@ -1169,118 +1199,127 @@ def make_wave_grower(
             # ``order`` (cumsum of valid — dense even when the intermediate-
             # monotone deferral clears mid-prefix picks).
             def round_pass(S):
-                with jax.named_scope("lgbm.select"):
-                    sidx = jnp.where(valid, order_c, S)          # (K,) slot|drop
+                with jax.named_scope(round_scope(S, slot_buckets)):
+                    with jax.named_scope("lgbm.select"):
+                        sidx = jnp.where(valid, order_c, S)  # (K,) slot|drop
 
-                    def to_slot(v, fill):
-                        base = jnp.full((S,) + v.shape[1:], fill, v.dtype)
-                        return base.at[sidx].set(v, mode="drop")
+                        def to_slot(v, fill):
+                            base = jnp.full((S,) + v.shape[1:], fill, v.dtype)
+                            return base.at[sidx].set(v, mode="drop")
 
-                    feats_s = to_slot(feats, 0)
-                    thrs_s = to_slot(thrs, 0)
-                    dls_s = to_slot(dls, False)
-                    # empty slots carry leaf id L: matches no row's leaf
-                    leafs_s = to_slot(leafs, L)
-                    nls_s = to_slot(nls, 0)
-                    sml_s = to_slot(sm_left, False)
-                    iscats_s = to_slot(iscats, False) if use_cat else None
-                    bitsets_s = to_slot(bitsets, 0) if use_cat else None
+                        feats_s = to_slot(feats, 0)
+                        thrs_s = to_slot(thrs, 0)
+                        dls_s = to_slot(dls, False)
+                        # empty slots carry leaf id L: matches no row's leaf
+                        leafs_s = to_slot(leafs, L)
+                        nls_s = to_slot(nls, 0)
+                        sml_s = to_slot(sm_left, False)
+                        iscats_s = to_slot(iscats, False) if use_cat else None
+                        bitsets_s = to_slot(bitsets, 0) if use_cat else None
 
-                    mt_s = meta.missing_type[feats_s]
-                    nan_s = meta.nan_bin[feats_s]
-                    zero_s = meta.zero_bin[feats_s]
+                        mt_s = meta.missing_type[feats_s]
+                        nan_s = meta.nan_bin[feats_s]
+                        zero_s = meta.zero_bin[feats_s]
 
-                def go_left_s(matrix):
-                    """(S, rows) left-decision of this round's splits —
-                    shared by the train partition and valid routing
-                    (``go_left_rule`` is the single decision source)."""
-                    bk = jax.vmap(lambda f: bins_of_fn(matrix, f))(feats_s)
-                    bk = bk.astype(jnp.int32)
-                    g = go_left_rule(bk, thrs_s[:, None], dls_s[:, None],
-                                     mt_s[:, None], nan_s[:, None],
-                                     zero_s[:, None])
-                    if use_cat:  # categorical bitset membership (bin-space)
-                        word = jnp.zeros(bk.shape, jnp.uint32)
-                        for wv in range(W):
-                            word = jnp.where((bk >> 5) == wv,
-                                             bitsets_s[:, wv][:, None], word)
-                        in_set = ((word >> (bk.astype(jnp.uint32) & 31))
-                                  & 1) == 1
-                        g = jnp.where(iscats_s[:, None], in_set, g)
-                    return g
+                    def go_left_s(matrix):
+                        """(S, rows) left-decision of this round's splits —
+                        shared by the train partition and valid routing
+                        (``go_left_rule`` is the single decision source)."""
+                        bk = jax.vmap(lambda f: bins_of_fn(matrix, f))(feats_s)
+                        bk = bk.astype(jnp.int32)
+                        g = go_left_rule(bk, thrs_s[:, None], dls_s[:, None],
+                                         mt_s[:, None], nan_s[:, None],
+                                         zero_s[:, None])
+                        if use_cat:  # categorical bitset membership
+                            word = jnp.zeros(bk.shape, jnp.uint32)
+                            for wv in range(W):
+                                word = jnp.where(
+                                    (bk >> 5) == wv,
+                                    bitsets_s[:, wv][:, None], word)
+                            in_set = ((word >> (bk.astype(jnp.uint32) & 31))
+                                      & 1) == 1
+                            g = jnp.where(iscats_s[:, None], in_set, g)
+                        return g
 
-                # the train rows' partition: one algorithm (go_left_rule,
-                # then assign_rows) in two memory forms, chosen from the
-                # shapes (ops/partition_pallas.py)
-                path = partition_path_of(S)
-                count_partition_round(path, S)
-                with jax.named_scope("lgbm.partition"):
-                    if path == "kernel":
-                        leaf_id, label = partition_pallas(
-                            bins, st.leaf_id,
-                            dict(feats=feats_s, thrs=thrs_s, dls=dls_s,
-                                 leafs=leafs_s, nls=nls_s, sml=sml_s,
-                                 mt=mt_s, nan=nan_s, zero=zero_s),
-                            use_sub=use_sub, missing=has_missing,
-                            interpret=pallas_interpret)
+                    # the train rows' partition: one algorithm (go_left_rule,
+                    # then assign_rows) in two memory forms, chosen from the
+                    # shapes (ops/partition_pallas.py)
+                    path = partition_path_of(S)
+                    count_partition_round(path, S)
+                    with jax.named_scope("lgbm.partition"):
+                        if path == "kernel":
+                            leaf_id, label = partition_pallas(
+                                bins, st.leaf_id,
+                                dict(feats=feats_s, thrs=thrs_s, dls=dls_s,
+                                     leafs=leafs_s, nls=nls_s, sml=sml_s,
+                                     mt=mt_s, nan=nan_s, zero=zero_s),
+                                use_sub=use_sub, missing=has_missing,
+                                interpret=pallas_interpret)
+                        else:
+                            leaf_id, label = (v[0] for v in assign_rows(
+                                go_left_s(bins), st.leaf_id[None, :],
+                                leafs_s[:, None], nls_s[:, None],
+                                sml_s[:, None], S, use_sub))
+                        vl_new = []
+                        if not pipeline:
+                            # pipelined rounds defer valid routing to the
+                            # next body's drain (route_pending) — off this
+                            # round's critical path, bit-identical updates
+                            for vb, vl in zip(valids, st.valid_lids):
+                                gv = go_left_s(vb)
+                                mine_v = vl[None, :] == leafs_s[:, None]
+                                go_rv = mine_v & (~gv)
+                                vl_new.append(vl + jnp.sum(
+                                    jnp.where(go_rv,
+                                              nls_s[:, None] - vl[None, :],
+                                              0),
+                                    axis=0))
+
+                    # sustained rounds (the LARGEST bucket of a big wave) may
+                    # run the configured cheaper deep precision; ramp rounds
+                    # and the root pass always keep full precision.  With
+                    # bucketing off (small N) there ARE no separate ramp
+                    # variants — everything stays full precision
+                    deep = S == K and K >= 32 and len(slot_buckets) > 1
+                    nsl = S if use_sub else 2 * S
+                    if S in quant_buckets:
+                        # stochastic-rounded int8 pass: integer histogram +
+                        # per-slot dequant scales, rounding stream keyed per
+                        # (tree, round)
+                        h, hsc = hist_wave_quant_fn(binned, g3, label, nsl,
+                                                    rkey)
                     else:
-                        leaf_id, label = (v[0] for v in assign_rows(
-                            go_left_s(bins), st.leaf_id[None, :],
-                            leafs_s[:, None], nls_s[:, None],
-                            sml_s[:, None], S, use_sub))
-                    vl_new = []
-                    if not pipeline:
-                        # pipelined rounds defer valid routing to the
-                        # next body's drain (route_pending) — off this
-                        # round's critical path, bit-identical updates
-                        for vb, vl in zip(valids, st.valid_lids):
-                            gv = go_left_s(vb)
-                            mine_v = vl[None, :] == leafs_s[:, None]
-                            go_rv = mine_v & (~gv)
-                            vl_new.append(vl + jnp.sum(
-                                jnp.where(go_rv,
-                                          nls_s[:, None] - vl[None, :],
-                                          0),
-                                axis=0))
-
-                # sustained rounds (the LARGEST bucket of a big wave) may
-                # run the configured cheaper deep precision; ramp rounds
-                # and the root pass always keep full precision.  With
-                # bucketing off (small N) there ARE no separate ramp
-                # variants — everything stays full precision
-                deep = S == K and K >= 32 and len(slot_buckets) > 1
-                nsl = S if use_sub else 2 * S
-                if S in quant_buckets:
-                    # stochastic-rounded int8 pass: integer histogram +
-                    # per-slot dequant scales, rounding stream keyed per
-                    # (tree, round)
-                    h, hsc = hist_wave_quant_fn(binned, g3, label, nsl,
-                                                rkey)
-                else:
-                    h = hist_wave_fn(binned, g3, label, nsl, deep=deep)
-                    hsc = jnp.ones((nsl, 3), jnp.float32)
-                full = 2 * K if not use_sub else K
-                if h.shape[0] < full:   # pad to the bucket-invariant width
-                    with jax.named_scope("lgbm.select"), \
-                            jax.named_scope(POOL_SCOPE):
-                        h = jnp.concatenate(
-                            [h, jnp.zeros((full - h.shape[0],) + h.shape[1:],
-                                          h.dtype)], axis=0)
-                        # padded slots dequantize as identity
-                        hsc = jnp.concatenate(
-                            [hsc, jnp.ones((full - hsc.shape[0], 3),
-                                           hsc.dtype)], axis=0)
-                return (h, hsc, leaf_id) + tuple(vl_new)
+                        h = hist_wave_fn(binned, g3, label, nsl, deep=deep)
+                        hsc = jnp.ones((nsl, 3), jnp.float32)
+                    full = 2 * K if not use_sub else K
+                    if h.shape[0] < full:   # pad to the bucket-invariant width
+                        with jax.named_scope("lgbm.select"), \
+                                jax.named_scope(POOL_SCOPE):
+                            h = jnp.concatenate(
+                                [h, jnp.zeros((full - h.shape[0],)
+                                              + h.shape[1:], h.dtype)],
+                                axis=0)
+                            # padded slots dequantize as identity
+                            hsc = jnp.concatenate(
+                                [hsc, jnp.ones((full - hsc.shape[0], 3),
+                                               hsc.dtype)], axis=0)
+                    return (h, hsc, leaf_id) + tuple(vl_new)
 
             if len(slot_buckets) > 1:
                 with jax.named_scope("lgbm.select"):
                     s_idx = jnp.zeros((), jnp.int32)
                     for S in slot_buckets[:-1]:
                         s_idx = s_idx + (n_split > S).astype(jnp.int32)
+                    # the bucket this round is taken in, counted where it
+                    # is chosen: exact by construction
+                    rounds = st.rounds + (
+                        jnp.arange(len(slot_buckets)) == s_idx)
                 outs = lax.switch(
                     s_idx,
                     [lambda S=S: round_pass(S) for S in slot_buckets])
             else:
+                with jax.named_scope("lgbm.select"):
+                    rounds = st.rounds + 1
                 outs = round_pass(slot_buckets[0])
             h_slot, hscale, leaf_id = outs[0], outs[1], outs[2]
             new_vlids = vlids_in if pipeline else tuple(outs[3:])
@@ -1400,6 +1439,7 @@ def make_wave_grower(
                                if use_groups else st.leaf_used),
                     num_leaves=st.num_leaves + n_split,
                     done=st.done | (n_split == 0),
+                    rounds=rounds,
                     pending=new_pending,
                 )
 
@@ -1417,9 +1457,10 @@ def make_wave_grower(
             # kill-at-k bit-exact resume guarantee is unchanged.
             vlids_out = tuple(route_pending(st.pending, vb, vl)
                               for vb, vl in zip(valids, vlids_out))
+        third = RootAndRounds(root_sum, st.rounds)
         if valids:
-            return tree, st.leaf_id, root_sum, vlids_out
-        return tree, st.leaf_id, root_sum
+            return tree, st.leaf_id, third, vlids_out
+        return tree, st.leaf_id, third
 
     grow._supports_valids = True
     return grow

@@ -235,6 +235,7 @@ def renew_tree(tree, leaf_id, g3, params: SplitParams, policy: RenewPolicy,
         mark, anc = inherited_error(
             jnp, tree.left_child, tree.right_child, tree.num_leaves,
             tree.internal_count, tree.leaf_count, policy)
+        any_marked = mark.any()
 
     def renewed(_):
         S_leaf = leaf_sums_fn(leaf_id, g3)                    # (L, 3)
@@ -264,7 +265,7 @@ def renew_tree(tree, leaf_id, g3, params: SplitParams, policy: RenewPolicy,
                     redo, gain.astype(jnp.float32), tree.split_gain))
             return new
 
-    return lax.cond(mark.any(), renewed, lambda _: tree, None)
+    return lax.cond(any_marked, renewed, lambda _: tree, None)
 
 
 def with_renewal(grow: Callable, params: SplitParams, policy: RenewPolicy,

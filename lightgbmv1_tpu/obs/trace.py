@@ -23,14 +23,18 @@ Two kinds of span, one ring:
   the ``phase_span()`` children inside it (``train.prepare`` /
   ``train.dispatch`` / ``train.bookkeep`` / ``train.wait``) carry the
   tree's ``iteration`` and add their time to it, and when it closes it
-  writes ONE tuple ``(iteration, t0_ns, prepare_ns, dispatch_ns,
-  bookkeep_ns, wait_ns, total_ns)`` into a bounded ring
-  (``iteration_records()``): which host phase a slow tree's extra
-  milliseconds passed in, without a profiler and without arming.  It is
-  host time: where the runtime blocks the host in a phase until the
-  device is done (a TPU does, in ``bookkeep``; ``GBDT._stopped``), that
-  phase holds the device's time too, and the record cannot tell them
-  apart.
+  writes ONE tuple of nine fields ``(iteration, t0_ns, prepare_ns,
+  dispatch_ns, bookkeep_ns, wait_ns, total_ns, renewed, rounds)`` into a
+  bounded ring (``iteration_records()``): which host phase a slow tree's
+  extra milliseconds passed in, without a profiler and without arming.
+  It is host time: where the runtime blocks the host in a phase until
+  the device is done (a TPU does, in ``bookkeep``; ``GBDT._stopped``),
+  that phase holds the device's time too, and the record cannot tell
+  them apart.  The last two fields are the device's own counts, read
+  back when the record is: ``renewed`` (models/renew.py) and ``rounds``,
+  the rounds the iteration's trees ran in each slot bucket of the wave
+  grower (``(b4, b16, bK)``): a tree that took one more round reads so
+  here, a tree the host stalled on reads the same rounds.
 
 Common to both:
 
@@ -85,7 +89,7 @@ _t_arm_unix_ns = 0              # wall-clock anchor of the SAME instant —
                                 # the cross-process alignment key agg.py
                                 # merges timelines on
 # (iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns, wait_ns,
-# total_ns), one a tree, armed or not
+# total_ns, renewed, rounds), one a tree, armed or not
 _iterations: collections.deque = collections.deque(maxlen=ITERATION_RING)
 
 _tls = threading.local()
@@ -287,7 +291,7 @@ class _IterationSpan(_BridgedSpan):
     """``train.iteration``: the bridged span one tree runs under, which
     its ``phase_span`` children add their time to."""
 
-    __slots__ = ("iteration", "phase_ns", "renewed", "_outer")
+    __slots__ = ("iteration", "phase_ns", "renewed", "rounds", "_outer")
 
     def __init__(self, iteration: int):
         super().__init__("train.iteration", "train",
@@ -299,6 +303,9 @@ class _IterationSpan(_BridgedSpan):
         # callable that gives it when the record is read, so that writing
         # the record never waits for the device
         self.renewed = None
+        # rounds the iteration's trees ran in each slot bucket of the wave
+        # grower (models/grower_wave.py), likewise a tuple or a callable
+        self.rounds = None
 
     def __enter__(self):
         self._outer = getattr(_tls, "iteration", None)
@@ -311,7 +318,8 @@ class _IterationSpan(_BridgedSpan):
         # the one place an iteration is written down, armed or not
         _iterations.append((self.iteration, self.t0,
                             *(self.phase_ns[p] for p in ITERATION_PHASES),
-                            self.dur_ns, _Later(self.renewed)))
+                            self.dur_ns, _Later(self.renewed),
+                            _Later(self.rounds)))
         return False
 
 
@@ -359,12 +367,17 @@ def phase_span(phase: str) -> _PhaseSpan:
 
 
 def iteration_records() -> List[tuple]:
-    """The last ``ITERATION_RING`` iterations, oldest first, each
-    ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns, wait_ns,
-    total_ns, renewed)``: times on the ``perf_counter_ns`` clock, and
-    ``renewed`` the nodes and leaves of the iteration's trees whose stored
-    sums were measured again from the rows (None where nobody said)."""
-    return [(*r[:-1], r[-1].get()) for r in _iterations]
+    """The last ``ITERATION_RING`` iterations, oldest first, each the
+    nine fields ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns,
+    wait_ns, total_ns, renewed, rounds)``: times on the
+    ``perf_counter_ns`` clock; ``renewed`` the nodes and leaves of the
+    iteration's trees whose stored sums were measured again from the rows;
+    ``rounds`` a tuple of the rounds those trees ran in each slot bucket
+    of the wave grower, smallest bucket first (``(b4, b16, bK)``, or
+    ``(bK,)`` without a ladder).  The last two are None where nobody
+    said (``rounds``: a grower that has no rounds), and are read from the
+    device on the first call that reaches them."""
+    return [(*r[:-2], r[-2].get(), r[-1].get()) for r in _iterations]
 
 
 # ---------------------------------------------------------------------------

@@ -95,6 +95,12 @@ MAX_ROW_TILE = 1024       # the largest row tile _row_tile_for returns: rows
                           # padded to it serve every slot bucket's tile
 _LANES = 128              # byte columns a stored u8 row occupies at least
 _COUNT_SCALE = 64.0       # power-of-two count quantizer => exact counts
+# ``jax.named_scope`` of what a pass does to make the kernel's operands and
+# is not the kernel: the bin layout where the pass was handed the raw
+# matrix (every pass of a row-sharded learner or a streamed block), and
+# g3's and the labels' padding and transposition.  Inside ``lgbm.hist``
+# wherever the pass is; placement's one-off layout is set-up and has none.
+LAYOUT_SCOPE = "lgbm.layout"
 
 
 def kernel_width(num_bins: int) -> int:
@@ -566,8 +572,9 @@ def hist_leaves_pallas(
     if isinstance(binned, HistBins):
         _count_operand(stored, tile_cols, windows, nfb, B)
     else:       # counted there
-        binned = prepare_hist_bins(binned, B, packed, row_tile=T,
-                                   resident=False)
+        with jax.named_scope(LAYOUT_SCOPE):
+            binned = prepare_hist_bins(binned, B, packed, row_tile=T,
+                                       resident=False)
     n_pad = binned.blocks[0].shape[0]
     if (n_pad < N or n_pad % T or len(binned.blocks) != -(-nfb // windows)
             or (binned.tile_cols, binned.windows) != (tile_cols, windows)):
@@ -582,14 +589,16 @@ def hist_leaves_pallas(
     # padded rows carry zero g3 => no effect; they and every row labelled
     # outside [0, L) (a wave's dead rows) take the one id, L, that no row of
     # the left operand has, whatever its padding
-    g3t = jnp.pad(g3.astype(jnp.float32), ((0, n_pad - N), (0, 0))).T  # (3, n_pad)
-    leaf_id = leaf_id.astype(jnp.int32)
-    leaf_p = jnp.pad(
-        jnp.where((leaf_id >= 0) & (leaf_id < L), leaf_id, L),
-        (0, n_pad - N), constant_values=L)[None, :]      # (1, n_pad)
+    with jax.named_scope(LAYOUT_SCOPE):
+        g3t = jnp.pad(g3.astype(jnp.float32),
+                      ((0, n_pad - N), (0, 0))).T           # (3, n_pad)
+        leaf_id = leaf_id.astype(jnp.int32)
+        leaf_p = jnp.pad(
+            jnp.where((leaf_id >= 0) & (leaf_id < L), leaf_id, L),
+            (0, n_pad - N), constant_values=L)[None, :]      # (1, n_pad)
 
-    iota_bins = (jnp.arange(B * fblk, dtype=jnp.int32)
-                 // fblk).astype(jnp.float32)[None, :]      # (1, B*fblk)
+        iota_bins = (jnp.arange(B * fblk, dtype=jnp.int32)
+                     // fblk).astype(jnp.float32)[None, :]   # (1, B*fblk)
 
     def one_block(bins_block, window=None):
         # Mosaic requires the bins block's lane dim to equal the array dim

@@ -173,6 +173,20 @@ def _psum_scatter(x, axis, dim):
         return lax.psum_scatter(x, axis, scatter_dimension=dim, tiled=True)
 
 
+def _over_padded_rows(sharded, N: int, N_pad: int):
+    """A row-sharded learner's ``grow``: ``sharded`` over g3 with zero rows
+    up to the shards' ``N_pad``, the leaf ids cut back to ``N``."""
+    def grow_fn(binned, g3, base_mask, key, cegb_used):
+        with jax.named_scope("lgbm.sample"):    # weightless rows, as bagged
+            g3p = jnp.pad(g3, ((0, N_pad - N), (0, 0)))
+        tree, leaf_id, third = sharded(binned, g3p, base_mask, key,
+                                       cegb_used)
+        with jax.named_scope("lgbm.sample"):
+            return tree, leaf_id[:N], third
+
+    return grow_fn
+
+
 def _sync_best_split(local: SplitResult, parent_sum, params: SplitParams,
                      axis, children: int = 1) -> SplitResult:
     """Elect the global best split from per-shard locals — the reference's
@@ -189,18 +203,19 @@ def _sync_best_split(local: SplitResult, parent_sum, params: SplitParams,
     are tied and the LOWEST FEATURE ID wins, matching the serial search's
     first-feature-in-band rule exactly (SplitInfo::operator> tie-break,
     split_info.hpp:147-152)."""
-    packed = _pack_split(local)
+    # the records' packing and the election ride the exchange's scope: they
+    # exist only because there is one
     with jax.named_scope(COLLECTIVE_SCOPE):
-        allp = lax.all_gather(packed, axis)        # (ndev, 11 + W)
-    # ``children``: how many of these one round gathers (the wave grower
-    # vmaps this over its 2K children)
-    _reduce_bytes("split", allp, children)
-    g = allp[:, 0]
-    m = jnp.max(g)
-    scale = leaf_gain(parent_sum[0], parent_sum[1], params)
-    in_band = g >= m - tie_tol(m, scale)
-    feat = jnp.where(in_band, allp[:, 1], jnp.inf)
-    return _unpack_split(allp[jnp.argmin(feat)])
+        allp = lax.all_gather(_pack_split(local), axis)    # (ndev, 11 + W)
+        # ``children``: how many of these one round gathers (the wave
+        # grower vmaps this over its 2K children)
+        _reduce_bytes("split", allp, children)
+        g = allp[:, 0]
+        m = jnp.max(g)
+        scale = leaf_gain(parent_sum[0], parent_sum[1], params)
+        in_band = g >= m - tie_tol(m, scale)
+        feat = jnp.where(in_band, allp[:, 1], jnp.inf)
+        return _unpack_split(allp[jnp.argmin(feat)])
 
 
 def parse_interaction_constraints(spec, num_features: int):
@@ -966,14 +981,8 @@ def build_trainer(
             check_vma=False,
         )
 
-        def grow_fn(binned, g3, base_mask, key, cegb_used):
-            pad = N_pad - N
-            g3p = jnp.pad(g3, ((0, pad), (0, 0)))
-            tree, leaf_id, root = sharded(binned, g3p, base_mask, key,
-                                          cegb_used)
-            return tree, leaf_id[:N], root
-
-        return finished(grow_fn, f"grow.{learner}"), binned_dev, N
+        return (finished(_over_padded_rows(sharded, N, N_pad),
+                         f"grow.{learner}"), binned_dev, N)
 
     if learner == "data":
         collective = config.data_parallel_collective
@@ -1062,10 +1071,11 @@ def build_trainer(
             as raw int32 (exact, order-invariant sums; ops/quantize.py
             global scales make shard partials commensurable)."""
             nb = h.ndim - 3                   # leading slot axes (0 or 1)
-            hp = jnp.pad(h, [(0, 0)] * nb
-                         + [(0, FH_pad - FH), (0, 0), (0, 0)])
-            if int_domain:
-                hp = hp.astype(jnp.int32)
+            with jax.named_scope(COLLECTIVE_SCOPE):     # the wire's form
+                hp = jnp.pad(h, [(0, 0)] * nb
+                             + [(0, FH_pad - FH), (0, 0), (0, 0)])
+                if int_domain:
+                    hp = hp.astype(jnp.int32)
             _reduce_bytes("hist", hp)
             if use_hier:
                 # level 1 (ICI): the full FH_pad block rides the fast
@@ -1075,7 +1085,8 @@ def build_trainer(
                 sl = _psum_scatter(sl, "host", nb)
             else:
                 sl = _psum_scatter(hp, "data", nb)
-            return sl.astype(jnp.float32)
+            with jax.named_scope(COLLECTIVE_SCOPE):
+                return sl.astype(jnp.float32)
 
         def _shard_lo():
             """First histogram column this device owns after the
@@ -1107,21 +1118,26 @@ def build_trainer(
             ReduceScatter (data_parallel_tree_learner.cpp:175-199).  The
             per-feature inputs are cut to the owned ids (ops/split.py
             narrow_meta) and the winner comes back under its global id."""
-            lo = _shard_lo()
-            own = own_tbl[lo // FH_loc]
-            if bundle is not None:
-                from ..io.bundle import expand_bundle_hist
+            # the scan's operands cut to the owned columns: the scan's scope
+            with jax.named_scope("lgbm.split"):
+                lo = _shard_lo()
+                own = own_tbl[lo // FH_loc]
+                if bundle is not None:
+                    from ..io.bundle import expand_bundle_hist
 
-                hist = expand_bundle_hist(hist, parent, bundle, B,
-                                          columns=own, first_column=lo)
-            _scan_columns(owned=FH_loc, scanned=hist.shape[0])
-            rk = jax.random.fold_in(key, uid + 1_000_003 + params.extra_seed) \
-                if params.extra_trees else None
+                    hist = expand_bundle_hist(hist, parent, bundle, B,
+                                              columns=own, first_column=lo)
+                _scan_columns(owned=FH_loc, scanned=hist.shape[0])
+                rk = jax.random.fold_in(
+                    key, uid + 1_000_003 + params.extra_seed) \
+                    if params.extra_trees else None
+                own_meta = narrow_meta(meta, own)
+                own_mask = take_columns(mask, own, False)
+                own_pen = (None if cegb_pen is None
+                           else take_columns(cegb_pen, own, 0.0))
             local = find_best_split(
-                hist, parent, narrow_meta(meta, own),
-                take_columns(mask, own, False), params, constraint, depth,
-                config.monotone_penalty, parent_output, rk,
-                None if cegb_pen is None else take_columns(cegb_pen, own, 0.0),
+                hist, parent, own_meta, own_mask, params, constraint, depth,
+                config.monotone_penalty, parent_output, rk, own_pen,
                 hist_scale=hist_scale)
             return _sync_best_split(local, parent, params, row_axes,
                                     round_children)
@@ -1216,14 +1232,8 @@ def build_trainer(
             check_vma=False,
         )
 
-        def grow_fn(binned, g3, base_mask, key, cegb_used):
-            pad = N_pad - N
-            g3p = jnp.pad(g3, ((0, pad), (0, 0)))
-            tree, leaf_id, root = sharded(binned, g3p, base_mask, key,
-                                          cegb_used)
-            return tree, leaf_id[:N], root
-
-        return finished(grow_fn, f"grow.{learner}"), binned_dev, N
+        return (finished(_over_padded_rows(sharded, N, N_pad),
+                         f"grow.{learner}"), binned_dev, N)
 
     if learner == "feature":
         mesh = _make_mesh(config.num_shards, "feature")

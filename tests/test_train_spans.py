@@ -97,8 +97,11 @@ def test_one_record_a_tree_with_the_tracer_disarmed():
     recs = trace.iteration_records()
     assert [r[0] for r in recs] == [1, 2, 3, 4, 5]
     covered = []
-    for it, t0, prepare, dispatch, bookkeep, wait, total, renewed in recs:
+    for (it, t0, prepare, dispatch, bookkeep, wait, total, renewed,
+         rounds) in recs:
         assert isinstance(renewed, int) and renewed >= 0
+        # 15 leaves at K = 3: no ladder, one bucket, at least 5 rounds
+        assert len(rounds) == 1 and rounds[0] >= 5
         assert min(prepare, dispatch, bookkeep, wait) > 0
         assert prepare + dispatch + bookkeep + wait <= total
         covered.append((prepare + dispatch + bookkeep + wait) / total)
@@ -107,6 +110,60 @@ def test_one_record_a_tree_with_the_tracer_disarmed():
     # does not fail it)
     assert sorted(covered)[len(covered) // 2] >= 0.95, covered
     assert [r[1] for r in recs] == sorted(r[1] for r in recs)
+
+
+@pytest.mark.parametrize("ladder", [True, False])
+def test_the_record_counts_rounds_by_slot_bucket(ladder, monkeypatch):
+    """Field 8, ``rounds``: what the device counted where it chose a
+    round's bucket is what the finished trees replay to, bucket by bucket,
+    on a three-bucket ladder and on the one bucket small data gets; it is
+    read from the device when the record is first read, not when it is
+    written, and reads the same again."""
+    from lightgbmv1_tpu.models import grower_wave
+
+    if ladder:
+        monkeypatch.setattr(grower_wave, "_BUCKET_MIN_N", 256)
+    b = _booster(rows=4000, num_leaves=127, min_data_in_leaf=2)
+    K = grower_wave.auto_wave_size(127)
+    buckets = grower_wave.slot_buckets_for(K, 4000)
+    assert buckets == ([4, 16, K] if ladder else [K])
+    for _ in range(3):
+        b.update()
+    # written as the span closed, still the device's: nothing waited
+    assert all(callable(r[-1]._v) for r in trace._iterations)
+    recs = trace.iteration_records()
+    assert all(isinstance(r[-1]._v, tuple) for r in trace._iterations)
+    live = [r[8] for r in recs]
+    assert live == [r[8] for r in trace.iteration_records()]
+    assert all(isinstance(n, int) for t in live for n in t)
+    # ... and is what the finished trees' structure and gains replay to
+    assert live == [
+        grower_wave.rounds_by_bucket(s, buckets)
+        for s in grower_wave.replay_wave_schedule(b._all_trees(), K)]
+    if ladder:          # the ramp 1, 2, 4, ... passes through every bucket
+        assert all(min(t) >= 1 for t in live), live
+
+
+def test_a_grower_without_rounds_leaves_the_field_empty():
+    """The level-wise grower has no wave rounds: ``rounds`` is None, as
+    ``renewed`` is where nobody said; and the record's phase fields are
+    where ``benchmarks/span_readers.py`` looks for them."""
+    import ast
+    import re
+
+    b = _booster(tree_growth="levelwise")
+    b.update()
+    rec = trace.iteration_records()[-1]
+    assert len(rec) == 9 and rec[8] is None
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "span_readers.py")
+    with open(path) as fh:
+        fields = ast.literal_eval(re.search(
+            r"^_FIELDS = (\{.*\})$", fh.read(), re.M).group(1))
+    assert fields == {"prepare": 2, "dispatch": 3, "bookkeep": 4,
+                      "wait": 5, "total": 6}
+    assert tuple(fields)[:4] == trace.ITERATION_PHASES
+    assert rec[6] >= sum(rec[2:6]) > 0
 
 
 def test_iteration_ring_is_bounded():
@@ -172,15 +229,24 @@ def test_bridged_span_is_cheap_with_no_profiler_session():
 # ---------------------------------------------------------------------------
 
 
-def _name_stacks(jaxpr, out):
+def _name_stacks(jaxpr, out, prefix=""):
+    """Every equation's scope path; a sub-jaxpr's stacks are relative to
+    the equation that holds it (a nested jit, a loop body, a branch), as
+    the lowering joins them."""
     for eqn in jaxpr.eqns:
-        out.add(str(eqn.source_info.name_stack))
+        here = "/".join(p for p in (prefix, str(eqn.source_info.name_stack))
+                        if p)
+        out.add(here)
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    _name_stacks(inner, out)
+                    _name_stacks(inner, out, here)
     return out
+
+
+def _parts(stack):
+    return stack.replace("(", "/").replace(")", "/").split("/")
 
 
 STEP_SCOPES = ("lgbm.objective", "lgbm.sample", "lgbm.score")
@@ -204,10 +270,53 @@ def test_every_phase_of_an_iteration_is_a_named_scope(path, objective):
         g._supports_fused_step = lambda: False
     jaxpr = jax.make_jaxpr(
         lambda: g.train_one_iter(check_stop=False))()
-    stacks = _name_stacks(jaxpr.jaxpr, set())
+    stacks = [_parts(s) for s in _name_stacks(jaxpr.jaxpr, set())]
     for scope in STEP_SCOPES + GROWER_SCOPES:
-        assert any(scope in s.replace("(", "/").replace(")", "/").split("/")
-                   for s in stacks), (scope, path, objective)
+        assert any(scope in s for s in stacks), (scope, path, objective)
+    # the wave grower's passes carry their bucket around the scopes they
+    # had: the root's, and the one bucket 1,200 rows get
+    for outer, inner in (("lgbm.round.root", "lgbm.hist"),
+                         ("lgbm.round.bK", "lgbm.hist"),
+                         ("lgbm.round.bK", "lgbm.partition"),
+                         ("lgbm.round.bK", "lgbm.select")):
+        assert any(outer in s and inner in s for s in stacks), \
+            (outer, inner, path, objective)
+    assert not any("lgbm.round.b4" in s for s in stacks)
+
+
+def test_a_ladder_names_every_bucket_and_the_pass_its_layout(monkeypatch):
+    """On a three-bucket ladder with the kernel's passes (the raw matrix
+    laid out in the pass, as a row-sharded learner's is): every bucket's
+    scope holds a histogram pass and a partition, and ``lgbm.layout`` sits
+    inside ``lgbm.hist`` inside a round: one op's path keeps all three
+    components."""
+    from lightgbmv1_tpu.models import grower_wave
+    from lightgbmv1_tpu.parallel import trainer
+
+    monkeypatch.setattr(grower_wave, "_BUCKET_MIN_N", 256)
+    # placement keeps the raw matrix: every pass lays it out itself
+    monkeypatch.setattr(trainer, "_place_hist_bins",
+                        lambda binned, num_bins, packed: binned)
+    b = _booster(rows=1500, num_leaves=127, min_data_in_leaf=2,
+                 hist_method="pallas")
+    b.update()
+    g = b._gbdt
+    jaxpr = jax.make_jaxpr(lambda: g.train_one_iter(check_stop=False))()
+    stacks = [_parts(s) for s in _name_stacks(jaxpr.jaxpr, set())]
+    rounds = ["lgbm.round.root", "lgbm.round.b4", "lgbm.round.b16",
+              "lgbm.round.bK"]
+    for outer in rounds:
+        assert any(outer in s and "lgbm.hist" in s and "lgbm.layout" in s
+                   for s in stacks), outer
+    for outer in rounds[1:]:
+        # (the largest bucket's result needs no padding: no lgbm.pool)
+        for inner in ("lgbm.partition", "lgbm.select",
+                      "lgbm.pool")[:2 if outer == rounds[-1] else 3]:
+            assert any(outer in s and inner in s for s in stacks), \
+                (outer, inner)
+    assert not any("lgbm.round.b31" in s for s in stacks)
+    # a layout op is never outside the histogram pass's scope
+    assert all("lgbm.hist" in s for s in stacks if "lgbm.layout" in s)
 
 
 def test_scopes_are_metadata_only(monkeypatch):
@@ -229,7 +338,7 @@ def test_scopes_are_metadata_only(monkeypatch):
     monkeypatch.setattr(jax, "named_scope", no_scope)
     bare = train()
     assert {"lgbm.objective", "lgbm.sample", "lgbm.score",
-            "lgbm.select"} <= set(calls)
+            "lgbm.select", "lgbm.round.root", "lgbm.round.bK"} <= set(calls)
     assert bare == scoped
 
 

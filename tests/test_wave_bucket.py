@@ -59,28 +59,29 @@ def test_bucketed_rounds_match_single_bucket(params, monkeypatch):
     np.testing.assert_allclose(a.predict(X), b.predict(X), rtol=1e-6)
 
 
-def test_round_probe_matches_tree_replay(monkeypatch):
-    """The _ROUND_PROBE hook fires once per executed wave round with the
-    round's split count, and replay_wave_schedule must reproduce the SAME
-    per-round schedule from the grown trees alone — the replay is what
-    bench.py records as wave_rounds_per_tree, so the timed program
-    carries no host callback."""
+def test_round_counter_matches_tree_replay():
+    """The grower counts its rounds by slot bucket on the device
+    (``WaveState.rounds``, handed back third and kept in the per-tree
+    record), and replay_wave_schedule must reproduce the SAME counts from
+    the grown trees alone — the replay is what bench.py records as
+    wave_rounds_per_tree, so the timed program carries no host callback."""
+    from lightgbmv1_tpu.obs import trace
+
     X, y = make_problem(n=1200)
-    live = []
-    monkeypatch.setattr(grower_wave, "_ROUND_PROBE",
-                        lambda k: live.append(int(k)))
+    trace.reset()
     m = lgb.train({"objective": "binary", "num_leaves": 31,
                    "leafwise_wave_size": 8, "tree_growth": "leafwise",
                    "verbosity": -1},
                   lgb.Dataset(X, label=y), num_boost_round=2)
-    import jax
-
-    jax.effects_barrier()   # debug.callback effects are async
+    live = [r[8] for r in trace.iteration_records()]
     trees = m._all_trees()
-    replayed = [k for s in grower_wave.replay_wave_schedule(trees, 8)
-                for k in s]
-    t = m._all_trees()[0]
+    buckets = grower_wave.slot_buckets_for(8, 1200)
+    assert buckets == [8]                    # below _BUCKET_MIN_N: no ladder
+    replayed = [grower_wave.rounds_by_bucket(s, buckets)
+                for s in grower_wave.replay_wave_schedule(trees, 8)]
+    t = trees[0]
     # a 31-leaf tree at K=8 needs >= ceil(30/8) = 4 rounds; the ramp
     # (1, 2, 4, 8, ...) makes it >= 6 when the tree fills its budget
-    assert len(live) >= 2 * max(1, int(np.ceil((t.num_leaves - 1) / 8)))
+    assert sum(map(sum, live)) >= \
+        2 * max(1, int(np.ceil((t.num_leaves - 1) / 8)))
     assert replayed == live
