@@ -321,8 +321,8 @@ class GBDT:
                 row_sharded=getattr(self.train_set, "is_row_sharded", False),
                 packed=self._packed,
             )
-        if self.binned is None:
-            self.binned = self._grow_binned
+        if self.binned is None:     # process-sharded rows: the learner's own
+            self.binned = bin_matrix(self._grow_binned)
         self._step = None  # fused per-iteration step, built lazily
 
     # ------------------------------------------------------------------

@@ -44,15 +44,21 @@ TPUs have no atomics; the design maps the OpenCL structure onto the MXU:
 
 The bin operand is made by ONE function, ``prepare_hist_bins``.  A learner
 calls it once at placement and hands ``hist_leaves_pallas`` the result
-(``HistBins``); a caller that hands over the raw ``(F, N)`` matrix gets the
-same layout made inside the pass, every pass (pad + transposition, and on
-the 16 and 64 rungs one slice per block: 7-9x the bins in temporaries).
-With a prepared operand the HBM traffic of a pass is the stored arrays + g3
-+ leaf_id and nothing else.  A TPU tiles a ``u8`` array ``T(8,128)(4,1)``,
-so a row of a stored array occupies 128 byte lanes whatever its shape says,
-and the operand costs ``arrays x n_pad x 128`` bytes
-(``prepared_bins_bytes``; the learner prepares it where that is at most a
-quarter of the device's memory, ``trainer._place_hist_bins``):
+(``HistBins``): the serial learner for the whole matrix, the row-sharded
+learners (``tree_learner=data`` and ``voting``) once a chip, each chip for
+its own shard of the rows inside one ``shard_map`` (it pads its own rows,
+so a global block is ``chips x n_pad_loc`` rows tall and a chip holds the
+``n_pad_loc`` of its rows).  The raw ``(F, N)`` matrix is left to the
+streamed blocks (``grower_stream.py``), ``tree_learner=feature`` and a
+learner whose operand is over the budget below: they get the same layout
+made inside the pass, every pass (pad + transposition, and on the 16 and 64
+rungs one slice per block: 7-9x the bins in temporaries).  With a prepared
+operand the HBM traffic of a pass is the stored arrays + g3 + leaf_id and
+nothing else.  A TPU tiles a ``u8`` array ``T(8,128)(4,1)``, so a row of a
+stored array occupies 128 byte lanes whatever its shape says, and the
+operand costs ``arrays x n_pad x 128`` bytes (``prepared_bins_bytes``; the
+learner prepares it where that is at most a quarter of a device's memory,
+judged on the rows one device holds, ``trainer._place_hist_bins``):
 
 * the 16 and 64 rungs store each feature block as its own row-major
   ``u8[n_pad, 128]`` array, ``tile_cols`` live columns and lane padding: the
@@ -97,9 +103,10 @@ _LANES = 128              # byte columns a stored u8 row occupies at least
 _COUNT_SCALE = 64.0       # power-of-two count quantizer => exact counts
 # ``jax.named_scope`` of what a pass does to make the kernel's operands and
 # is not the kernel: the bin layout where the pass was handed the raw
-# matrix (every pass of a row-sharded learner or a streamed block), and
-# g3's and the labels' padding and transposition.  Inside ``lgbm.hist``
-# wherever the pass is; placement's one-off layout is set-up and has none.
+# matrix (every pass of a streamed block, of tree_learner=feature and of a
+# learner over the bytes rule's budget), and g3's and the labels' padding
+# and transposition.  Inside ``lgbm.hist`` wherever the pass is; placement's
+# one-off layout is set-up and has none.
 LAYOUT_SCOPE = "lgbm.layout"
 
 
@@ -396,8 +403,12 @@ class HistBins:
     which the call takes whole.  ``matrix`` is the untouched ``(F, N)``
     matrix (packed: ``(ceil(F/2), N)``) the blocks were cut from, which
     everything but the histogram pass (partition decisions, tree walks)
-    keeps reading.  A pytree of arrays; ``tile_cols`` and ``windows`` are
-    static."""
+    keeps reading.  Under a row-sharded learner every chip made the blocks
+    of its own shard: globally ``matrix`` is split on its rows
+    ``P(None, rows)``, a block ``P(rows, None)`` with ``chips x n_pad_loc``
+    rows, and inside the learner's ``shard_map`` both are a shard's (the
+    block's rows past the shard's are that chip's own padding).  A pytree
+    of arrays; ``tile_cols`` and ``windows`` are static."""
 
     matrix: jax.Array
     blocks: Tuple[jax.Array, ...]

@@ -291,6 +291,17 @@ def test_reduce_scatter_feature_count_not_divisible():
         atol=1e-5)
 
 
+def _bundling_columns(rng, n, logit):
+    """24 one-hot-ish columns in 6 exclusive groups of 4, which EFB bundles;
+    ``logit`` gains each group's effect in place."""
+    which = rng.randint(0, 4, (6, n))
+    hot = np.zeros((n, 24))
+    for b in range(6):
+        hot[np.arange(n), 4 * b + which[b]] = rng.rand(n) + 0.5
+        logit += 0.5 * (which[b] - 1.5) * (b % 3 - 1)
+    return hot
+
+
 def _owned_slice_problem(case):
     """13 columns (not a multiple of 2, 4 or 8): a categorical one, one
     with NaNs, one half zeros; ``efb`` appends 24 one-hot-ish columns that
@@ -311,12 +322,7 @@ def _owned_slice_problem(case):
     elif case == "extra_trees":
         params["extra_trees"] = True
     elif case == "efb":
-        which = rng.randint(0, 4, (6, n))
-        hot = np.zeros((n, 24))
-        for b in range(6):
-            hot[np.arange(n), 4 * b + which[b]] = rng.rand(n) + 0.5
-            logit += 0.5 * (which[b] - 1.5) * (b % 3 - 1)
-        X = np.concatenate([hot, X], axis=1)
+        X = np.concatenate([_bundling_columns(rng, n, logit), X], axis=1)
         cat += 24
     y = (logit + 0.4 * rng.randn(n) > 0).astype(np.float64)
 
@@ -734,3 +740,233 @@ def test_data_parallel_partition_kernel_grows_the_gather_form_s_trees(
     assert values_k == values_g
     np.testing.assert_array_equal(scores_k, scores_g)
     assert grown(common, "kernel")[0] == sig_k
+
+
+# ---------------------------------------------------------------------------
+# PR 37: the row-sharded learners place a prepared bin operand: a
+# hist_pallas.HistBins whose blocks each chip made from its own shard
+# ---------------------------------------------------------------------------
+def _shard_operand_problem(form):
+    """1,203 rows with NaNs: no multiple of 2 or 4 shards, and a shard of
+    602 or 301 rows no multiple of ``MAX_ROW_TILE``.  ``efb`` prepends 24
+    one-hot-ish columns that bundle (the histograms run over bundle
+    columns), ``packed4`` bins them in a nibble."""
+    rng = np.random.RandomState(37)
+    n = 1203
+    X = rng.randn(n, 9)
+    X[rng.rand(*X.shape) < 0.05] = np.nan
+    logit = np.nan_to_num(X[:, 0]) - np.nan_to_num(X[:, 1])
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 5, "verbosity": -1,
+              "hist_method": "pallas", "hist_dtype": "f32"}
+    if form == "efb":
+        X = np.concatenate([_bundling_columns(rng, n, logit), X], axis=1)
+    elif form == "packed4":
+        params["max_bin"] = 15
+    y = (logit + 0.5 * rng.randn(n) > 0).astype(np.float64)
+    return X, y, params
+
+
+def _grown_once(params, X, y):
+    """The learner built, and ONE tree of its ``grow`` on the first
+    iteration's gradients: every field of the tree and the rows' leaves."""
+    cfg = Config.from_dict(params)
+    g = create_boosting(cfg, BinnedDataset.from_numpy(X, label=y,
+                                                      config=cfg))
+    n, f = X.shape
+    g3 = jnp.asarray(np.stack([0.5 - y, np.full(n, 0.25), np.ones(n)], 1),
+                     jnp.float32)
+    tree, leaf_id, _ = g._grow(g._grow_binned, g3, jnp.ones(f, bool),
+                               jax.random.PRNGKey(0), g._cegb_used)
+    return g, jax.tree.map(np.asarray, tree), np.asarray(leaf_id)
+
+
+def _registry(prefix):
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    return {k: v for k, v in default_registry().snapshot().items()
+            if k.startswith(prefix)}
+
+
+def _layouts():
+    got = _registry("hist_bins_layout_total")
+    return tuple(int(got.get('hist_bins_layout_total{site="%s"}' % s, 0))
+                 for s in ("placement", "pass"))
+
+
+_SHARD_OPERAND_CASES = [
+    ("data", 2, "u8"), ("data", 4, "u8"), ("voting", 2, "u8"),
+    ("data", 4, "efb"), ("data", 2, "packed4"),
+    pytest.param("data", 4, "hier", marks=pytest.mark.slow),
+    pytest.param("voting", 4, "hier", marks=pytest.mark.slow)]
+
+
+@pytest.mark.parametrize("learner,shards,form", _SHARD_OPERAND_CASES)
+def test_row_sharded_learner_grows_the_raw_shard_s_tree_on_prepared_bins(
+        monkeypatch, learner, shards, form):
+    """A row-sharded learner whose chips laid their shards out at placement
+    (``HistBins``: a block is ``shards x n_pad_loc`` rows, a chip's own
+    rows padded to ``MAX_ROW_TILE``) grows the tree of the same learner on
+    the raw shard (over the bytes rule's budget), bit for bit in every
+    field and in the rows' leaves, and the serial learner's node for node.
+    Counted as it is traced: placement lays out once and no pass does; the
+    gauge holds a SHARD's bytes; the learner's ``shard_map`` takes the spec
+    tree of what was placed."""
+    from jax.sharding import PartitionSpec as P
+    from lightgbmv1_tpu.ops.hist_pallas import (MAX_ROW_TILE, HistBins,
+                                                bin_matrix,
+                                                hist_leaves_pallas,
+                                                prepared_bins_bytes)
+    from lightgbmv1_tpu.parallel import trainer
+
+    X, y, params = _shard_operand_problem(form)
+    over = {"tree_learner": learner, "num_shards": shards}
+    axes = "data"
+    if form == "hier":          # the (host, chip) mesh, 2 x 2
+        over.update(num_hosts=2, data_parallel_collective="hierarchical")
+        axes = ("host", "chip")
+    if learner == "voting":     # every column elected: the data learner
+        over["top_k"] = X.shape[1]
+    before = _layouts()
+    g, tree, leaf_id = _grown_once({**params, **over}, X, y)
+    placed = g._grow_binned
+    assert isinstance(placed, HistBins)
+    assert (g._bundle is not None) == (form == "efb")
+    assert g._packed == (form == "packed4")
+    # one layout at placement, none in the root's or a round's pass
+    assert tuple(a - b for a, b in zip(_layouts(), before)) == (1, 0)
+    stored, n_pad_all = bin_matrix(placed).shape
+    n_loc = n_pad_all // shards
+    assert n_pad_all == -(-len(X) // shards) * shards and n_loc % MAX_ROW_TILE
+    n_pad_loc = -(-n_loc // MAX_ROW_TILE) * MAX_ROW_TILE
+    assert all(b.shape == (shards * n_pad_loc, 128) for b in placed.blocks)
+    for b in placed.blocks:     # a chip holds the blocks of its rows only
+        assert {s.data.shape for s in b.addressable_shards} == {
+            (n_pad_loc, 128)}
+        assert len({s.device for s in b.addressable_shards}) == shards
+    # chip d's block rows are its shard's columns, then the row padding
+    m = np.asarray(bin_matrix(placed))
+    b0 = np.asarray(placed.blocks[0])
+    cols = placed.tile_cols
+    for d in range(shards):
+        rows = b0[d * n_pad_loc:(d + 1) * n_pad_loc]
+        np.testing.assert_array_equal(
+            rows[:n_loc, :min(cols, stored)],
+            m[:min(cols, stored), d * n_loc:(d + 1) * n_loc].T)
+        assert (rows[n_loc:, :cols] == (0 if g._packed else 255)).all()
+    num_bins = (g.train_set.padded_bundle_bin if form == "efb"
+                else g.num_bins)
+    need = prepared_bins_bytes(stored, n_loc, num_bins, g._packed)
+    assert need == len(placed.blocks) * n_pad_loc * 128
+    assert _registry("hist_bins_prepared_bytes") == {
+        "hist_bins_prepared_bytes": need}
+    specs = trainer._binned_specs(placed, axes)
+    assert isinstance(specs, HistBins) and specs.matrix == P(None, axes)
+    assert specs.blocks == (P(axes, None),) * len(placed.blocks)
+
+    # the same learner over the budget: the raw shard, laid out in a pass
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 0)
+    hist_leaves_pallas.clear_cache()    # the counter counts traces
+    before = _layouts()
+    g_raw, tree_raw, leaf_raw = _grown_once({**params, **over}, X, y)
+    assert not isinstance(g_raw._grow_binned, HistBins)
+    assert trainer._binned_specs(g_raw._grow_binned, axes) == P(None, axes)
+    placement, passes = (a - b for a, b in zip(_layouts(), before))
+    assert placement == 0 and passes >= 1
+    assert _registry("hist_bins_prepared_bytes") == {
+        "hist_bins_prepared_bytes": 0}
+    assert int(tree.num_leaves) > 8
+    for name, a, b in zip(tree._fields, tree, tree_raw):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    np.testing.assert_array_equal(leaf_id, leaf_raw)
+
+    # the serial learner's tree (its own prepared operand), node for node
+    monkeypatch.undo()
+    _, serial, leaf_serial = _grown_once(params, X, y)
+    for name in ("num_leaves", "split_feature", "threshold_bin",
+                 "default_left", "left_child", "right_child"):
+        np.testing.assert_array_equal(getattr(tree, name),
+                                      getattr(serial, name), err_msg=name)
+    np.testing.assert_array_equal(leaf_id, leaf_serial)
+    np.testing.assert_allclose(tree.leaf_value, serial.leaf_value,
+                               rtol=1e-4, atol=1e-6)
+
+
+class _AsTpu:
+    """``jax`` as ``parallel/trainer.py`` sees it on the chip: the kernels
+    are traced for Mosaic, not for the interpreter."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    @staticmethod
+    def default_backend():
+        return "tpu"
+
+
+def test_no_layout_of_the_shard_in_grow_data_lowered_for_the_chip(
+        monkeypatch):
+    """grow.data at the four-chip cell's shapes (4 shards, 67 features, 64
+    bins), lowered for ``tpu``: nowhere (so not inside the ``while``) is a
+    ``u8[67, ...]`` or ``u8[68, ...]`` array padded or transposed, no
+    ``u8`` pad or transposition is left at all, and the bin operand of
+    every histogram kernel call is the first 32 columns of a
+    ``u8[n_pad_loc, 128]`` parameter of the pass: a chip's block as it was
+    placed.  Over the budget the pass pads, transposes and cuts the shard
+    as the parent did."""
+    import re
+    from lightgbmv1_tpu.parallel import trainer
+
+    def lowered():
+        rng = np.random.RandomState(0)
+        n, features = 2048, 67
+        X = rng.randn(n, features)
+        cfg = Config.from_dict({
+            "objective": "binary", "verbosity": -1, "num_leaves": 255,
+            "max_bin": 63, "tree_learner": "data", "num_shards": 4,
+            "hist_method": "pallas"})
+        gb = create_boosting(cfg, BinnedDataset.from_numpy(
+            X, label=(X[:, 0] > 0).astype(np.float64), config=cfg))
+        return gb._grow._jit.trace(
+            gb._grow_binned, jnp.zeros((n, 3), jnp.float32),
+            jnp.ones(features, bool), jax.random.PRNGKey(0),
+            jnp.zeros(features, bool)).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    def u8_relayouts(txt):
+        return [m.groups() for m in re.finditer(
+            r"stablehlo\.(pad|transpose)\b[^\n]*\(tensor<([0-9x]+)xui8>"
+            r"[^\n]*->\s*tensor<([0-9x]+)xui8>", txt)]
+
+    def hist_passes(txt):
+        """``(parameter types, body)`` of every ``hist_leaves_pallas``."""
+        return re.findall(
+            r"func\.func private @hist_leaves_pallas\w*\(([^\n]*)\) -> "
+            r"[^\n]*\{\n(.*?)\n  \}", txt, re.S)
+
+    monkeypatch.setattr(trainer, "jax", _AsTpu())
+    txt = lowered()
+    assert "stablehlo.while" in txt and "tpu_custom_call" in txt
+    assert u8_relayouts(txt) == []
+    passes = hist_passes(txt)
+    assert len(passes) >= 2         # the root's and a round's
+    for args, body in passes:
+        blocks = re.findall(r"(%arg\d+): tensor<1024x128xui8>", args)
+        assert len(blocks) == 3, args
+        cut = dict(re.findall(
+            r"(%\d+) = stablehlo\.slice (%arg\d+) \[0:1024, 0:32\] : "
+            r"\(tensor<1024x128xui8>\) -> tensor<1024x32xui8>", body))
+        assert sorted(cut.values()) == sorted(blocks)
+        calls = [ln for ln in body.split("\n") if "tpu_custom_call" in ln]
+        assert len(calls) == 3
+        for ln in calls:
+            operands = re.search(r"custom_call @tpu_custom_call\(([^)]*)\)",
+                                 ln).group(1).split(", ")
+            assert len([o for o in operands if o in cut]) == 1, ln
+            assert "tensor<1024x32xui8>" in ln
+
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 0)
+    raw = lowered()
+    # (to the pass's own row tile: 1024 rows up to 16 slots, 512 at 63)
+    assert {(op, src) for op, src, _ in u8_relayouts(raw)} == {
+        ("pad", "67x512"), ("transpose", "96x1024"), ("transpose", "96x512")}

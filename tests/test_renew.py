@@ -173,16 +173,20 @@ def test_leaf_sums_match_bincount(method):
     assert (got[:, 2] == want[:, 2]).all()
 
 
-def test_data_learner_is_traced_and_counted():
+@pytest.mark.parametrize("method", ["auto", "pallas"])
+def test_data_learner_is_traced_and_counted(method):
     """``lgbm.collective`` in the data learner's lowered step and not in
     the serial one; the gauge's bytes from the shapes; the per-tree count
-    in the iteration record."""
+    in the iteration record.  Under ``pallas`` both learners hand the step
+    the prepared operand (``HistBins``; the data learner's blocks a shard's
+    rows each), under ``auto`` the matrix."""
+    from lightgbmv1_tpu.ops.hist_pallas import HistBins
     from lightgbmv1_tpu.parallel.trainer import COLLECTIVE_SCOPE
 
     n, f, leaves, bins = 4000, 6, 15, 31
     X, y = binary_rows(n, f)
     base = {"objective": "binary", "num_leaves": leaves, "max_bin": bins,
-            "verbosity": -1, "min_data_in_leaf": 20}
+            "verbosity": -1, "min_data_in_leaf": 20, "hist_method": method}
     default_registry().reset(["dp_reduce_bytes_per_round"])
     texts = {}
     for name, extra in (("serial", {}),
@@ -190,6 +194,7 @@ def test_data_learner_is_traced_and_counted():
         booster = first_tree({**base, **extra}, X, y)
         gbdt = booster._gbdt
         grow = gbdt._grow
+        assert isinstance(gbdt._grow_binned, HistBins) == (method == "pallas")
         g3 = jax.numpy.zeros((n, 3), jax.numpy.float32)
         lowered = jax.jit(grow.__wrapped__ if hasattr(grow, "__wrapped__")
                           else grow).lower(

@@ -286,17 +286,17 @@ def test_every_phase_of_an_iteration_is_a_named_scope(path, objective):
 
 def test_a_ladder_names_every_bucket_and_the_pass_its_layout(monkeypatch):
     """On a three-bucket ladder with the kernel's passes (the raw matrix
-    laid out in the pass, as a row-sharded learner's is): every bucket's
-    scope holds a histogram pass and a partition, and ``lgbm.layout`` sits
-    inside ``lgbm.hist`` inside a round: one op's path keeps all three
-    components."""
+    laid out in the pass, as a learner's over the bytes rule's budget or a
+    streamed block is): every bucket's scope holds a histogram pass and a
+    partition, and ``lgbm.layout`` sits inside ``lgbm.hist`` inside a
+    round: one op's path keeps all three components."""
     from lightgbmv1_tpu.models import grower_wave
     from lightgbmv1_tpu.parallel import trainer
 
     monkeypatch.setattr(grower_wave, "_BUCKET_MIN_N", 256)
-    # placement keeps the raw matrix: every pass lays it out itself
-    monkeypatch.setattr(trainer, "_place_hist_bins",
-                        lambda binned, num_bins, packed: binned)
+    # over the budget placement keeps the raw matrix: every pass lays it
+    # out itself
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 0)
     b = _booster(rows=1500, num_leaves=127, min_data_in_leaf=2,
                  hist_method="pallas")
     b.update()
