@@ -23,6 +23,10 @@ where "the kernel raised" is one of the two passing outcomes):
   hist256    the same at max_bin=255, the library's default: two trees
              through lightgbmv1_tpu.train, then the kernel's 256-bin rung
              on the lane-dense operand the trainer placed, 1-64 slots
+  hist64dense  the 64-bin rung on the lane-dense operand (the form the
+             bytes rule gives a table whose blocks are over a quarter of the
+             device: hist_pallas.hist_bins_form) against one array a block,
+             67 columns at 63 bins, 1 and 63 slots: ``equal`` or ``differs``
   partition  the row-tiled partition kernel vs the gather form it stands
              for, 1-64 slots on 28 and 137 columns: integers equal, and the
              ms a round of both (what ``partition_path``'s rule is fitted
@@ -63,7 +67,7 @@ from lightgbmv1_tpu.models.grower_wave import auto_wave_size, slot_buckets_for
 from lightgbmv1_tpu.obs import xla as obs_xla
 from lightgbmv1_tpu.ops.hist_pallas import (MAX_ROW_TILE, HistBins,
                                             bin_matrix, hist_leaves_pallas,
-                                            kernel_width)
+                                            kernel_width, prepare_hist_bins)
 from lightgbmv1_tpu.ops.partition_pallas import (partition_gather,
                                                  partition_pallas,
                                                  partition_path)
@@ -358,6 +362,41 @@ class Smoke:
                  prepared_operand=prepared, counts="exact",
                  worst_error_over_sum_abs=worst,
                  live_slots_worst_error_over_sum_abs=worst_live)
+
+    # -- the 64 rung's two operand forms ------------------------------------
+    def hist64dense(self):
+        """A pass of the 64-bin rung over the lane-dense operand (67 columns
+        in one ``u8[n_pad, 128]`` array, three 32-column windows picked by
+        the MXU) and over one array a block: the same one-hot, the same
+        left operand, the same row tiles, so the same bits."""
+        columns, B = 67, 64
+        rows = 3_000 if self.rehearse else 200_000
+        rng = np.random.RandomState(64)
+        bins = jax.numpy.asarray(
+            rng.randint(0, B - 1, (columns, rows)).astype(np.uint8))
+        g3 = jax.numpy.asarray(np.stack(
+            [rng.randn(rows), rng.rand(rows) + 0.1, np.ones(rows)],
+            axis=1).astype(np.float32))
+        dense = prepare_hist_bins(bins, B, dense=True)
+        block = prepare_hist_bins(bins, B)
+        assert (dense.windows, len(dense.blocks)) == (4, 1)
+        assert (block.windows, len(block.blocks)) == (1, 3)
+        interpret = self.device["platform"] == "cpu"
+        verdict = {}
+        for slots, precision in ((1, "bf16x2"), (63, "bf16")):
+            label = jax.numpy.asarray(
+                rng.randint(0, slots + 1, rows).astype(np.int32))
+            got, want = (np.asarray(hist_leaves_pallas(
+                operand, g3, label, slots, B, precision=precision,
+                interpret=interpret)) for operand in (dense, block))
+            assert got.shape == (slots, columns, B, 3)
+            assert got[..., 2].sum() == float((np.asarray(label) < slots).sum()
+                                              * columns)
+            verdict[f"slots{slots}_{precision}"] = (
+                "equal" if np.array_equal(got, want) else "differs")
+        self.say("hist64dense", shape=[columns, rows], num_bins=B,
+                 lane_dense_vs_block=verdict)
+        assert set(verdict.values()) == {"equal"}, verdict
 
     # -- partition kernel vs the gather form --------------------------------
     def partition(self):
@@ -712,7 +751,7 @@ def main(argv=None) -> int:
     print(json.dumps({"leg": "device", **smoke.device}), flush=True)
 
     for leg in (smoke.wide, smoke.train, smoke.hist, smoke.hist256,
-                smoke.partition, smoke.predict,
+                smoke.hist64dense, smoke.partition, smoke.predict,
                 smoke.serve, smoke.variants, smoke.optin, smoke.multichip):
         leg()
 
@@ -740,6 +779,8 @@ def main(argv=None) -> int:
             "hist_counts": "exact",
             "hist256_worst_error_over_sum_abs":
                 smoke.out["hist256"]["worst_error_over_sum_abs"],
+            "hist64_lane_dense_vs_block":
+                smoke.out["hist64dense"]["lane_dense_vs_block"],
             "partition_kernel_vs_gather": "equal",
             "predict_leaf_indices": "equal",
             "predict_raw_score_max_delta":

@@ -1189,8 +1189,12 @@ class GBDT:
             raise CheckpointError("tree array stack does not match the "
                                   "manifest tree count")
         self._device_trees = [
-            TreeArrays(**{f: jnp.asarray(stacked[f][i])
-                          for f in TreeArrays._fields})
+            # (a checkpoint from before the counts were int32 holds them
+            # as float32)
+            TreeArrays(**{f: jnp.asarray(
+                stacked[f][i],
+                jnp.int32 if f in ("internal_count", "leaf_count") else None)
+                for f in TreeArrays._fields})
             for i in range(T)
         ]
         self.models = [None] * T

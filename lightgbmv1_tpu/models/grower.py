@@ -668,10 +668,12 @@ def make_leafwise_grower(
                     split_gain=t.split_gain.at[node].set(gain),
                     internal_value=t.internal_value.at[node].set(pout),
                     internal_weight=t.internal_weight.at[node].set(parent_sum[1]),
-                    internal_count=t.internal_count.at[node].set(parent_sum[2]),
+                    internal_count=t.internal_count.at[node].set(
+                        parent_sum[2].astype(jnp.int32)),
                     leaf_value=t.leaf_value.at[leaf].set(out_l).at[nl].set(out_r),
                     leaf_weight=t.leaf_weight.at[leaf].set(lsum[1]).at[nl].set(rsum[1]),
-                    leaf_count=t.leaf_count.at[leaf].set(lsum[2]).at[nl].set(rsum[2]),
+                    leaf_count=t.leaf_count.at[leaf].set(lsum[2].astype(jnp.int32))
+                    .at[nl].set(rsum[2].astype(jnp.int32)),
                     leaf_parent=t.leaf_parent.at[leaf].set(node).at[nl].set(node),
                 )
 
@@ -1105,13 +1107,14 @@ def make_levelwise_grower(
                 internal_weight=t.internal_weight.at[nd].set(
                     leaf_sums[:Ld, 1], mode="drop"),
                 internal_count=t.internal_count.at[nd].set(
-                    leaf_sums[:Ld, 2], mode="drop"),
+                    leaf_sums[:Ld, 2].astype(jnp.int32), mode="drop"),
                 leaf_value=t.leaf_value.at[ld_idx].set(left_out, mode="drop")
                 .at[nl].set(right_out, mode="drop"),
                 leaf_weight=t.leaf_weight.at[ld_idx].set(res.left_sum[:, 1], mode="drop")
                 .at[nl].set(res.right_sum[:, 1], mode="drop"),
-                leaf_count=t.leaf_count.at[ld_idx].set(res.left_sum[:, 2], mode="drop")
-                .at[nl].set(res.right_sum[:, 2], mode="drop"),
+                leaf_count=t.leaf_count.at[ld_idx].set(
+                    res.left_sum[:, 2].astype(jnp.int32), mode="drop")
+                .at[nl].set(res.right_sum[:, 2].astype(jnp.int32), mode="drop"),
                 leaf_parent=t.leaf_parent.at[ld_idx].set(nd, mode="drop")
                 .at[nl].set(nd, mode="drop"),
             )

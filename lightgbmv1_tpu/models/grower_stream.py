@@ -346,12 +346,13 @@ class StreamGrower:
             split_gain=t.split_gain.at[node].set(gain),
             internal_value=t.internal_value.at[node].set(pout),
             internal_weight=t.internal_weight.at[node].set(parent_sum[1]),
-            internal_count=t.internal_count.at[node].set(parent_sum[2]),
+            internal_count=t.internal_count.at[node].set(
+                parent_sum[2].astype(jnp.int32)),
             leaf_value=t.leaf_value.at[leaf].set(out_l).at[nl].set(out_r),
             leaf_weight=t.leaf_weight.at[leaf].set(lsum[1])
             .at[nl].set(rsum[1]),
-            leaf_count=t.leaf_count.at[leaf].set(lsum[2])
-            .at[nl].set(rsum[2]),
+            leaf_count=t.leaf_count.at[leaf].set(lsum[2].astype(jnp.int32))
+            .at[nl].set(rsum[2].astype(jnp.int32)),
             leaf_parent=t.leaf_parent.at[leaf].set(node).at[nl].set(node),
         )
 

@@ -462,7 +462,7 @@ class _FieldStore:
             internal_weight=t.internal_weight.at[r["nidx"]]
             .set(r["psum"][:, 1], mode="drop"),
             internal_count=t.internal_count.at[r["nidx"]]
-            .set(r["psum"][:, 2], mode="drop"),
+            .set(r["psum"][:, 2].astype(jnp.int32), mode="drop"),
             leaf_value=t.leaf_value.at[r["lidx"]]
             .set(r["out_l"], mode="drop")
             .at[r["nlidx"]].set(r["out_r"], mode="drop"),
@@ -470,8 +470,9 @@ class _FieldStore:
             .set(r["lsums"][:, 1], mode="drop")
             .at[r["nlidx"]].set(r["rsums"][:, 1], mode="drop"),
             leaf_count=t.leaf_count.at[r["lidx"]]
-            .set(r["lsums"][:, 2], mode="drop")
-            .at[r["nlidx"]].set(r["rsums"][:, 2], mode="drop"),
+            .set(r["lsums"][:, 2].astype(jnp.int32), mode="drop")
+            .at[r["nlidx"]].set(r["rsums"][:, 2].astype(jnp.int32),
+                               mode="drop"),
             leaf_parent=t.leaf_parent.at[r["lidx"]]
             .set(r["nidx"], mode="drop")
             .at[r["nlidx"]].set(r["nidx"], mode="drop"),
@@ -672,10 +673,10 @@ class _PackedStore:
             split_gain=nt[:, self.NGAIN],
             internal_value=nt[:, self.NIVAL],
             internal_weight=nt[:, self.NIW],
-            internal_count=nt[:, self.NIC],
+            internal_count=nt[:, self.NIC].astype(jnp.int32),
             leaf_value=ft[:, self.LVAL],
             leaf_weight=ft[:, self.LWEIGHT],
-            leaf_count=ft[:, self.LCNT],
+            leaf_count=ft[:, self.LCNT].astype(jnp.int32),
             leaf_parent=ft[:, self.LPAR].astype(jnp.int32),
             is_cat=(s["n_iscat"] if self.use_cat
                     else jnp.zeros(L1, bool)),

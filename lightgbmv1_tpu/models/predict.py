@@ -1095,8 +1095,9 @@ class BatchPredictor:
             default_left=a.default_left, missing_type=a.missing_type,
             left_child=a.left_child, right_child=a.right_child,
             split_gain=zf, internal_value=zf, internal_weight=zf,
-            internal_count=zf, leaf_value=a.leaf_value, leaf_weight=zl,
-            leaf_count=zl,
+            internal_count=jnp.zeros((T, L1), jnp.int32),
+            leaf_value=a.leaf_value, leaf_weight=zl,
+            leaf_count=jnp.zeros((T, L), jnp.int32),
             leaf_parent=jnp.full((T, L), -1, jnp.int32),
             is_cat=a.is_cat, cat_bitset=a.cat_bitset,
         )

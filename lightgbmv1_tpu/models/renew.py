@@ -35,7 +35,10 @@ before anything reads it, for every grower and learner alike:
   precision the user chose, not a better one), node sums follow bottom-up,
   and marked leaves and nodes take their weight and output, and splits
   with a marked child their gain, from the measured sums.  Unmarked
-  entries keep what the scan gave them.
+  entries keep what the scan gave them.  The same pass counts every
+  leaf's rows, and the stored counts (int32) are those and their integer
+  sums up the tree: the scan's counts are float32 sums and differences,
+  exact only below 2^24 rows a node.
 
 The reference library has the same remedy for its own low-precision
 histograms: ``quant_train_renew_leaf`` recomputes leaf outputs from the
@@ -246,7 +249,16 @@ def renew_tree(tree, leaf_id, g3, params: SplitParams, policy: RenewPolicy,
             S = jnp.concatenate([S_node, S_leaf])             # (M, 3)
             out = leaf_output(S[:, 0], S[:, 1], params)
             m_node, m_leaf = mark[:L1], mark[L1:]
+            # the rows of every leaf and node, counted again whether marked
+            # or not: the grower's counts are float32 sums and differences,
+            # exact only below 2^24 rows a node (a 26.6 M-row root's larger
+            # child reads one row off, and every count derived from it by
+            # subtraction after it); a leaf's measured count is exact below
+            # 2^24 rows a LEAF, and the nodes' are integer sums of those
+            n_leaf = jnp.round(S_leaf[:, 2]).astype(jnp.int32)
+            n_node = jnp.sum(jnp.where(under, n_leaf[:, None], 0), axis=0)
             new = tree._replace(
+                leaf_count=n_leaf, internal_count=n_node,
                 internal_weight=jnp.where(m_node, S_node[:, 1],
                                           tree.internal_weight),
                 internal_value=jnp.where(m_node, out[:L1],

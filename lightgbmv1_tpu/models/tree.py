@@ -44,10 +44,10 @@ class TreeArrays(NamedTuple):
     split_gain: jax.Array       # (L-1,) float32
     internal_value: jax.Array   # (L-1,) float32
     internal_weight: jax.Array  # (L-1,) float32
-    internal_count: jax.Array   # (L-1,) float32
+    internal_count: jax.Array   # (L-1,) int32: rows, exact past 2^24
     leaf_value: jax.Array       # (L,) float32
     leaf_weight: jax.Array      # (L,) float32
-    leaf_count: jax.Array       # (L,) float32
+    leaf_count: jax.Array       # (L,) int32
     leaf_parent: jax.Array      # (L,) int32
     is_cat: jax.Array           # (L-1,) bool — categorical (bitset) split
     cat_bitset: jax.Array       # (L-1, W) uint32 — bin-space membership
@@ -118,10 +118,10 @@ def empty_tree(max_leaves: int, cat_words: int = 1) -> TreeArrays:
         split_gain=jnp.zeros(L1, jnp.float32),
         internal_value=jnp.zeros(L1, jnp.float32),
         internal_weight=jnp.zeros(L1, jnp.float32),
-        internal_count=jnp.zeros(L1, jnp.float32),
+        internal_count=jnp.zeros(L1, jnp.int32),
         leaf_value=jnp.zeros(L, jnp.float32),
         leaf_weight=jnp.zeros(L, jnp.float32),
-        leaf_count=jnp.zeros(L, jnp.float32),
+        leaf_count=jnp.zeros(L, jnp.int32),
         leaf_parent=jnp.full(L, -1, jnp.int32),
         is_cat=jnp.zeros(L1, bool),
         cat_bitset=jnp.zeros((L1, cat_words), jnp.uint32),
@@ -341,10 +341,10 @@ def host_trees_to_stacked(trees, num_leaves: int = 0) -> TreeArrays:
             split_gain=pad(t.split_gain, L1, 0.0, np.float32),
             internal_value=pad(t.internal_value, L1, 0.0, np.float32),
             internal_weight=pad(t.internal_weight, L1, 0.0, np.float32),
-            internal_count=pad(t.internal_count, L1, 0, np.float32),
+            internal_count=pad(t.internal_count, L1, 0, np.int32),
             leaf_value=pad(t.leaf_value, L, 0.0, np.float32),
             leaf_weight=pad(t.leaf_weight, L, 0.0, np.float32),
-            leaf_count=pad(t.leaf_count, L, 0, np.float32),
+            leaf_count=pad(t.leaf_count, L, 0, np.int32),
             leaf_parent=pad(t.leaf_parent, L, -1, np.int32),
             is_cat=pad(t.is_cat, L1, False, bool),
             cat_bitset=pad2(t.cat_bitset, L1, W),
@@ -624,10 +624,10 @@ class HostTree:
             split_gain=pad(self.split_gain, L1, np.float32),
             internal_value=pad(self.internal_value, L1, np.float32),
             internal_weight=pad(self.internal_weight, L1, np.float32),
-            internal_count=pad(self.internal_count, L1, np.float32),
+            internal_count=pad(self.internal_count, L1, np.int32),
             leaf_value=pad(self.leaf_value, L, np.float32),
             leaf_weight=pad(self.leaf_weight, L, np.float32),
-            leaf_count=pad(self.leaf_count, L, np.float32),
+            leaf_count=pad(self.leaf_count, L, np.int32),
             leaf_parent=pad(self.leaf_parent, L, np.int32, -1),
             is_cat=pad(self.is_cat, L1, bool),
             cat_bitset=jnp.asarray(bitset),

@@ -51,43 +51,56 @@ so a global block is ``chips x n_pad_loc`` rows tall and a chip holds the
 ``n_pad_loc`` of its rows).  The raw ``(F, N)`` matrix is left to the
 streamed blocks (``grower_stream.py``), ``tree_learner=feature`` and a
 learner whose operand is over the budget below: they get the same layout
-made inside the pass, every pass (pad + transposition, and on the 16 and 64
-rungs one slice per block: 7-9x the bins in temporaries).  With a prepared
+made inside the pass, every pass (pad + transposition, and in the block
+form one slice per block: 7-9x the bins in temporaries).  With a prepared
 operand the HBM traffic of a pass is the stored arrays + g3 + leaf_id and
 nothing else.  A TPU tiles a ``u8`` array ``T(8,128)(4,1)``, so a row of a
 stored array occupies 128 byte lanes whatever its shape says, and the
-operand costs ``arrays x n_pad x 128`` bytes (``prepared_bins_bytes``; the
-learner prepares it where that is at most a quarter of a device's memory,
-judged on the rows one device holds, ``trainer._place_hist_bins``):
+operand costs ``arrays x n_pad x 128`` bytes (``prepared_bins_bytes``).
 
-* the 16 and 64 rungs store each feature block as its own row-major
-  ``u8[n_pad, 128]`` array, ``tile_cols`` live columns and lane padding: the
-  32-column blocks of a 64-bin pass read 4x the bins' own bytes
-  (``mslr-train``: 5 blocks x 2,271,232 rows = 1,453,588,480 B;
-  ``epsilon-train``: 63 x 400,384 x 128 = 3,228,696,576 B).  Stored AT lane
+The operand has two forms, and a pass reads which off the ``HistBins`` it
+is handed (``windows``), not off the bin count:
+
+* **block**: each feature block its own row-major ``u8[n_pad, 128]``
+  array, ``tile_cols`` live columns and lane padding.  Stored AT lane
   width, the array's default device layout is the row-major one the
-  kernel's call takes — a tall ``u8[n, 32]`` array would be stored
-  column-major and copied in every pass — and the pass's column slice back
-  to ``tile_cols`` is a bitcast there.  (The same storage would serve the 64
-  rung on the lane-dense form below at a quarter of these bytes: not done,
-  ROADMAP S1 (h).)
-* the 256 rung's blocks are 8 columns wide (16 at 128 bins), so one array a
-  block would store 16x the bins (``higgs-255b-train``, 10,500,000 x 28: 4
-  blocks = 5,376,049,152 B, over the quarter).  Its operand is lane-dense:
-  the matrix's columns side by side, 128 a ``u8[n_pad, 128]`` array (all 28
-  of that cell in ONE: 1,344,012,288 B), and feature block ``fb`` of a pass
-  is the static window of ``tile_cols`` columns ``(fb % windows) *
-  tile_cols`` columns into array ``fb // windows``.  Each call takes the
-  whole array a (T, 128) tile at a time and the kernel picks its window
+  kernel's call takes (a tall ``u8[n, 32]`` array would be stored
+  column-major and copied in every pass) and the pass's column slice back
+  to ``tile_cols`` is a bitcast there.  The 16 rung's blocks (128 columns)
+  fill the lanes; the 64 rung's 32-column blocks store 4x the bins' own
+  bytes at 128 columns and 5.7x at 67.
+* **dense** (lane-dense): the matrix's columns side by side, 128 a
+  ``u8[n_pad, 128]`` array, and feature block ``fb`` of a pass is the
+  static window of ``tile_cols`` columns ``(fb % windows) * tile_cols``
+  columns into array ``fb // windows`` (``windows`` = 4 of the 64 rung's
+  32-column blocks, 16 of the 256 rung's 8-column ones).  Each call takes
+  the whole array a (T, 128) tile at a time and the kernel picks its window
   with the MXU (``_kernel``: a 128 x 128 selection, which also repeats the
-  window across the lanes).
+  window across the lanes).  The 256 rung has this form only: one array a
+  block would store 16x its bins.
+
+**The bytes rule** (``hist_bins_form``, called by
+``trainer._place_hist_bins``; one budget, no option): the operand may hold a
+quarter of a device's ``bytes_limit`` (4,227,334,016 B on the v5e), judged
+on the rows ONE device holds.  Three outcomes: the block form where it fits
+(the 16 and 64 rungs); else the lane-dense form where that fits (the 64 and
+256 rungs); else the raw matrix.  The benchmark's five cells:
+``mslr-train`` block, 5 x ``u8[2271232,128]`` = 1,453,588,480 B;
+``epsilon-train`` block, 63 x ``u8[400384,128]`` = 3,228,696,576 B;
+``criteo-dp4-train`` block, 3 x ``u8[4000768,128]`` = 1,536,294,912 B a
+chip; ``higgs-255b-train`` dense, one ``u8[10500096,128]`` =
+1,344,012,288 B (5,376,049,152 as one array a block); ``criteo-tall-train``
+(26,562,500 x 67) dense, one ``u8[26562560,128]`` = 3,400,007,680 B, its
+block form 10,200,023,040 B being 2.4x the rule.  At 67 columns the block
+form reaches 11.0 M rows a device, the dense form 33.0 M, the raw matrix
+whatever fits beside its passes' temporaries.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -395,12 +408,15 @@ class HistBins:
     """The kernel's bin operand, made once for a dataset by
     ``prepare_hist_bins``: row-major ``u8[n_pad, 128k]`` arrays, each
     holding ``windows`` feature blocks of ``tile_cols`` byte columns side by
-    side (module docstring).  With ``windows`` 1 (the 16 and 64 rungs)
+    side (module docstring).  With ``windows`` 1 (the block form: the 16
+    rung, and the 64 rung where the bytes rule admits it)
     ``blocks[fb][:, :tile_cols]`` is feature block ``fb``, the operand of
     that block's ``pallas_call``, and the columns beyond are the lane
-    padding the device would add anyway; on the 256 rung block ``fb`` is
-    columns ``(fb % windows) * tile_cols ...`` of ``blocks[fb // windows]``,
-    which the call takes whole.  ``matrix`` is the untouched ``(F, N)``
+    padding the device would add anyway; with more (the lane-dense form:
+    the 256 rung, and the 64 rung over the rule) block ``fb`` is columns
+    ``(fb % windows) * tile_cols ...`` of ``blocks[fb // windows]``, which
+    the call takes whole.  ``windows`` IS the form: a pass reads it here.
+    ``matrix`` is the untouched ``(F, N)``
     matrix (packed: ``(ceil(F/2), N)``) the blocks were cut from, which
     everything but the histogram pass (partition decisions, tree walks)
     keeps reading.  Under a row-sharded learner every chip made the blocks
@@ -421,7 +437,17 @@ def bin_matrix(binned) -> jax.Array:
     return binned.matrix if isinstance(binned, HistBins) else binned
 
 
-def _feature_blocks(stored_rows: int, num_bins: int, packed: bool):
+def _dense(num_bins: int, dense: bool) -> bool:
+    """Whether the operand of such a pass is lane-dense: the 256 rung's
+    always, the 64 rung's where asked (``dense``: the form the bytes rule
+    chose, ``hist_bins_form``), the 16 rung's never (its blocks, packed
+    or not, fill an array's 128 lanes as they are)."""
+    rung = kernel_width(num_bins)
+    return rung == 256 or (dense and rung == 64)
+
+
+def _feature_blocks(stored_rows: int, num_bins: int, packed: bool,
+                    dense: bool = False):
     """``(fblk, tile_cols, nfb)`` of a matrix with ``stored_rows`` feature
     rows: features per one-hot block, byte columns per block, and the
     number of blocks.  Packed, ``fblk`` counts UNPACKED features and
@@ -430,11 +456,11 @@ def _feature_blocks(stored_rows: int, num_bins: int, packed: bool):
     if packed:
         fblk = max(2, min(2 * stored_rows, MAX_LANES // num_bins) & ~1)
         tile_cols = fblk // 2
-    elif kernel_width(num_bins) == 256:
+    elif _dense(num_bins, dense):
         # a window of a lane-dense array: always whole, whatever the matrix
         # holds (columns beyond it are padding, sliced away by the pass),
         # and a power of two, so that windows tile the 128 lanes (8 columns
-        # at 256 bins, 16 at 128)
+        # at 256 bins, 16 at 128, 32 at 64)
         fblk = tile_cols = 1 << ((MAX_LANES // num_bins).bit_length() - 1)
     else:
         fblk = max(1, min(stored_rows, MAX_LANES // num_bins))
@@ -442,21 +468,44 @@ def _feature_blocks(stored_rows: int, num_bins: int, packed: bool):
     return fblk, tile_cols, -(-stored_rows // tile_cols)
 
 
-def _block_windows(tile_cols: int, num_bins: int) -> int:
-    """Feature blocks one stored array holds side by side.  The 256 rung's
-    blocks are 8 byte columns wide: one array a block would store 16x the
-    bins, so 16 of them share an array's 128 lanes.  The 16 and 64 rungs
-    keep one array a block."""
-    return _LANES // tile_cols if kernel_width(num_bins) == 256 else 1
+def _block_windows(tile_cols: int, num_bins: int, dense: bool = False) -> int:
+    """Feature blocks one stored array holds side by side: in the
+    lane-dense form as many as tile its 128 lanes (16 of the 256 rung's
+    8-column blocks, 4 of the 64 rung's 32-column ones), else one."""
+    return _LANES // tile_cols if _dense(num_bins, dense) else 1
 
 
 def prepared_bins_bytes(stored_rows: int, num_rows: int, num_bins: int,
-                        packed: bool = False) -> int:
+                        packed: bool = False, dense: bool = False) -> int:
     """Bytes of the blocks ``prepare_hist_bins`` makes of such a matrix."""
-    _, tile_cols, nfb = _feature_blocks(stored_rows, num_bins, packed)
-    windows = _block_windows(tile_cols, num_bins)
+    _, tile_cols, nfb = _feature_blocks(stored_rows, num_bins, packed, dense)
+    windows = _block_windows(tile_cols, num_bins, dense)
     n_pad = -(-num_rows // MAX_ROW_TILE) * MAX_ROW_TILE
     return -(-nfb // windows) * n_pad * (-(-tile_cols // _LANES) * _LANES)
+
+
+def hist_bins_form(stored_rows: int, num_rows: int, num_bins: int,
+                   packed: bool, budget: Optional[int]):
+    """The bytes rule (module docstring), one pure function: ``(form,
+    need)`` of a matrix of ``stored_rows`` stored columns and the
+    ``num_rows`` rows ONE device holds.  ``need`` maps each form the rung
+    has to the bytes of its operand: ``"block"`` (one array a feature
+    block: the 16 and 64 rungs and packed bins) and ``"dense"`` (the
+    lane-dense arrays: the 64 and 256 rungs).  ``form`` is the first of
+    them, in that order, that fits ``budget`` bytes, or ``"raw"`` where
+    none does (the passes lay the matrix out themselves); no budget
+    (``None``: XLA:CPU reports no limit) takes the first."""
+    need = {}
+    if kernel_width(num_bins) != 256:
+        need["block"] = prepared_bins_bytes(stored_rows, num_rows, num_bins,
+                                            packed)
+    if _dense(num_bins, True):
+        need["dense"] = prepared_bins_bytes(stored_rows, num_rows, num_bins,
+                                            packed, dense=True)
+    for form, cost in need.items():
+        if budget is None or cost <= budget:
+            return form, need
+    return "raw", need
 
 
 def _count_operand(stored_rows: int, tile_cols: int, windows: int, nfb: int,
@@ -482,7 +531,7 @@ def _count_operand(stored_rows: int, tile_cols: int, windows: int, nfb: int,
 
 def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
                       row_tile: int = MAX_ROW_TILE,
-                      resident: bool = True) -> HistBins:
+                      resident: bool = True, dense: bool = False) -> HistBins:
     """The ONLY place the kernel's bin layout is made: ``(F, N)`` uint8
     bins (packed: ``(ceil(F/2), N)``) -> ``HistBins``.
 
@@ -499,7 +548,9 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
     lane width (module docstring; the padding columns are never read).
     ``hist_leaves_pallas`` passes False where it was handed the raw matrix
     and makes the layout inside the pass, consumed at once at its own
-    width; the 256 rung's lane-dense arrays are the same either way.
+    width; lane-dense arrays are the same either way.  ``dense`` asks the
+    64 rung for the lane-dense form (``hist_bins_form`` says when); the 256
+    rung has no other.
     Traceable; each trace counts in ``hist_bins_layout_total`` under
     ``site="placement"`` (resident) or ``"pass"``."""
     if binned.dtype not in (jnp.uint8, np.uint8):
@@ -516,8 +567,8 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
         label_names=("site",)).labels(
             site="placement" if resident else "pass").inc()
     stored, N = binned.shape
-    _, tile_cols, nfb = _feature_blocks(stored, num_bins, packed)
-    windows = _block_windows(tile_cols, num_bins)
+    _, tile_cols, nfb = _feature_blocks(stored, num_bins, packed, dense)
+    windows = _block_windows(tile_cols, num_bins, dense)
     _count_operand(stored, tile_cols, windows, nfb, num_bins)
     # byte columns of one stored array, and how many arrays
     width = _LANES if windows > 1 else tile_cols
@@ -573,8 +624,10 @@ def hist_leaves_pallas(
     L, B = num_leaves, num_bins
     stored, N = bin_matrix(binned).shape
     F = (num_features or 2 * stored) if packed else stored
-    fblk, tile_cols, nfb = _feature_blocks(stored, B, packed)
-    windows = _block_windows(tile_cols, B)
+    # the form is the operand's own: the 64 rung has two (module docstring)
+    dense = isinstance(binned, HistBins) and binned.windows > 1
+    fblk, tile_cols, nfb = _feature_blocks(stored, B, packed, dense)
+    windows = _block_windows(tile_cols, B, dense)
     f_pad = nfb * fblk
     out_rows = pass_rows(L, precision)[2]
     _count_pass_rows(L, precision)

@@ -395,17 +395,13 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
     return "packed4"
 
 
-# The bytes rule: share of the device's memory (``bytes_limit``) the prepared
-# histogram operands may hold resident; over it the passes lay the bins out
-# on the fly.  The operands cost ``hist_pallas.prepared_bins_bytes``: stored
-# arrays x padded rows x 128 lanes, one array a feature block on the 16 and
-# 64 rungs, one a 128 columns on the 256 rung (module docstring there).  A
-# row-sharded learner is judged on one chip's shard of the rows.  On the v5e
-# (16,909,336,064 B, a quarter 4,227,334,016) the benchmark's cells read:
-# mslr-train 1,453,588,480 B (5 blocks), epsilon-train 3,228,696,576 (63),
-# higgs-255b-train 1,344,012,288 (one array; 5,376,049,152 and raw as one
-# array a block), criteo-dp4-train 1,536,294,912 a chip (3 blocks of a
-# 4,000,000-row shard).
+# The bytes rule's budget: the share of a device's memory (``bytes_limit``)
+# the histogram kernel's prepared bin operand may hold resident.  The rule,
+# its three outcomes (the block form, the lane-dense form, the raw matrix)
+# and the bytes of the benchmark's five cells are stated once, in
+# ``ops/hist_pallas``'s module docstring, and computed by
+# ``hist_pallas.hist_bins_form``; a row-sharded learner is judged on one
+# chip's shard of the rows.  On the v5e a quarter is 4,227,334,016 B.
 _HIST_BINS_SHARE = 0.25
 
 
@@ -421,38 +417,55 @@ def _hist_bins_budget() -> Optional[int]:
 def _place_hist_bins(binned_dev: jax.Array, num_bins: int, packed: bool,
                      mesh: Optional[Mesh] = None, row_axes=None):
     """Lay the placed bins out for the histogram kernel ONCE
-    (ops/hist_pallas.prepare_hist_bins, one jitted call on the device), or
-    hand the matrix back where the operands would not fit the budget: the
-    kernel tells the two apart by type and lays a raw matrix out in every
+    (ops/hist_pallas.prepare_hist_bins, one jitted call on the device), in
+    the form the bytes rule gives (``hist_pallas.hist_bins_form``), or
+    hand the matrix back where neither form fits the budget: the kernel
+    reads the form off the operand and lays a raw matrix out in every
     pass.  With ``mesh`` the matrix is sharded ``P(None, row_axes)`` and
     every chip lays out its own shard: it pads its own rows and holds the
     blocks of those rows only (global blocks ``P(row_axes, None)``), and
     the bytes are a chip's."""
     from ..io.dataset import construct_phase
     from ..obs.metrics import default_registry
-    from ..ops.hist_pallas import prepare_hist_bins, prepared_bins_bytes
+    from ..ops.hist_pallas import hist_bins_form, prepare_hist_bins
 
     shards = 1 if mesh is None else mesh.devices.size
-    need = prepared_bins_bytes(binned_dev.shape[0],
-                               binned_dev.shape[1] // shards, num_bins,
-                               packed)
     budget = _hist_bins_budget()
-    prepared = budget is None or need <= budget
-    default_registry().gauge(
+    form, need = hist_bins_form(binned_dev.shape[0],
+                                binned_dev.shape[1] // shards, num_bins,
+                                packed, budget)
+    registry = default_registry()
+    registry.gauge(
         "hist_bins_prepared_bytes",
         "Bytes a device holds of the histogram kernel's prepared bin "
         "operands (0: the passes lay the bins out on the fly)"
-    ).set(need if prepared else 0)
-    if not prepared:
-        log_info(f"histogram bins stay raw: the prepared operands "
-                 f"({need >> 20} MiB) exceed {_HIST_BINS_SHARE:.0%} of "
-                 f"device memory ({budget >> 20} MiB)")
+    ).set(need.get(form, 0))
+    would = registry.gauge(
+        "hist_bins_need_bytes",
+        "Bytes a device would hold of the histogram kernel's bin operand "
+        "in each form its rung has", label_names=("form",))
+    for name, cost in need.items():
+        would.labels(form=name).set(cost)
+    registry.gauge(
+        "hist_bins_budget_bytes",
+        "Bytes the bytes rule lets the prepared bin operand hold on a "
+        "device (0: the backend reports no limit)").set(budget or 0)
+    if form != next(iter(need)):        # the rung's first form is over
+        sizes = ", ".join(f"{name} {cost >> 20} MiB"
+                          for name, cost in need.items())
+        took = ("stay raw" if form == "raw"
+                else "take the lane-dense form")
+        log_info(f"histogram bins {took}: the prepared operands ({sizes}) "
+                 f"against {_HIST_BINS_SHARE:.0%} of device memory "
+                 f"({budget >> 20} MiB)")
+    if form == "raw":
         return binned_dev
 
     def blocks_of(b):
         # only the blocks leave the jit: the matrix stays the placed buffer
-        return dataclasses.replace(prepare_hist_bins(b, num_bins, packed),
-                                   matrix=None)
+        return dataclasses.replace(
+            prepare_hist_bins(b, num_bins, packed, dense=form == "dense"),
+            matrix=None)
 
     if mesh is not None:
         blocks_of = jax.shard_map(

@@ -892,6 +892,82 @@ def test_row_sharded_learner_grows_the_raw_shard_s_tree_on_prepared_bins(
                                rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.parametrize("learner", ["data", "voting"])
+def test_a_shard_over_the_rule_takes_the_lane_dense_form(monkeypatch,
+                                                         learner):
+    """67 columns at 63 bins on 2 shards: with a budget between the two
+    forms' bytes of ONE shard every chip lays its shard out lane-dense (one
+    ``u8[n_pad_loc, 128]`` array of 4 windows where the block form holds
+    three), inside the same ``shard_map`` and through the same call, and
+    the tree is the block form's in every field and in the rows' leaves,
+    and the serial learner's on its own lane-dense operand node for
+    node."""
+    from lightgbmv1_tpu.ops.hist_pallas import (MAX_ROW_TILE, HistBins,
+                                                hist_leaves_pallas)
+    from lightgbmv1_tpu.parallel import trainer
+
+    rng = np.random.RandomState(38)
+    n, shards = 1203, 2
+    X = rng.randn(n, 67)
+    logit = X[:, 0] - X[:, 1] + 0.5 * X[:, 40] - 0.5 * X[:, 66]
+    y = (logit + 0.5 * rng.randn(n) > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 5, "verbosity": -1,
+              "hist_method": "pallas", "hist_dtype": "f32"}
+    over = {"tree_learner": learner, "num_shards": shards}
+    if learner == "voting":     # every column elected: the data learner
+        over["top_k"] = X.shape[1]
+    n_pad_loc = MAX_ROW_TILE            # a shard's 602 rows
+    block_b, dense_b = 3 * n_pad_loc * 128, n_pad_loc * 128
+
+    g, tree, leaf_id = _grown_once({**params, **over}, X, y)
+    assert g._grow_binned.windows == 1 and len(g._grow_binned.blocks) == 3
+    assert _registry("hist_bins_prepared_bytes") == {
+        "hist_bins_prepared_bytes": block_b}
+
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 2 * dense_b)
+    hist_leaves_pallas.clear_cache()    # the counter counts traces
+    before = _layouts()
+    g_d, tree_d, leaf_d = _grown_once({**params, **over}, X, y)
+    placed = g_d._grow_binned
+    assert isinstance(placed, HistBins)
+    assert (placed.tile_cols, placed.windows) == (32, 4)
+    assert [b.shape for b in placed.blocks] == [(shards * n_pad_loc, 128)]
+    assert {s.data.shape for s in placed.blocks[0].addressable_shards} == {
+        (n_pad_loc, 128)}
+    assert tuple(a - b for a, b in zip(_layouts(), before)) == (1, 0)
+    assert _registry("hist_bins_prepared_bytes") == {
+        "hist_bins_prepared_bytes": dense_b}
+    assert _registry("hist_bins_need_bytes") == {
+        'hist_bins_need_bytes{form="block"}': block_b,
+        'hist_bins_need_bytes{form="dense"}': dense_b}
+    # chip d's rows of the array are its shard's columns side by side
+    m = np.asarray(placed.matrix)
+    n_loc = m.shape[1] // shards
+    stored = np.asarray(placed.blocks[0])
+    for d in range(shards):
+        rows = stored[d * n_pad_loc:(d + 1) * n_pad_loc]
+        np.testing.assert_array_equal(
+            rows[:n_loc, :67], m[:, d * n_loc:(d + 1) * n_loc].T)
+        assert (rows[n_loc:] == 255).all() and (rows[:, 67:] == 255).all()
+    assert int(tree.num_leaves) > 8
+    for name, a, b in zip(tree._fields, tree, tree_d):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    np.testing.assert_array_equal(leaf_id, leaf_d)
+
+    # the serial learner, driven into the same form by a whole table's bytes
+    monkeypatch.setattr(trainer, "_hist_bins_budget", lambda: 2 * 2 * dense_b)
+    g_s, serial, leaf_serial = _grown_once(params, X, y)
+    assert g_s._grow_binned.windows == 4
+    for name in ("num_leaves", "split_feature", "threshold_bin",
+                 "default_left", "left_child", "right_child"):
+        np.testing.assert_array_equal(getattr(tree_d, name),
+                                      getattr(serial, name), err_msg=name)
+    np.testing.assert_array_equal(leaf_d, leaf_serial)
+    np.testing.assert_allclose(tree_d.leaf_value, serial.leaf_value,
+                               rtol=1e-4, atol=1e-6)
+
+
 class _AsTpu:
     """``jax`` as ``parallel/trainer.py`` sees it on the chip: the kernels
     are traced for Mosaic, not for the interpreter."""

@@ -156,6 +156,46 @@ def test_marks_follow_the_error_accounting():
     assert mark_b.sum() <= mark.sum() and not mark_b[L:leaf(5)].any()
 
 
+def test_renewal_counts_rows_exactly_past_2_to_the_24():
+    """A 26,562,500-row root whose larger child holds an odd 17,562,499
+    rows: float32 has no such number (its grid there is 2 wide), so the
+    grower's counts, sums and differences of float32, store the child one
+    row off and every count cut from it by subtraction after it.  The
+    renewal's pass counts each leaf's rows (exact below 2^24 rows a leaf)
+    and the nodes' counts are integer sums of those: int32, to the row."""
+    import jax.numpy as jnp
+    from lightgbmv1_tpu.models.tree import empty_tree
+    from lightgbmv1_tpu.ops.split import SplitParams
+
+    # root 0 -> (leaf 0, node 1); node 1 -> (leaf 1, leaf 2)
+    true_leaves = np.array([9_000_001, 8_562_499, 9_000_000], np.int64)
+    assert int(np.float32(17_562_499)) != 17_562_499     # no such float32
+    as_grown = np.float32(26_562_500) - np.float32(9_000_001)
+    assert int(as_grown) != 17_562_499
+    tree = empty_tree(3)._replace(
+        num_leaves=jnp.asarray(3, jnp.int32),
+        left_child=jnp.asarray([-1, -2], jnp.int32),
+        right_child=jnp.asarray([1, -3], jnp.int32),
+        # what the scan's float32 arithmetic leaves: one row off down the
+        # right edge
+        internal_count=jnp.asarray([26_562_500, int(as_grown)], jnp.int32),
+        leaf_count=jnp.asarray(
+            [9_000_001, 8_562_499, int(as_grown) - 8_562_499], jnp.int32),
+        leaf_weight=jnp.asarray([2.0e6, 2.0e6, 2.0e6], jnp.float32),
+        internal_weight=jnp.asarray([6.0e6, 4.0e6], jnp.float32))
+    assert tree.leaf_count.dtype == tree.internal_count.dtype == jnp.int32
+    measured = jnp.asarray(np.stack(
+        [np.zeros(3), true_leaves * 0.25, true_leaves], axis=1), jnp.float32)
+    deep = renew.RenewPolicy(eps_root=2.0 ** -17, eps_rest=2.0 ** -9,
+                             subtracts=True, gains=True)
+    got = renew.renew_tree(tree, None, None, SplitParams(), deep,
+                           lambda leaf_id, g3: measured)
+    assert got.leaf_count.dtype == got.internal_count.dtype == jnp.int32
+    np.testing.assert_array_equal(got.leaf_count, true_leaves)
+    np.testing.assert_array_equal(got.internal_count,
+                                  [26_562_500, 17_562_499])
+
+
 @pytest.mark.parametrize("method", ["scatter", "onehot", "pallas"])
 def test_leaf_sums_match_bincount(method):
     rng = np.random.RandomState(0)

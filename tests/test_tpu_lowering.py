@@ -433,10 +433,58 @@ def test_dense_256_rung_compiles_at_higgs_size(one_chip, slots, credited,
                          r"(copy|slice|fusion|transpose)\(", txt)
 
 
+@pytest.mark.parametrize("slots,precision,credited", [
+    (1, "bf16x2", 2), (4, "bf16x2", 5), (16, "bf16x2", 16), (63, "bf16", 64)])
+def test_dense_64_rung_compiles_at_criteo_tall_size(one_chip, slots,
+                                                    precision, credited):
+    """The 64-bin rung over its lane-dense prepared operand, compiled for
+    the chip at ``criteo-tall-train``'s own shapes (26,562,500 x 67 -> one
+    ``u8[26562560,128]`` array of 3.4e9 elements, three calls of 32 x 64
+    lanes): every call takes the stored array whole (no pad, transposition,
+    copy, slice or fusion makes a ``u8`` operand), keeps the name and the
+    result shape the roofline reader parses (rows off the operand, 32
+    features off the result), and the pass's temporaries are the
+    transposed g3 and leaf ids alone."""
+    from lightgbmv1_tpu.ops.hist_pallas import prepare_hist_bins
+
+    N, F, B, n_pad = 26_562_500, 67, 64, 26_562_560
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    prepared = jax.tree_util.tree_map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda b: prepare_hist_bins(b, B, dense=True),
+                       shape((F, N), jnp.uint8)))
+    assert [b.shape for b in prepared.blocks] == [(n_pad, 128)]
+    assert (prepared.tile_cols, prepared.windows) == (32, 4)
+
+    def fn(b, g, l):
+        return hist_leaves_pallas(b, g, l, slots, B, precision=precision)
+
+    rest = (shape((N, 3), jnp.float32), shape((N,), jnp.int32))
+    lowered = jax.jit(fn).trace(prepared, *rest).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert _big_u8_relayouts(lowered, n_pad) == []
+    assert _kernel_u8_operands(lowered) == [[f"{n_pad}x128"]] * 3
+    got = compile_for_chip(fn, prepared, *rest)
+    assert got.memory_analysis().temp_size_in_bytes < n_pad * 24
+    txt = got.as_text()
+    calls = _hist_calls(txt)
+    assert len(calls) == 3, calls
+    for ln in calls:
+        assert _roofline_call_shapes(ln, B) == {
+            "features": 32, "slots": credited, "rows": n_pad}
+    assert not re.search(rf"= u8\[{n_pad},\d+\]\S* "
+                         r"(copy|slice|fusion|transpose|pad)\(", txt)
+
+
 def test_prepared_bytes_of_the_cells():
-    """The bytes rule's arithmetic (``trainer._place_hist_bins``): the 256
-    rung stores 128 byte columns an array; the 64 rung one array a block,
-    **unchanged**: ``mslr-train`` 5 blocks, ``epsilon-train`` 63."""
+    """The bytes rule's arithmetic (``hist_pallas.hist_bins_form``): the
+    256 rung stores 128 byte columns an array; the 64 rung one array a
+    block where that fits, **unchanged**: ``mslr-train`` 5 blocks,
+    ``epsilon-train`` 63; ``criteo-tall-train``'s 3 blocks are 2.4x the
+    rule and its 67 columns fit one lane-dense array."""
     from lightgbmv1_tpu.ops.hist_pallas import (_feature_blocks,
                                                 prepared_bins_bytes)
 
@@ -449,6 +497,12 @@ def test_prepared_bytes_of_the_cells():
     assert nfb * 10_500_096 * 128 == 5_376_049_152 > 16_909_336_064 // 4
     # a matrix wider than one array's 128 columns takes two
     assert prepared_bins_bytes(137, 10_500_000, 256) == 2 * 1_344_012_288
+    # one machine's rows of criteo-tall-67f: the block form is over the
+    # quarter, the lane-dense form a third of it
+    assert prepared_bins_bytes(67, 26_562_500, 64) == 10_200_023_040 \
+        > 16_909_336_064 // 4
+    assert prepared_bins_bytes(67, 26_562_500, 64, dense=True) == \
+        3_400_007_680 < 16_909_336_064 // 4
 
 
 def _probe_meta(F, B):
