@@ -128,8 +128,9 @@ def estimated_wave_schedule(K=None, budget=254):
         rounds.append(min(k, budget - splits))
         splits += rounds[-1]
         k = min(2 * k, K)
-    return {"schedule": rounds, "rounds_per_tree": len(rounds),
-            "estimated": True}
+    # the round that spends the budget runs no histogram pass
+    return {"schedule": rounds, "measured": rounds[:-1],
+            "rounds_per_tree": len(rounds), "estimated": True}
 
 
 def probe_round_schedule(model, n_trees=5, K=None):
@@ -141,8 +142,12 @@ def probe_round_schedule(model, n_trees=5, K=None):
     (grower_wave.replay_wave_schedule: no host callback inside the timed
     program and no device round-trip at all).  A CPU test pins replay ==
     the grower's own per-bucket round counts (``WaveState.rounds``, the
-    ``rounds`` field of the per-tree record: tests/test_wave_bucket.py)."""
+    ``rounds`` field of the per-tree record: tests/test_wave_bucket.py).
+    ``schedule`` holds every round (each partitions its rows), ``measured``
+    those that ran a histogram pass: a tree's budget-spending round does
+    not (grower_wave.measured_rounds; tests/test_wave_last_round.py)."""
     from lightgbmv1_tpu.models.grower_wave import (auto_wave_size,
+                                                    measured_rounds,
                                                     replay_wave_schedule)
 
     if K is None:   # the bench config leaves leafwise_wave_size on auto
@@ -153,6 +158,8 @@ def probe_round_schedule(model, n_trees=5, K=None):
         return None
     rounds = [k for s in scheds for k in s]
     return {"schedule": rounds,
+            "measured": [k for s in scheds
+                         for k in measured_rounds(s, 255)],
             "rounds_per_tree": len(rounds) / len(scheds)}
 
 
@@ -168,7 +175,9 @@ def measure_hist_and_roofline(ds, N, schedule=None):
 
     ``hist_ms_per_iter`` is derived from the PROBED round schedule: each
     round's pass is priced at its slot bucket's measured time (the wave
-    grower runs sliced 4/16/64-slot variants), plus the 1-slot root pass.
+    grower runs sliced 4/16/64-slot variants), plus the 1-slot root pass;
+    the round that spends a tree's last leaves runs no pass and is priced
+    at nothing (``schedule["measured"]``).
     """
     import jax
     import jax.numpy as jnp
@@ -307,11 +316,11 @@ def measure_hist_and_roofline(ds, N, schedule=None):
         if schedule.get("estimated"):
             out["wave_rounds_estimated"] = True
     else:
-        est = estimated_wave_schedule(K)
-        rounds, iters = est["schedule"], 1
+        schedule = estimated_wave_schedule(K)
+        rounds, iters = schedule["schedule"], 1
         out["wave_rounds_estimated"] = True
-    per_iter = (sum(pass_ms[bucket_of(k)] for k in rounds) / iters
-                + pass_ms[1])
+    per_iter = (sum(pass_ms[bucket_of(k)] for k in schedule["measured"])
+                / iters + pass_ms[1])
     out["wave_rounds_per_tree"] = round(len(rounds) / iters, 2)
     out["hist_ms_per_iter"] = round(per_iter, 2)
     return out

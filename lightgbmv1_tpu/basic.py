@@ -581,7 +581,7 @@ class Booster:
             # boundary; off (default) costs nothing (models/gbdt.py)
             self._gbdt.check_finite_boundary()
             it.renewed = self._gbdt.renewed_count_later(first_tree)
-            it.rounds = self._gbdt.rounds_later()
+            it.rounds, it.hist_skipped = self._gbdt.round_counts_later()
         # observability: per-iteration wall into the shared registry
         # (always on — one histogram observe vs a ms-scale iteration)
         _obs_iteration_metrics().observe(it.dur_ns / 1e6)

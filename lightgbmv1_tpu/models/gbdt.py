@@ -42,6 +42,16 @@ from .tree import (HostTree, TreeArrays, leaf_lookup,
                    tree_predict_binned, tree_used_features)
 
 
+def _round_counts(third):
+    """A grower's third result as the iteration record's counts: the wave
+    grower's rounds by slot bucket and those that ran no histogram pass
+    (grower_wave.RootAndRounds); None from the others, which hand back the
+    root's sums alone."""
+    if hasattr(third, "rounds"):
+        return third.rounds, third.hist_skipped
+    return None
+
+
 class FiniteGuardError(RuntimeError):
     """``finite_guard=raise``: non-finite training state (NaN/Inf
     gradients propagated into the score cache) detected at an iteration
@@ -224,10 +234,12 @@ class GBDT:
 
         self.models: List[Optional[HostTree]] = []  # flat: iter-major, class-minor
         self._device_trees: List[TreeArrays] = []
-        # the last iteration's rounds by slot bucket, as the wave grower
-        # counted them on the device: arrays to add up, read only when the
-        # iteration's record is (rounds_later); None where no grower said
-        self._rounds = None
+        # the last iteration's rounds by slot bucket and those of them that
+        # ran no histogram pass, a pair a tree as the wave grower counted
+        # them on the device: arrays to add up, read only when the
+        # iteration's record is (round_counts_later); None where no grower
+        # said
+        self._round_counts = None
         self._model_shrink: List[float] = []
         self._model_bias: List[float] = []
         # Host trees are materialized lazily (one batched device_get at the
@@ -383,7 +395,7 @@ class GBDT:
             bag = self._bag_fraction_mask(None, iteration)
             trees = []
             leaf_ids = []
-            rounds = []
+            counts = []
             train_preds = []
             valid_preds = [[] for _ in valid_binned]
             grow_valids = getattr(self._grow, "_supports_valids", False)
@@ -402,9 +414,7 @@ class GBDT:
                         binned, g3, feat_masks[k], key, cegb_used
                     )
                     vlids = None
-                # the wave grower counts its rounds by slot bucket
-                # (grower_wave.RootAndRounds); the others hand back sums
-                rounds.append(getattr(third, "rounds", None))
+                counts.append(_round_counts(third))
                 if self._cegb_enabled:
                     cegb_used = self._update_cegb_state(
                         cegb_used, tree_dev, leaf_id)
@@ -446,9 +456,10 @@ class GBDT:
                 leaf_ids = jnp.stack(leaf_ids)
             # beside the stacked trees, not a field of them: ``bookkeep``
             # slices those field by field, and keeps this as it is
-            rounds = None if None in rounds else sum(rounds)
+            counts = (None if None in counts else
+                      tuple(sum(c) for c in zip(*counts)))
             return (train_score, valid_scores, stacked, leaf_ids,
-                    cegb_used, rounds)
+                    cegb_used, counts)
 
         self._step_fn = step
         # args 2/3 are the train/valid score caches — the buffers the
@@ -509,20 +520,25 @@ class GBDT:
             return 0
         return lambda: sum(count_marked(t, policy) for t in trees)
 
-    def rounds_later(self):
-        """For the iteration record (obs/trace.py): a callable giving the
-        rounds the last iteration's trees ran in each slot bucket of the
-        wave grower, smallest bucket first (``(b4, b16, bK)``, ``(bK,)``
-        without a ladder: grower_wave.slot_buckets_for), or None where the
-        grower has no rounds.  The device counted them (``WaveState.rounds``)
-        and they stay there until the record is read: no host operation
-        and no wait here.  Taken once: an iteration that counts none
-        (DART's own step) does not hand on the one before it."""
-        counted, self._rounds = self._rounds, None
+    def round_counts_later(self):
+        """For the iteration record (obs/trace.py): two callables, one
+        giving the rounds the last iteration's trees ran in each slot
+        bucket of the wave grower, smallest bucket first (``(b4, b16, bK)``,
+        ``(bK,)`` without a ladder: grower_wave.slot_buckets_for), the
+        other how many of those rounds ran no histogram pass
+        (grower_wave.children_can_split); None twice where the grower has
+        no rounds.  The device counted them (``WaveState.rounds``,
+        ``.hist_skipped``) and they stay there until the record is read:
+        no host operation and no wait here.  Taken once: an iteration that
+        counts none (DART's own step) does not hand on the one before
+        it."""
+        counted, self._round_counts = self._round_counts, None
         if counted is None:
-            return None
-        return lambda: tuple(
-            int(n) for n in np.sum([np.asarray(c) for c in counted], axis=0))
+            return None, None
+        rounds, skipped = zip(*counted)
+        return (lambda: tuple(int(n) for n in np.sum(
+                    [np.asarray(c) for c in rounds], axis=0)),
+                lambda: sum(int(c) for c in skipped))
 
     def check_finite_boundary(self) -> None:
         """Iteration-boundary finite check (``finite_guard=warn|raise``).
@@ -671,9 +687,9 @@ class GBDT:
                     self._cegb_used)
         with obs_trace.phase_span("dispatch"):
             (new_train, new_valid, stacked, leaf_ids,
-             self._cegb_used, rounds) = self._step(*args)
+             self._cegb_used, counts) = self._step(*args)
         with obs_trace.phase_span("bookkeep"):
-            self._rounds = None if rounds is None else [rounds]
+            self._round_counts = None if counts is None else [counts]
             self._train_scores.score = new_train
             for vs, s in zip(self._valid_scores, new_valid):
                 vs.score = s
@@ -837,19 +853,19 @@ class GBDT:
             if custom_grad is None:
                 grad, hess = self._gradients()
             bag = self._bagging_mask(self.iter)
-            new_trees, rounds = [], []
+            new_trees, counts = [], []
             for k in range(self.num_class):
                 g3 = self._sample_g3(grad[:, k], hess[:, k], bag, self.iter)
                 key = jax.random.fold_in(self._rng_key, self.iter * self.num_class + k)
                 base_mask = jnp.asarray(self._tree_feature_mask())
                 tree_dev, leaf_id, third = self._grow(
                     self._grow_binned, g3, base_mask, key, self._cegb_used)
-                rounds.append(getattr(third, "rounds", None))
+                counts.append(_round_counts(third))
                 if self._cegb_enabled:
                     self._cegb_used = self._update_cegb_state(
                         self._cegb_used, tree_dev, leaf_id)
                 new_trees.append(self._finish_tree(tree_dev, leaf_id, k))
-            self._rounds = None if None in rounds else rounds
+            self._round_counts = None if None in counts else counts
         self.iter += 1
         return check_stop and self._stopped(new_trees)
 

@@ -150,6 +150,8 @@ def replay_wave_schedule(trees, K: int):
     timed program (the live count is the grower's own: ``WaveState.rounds``,
     handed back as ``RootAndRounds.rounds``; the parity test ties the two
     together, tests/test_wave_bucket.py).
+    Every replayed round partitioned its rows; the rounds among them that
+    also ran a histogram pass are ``measured_rounds``.
     Caveats: fp-equal gain ties replay by node index (the device breaks
     ties by leaf index), and the intermediate-monotone same-round
     deferral is not modeled — neither occurs in the bench configs."""
@@ -175,11 +177,25 @@ def replay_wave_schedule(trees, K: int):
 def rounds_by_bucket(schedule, slot_buckets):
     """One replayed tree's rounds counted by the slot bucket each ran in,
     in ``WaveState.rounds``' order: the grower's own rule (``s_idx``), a
-    round of ``n`` splits takes the smallest bucket that holds them."""
+    round of ``n`` splits takes the smallest bucket that holds them.  A
+    round counts whether or not its histogram pass ran
+    (``measured_rounds``)."""
     counts = [0] * len(slot_buckets)
     for n in schedule:
         counts[sum(n > S for S in slot_buckets[:-1])] += 1
     return tuple(counts)
+
+
+def measured_rounds(schedule, num_leaves: int):
+    """The rounds of one replayed tree that ran a histogram pass: all but
+    the one that spends the ``num_leaves`` budget, whose children nothing
+    can split (``children_can_split``; ``len(schedule) - len(...)`` is the
+    tree's ``hist_skipped``).  What prices a tree's passes or its
+    histogram exchange sums over these; its partitions, over the whole
+    schedule.  A depth limit's last level is not modeled, like the
+    caveats of ``replay_wave_schedule``: no bench config sets one."""
+    spent = 1 + sum(schedule) >= num_leaves
+    return list(schedule[:-1] if spent else schedule)
 
 
 def auto_wave_size(num_leaves: int) -> int:
@@ -216,6 +232,21 @@ def round_scope(S: int, slot_buckets) -> str:
     return "lgbm.round." + ("bK" if S == slot_buckets[-1] else f"b{S}")
 
 
+def children_can_split(num_leaves, n_split, L: int, depth_ok, cvalid):
+    """Whether a round's histogram pass has a reader: some child of the
+    round may be split later.  ``num_leaves`` leaves before the round,
+    ``n_split`` splits in it, ``L`` the tree's budget; ``depth_ok`` and
+    ``cvalid`` (2K,): a child is above ``max_depth``, a child is a real
+    split's.  False for the round that spends the budget (the loop ends
+    after it) and for a round whose children all sit at ``max_depth``
+    (their gains are stored as ``-inf``, so they are never picked): the
+    pass's result would feed the subtraction, the children's scan and
+    their rows of the histogram state only, and nothing of the returned
+    tree or leaf ids.  Replicated under a row-sharded learner, as
+    ``n_split`` is."""
+    return (num_leaves + n_split < L) & jnp.any(depth_ok & cvalid)
+
+
 class RootAndRounds(NamedTuple):
     """What the wave grower hands back third, where the other growers hand
     back the root's sums alone."""
@@ -223,6 +254,8 @@ class RootAndRounds(NamedTuple):
     rounds: jax.Array         # (len(slot_buckets_for(K, N)),) int32: the
                               # rounds this tree ran in each slot bucket,
                               # smallest bucket first
+    hist_skipped: jax.Array   # () int32: those of them that ran no
+                              # histogram pass (``children_can_split``)
 
 
 def _box_adjacency_per_feature(lo, hi, feats):
@@ -312,6 +345,8 @@ class WaveState(NamedTuple):
     rounds: jax.Array         # (buckets,) int32 — rounds run so far in each
                               # slot bucket (the per-tree record's count
                               # beside ``lgbm.round.*``'s device time)
+    hist_skipped: jax.Array   # () int32 — rounds so far whose histogram
+                              # pass had no reader and did not run
     pending: dict = {}        # async_wave_pipeline: the previous round's
                               # DEFERRED commits — the (2K, F, B, 3) child
                               # histograms + their scatter indices and the
@@ -988,6 +1023,7 @@ def make_wave_grower(
                 num_leaves=jnp.asarray(1, jnp.int32),
                 done=jnp.asarray(L <= 1),
                 rounds=jnp.zeros(len(slot_buckets), jnp.int32),
+                hist_skipped=jnp.zeros((), jnp.int32),
                 pending=pend0,
             )
 
@@ -1155,6 +1191,12 @@ def make_wave_grower(
                 d = rd["pdepth"] + 1                              # (K,)
                 cdepth = jnp.stack([d, d], axis=1).reshape(2 * K)
                 depth_ok = (max_depth <= 0) | (cdepth < max_depth)
+                cvalid = jnp.stack([valid, valid], axis=1).reshape(2 * K)
+                # a round none of whose children can be split measures no
+                # histograms: every cell's last round, and a depth-limited
+                # tree's last level
+                measure = children_can_split(st.num_leaves, n_split, L,
+                                             depth_ok, cvalid)
 
                 cuids = jnp.stack([2 * nodes + 1, 2 * nodes + 2],
                                   axis=1).reshape(2 * K)
@@ -1283,27 +1325,40 @@ def make_wave_grower(
                     # variants — everything stays full precision
                     deep = S == K and K >= 32 and len(slot_buckets) > 1
                     nsl = S if use_sub else 2 * S
-                    if S in quant_buckets:
-                        # stochastic-rounded int8 pass: integer histogram +
-                        # per-slot dequant scales, rounding stream keyed per
-                        # (tree, round)
-                        h, hsc = hist_wave_quant_fn(binned, g3, label, nsl,
-                                                    rkey)
-                    else:
-                        h = hist_wave_fn(binned, g3, label, nsl, deep=deep)
-                        hsc = jnp.ones((nsl, 3), jnp.float32)
                     full = 2 * K if not use_sub else K
-                    if h.shape[0] < full:   # pad to the bucket-invariant width
+
+                    def measured():
+                        if S in quant_buckets:
+                            # stochastic-rounded int8 pass: integer
+                            # histogram + per-slot dequant scales, rounding
+                            # stream keyed per (tree, round)
+                            h, hsc = hist_wave_quant_fn(binned, g3, label,
+                                                        nsl, rkey)
+                        else:
+                            h = hist_wave_fn(binned, g3, label, nsl,
+                                             deep=deep)
+                            hsc = jnp.ones((nsl, 3), jnp.float32)
+                        if h.shape[0] < full:   # the bucket-invariant width
+                            with jax.named_scope("lgbm.select"), \
+                                    jax.named_scope(POOL_SCOPE):
+                                h = jnp.concatenate(
+                                    [h, jnp.zeros((full - h.shape[0],)
+                                                  + h.shape[1:], h.dtype)],
+                                    axis=0)
+                                # padded slots dequantize as identity
+                                hsc = jnp.concatenate(
+                                    [hsc, jnp.ones((full - hsc.shape[0], 3),
+                                                   hsc.dtype)], axis=0)
+                        return h, hsc
+
+                    def unmeasured():
                         with jax.named_scope("lgbm.select"), \
                                 jax.named_scope(POOL_SCOPE):
-                            h = jnp.concatenate(
-                                [h, jnp.zeros((full - h.shape[0],)
-                                              + h.shape[1:], h.dtype)],
-                                axis=0)
-                            # padded slots dequantize as identity
-                            hsc = jnp.concatenate(
-                                [hsc, jnp.ones((full - hsc.shape[0], 3),
-                                               hsc.dtype)], axis=0)
+                            return (jnp.zeros((full,) + hist0.shape,
+                                              hist0.dtype),
+                                    jnp.ones((full, 3), jnp.float32))
+
+                    h, hsc = lax.cond(measure, measured, unmeasured)
                     return (h, hsc, leaf_id) + tuple(vl_new)
 
             if len(slot_buckets) > 1:
@@ -1366,7 +1421,6 @@ def make_wave_grower(
                 )(hist, csums, cmask, cuids, cconstr, cdepth, couts)
             with jax.named_scope("lgbm.select"):
                 cgain = jnp.where(depth_ok, res.gain, -jnp.inf)
-                cvalid = jnp.stack([valid, valid], axis=1).reshape(2 * K)
                 cidx = jnp.where(cvalid, cleafs, L + 1)           # drop slot
 
                 # ---- tree assembly + frontier commit ------------------------
@@ -1441,6 +1495,7 @@ def make_wave_grower(
                     num_leaves=st.num_leaves + n_split,
                     done=st.done | (n_split == 0),
                     rounds=rounds,
+                    hist_skipped=st.hist_skipped + (~measure),
                     pending=new_pending,
                 )
 
@@ -1458,7 +1513,7 @@ def make_wave_grower(
             # kill-at-k bit-exact resume guarantee is unchanged.
             vlids_out = tuple(route_pending(st.pending, vb, vl)
                               for vb, vl in zip(valids, vlids_out))
-        third = RootAndRounds(root_sum, st.rounds)
+        third = RootAndRounds(root_sum, st.rounds, st.hist_skipped)
         if valids:
             return tree, st.leaf_id, third, vlids_out
         return tree, st.leaf_id, third

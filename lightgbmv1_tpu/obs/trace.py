@@ -23,18 +23,23 @@ Two kinds of span, one ring:
   the ``phase_span()`` children inside it (``train.prepare`` /
   ``train.dispatch`` / ``train.bookkeep`` / ``train.wait``) carry the
   tree's ``iteration`` and add their time to it, and when it closes it
-  writes ONE tuple of nine fields ``(iteration, t0_ns, prepare_ns,
-  dispatch_ns, bookkeep_ns, wait_ns, total_ns, renewed, rounds)`` into a
-  bounded ring (``iteration_records()``): which host phase a slow tree's
-  extra milliseconds passed in, without a profiler and without arming.
+  writes ONE tuple of ten fields ``(iteration, t0_ns, prepare_ns,
+  dispatch_ns, bookkeep_ns, wait_ns, total_ns, renewed, rounds,
+  hist_skipped)`` into a bounded ring (``iteration_records()``): which
+  host phase a slow tree's extra milliseconds passed in, without a
+  profiler and without arming.
   It is host time: where the runtime blocks the host in a phase until
   the device is done (a TPU does, in ``bookkeep``; ``GBDT._stopped``),
   that phase holds the device's time too, and the record cannot tell
-  them apart.  The last two fields are the device's own counts, read
-  back when the record is: ``renewed`` (models/renew.py) and ``rounds``,
+  them apart.  The last three fields are the device's own counts, read
+  back when the record is: ``renewed`` (models/renew.py), ``rounds``,
   the rounds the iteration's trees ran in each slot bucket of the wave
   grower (``(b4, b16, bK)``): a tree that took one more round reads so
-  here, a tree the host stalled on reads the same rounds.
+  here, a tree the host stalled on reads the same rounds; and
+  ``hist_skipped``, those of the rounds that ran no histogram pass
+  because no child of theirs could be split (a tree's last round where
+  it spends its leaves), so ``sum(rounds) - hist_skipped`` plus one a
+  tree for the root is the iteration's histogram passes.
 
 Common to both:
 
@@ -291,7 +296,8 @@ class _IterationSpan(_BridgedSpan):
     """``train.iteration``: the bridged span one tree runs under, which
     its ``phase_span`` children add their time to."""
 
-    __slots__ = ("iteration", "phase_ns", "renewed", "rounds", "_outer")
+    __slots__ = ("iteration", "phase_ns", "renewed", "rounds",
+                 "hist_skipped", "_outer")
 
     def __init__(self, iteration: int):
         super().__init__("train.iteration", "train",
@@ -304,8 +310,10 @@ class _IterationSpan(_BridgedSpan):
         # the record never waits for the device
         self.renewed = None
         # rounds the iteration's trees ran in each slot bucket of the wave
-        # grower (models/grower_wave.py), likewise a tuple or a callable
+        # grower (models/grower_wave.py), likewise a tuple or a callable;
+        # and how many of those rounds ran no histogram pass
         self.rounds = None
+        self.hist_skipped = None
 
     def __enter__(self):
         self._outer = getattr(_tls, "iteration", None)
@@ -319,7 +327,7 @@ class _IterationSpan(_BridgedSpan):
         _iterations.append((self.iteration, self.t0,
                             *(self.phase_ns[p] for p in ITERATION_PHASES),
                             self.dur_ns, _Later(self.renewed),
-                            _Later(self.rounds)))
+                            _Later(self.rounds), _Later(self.hist_skipped)))
         return False
 
 
@@ -368,16 +376,20 @@ def phase_span(phase: str) -> _PhaseSpan:
 
 def iteration_records() -> List[tuple]:
     """The last ``ITERATION_RING`` iterations, oldest first, each the
-    nine fields ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns,
-    wait_ns, total_ns, renewed, rounds)``: times on the
+    ten fields ``(iteration, t0_ns, prepare_ns, dispatch_ns, bookkeep_ns,
+    wait_ns, total_ns, renewed, rounds, hist_skipped)``: times on the
     ``perf_counter_ns`` clock; ``renewed`` the nodes and leaves of the
     iteration's trees whose stored sums were measured again from the rows;
     ``rounds`` a tuple of the rounds those trees ran in each slot bucket
     of the wave grower, smallest bucket first (``(b4, b16, bK)``, or
-    ``(bK,)`` without a ladder).  The last two are None where nobody
-    said (``rounds``: a grower that has no rounds), and are read from the
-    device on the first call that reaches them."""
-    return [(*r[:-2], r[-2].get(), r[-1].get()) for r in _iterations]
+    ``(bK,)`` without a ladder); ``hist_skipped`` how many of those rounds
+    ran no histogram pass (no child of theirs could be split: 1 a tree
+    that spends its leaves, 0 for one that runs out of gain).  The last
+    three are None where nobody said (``rounds`` and ``hist_skipped``: a
+    grower that has no rounds), and are read from the device on the first
+    call that reaches them."""
+    return [(*r[:-3], *(later.get() for later in r[-3:]))
+            for r in _iterations]
 
 
 # ---------------------------------------------------------------------------

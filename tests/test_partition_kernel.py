@@ -106,9 +106,12 @@ def _forced(monkeypatch, path):
 
 @pytest.mark.parametrize("use_sub", [True, False], ids=["sub", "pool_free"])
 def test_whole_grow_kernel_against_gather(monkeypatch, use_sub):
-    """One 31-leaf tree over 4- and 15-slot buckets with the kernel in
+    """One 40-leaf tree over 4- and 15-slot buckets with the kernel in
     every round and with the gather form in every round: the same tree,
-    leaf ids and, round for round, histogram labels."""
+    leaf ids and, round for round, histogram labels (of the rounds that
+    measure their children: the one that spends the last leaves runs no
+    pass, so the tree has leaves for a round after the first of 15
+    splits)."""
     rng = np.random.RandomState(7)
     N, F = 3000, 28
     bins = jnp.asarray(rng.randint(0, B, (F, N)).astype(np.uint8))
@@ -131,7 +134,7 @@ def test_whole_grow_kernel_against_gather(monkeypatch, use_sub):
 
         _forced(monkeypatch, path)
         grow = gw.make_wave_grower(
-            num_leaves=31, num_bins=B, meta=_meta(F, rng=np.random.RandomState(1)),
+            num_leaves=40, num_bins=B, meta=_meta(F, rng=np.random.RandomState(1)),
             params=SplitParams(min_data_in_leaf=2.0), wave_size=15,
             hist_wave_fn=hist, hist_method="pallas", pallas_interpret=True)
         tree, leaf_id, _ = jax.block_until_ready(jax.jit(grow)(
@@ -142,7 +145,7 @@ def test_whole_grow_kernel_against_gather(monkeypatch, use_sub):
     tree_k, leaf_k, labels_k = run("kernel")
     assert _traced_since(before) == {("kernel", 4): 1, ("kernel", 15): 1}
     tree_g, leaf_g, labels_g = run("gather")
-    assert int(tree_k.num_leaves) == 31
+    assert int(tree_k.num_leaves) == 40
     for a, b in zip(tree_k, tree_g):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(leaf_k, leaf_g)

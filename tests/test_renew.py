@@ -242,7 +242,7 @@ def test_data_learner_is_traced_and_counted(method):
             jax.random.PRNGKey(0), gbdt._cegb_used)
         texts[name] = lowered.as_text(debug_info=True)
         rec = obs_trace.iteration_records()[-1]
-        assert len(rec) == 9 and isinstance(rec[7], int)
+        assert len(rec) == 10 and isinstance(rec[7], int)
         # one slot bucket at this size; the row-sharded learner hands the
         # count out of its shard_map like the serial one
         assert len(rec[8]) == 1 and rec[8][0] >= 3

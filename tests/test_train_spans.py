@@ -98,10 +98,11 @@ def test_one_record_a_tree_with_the_tracer_disarmed():
     assert [r[0] for r in recs] == [1, 2, 3, 4, 5]
     covered = []
     for (it, t0, prepare, dispatch, bookkeep, wait, total, renewed,
-         rounds) in recs:
+         rounds, hist_skipped) in recs:
         assert isinstance(renewed, int) and renewed >= 0
         # 15 leaves at K = 3: no ladder, one bucket, at least 5 rounds
         assert len(rounds) == 1 and rounds[0] >= 5
+        assert hist_skipped in (0, 1)
         assert min(prepare, dispatch, bookkeep, wait) > 0
         assert prepare + dispatch + bookkeep + wait <= total
         covered.append((prepare + dispatch + bookkeep + wait) / total)
@@ -130,9 +131,9 @@ def test_the_record_counts_rounds_by_slot_bucket(ladder, monkeypatch):
     for _ in range(3):
         b.update()
     # written as the span closed, still the device's: nothing waited
-    assert all(callable(r[-1]._v) for r in trace._iterations)
+    assert all(callable(r[8]._v) for r in trace._iterations)
     recs = trace.iteration_records()
-    assert all(isinstance(r[-1]._v, tuple) for r in trace._iterations)
+    assert all(isinstance(r[8]._v, tuple) for r in trace._iterations)
     live = [r[8] for r in recs]
     assert live == [r[8] for r in trace.iteration_records()]
     assert all(isinstance(n, int) for t in live for n in t)
@@ -154,7 +155,7 @@ def test_a_grower_without_rounds_leaves_the_field_empty():
     b = _booster(tree_growth="levelwise")
     b.update()
     rec = trace.iteration_records()[-1]
-    assert len(rec) == 9 and rec[8] is None
+    assert len(rec) == 10 and rec[8] is None and rec[9] is None
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks", "span_readers.py")
     with open(path) as fh:
