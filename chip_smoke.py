@@ -451,7 +451,7 @@ class Smoke:
                                        else v.astype(np.int32))
                         for k, v in cols.items()}
                 row = {"rule": partition_path(columns, S, self.n,
-                                              pallas=True, plain_u8=True,
+                                              pallas=True, layout="u8",
                                               use_cat=False)}
                 for use_sub in (True, False):
                     gather = functools.partial(partition_gather,

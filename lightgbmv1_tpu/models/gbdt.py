@@ -138,8 +138,9 @@ class GBDT:
                                             train_set.num_bins)
         # 4-bit packing (reference DenseBin<..,IS_4BIT>, dense_bin.hpp:52):
         # two bins per byte when every feature fits 4 bits — halves the
-        # binned matrix in HBM and the hist pass's dominant read stream
-        # (in-VMEM nibble unpack).
+        # stored matrix in HBM and what a partition round reads of it; the
+        # histogram kernel's prepared operand is as wide either way
+        # (ops/hist_pallas.pack4bit).
         # Layout resolution + once-per-build logging:
         # parallel/trainer.select_bin_layout (config.bin_layout).
         self._packed = False
@@ -161,7 +162,8 @@ class GBDT:
                 from ..ops.hist_pallas import pack4bit
 
                 self._packed = True
-                self._host_matrix = pack4bit(self._host_matrix)
+                with construct_phase("pack"):
+                    self._host_matrix = pack4bit(self._host_matrix)
         if self._is_streaming:
             self.binned = None
         elif getattr(train_set, "is_row_sharded", False):

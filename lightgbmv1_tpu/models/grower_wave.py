@@ -98,7 +98,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.hist_pallas import bin_matrix
+from ..ops.hist_pallas import bin_matrix, packed_bins_of_feat
 from ..ops.partition_pallas import (MAX_LEAF_IDS, assign_rows,
                                     count_partition_bytes,
                                     count_partition_round, partition_pallas,
@@ -788,7 +788,9 @@ def make_wave_grower(
     data-parallel).
     ``bins_of_fn(binned, feat) -> (N,)`` — ORIGINAL bins of a feature; the
     EFB path substitutes the bundle-column decode (io/bundle.py
-    bundle_bins_of_feat), so ``binned`` may be the (BF, N) bundled matrix.
+    bundle_bins_of_feat), so ``binned`` may be the (BF, N) bundled matrix,
+    and the 4-bit path ``hist_pallas.packed_bins_of_feat``, whose
+    ``(ceil(F/2), N)`` layout the partition kernel decodes too.
     ``fused_bookkeeping`` selects the per-round state layout: packed
     tables with one coalesced scatter each (_PackedStore, default) or the
     legacy per-field scatters (_FieldStore); trees are bit-identical
@@ -894,12 +896,19 @@ def make_wave_grower(
         # is by the replicated n_split, so row shards stay in lockstep.
         slot_buckets = slot_buckets_for(K, N)
 
+        # what a row of the stored matrix holds: a feature's bins, two
+        # features' nibbles (``packed_bins_of_feat`` reads them), or what
+        # the kernel does not decode (an EFB bundle column, wider bins)
+        layout = ""
+        if bins.dtype == jnp.uint8 and plain_bins and bins.shape[0] == F:
+            layout = "u8"
+        elif bins.dtype == jnp.uint8 and bins_of_fn is packed_bins_of_feat:
+            layout = "packed4"
+
         def partition_path_of(S):
             return partition_path(
                 bins.shape[0], S, N, pallas=hist_method == "pallas",
-                plain_u8=(plain_bins and bins.dtype == jnp.uint8
-                          and bins.shape[0] == F),
-                use_cat=use_cat)
+                layout=layout, use_cat=use_cat)
 
         count_partition_bytes(partition_path_of(slot_buckets[-1]),
                               bins.shape[0], slot_buckets[-1], N)
@@ -1297,6 +1306,7 @@ def make_wave_grower(
                                      leafs=leafs_s, nls=nls_s, sml=sml_s,
                                      mt=mt_s, nan=nan_s, zero=zero_s),
                                 use_sub=use_sub, missing=has_missing,
+                                packed=layout == "packed4",
                                 interpret=pallas_interpret)
                         else:
                             leaf_id, label = (v[0] for v in assign_rows(

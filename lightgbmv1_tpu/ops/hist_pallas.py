@@ -287,8 +287,10 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
     cb = max(1, min(B, 512 // fblk))         # bins per chunk
     n_chunks = -(-B // cb)
     if packed:
-        # unpack two 4-bit bins per byte in VMEM: HBM traffic for the
-        # binned matrix halves (the hist pass's dominant stream)
+        # unpack two 4-bit bins per byte in VMEM.  The tile's rows are
+        # 128 lanes wide in HBM packed or not, so this pass reads what the
+        # unpacked one reads: packing halves the matrix a learner stores
+        # and what a partition round reads of it
         bi = bins_ref[...].astype(jnp.int32)
         bins_f = jnp.concatenate([bi & 15, bi >> 4], axis=1) \
             .astype(jnp.float32)
@@ -361,8 +363,11 @@ def pack4bit(binned: np.ndarray) -> np.ndarray:
     """(F, N) uint8 bins < 16 -> (ceil(F/2), N) packed bytes, two features
     per byte (lo nibble = feature 2p, hi = 2p+1) — the analog of the
     reference's 4-bit dense bins (DenseBin<VAL_T, IS_4BIT=true>,
-    src/io/dense_bin.hpp:52): halves the binned matrix's HBM footprint and
-    the hist pass's dominant memory stream at max_bin <= 15."""
+    src/io/dense_bin.hpp:52): at max_bin <= 15 it halves the stored
+    matrix in HBM and what a partition round reads of it
+    (``partition_pallas`` decodes the nibble).  It does not halve the
+    histogram pass's read: ``prepare_hist_bins`` pads a block to an
+    array's 128 lanes, packed (14 live at 28 features) or not (28)."""
     binned = np.asarray(binned)
     F, N = binned.shape
     if F % 2:
@@ -440,8 +445,10 @@ def bin_matrix(binned) -> jax.Array:
 def _dense(num_bins: int, dense: bool) -> bool:
     """Whether the operand of such a pass is lane-dense: the 256 rung's
     always, the 64 rung's where asked (``dense``: the form the bytes rule
-    chose, ``hist_bins_form``), the 16 rung's never (its blocks, packed
-    or not, fill an array's 128 lanes as they are)."""
+    chose, ``hist_bins_form``), the 16 rung's never: a block is at most
+    128 byte columns unpacked, 64 packed (14 of an array's 128 lanes at 28
+    features), one resident array whose lane padding the pass's cut leaves
+    as a bitcast."""
     rung = kernel_width(num_bins)
     return rung == 256 or (dense and rung == 64)
 

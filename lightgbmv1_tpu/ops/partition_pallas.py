@@ -22,6 +22,14 @@ histogram label (``assign_rows``).  Two memory forms of that one algorithm:
   leaf ids and labels.  Nothing ``(S, N)``-shaped touches HBM; the matrix
   is read whole, ``F_pad32 x N`` bytes, where the gather reads ``S`` rows.
 
+The kernel reads both layouts a booster stores its bins in: ``u8``, one
+row a feature, and ``packed4`` (``hist_pallas.pack4bit``, ``max_bin <=
+15``), ``ceil(F/2)`` rows with feature ``f`` in the nibble ``4 * (f & 1)``
+bits up of row ``f >> 1``: the selection picks the byte row and a shift
+and a mask on the ``(S, T)`` product give the bin id, as
+``hist_pallas.packed_bins_of_feat`` states the layout.  EFB bundle
+columns and categorical columns keep the gather form.
+
 ``partition_path`` says which form a round takes, from shapes alone.
 """
 
@@ -34,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .hist_pallas import packed_bins_of_feat
 from .split import MISSING_NONE, go_left_rule
 
 KERNEL_NAME = "partition_pallas"
@@ -76,6 +85,8 @@ MAX_LEAF_IDS = 1 << 21
 _HIT = 1 << 30
 # rows of the ``(9, S, 1)`` per-slot operand
 _COLS = ("feats", "thrs", "dls", "leafs", "nls", "sml", "mt", "nan", "zero")
+# the stored layouts the kernel decodes (``partition_path``)
+KERNEL_LAYOUTS = ("u8", "packed4")
 
 
 def _pad(n: int, to: int) -> int:
@@ -83,21 +94,25 @@ def _pad(n: int, to: int) -> int:
 
 
 def partition_path(columns: int, slots: int, rows: int, *, pallas: bool,
-                   plain_u8: bool, use_cat: bool) -> str:
+                   layout: str, use_cat: bool) -> str:
     """``"kernel"`` or ``"gather"`` for a round of ``slots`` slots over a
     stored matrix of ``columns`` rows by ``rows`` columns: the one place
     the choice is made, called when a bucket's pass is traced.
 
     The kernel serves what it can read: the resolved histogram method is
     the Pallas one (XLA:CPU keeps the gather form; the interpreter runs the
-    kernel in tests), the matrix is the plain ``u8`` one row a feature (EFB
-    decodes a bundle column and ``packed4`` a nibble: both keep the gather
-    form), no column is categorical (bitset membership stays with the
-    gather form), and there is a whole chunk of rows (Mosaic takes a 1-D
-    array of fewer in another tiling than the compiler gives it).  Then
-    the widths decide, by each form's cost a row in picoseconds (the
-    constants above, with the chip readings they are fitted from)."""
-    if not (pallas and plain_u8) or use_cat or rows < _ROW_CHUNK:
+    kernel in tests), the matrix's ``layout`` is one the kernel decodes
+    (``KERNEL_LAYOUTS``: ``u8``, one row a feature, and ``packed4``, two
+    features' nibbles a row; an EFB bundle column and bins wider than a
+    byte keep the gather form), no column is categorical (bitset
+    membership stays with the gather form), and there is a whole chunk of
+    rows (Mosaic takes a 1-D array of fewer in another tiling than the
+    compiler gives it).  Then the widths decide, by each form's cost a row
+    in picoseconds (the constants above, with the chip readings they are
+    fitted from): the kernel's byte term prices the rows it reads, the
+    stored ones (``ceil(F/2)`` packed), padded to a tile."""
+    if not pallas or layout not in KERNEL_LAYOUTS or use_cat \
+            or rows < _ROW_CHUNK:
         return "gather"
     stored = _pad(columns, _U8_ROWS)
     if stored * _ROW_CHUNK > _BLOCK_BYTES:
@@ -183,12 +198,15 @@ def assign_rows(gl, leaf, leafs, nls, sml, slots: int, use_sub: bool):
     return new, jnp.where(hit, word & ((1 << _LABEL_BITS) - 1), dead)
 
 
-@functools.partial(jax.jit, static_argnames=("use_sub",))
-def partition_gather(bins, leaf_id, cols, *, use_sub: bool):
-    """``partition_pallas``'s round in the gather form on a plain ``u8``
-    matrix: what the kernel is held to in tests and on the chip."""
+@functools.partial(jax.jit, static_argnames=("use_sub", "packed"))
+def partition_gather(bins, leaf_id, cols, *, use_sub: bool,
+                     packed: bool = False):
+    """``partition_pallas``'s round in the gather form on the same stored
+    matrix (``packed``: ``packed_bins_of_feat`` decodes a feature's
+    nibbles): what the kernel is held to in tests and on the chip."""
     col = {name: cols[name][:, None] for name in _COLS}
-    ids = jax.vmap(lambda f: bins[f])(cols["feats"]).astype(jnp.int32)
+    ids = jax.vmap(lambda f: packed_bins_of_feat(bins, f) if packed
+                   else bins[f])(cols["feats"]).astype(jnp.int32)
     gl = go_left_rule(ids, col["thrs"], col["dls"], col["mt"], col["nan"],
                       col["zero"])
     new, label = assign_rows(gl, leaf_id[None, :], col["leafs"], col["nls"],
@@ -197,16 +215,18 @@ def partition_gather(bins, leaf_id, cols, *, use_sub: bool):
 
 
 def _kernel(cols_ref, bins_ref, leaf_ref, new_ref, label_ref, *, slots,
-            use_sub, missing):
+            use_sub, missing, packed):
     """Grid: (row blocks,).  cols (9, Sp, 1) int32, one (Sp, 1) column a
     name of ``_COLS`` (slots past the live ones carry a leaf id no row
-    has); bins (Fp, T) uint8, the rows past the matrix's F undefined bytes
-    that the selection multiplies by 0; leaf, new, label (T,) int32: the
-    ids' own 1-D arrays (as a ``(1, N)`` view they were a copy each way, in
-    and out, wherever the compiler keeps an array of their size in its
-    fast memory: 0.27-0.36 ms a round at 4 M and 2.27 M rows, chip run of
-    2026-10-03).  The lanes of an edge block past the last row compute on
-    undefined bytes and are not written back."""
+    has); bins (Fp, T) uint8, the rows past the matrix's stored ones
+    undefined bytes that the selection multiplies by 0 (``packed``: row
+    ``f >> 1`` holds feature ``f``'s bins in its nibble ``f & 1``);
+    leaf, new, label (T,) int32: the ids' own 1-D arrays (as a ``(1, N)``
+    view they were a copy each way, in and out, wherever the compiler
+    keeps an array of their size in its fast memory: 0.27-0.36 ms a round
+    at 4 M and 2.27 M rows, chip run of 2026-10-03).  The lanes of an edge
+    block past the last row compute on undefined bytes and are not written
+    back."""
     c = {name: cols_ref[i] for i, name in enumerate(_COLS)}
     Sp = c["feats"].shape[0]
     Fp, T = bins_ref.shape
@@ -214,8 +234,10 @@ def _kernel(cols_ref, bins_ref, leaf_ref, new_ref, label_ref, *, slots,
     # product with the tile read as signed bytes is that feature's bin id
     # less 256 where the id is 128 or more, so its low byte is the id.  The
     # tile goes to the MXU as it is stored: nothing widens it.
+    # (packed: at its feature's byte row)
     sel = (lax.broadcasted_iota(jnp.int32, (Sp, Fp), 1)
-           == c["feats"]).astype(jnp.int32).astype(jnp.int8)
+           == (c["feats"] >> 1 if packed else c["feats"])
+           ).astype(jnp.int32).astype(jnp.int8)
     # with no missing type on any column the rule's NaN / zero terms fold
     mt = c["mt"] if missing else MISSING_NONE
 
@@ -226,6 +248,8 @@ def _kernel(cols_ref, bins_ref, leaf_ref, new_ref, label_ref, *, slots,
             sel, lax.bitcast_convert_type(bins_ref[at], jnp.int8),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32) & 255          # (Sp, rows)
+        if packed:      # the feature's nibble of the byte
+            ids = (ids >> (4 * (c["feats"] & 1))) & 15
         gl = go_left_rule(ids, c["thrs"], c["dls"] != 0, mt, c["nan"],
                           c["zero"])
         new, label = assign_rows(
@@ -234,20 +258,22 @@ def _kernel(cols_ref, bins_ref, leaf_ref, new_ref, label_ref, *, slots,
         new_ref[rows], label_ref[rows] = new[0], label[0]
 
 
-@functools.partial(jax.jit, static_argnames=("use_sub", "missing",
+@functools.partial(jax.jit, static_argnames=("use_sub", "missing", "packed",
                                              "row_block", "interpret"))
 def partition_pallas(bins, leaf_id, cols, *, use_sub: bool,
-                     missing: bool = True, row_block: int = 0,
-                     interpret: bool = False):
+                     missing: bool = True, packed: bool = False,
+                     row_block: int = 0, interpret: bool = False):
     """``(new leaf ids, labels)``, each ``(N,)`` int32, of one round.
 
     ``bins`` is the ``(F, N)`` uint8 matrix as it is stored (a row-sharded
-    learner's shard inside its ``shard_map``), ``leaf_id`` ``(N,)`` int32,
-    ``cols`` a dict of the round's ``(S,)`` per-slot columns under the
-    names of ``_COLS``: the split's feature, threshold and default
-    direction, the leaf it splits (an empty slot: a leaf id no row has),
-    the new right leaf, whether the left child is the smaller, and the
-    feature's ``missing_type`` / ``nan_bin`` / ``zero_bin``.  ``use_sub``
+    learner's shard inside its ``shard_map``; ``packed``: the ``(ceil(F/2),
+    N)`` bytes of ``hist_pallas.pack4bit``, whose odd-F phantom nibble no
+    slot names), ``leaf_id`` ``(N,)`` int32, ``cols`` a dict of the
+    round's ``(S,)`` per-slot columns under the names of ``_COLS``: the
+    split's feature, threshold and default direction, the leaf it splits
+    (an empty slot: a leaf id no row has), the new right leaf, whether the
+    left child is the smaller, and the feature's ``missing_type`` /
+    ``nan_bin`` / ``zero_bin``.  ``use_sub``
     picks the labeling: the smaller child's slot or S; else ``2 * slot +
     right`` or 2S.  ``missing=False`` says no column has a missing type
     (every ``mt`` is ``MISSING_NONE``): the kernel hands ``go_left_rule``
@@ -265,7 +291,7 @@ def partition_pallas(bins, leaf_id, cols, *, use_sub: bool,
     T = min(row_block or _row_block(Fp), _pad(N, _ROW_CHUNK))
     new, label = pl.pallas_call(
         functools.partial(_kernel, slots=S, use_sub=use_sub,
-                          missing=missing),
+                          missing=missing, packed=packed),
         grid=(-(-N // T),),
         in_specs=[pl.BlockSpec((len(_COLS), Sp, 1), lambda i: (0, 0, 0)),
                   pl.BlockSpec((Fp, T), lambda i: (0, i)),

@@ -363,7 +363,23 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
     pairing), and not ``gpu_use_dp`` (an explicit request for the widest
     histogram datapath; packing narrows the read stream — dp wins, the
     int8sr precedent).  ``auto`` packs exactly when eligible, silently on
-    refusal; an EXPLICIT ``packed4`` refusal logs the staged warning."""
+    refusal; an EXPLICIT ``packed4`` refusal logs the staged warning.
+    The gauge ``bin_layout_engaged{layout}`` reads 1 for the layout taken
+    and 0 for the other."""
+    layout = _resolve_bin_layout(config, num_total_bin, bin_dtype, bundled)
+    from ..obs.metrics import default_registry
+
+    engaged = default_registry().gauge(
+        "bin_layout_engaged",
+        "1 for the layout the last booster built stores its bins in",
+        label_names=("layout",))
+    for name in ("u8", "packed4"):
+        engaged.labels(layout=name).set(float(name == layout))
+    return layout
+
+
+def _resolve_bin_layout(config: Config, num_total_bin: int, bin_dtype,
+                        bundled: bool) -> str:
     if config.bin_layout == "u8":
         return "u8"
     explicit = config.bin_layout == "packed4"
@@ -390,8 +406,8 @@ def select_bin_layout(config: Config, *, num_total_bin: int, bin_dtype,
             log_warning(f"bin_layout=packed4: {reason}; storing u8 bins")
         return "u8"
     log_info("bin_layout=packed4: 4-bit packed bins engaged — two bins "
-             "per byte, the (F, N) binned read and the streaming cache "
-             "shards halve (ops/hist_pallas.pack4bit)")
+             "per byte, the stored (F, N) matrix, the partition's read and "
+             "the streaming cache shards halve (ops/hist_pallas.pack4bit)")
     return "packed4"
 
 

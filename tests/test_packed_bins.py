@@ -147,6 +147,62 @@ def test_packed_parity_int8sr(monkeypatch):
                    problem=make_binary_problem(n=1600), iters=2)
 
 
+def _kernel_rounds():
+    """``partition_rounds_traced_total`` by path, summed over buckets."""
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    out = {}
+    for key, value in default_registry().snapshot().items():
+        if key.startswith("partition_rounds_traced_total{"):
+            path = key.split('path="')[1].split('"')[0]
+            out[path] = out.get(path, 0) + int(value)
+    return out
+
+
+def test_packed_parity_with_the_partition_kernel(monkeypatch):
+    """``max_bin=15`` through ``lgb.Dataset`` / ``Booster.update`` with
+    ``bin_layout`` at its default: ``packed4`` engages, every round's
+    partition takes the kernel on the ``(ceil(F/2), N)`` bytes (counted
+    under ``path="kernel"``, none under ``"gather"``), and the trees are
+    the ``bin_layout=u8`` booster's bit for bit, an odd F among them."""
+    import lightgbmv1_tpu as lgb
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
+    X, y = make_binary_problem(n=2500, f=9, seed=5)
+    params = {**_BASE, "max_bin": 15, "num_leaves": 40,
+              "leafwise_wave_size": 15}
+    texts = {}
+    for layout in ("auto", "u8"):
+        before = _kernel_rounds()
+        booster = lgb.Booster({**params, "bin_layout": layout},
+                              lgb.Dataset(X, label=y, params=params))
+        for _ in range(3):
+            booster.update()
+        since = {k: v - before.get(k, 0) for k, v in _kernel_rounds().items()}
+        assert since.get("kernel", 0) >= 2 and not since.get("gather"), \
+            (layout, since)
+        engaged = default_registry().snapshot()
+        assert engaged['bin_layout_engaged{layout="packed4"}'] == \
+            (layout == "auto")
+        assert engaged['bin_layout_engaged{layout="u8"}'] == (layout == "u8")
+        texts[layout] = booster.model_to_string()
+    assert "Tree=2" in texts["u8"]
+    assert texts["auto"] == texts["u8"], "packed4 trees diverged from u8"
+
+
+def test_packed_matrix_is_packed_under_its_own_span():
+    """``GBDT.__init__`` packs the host matrix inside ``data.pack``: the
+    phase's seconds land in ``dataset_construct_seconds{phase="pack"}``."""
+    from lightgbmv1_tpu.obs.metrics import default_registry
+
+    key = 'dataset_construct_seconds{phase="pack"}'
+    before = default_registry().snapshot().get(key, 0.0)
+    X, y = make_binary_problem(n=700, f=6, seed=11)
+    _train({"max_bin": 15}, X, y, iters=1)
+    assert default_registry().snapshot()[key] > before
+
+
 def test_packed_valid_routing_parity():
     """Valid rows route through the packed decision lane (nibble decode
     of the split feature): valid METRICS and trees must be bit-equal

@@ -1,7 +1,8 @@
 """The row-tiled partition kernel (ops/partition_pallas.py) under the
 interpreter against the gather form it replaces: integers, so equal or
-wrong.  And ``partition_path``, the rule on shapes that picks the form,
-with the counter and the gauge that say what it picked."""
+wrong.  The ``packed4`` layout (two features' nibbles a byte) against a
+plain numpy reference.  And ``partition_path``, the rule on shapes that
+picks the form, with the counter and the gauge that say what it picked."""
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,8 @@ import pytest
 import lightgbmv1_tpu as lgb
 from lightgbmv1_tpu.models import grower_wave as gw
 from lightgbmv1_tpu.obs.metrics import default_registry
-from lightgbmv1_tpu.ops.hist_pallas import pack4bit, packed_bins_of_feat
+from lightgbmv1_tpu.ops.hist_pallas import (pack4bit, packed_bins_of_feat,
+                                            unpack4bit)
 from lightgbmv1_tpu.ops.histogram import hist_wave
 from lightgbmv1_tpu.ops.partition_pallas import (partition_bytes,
                                                  partition_gather,
@@ -84,6 +86,77 @@ def test_kernel_equals_gather(S, F, use_sub):
         assert set(np.unique(new[new != leaf_id])) <= set(
             range(100, 100 + S))
     assert len(seen) == 6, seen
+
+
+def _reference_round(bins, leaf_id, cols, use_sub):
+    """What a round does, in numpy on the unpacked ``(F, N)`` bins: a row
+    of the leaf slot s splits reads its bin at the slot's feature, goes
+    left where ``go_left_rule`` says (NaN / zero rows follow the default
+    direction, the rest ``bin <= thr``), stays in its leaf or moves to the
+    slot's new right leaf, and takes the label ``assign_rows`` documents;
+    a row of a leaf no slot splits keeps its leaf and the dead label."""
+    c = {k: np.asarray(v).astype(np.int64) for k, v in cols.items()}
+    S = len(c["feats"])
+    dead = S if use_sub else 2 * S
+    new, label = leaf_id.astype(np.int64).copy(), np.full(len(leaf_id), dead)
+    for s in range(S):
+        mine = leaf_id == c["leafs"][s]
+        b = bins[c["feats"][s]].astype(np.int64)
+        na = (((c["mt"][s] == MISSING_NAN) & (b == c["nan"][s]))
+              | ((c["mt"][s] == MISSING_ZERO) & (b == c["zero"][s])))
+        left = np.where(na, c["dls"][s] != 0, b <= c["thrs"][s])
+        new[mine & ~left] = c["nls"][s]
+        if use_sub:
+            label[mine & (left == (c["sml"][s] != 0))] = s
+        else:
+            label[mine] = 2 * s + (~left[mine])
+    return new, label
+
+
+@pytest.mark.parametrize("missing", [True, False], ids=["missing", "none"])
+@pytest.mark.parametrize("F", [7, 28, 67])
+@pytest.mark.parametrize("S", [1, 4, 16, 63])
+def test_packed_kernel_equals_reference(S, F, missing):
+    """The kernel on the ``packed4`` matrix (``(ceil(F/2), N)``: the
+    selection picks byte row ``f >> 1``, a shift and a mask the nibble)
+    against the numpy reference on the unpacked bins, and beside it the
+    gather form on the same packed matrix and the ``u8`` kernel on the
+    unpacked one: an odd F (the last feature's byte has a phantom hi
+    nibble), a slot on the last feature and one splitting at bin 0, rows
+    in every slot's nan and zero bins, an edge block, both labelings,
+    every missing type or none (the kernel's static fold)."""
+    rng = np.random.RandomState(7000 + 100 * S + F)
+    N = 2048 + 300 + 37
+    bins, leaf_id, cols = _round(rng, F, S, N, offset=S % 3)
+    cols["feats"] = cols["feats"].at[0].set(F - 1)
+    if S > 1:
+        cols["thrs"] = cols["thrs"].at[1].set(0)
+    if not missing:
+        cols["mt"] = jnp.full(S, MISSING_NONE, jnp.int32)
+    packed = jnp.asarray(pack4bit(bins))
+    assert packed.shape == (-(-F // 2), N)
+    np.testing.assert_array_equal(unpack4bit(np.asarray(packed), F), bins)
+    for use_sub in (True, False):
+        want = _reference_round(bins, leaf_id, cols, use_sub)
+        forms = {
+            "packed kernel": partition_pallas(
+                packed, jnp.asarray(leaf_id), cols, use_sub=use_sub,
+                missing=missing, packed=True, row_block=2048,
+                interpret=True),
+            "packed gather": partition_gather(
+                packed, jnp.asarray(leaf_id), cols, use_sub=use_sub,
+                packed=True),
+            "u8 kernel": partition_pallas(
+                jnp.asarray(bins), jnp.asarray(leaf_id), cols,
+                use_sub=use_sub, missing=missing, row_block=2048,
+                interpret=True)}
+        for form, got in forms.items():
+            for g, w in zip(got, want):
+                assert g.dtype == jnp.int32 and g.shape == (N,)
+                np.testing.assert_array_equal(np.asarray(g), w,
+                                              err_msg=form)
+        # the round moved rows and labelled rows of every live slot
+        assert (want[0] != leaf_id).any()
 
 
 def _meta(F, rng=None):
@@ -181,7 +254,7 @@ def test_booster_with_the_kernel_is_the_booster_without(monkeypatch):
 # ---- the rule --------------------------------------------------------------
 
 def _path(columns, slots, rows=1_000_000, **kw):
-    kw = {"pallas": True, "plain_u8": True, "use_cat": False, **kw}
+    kw = {"pallas": True, "layout": "u8", "use_cat": False, **kw}
     return partition_path(columns, slots, rows, **kw)
 
 
@@ -200,11 +273,25 @@ def test_partition_path_at_the_cells_shapes(columns, slots, path):
 def test_partition_path_keeps_the_gather_form_for_what_the_kernel_cannot_read():
     assert _path(28, 63) == "kernel"
     assert _path(28, 63, use_cat=True) == "gather"
-    assert _path(28, 63, plain_u8=False) == "gather"    # EFB, packed4, u16
+    assert _path(28, 63, layout="bundle") == "gather"   # an EFB column
+    assert _path(28, 63, layout="wide") == "gather"     # 16-bit bins
     assert _path(28, 63, pallas=False) == "gather"      # XLA:CPU's methods
     # under a chunk of rows Mosaic and the compiler tile a 1-D array apart
     assert _path(28, 63, rows=1024) == "kernel"
     assert _path(28, 63, rows=1023) == "gather"
+
+
+@pytest.mark.parametrize("slots", [1, 4, 16, 63])
+def test_partition_path_admits_packed4(slots):
+    """``higgs-15b-train``'s matrix, 28 features as 14 stored rows at
+    10,500,000 rows: the kernel in every bucket, priced on the stored rows
+    (14 pad to a tile's 32, as 28 do); categorical columns and too few
+    rows still keep the gather form."""
+    assert _path(14, slots, rows=10_500_000, layout="packed4") == "kernel"
+    assert _path(14, slots, rows=10_500_000, layout="packed4") == \
+        _path(28, slots, rows=10_500_000)
+    assert _path(14, slots, layout="packed4", use_cat=True) == "gather"
+    assert _path(14, slots, rows=1023, layout="packed4") == "gather"
 
 
 def _traced():
@@ -272,11 +359,27 @@ def test_counter_and_gauge_at_higgs_and_epsilon_shapes():
     assert _bytes_gauge("kernel") == 160 * 2_270_296 + 12 * 2_270_296
 
 
+def test_counter_and_gauge_at_the_packed_higgs_shape():
+    """``higgs-15b-train``'s packed matrix (14 stored rows of 28 features
+    at 10,500,000 rows): every bucket's round counted under
+    ``path="kernel"``, the bytes those of the stored rows padded to a tile
+    and 12 a row."""
+    N = 10_500_000
+    before = _traced()
+    _trace_grow((14, N), features=28, bins_of_fn=packed_bins_of_feat)
+    assert _traced_since(before) == {
+        ("kernel", 4): 1, ("kernel", 16): 1, ("kernel", 63): 1}
+    assert _bytes_gauge("kernel") == 32 * N + 12 * N \
+        == partition_bytes("kernel", 14, 63, N)
+
+
 def test_grower_keeps_the_gather_form_off_the_plain_u8_matrix():
     """What the grower can see of its input decides: a categorical column,
-    a ``packed4`` matrix (its own ``bins_of_fn``), a bundled one (fewer
-    stored columns than features), 16-bit bins, a histogram method that is
-    not the Pallas one."""
+    a bundled matrix (fewer stored columns than features, its own
+    ``bins_of_fn``), 16-bit bins, a histogram method that is not the
+    Pallas one keep the gather form; the plain ``u8`` matrix and the
+    ``packed4`` one (``packed_bins_of_feat`` over ``ceil(F/2)`` rows) take
+    the kernel."""
     gather = {("gather", 4): 1, ("gather", 16): 1, ("gather", 63): 1}
     N = 100_000
     cat = _meta(28)._replace(
@@ -284,7 +387,7 @@ def test_grower_keeps_the_gather_form_off_the_plain_u8_matrix():
     packed = pack4bit(np.zeros((28, 256), np.uint8))
     for kw in (dict(matrix_shape=(28, N), meta=cat),
                dict(matrix_shape=(packed.shape[0], N), features=28,
-                    bins_of_fn=packed_bins_of_feat),
+                    bins_of_fn=packed_bins_of_feat, meta=cat),
                dict(matrix_shape=(9, N), features=28,
                     bins_of_fn=lambda b, f: b[f % 9]),
                dict(matrix_shape=(28, N), dtype=jnp.uint16),
@@ -292,10 +395,13 @@ def test_grower_keeps_the_gather_form_off_the_plain_u8_matrix():
         before = _traced()
         _trace_grow(**kw)
         assert _traced_since(before) == gather, kw
-    before = _traced()
-    _trace_grow((28, N))
-    assert set(_traced_since(before)) == {
-        ("kernel", 4), ("kernel", 16), ("kernel", 63)}
+    for kw in (dict(matrix_shape=(28, N)),
+               dict(matrix_shape=(packed.shape[0], N), features=28,
+                    bins_of_fn=packed_bins_of_feat)):
+        before = _traced()
+        _trace_grow(**kw)
+        assert set(_traced_since(before)) == {
+            ("kernel", 4), ("kernel", 16), ("kernel", 63)}, kw
 
 
 def test_trainer_on_xla_cpu_keeps_the_gather_form(monkeypatch):

@@ -571,6 +571,81 @@ def test_partition_kernel_lowers(rows, slots, use_sub):
          for name in _COLS})
 
 
+@pytest.mark.parametrize("slots", [1, 4, 16, 63])
+def test_partition_kernel_packed4_lowers(rows, slots):
+    """The partition kernel on a ``packed4`` matrix (27 features: an odd
+    tail) at the wave ladder's buckets: the byte row's selection and the
+    nibble's shift and mask lower."""
+    from lightgbmv1_tpu.ops.partition_pallas import _COLS, partition_pallas
+
+    rng, N, _ = rows
+    packed = jnp.asarray(pack4bit(
+        rng.randint(0, 16, (27, N - 100)).astype(np.uint8)))
+    lower_for_tpu(
+        lambda b, l, c: partition_pallas(b, l, c, use_sub=True, packed=True),
+        packed, jnp.zeros(N - 100, jnp.int32),
+        {name: jnp.zeros(slots, bool if name in ("dls", "sml") else jnp.int32)
+         for name in _COLS})
+
+
+def test_packed_pass_and_step_compile_at_the_cell_s_size(one_chip):
+    """``higgs-15b-train``'s shapes compiled for the described v5e: 28
+    features at 16 bins packed into 14 stored rows of 10,500,000.  The
+    16 rung's pass over the prepared operand (one ``u8[10500096,128]``
+    array, 14 live lanes) cuts it to its 14 columns with a bitcast, no
+    copy; the wave grower's ``grow`` calls ``partition_pallas`` in each of
+    its three buckets and leaves no ``(slots, rows)`` op under
+    ``lgbm.partition``, and its temporaries stay under one 63 x rows
+    array of bytes."""
+    from lightgbmv1_tpu.models import grower_wave as gw
+    from lightgbmv1_tpu.ops.hist_pallas import (packed_bins_of_feat,
+                                                prepare_hist_bins)
+    from lightgbmv1_tpu.ops.histogram import hist_wave
+    from lightgbmv1_tpu.ops.partition_pallas import KERNEL_NAME
+
+    F, N, B = 28, 10_500_000, 16
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    prepared = jax.tree_util.tree_map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda b: prepare_hist_bins(b, B, packed=True),
+                       shape((F // 2, N), jnp.uint8)))
+    assert [b.shape for b in prepared.blocks] == [(10_500_096, 128)]
+    g3 = shape((N, 3), jnp.float32)
+    got = compile_for_chip(
+        lambda b, g, l: hist_leaves_pallas(b, g, l, 63, B, precision="bf16",
+                                           packed=True, num_features=F),
+        prepared, g3, shape((N,), jnp.int32))
+    txt = got.as_text()
+    operand = r"u8\[10500096,14\]\{1,0:"
+    assert re.search(rf"= {operand}\S* bitcast\(", txt)
+    assert not re.search(rf"= {operand}\S* (copy|slice|fusion)\(", txt)
+    assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,448\]", txt)
+
+    grow = gw.make_wave_grower(
+        num_leaves=255, num_bins=B, meta=_probe_meta(F, B),
+        params=SplitParams(min_data_in_leaf=1.0,
+                           min_sum_hessian_in_leaf=100.0), wave_size=63,
+        hist_wave_fn=lambda b, g, l, n, deep=False: hist_wave(
+            b, g, l, n, B, method="pallas",
+            precision="bf16" if deep else "bf16x2", packed=True,
+            num_features=F),
+        bins_of_fn=packed_bins_of_feat, hist_method="pallas")
+    got = compile_for_chip(
+        lambda b, g: grow(b, g, jnp.ones(F, bool), jax.random.PRNGKey(0)),
+        prepared, g3)
+    txt = got.as_text()
+    assert len(re.findall(
+        rf"%{KERNEL_NAME}[.0-9]* = .*custom_call_target=\"tpu_custom_call\"",
+        txt)) == 3
+    for line in txt.splitlines():
+        m = re.search(rf"= \w+\[(\d+),{N}\]", line)
+        assert not (m and "lgbm.partition/" in line), line
+    assert got.memory_analysis().temp_size_in_bytes < 63 * N
+
+
 @pytest.mark.parametrize("columns,rows_,bins,prepared", [
     (28, 10_500_000, 256, True),      # higgs-255b-train
     (67, 4_000_000, 64, False),       # criteo-dp4-train, a chip's shard
@@ -614,7 +689,7 @@ def test_partition_kernel_compiles_in_the_step_at_the_cells_sizes(
         matrix, shape((rows_, 3), jnp.float32))
     txt = got.as_text()
     paths = {S: partition_path(columns, S, rows_, pallas=True,
-                               plain_u8=True, use_cat=False)
+                               layout="u8", use_cat=False)
              for S in (4, 16, 63)}
     assert paths[63] == "kernel"
     kernels = sum(p == "kernel" for p in paths.values())
