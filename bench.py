@@ -449,7 +449,7 @@ def measure_packed(X, y, backend, n_iters):
     every backend, at its own ``max_bin=15`` config (the nibble regime):
 
     * **parity** — trees of the packed run must byte-compare to the
-      unpacked run's model text: the kernel unpacks nibbles in VMEM
+      unpacked run's model text: placement unpacks the kernel's operand
       onto the identical arithmetic, so packing is a pure
       storage-layout change (the lane tests/test_packed_bins.py pins).
     * **analytic bytes** — the per-round binned HBM read halves:

@@ -545,7 +545,7 @@ class Smoke:
         assert_trees_agree(packed, twin, "packed4 vs u8", exact=True)
         packed_hist = None
         if engaged:
-            # the nibble-unpacking kernel against the plain reference too
+            # the pass over the packed matrix against the plain reference too
             packed_hist = self.check_hist(
                 packed._gbdt.binned,
                 np.asarray(twin._gbdt.binned),

@@ -323,13 +323,13 @@ class Config:
     # choice, bin.h): "packed4" stores two 4-bit bins per byte —
     # (ceil(F/2), N) instead of (F, N) — so the per-round HBM binned
     # read, the streaming block cache's disk/H2D bytes, and the kernels'
-    # VMEM row-tile footprint all halve; the histogram kernel unpacks
-    # nibbles in VMEM (ops/hist_pallas.pack4bit layout: lo nibble =
-    # feature 2p, hi = 2p+1).  Needs num_total_bin <= 16 (max_bin <= 15
-    # plus the missing bin), uint8 bins, no EFB bundling, the pallas
-    # hist method, and not gpu_use_dp / feature-parallel.  "auto" packs
-    # exactly when eligible (silent); an explicit "packed4" on an
-    # ineligible config falls back to "u8" with the staged warning.
+    # VMEM row-tile footprint all halve; the histogram kernel's operand
+    # is unpacked once, at placement (ops/hist_pallas.pack4bit layout:
+    # lo nibble = feature 2p, hi = 2p+1).  Needs num_total_bin <= 16
+    # (max_bin <= 15 plus the missing bin), uint8 bins, no EFB bundling,
+    # the pallas hist method, and not gpu_use_dp / feature-parallel.
+    # "auto" packs exactly when eligible (silent); an explicit "packed4"
+    # on an ineligible config falls back to "u8" with the staged warning.
     # Trees are bit-identical across layouts (`tests/test_packed_bins.py`).
     bin_layout: str = "auto"   # auto | u8 | packed4
     hist_dtype: str = "bf16x2"     # bf16 | bf16x2 | f32 | int8 (quantized) precision

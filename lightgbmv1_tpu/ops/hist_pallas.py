@@ -66,9 +66,16 @@ is handed (``windows``), not off the bin count:
   width, the array's default device layout is the row-major one the
   kernel's call takes (a tall ``u8[n, 32]`` array would be stored
   column-major and copied in every pass) and the pass's column slice back
-  to ``tile_cols`` is a bitcast there.  The 16 rung's blocks (128 columns)
-  fill the lanes; the 64 rung's 32-column blocks store 4x the bins' own
-  bytes at 128 columns and 5.7x at 67.
+  to ``tile_cols`` is a bitcast there.  The 64 rung's 32-column blocks
+  store 4x the bins' own bytes at 128 columns and 5.7x at 67.  **The 16
+  rung's blocks are repeated** (``_feature_blocks``): a block of up to 128
+  features, unpacked where the matrix is ``packed4``, padded to a power of
+  two ``fblk`` of at least 8 and copied ``128 // fblk`` times across the
+  lanes (lane l holds feature l % fblk: 4 copies of 32 at 28 features), so
+  the call takes the whole array and the kernel builds its one-hot by
+  repeating whole vregs of the tile, with no nibble unpack or lane
+  repeat of narrow pieces left in the pass.  The bytes are the ones a
+  block stored before it was repeated.
 * **dense** (lane-dense): the matrix's columns side by side, 128 a
   ``u8[n_pad, 128]`` array, and feature block ``fb`` of a pass is the
   static window of ``tile_cols`` columns ``(fb % windows) * tile_cols``
@@ -192,23 +199,22 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
 
 
 def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
-            num_bins, fblk, precision, interpret, packed=False, window=None):
+            num_bins, fblk, precision, interpret, window=None):
     """Grid: (feature_blocks, row_tiles); out revisited across row tiles.
 
     iota_ref: (1, FBLK*B) f32          — precomputed ``lane // FBLK`` pattern
                                          (bin ids are < 256 => exact;
                                          v5e has no int8 vector compare)
-    bins_ref: (T, FBLK) uint8          — row-major bin tile; with ``packed``
-                                         each byte holds TWO 4-bit bins
-                                         (lo nibble = feature 2p, hi = 2p+1 —
-                                         reference DenseBin<.., IS_4BIT=true>
-                                         src/io/dense_bin.hpp:52) and the
-                                         effective feature block is 2*FBLK
-                                         wide, ordered [lo nibbles | hi];
-                                         with ``window`` a (T, 128) tile
-                                         of a lane-dense array, of which
-                                         the block is the FBLK columns
-                                         from column ``window`` on
+    bins_ref: (T, FBLK) uint8          — row-major bin tile of one block
+                                         (the 64 rung's block form); a
+                                         (T, 128) tile in the two 128-lane
+                                         forms: with ``window`` a tile of
+                                         a lane-dense array, of which the
+                                         block is the FBLK columns from
+                                         column ``window`` on; without, the
+                                         16 rung's repeated block, lane l
+                                         holding feature l % FBLK (unpacked
+                                         at placement, packed or not)
     g3_ref:   (3, T) f32               — grad / hess / count (pre-transposed)
     leaf_ref: (1, T) int32             — leaf id per row, never negative;
                                          a row whose id is num_leaves or
@@ -286,16 +292,7 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
     # ids over one chunk of bins is chunk-invariant, so it is hoisted.
     cb = max(1, min(B, 512 // fblk))         # bins per chunk
     n_chunks = -(-B // cb)
-    if packed:
-        # unpack two 4-bit bins per byte in VMEM.  The tile's rows are
-        # 128 lanes wide in HBM packed or not, so this pass reads what the
-        # unpacked one reads: packing halves the matrix a learner stores
-        # and what a partition round reads of it
-        bi = bins_ref[...].astype(jnp.int32)
-        bins_f = jnp.concatenate([bi & 15, bi >> 4], axis=1) \
-            .astype(jnp.float32)
-    else:
-        bins_f = bins_ref[...].astype(jnp.int32).astype(jnp.float32)
+    bins_f = bins_ref[...].astype(jnp.int32).astype(jnp.float32)
     pattern = None
     if window is not None:
         # the block's FBLK columns, which start ``window`` columns into the
@@ -315,6 +312,13 @@ def _kernel(iota_ref, bins_ref, g3_ref, leaf_ref, out_ref, *, num_leaves,
             bins_f.astype(dt), sel, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST if interpret else None)
+    elif bins_f.shape[1] == _LANES:
+        # the 16 rung's tile is its own repeat pattern: a chunk of bins is
+        # whole vregs of it.  An in-kernel nibble unpack (a lane concatenate
+        # of two 14-lane pieces) and a repeat of 28-lane pieces took 39-46
+        # ms a call at 10.5 M rows x 28 features on the v5e, whatever the
+        # slots
+        pattern = bins_f
 
     for c in range(n_chunks):
         cb_c = min(cb, B - c * cb)
@@ -366,8 +370,9 @@ def pack4bit(binned: np.ndarray) -> np.ndarray:
     src/io/dense_bin.hpp:52): at max_bin <= 15 it halves the stored
     matrix in HBM and what a partition round reads of it
     (``partition_pallas`` decodes the nibble).  It does not halve the
-    histogram pass's read: ``prepare_hist_bins`` pads a block to an
-    array's 128 lanes, packed (14 live at 28 features) or not (28)."""
+    histogram pass's read: ``prepare_hist_bins`` unpacks the matrix once,
+    at placement, and repeats each block across an array's 128 lanes, the
+    same bytes packed or not."""
     binned = np.asarray(binned)
     F, N = binned.shape
     if F % 2:
@@ -417,8 +422,10 @@ class HistBins:
     rung, and the 64 rung where the bytes rule admits it)
     ``blocks[fb][:, :tile_cols]`` is feature block ``fb``, the operand of
     that block's ``pallas_call``, and the columns beyond are the lane
-    padding the device would add anyway; with more (the lane-dense form:
-    the 256 rung, and the 64 rung over the rule) block ``fb`` is columns
+    padding the device would add anyway (the 16 rung's ``tile_cols`` is
+    128: its block, unpacked, fills the lanes with copies of itself); with
+    more (the lane-dense form: the 256 rung, and the 64 rung over the
+    rule) block ``fb`` is columns
     ``(fb % windows) * tile_cols ...`` of ``blocks[fb // windows]``, which
     the call takes whole.  ``windows`` IS the form: a pass reads it here.
     ``matrix`` is the untouched ``(F, N)``
@@ -445,10 +452,8 @@ def bin_matrix(binned) -> jax.Array:
 def _dense(num_bins: int, dense: bool) -> bool:
     """Whether the operand of such a pass is lane-dense: the 256 rung's
     always, the 64 rung's where asked (``dense``: the form the bytes rule
-    chose, ``hist_bins_form``), the 16 rung's never: a block is at most
-    128 byte columns unpacked, 64 packed (14 of an array's 128 lanes at 28
-    features), one resident array whose lane padding the pass's cut leaves
-    as a bitcast."""
+    chose, ``hist_bins_form``), the 16 rung's never: its blocks already
+    fill an array's 128 lanes, repeated (``_feature_blocks``)."""
     rung = kernel_width(num_bins)
     return rung == 256 or (dense and rung == 64)
 
@@ -456,14 +461,21 @@ def _dense(num_bins: int, dense: bool) -> bool:
 def _feature_blocks(stored_rows: int, num_bins: int, packed: bool,
                     dense: bool = False):
     """``(fblk, tile_cols, nfb)`` of a matrix with ``stored_rows`` feature
-    rows: features per one-hot block, byte columns per block, and the
-    number of blocks.  Packed, ``fblk`` counts UNPACKED features and
-    must be even (each byte column contributes its lo and hi nibble
-    feature)."""
-    if packed:
-        fblk = max(2, min(2 * stored_rows, MAX_LANES // num_bins) & ~1)
-        tile_cols = fblk // 2
-    elif _dense(num_bins, dense):
+    rows: features per one-hot block, byte columns of a block's call, and
+    the number of blocks.
+
+    The 16 rung's blocks are **repeated**: ``fblk`` (unpacked) features, a
+    power of two from 8 to 128, copied ``128 // fblk`` times across the 128
+    lanes, so that lane l of a row holds feature l % fblk and a chunk of
+    ``512 // fblk`` bins of the one-hot is whole vregs of the tile
+    (``_kernel``).  A call takes the whole array (``tile_cols`` 128); packed
+    bins (two features a byte, ``stored_rows`` the bytes) are unpacked by
+    ``prepare_hist_bins``."""
+    if kernel_width(num_bins) == 16:
+        features = 2 * stored_rows if packed else stored_rows
+        fblk = max(8, 1 << (min(features, _LANES) - 1).bit_length())
+        return fblk, _LANES, -(-features // fblk)
+    if _dense(num_bins, dense):
         # a window of a lane-dense array: always whole, whatever the matrix
         # holds (columns beyond it are padding, sliced away by the pass),
         # and a power of two, so that windows tile the 128 lanes (8 columns
@@ -515,25 +527,72 @@ def hist_bins_form(stored_rows: int, num_rows: int, num_bins: int,
     return "raw", need
 
 
-def _count_operand(stored_rows: int, tile_cols: int, windows: int, nfb: int,
-                   num_bins: int) -> None:
+def _count_operand(features: int, tile_cols: int, windows: int, nfb: int,
+                   num_bins: int, copies: int = 1) -> None:
     """Trace time: ``hist_operand_lanes{what}``, the byte columns a row of
-    the fullest stored array occupies and those of them that carry a
-    feature, and ``hist_pass_blocks{rung}``, the kernel calls of a pass."""
+    the fullest stored array occupies and the distinct features it
+    carries, ``hist_block_copies{rung}``, the copies of one feature block
+    a row holds (the 16 rung's repeated blocks), and
+    ``hist_pass_blocks{rung}``, the kernel calls of a pass."""
     from ..obs.metrics import default_registry
 
+    rung = str(kernel_width(num_bins))
     lanes = default_registry().gauge(
         "hist_operand_lanes",
         "Byte columns of a row of the histogram kernel's stored bin "
-        "operand: as stored, and carrying a feature",
+        "operand: as stored, and carrying a distinct feature",
         label_names=("what",))
     lanes.labels(what="stored").set(float(-(-tile_cols // _LANES) * _LANES))
-    lanes.labels(what="live").set(float(min(stored_rows,
-                                            windows * tile_cols)))
+    lanes.labels(what="live").set(float(min(features,
+                                            windows * tile_cols // copies)))
+    default_registry().gauge(
+        "hist_block_copies",
+        "Copies of one feature block a stored row of the histogram "
+        "kernel's bin operand holds", label_names=("rung",)).labels(
+            rung=rung).set(float(copies))
     default_registry().gauge(
         "hist_pass_blocks", "Kernel calls of one histogram pass",
-        label_names=("rung",)).labels(
-            rung=str(kernel_width(num_bins))).set(float(nfb))
+        label_names=("rung",)).labels(rung=rung).set(float(nfb))
+
+
+# Rows one step of the 16 rung's layout makes.  The whole matrix's unpack
+# and repeat in one program compiled for the v5e in 23-180 s at 10.5 M rows
+# (its compile time grows with the rows); a loop over steps of this many
+# compiles in about a second, and holds one step's temporaries.
+_LAYOUT_ROWS = 131072
+
+
+def _repeated_blocks(binned, packed: bool, fblk: int, nfb: int,
+                     n_pad: int) -> Tuple[jax.Array, ...]:
+    """The 16 rung's arrays (``_feature_blocks``): ``nfb`` row-major
+    ``u8[n_pad, 128]``, lane l of array ``a`` holding feature
+    ``a * fblk + l % fblk`` (bin 255 past the matrix's features, which
+    matches no bin below 16), a packed matrix split into its nibbles on
+    the way, ``_LAYOUT_ROWS`` rows a step."""
+    stored, N = binned.shape
+    features = 2 * stored if packed else stored
+    rows = jnp.pad(binned, ((0, 0), (0, n_pad - N)))
+    step = min(_LAYOUT_ROWS, n_pad)
+
+    def lay(x):                   # (stored, step) -> nfb x (step, 128)
+        x = x.astype(jnp.int32)
+        if packed:                # lo nibble feature 2p, hi 2p+1 (pack4bit)
+            x = jnp.stack([x & 15, x >> 4], axis=1).reshape(features, step)
+        x = jnp.pad(x, ((0, nfb * fblk - features), (0, 0)),
+                    constant_values=255).reshape(nfb, fblk, step)
+        x = jnp.tile(x, (1, _LANES // fblk, 1))
+        return [a.T.astype(jnp.uint8) for a in x]
+
+    def body(k, out):
+        # the last step is clamped back onto the rows' end: it lays some
+        # rows out twice, alike
+        start = jnp.minimum(k * step, n_pad - step)
+        made = lay(lax.dynamic_slice_in_dim(rows, start, step, axis=1))
+        return tuple(lax.dynamic_update_slice_in_dim(o, m, start, axis=0)
+                     for o, m in zip(out, made))
+
+    out = tuple(jnp.zeros((n_pad, _LANES), jnp.uint8) for _ in range(nfb))
+    return lax.fori_loop(0, -(-n_pad // step), body, out)
 
 
 def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
@@ -548,16 +607,17 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
     smaller adds zeros from the extra all-padding tiles and its sums stay
     bit-identical.  Padded features get bin 255 (matches no b < 256 when
     B < 256; for B == 256 they land in bin 255 of a feature the caller
-    slices away); packed pad bytes are 0 -> phantom features collect bin 0
-    and are dropped by the caller's permutation.
+    slices away).  The 16 rung's blocks are repeated across the lanes
+    (``_feature_blocks``), a packed matrix unpacked here first: the phantom
+    feature of an odd count (its nibble 0) is sliced away by the pass.
 
     ``resident`` blocks are what a learner keeps on the device: stored at
     lane width (module docstring; the padding columns are never read).
     ``hist_leaves_pallas`` passes False where it was handed the raw matrix
     and makes the layout inside the pass, consumed at once at its own
-    width; lane-dense arrays are the same either way.  ``dense`` asks the
-    64 rung for the lane-dense form (``hist_bins_form`` says when); the 256
-    rung has no other.
+    width; lane-dense arrays and the 16 rung's are the same either way.
+    ``dense`` asks the 64 rung for the lane-dense form (``hist_bins_form``
+    says when); the 256 rung has no other.
     Traceable; each trace counts in ``hist_bins_layout_total`` under
     ``site="placement"`` (resident) or ``"pass"``."""
     if binned.dtype not in (jnp.uint8, np.uint8):
@@ -574,22 +634,26 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
         label_names=("site",)).labels(
             site="placement" if resident else "pass").inc()
     stored, N = binned.shape
-    _, tile_cols, nfb = _feature_blocks(stored, num_bins, packed, dense)
+    fblk, tile_cols, nfb = _feature_blocks(stored, num_bins, packed, dense)
     windows = _block_windows(tile_cols, num_bins, dense)
-    _count_operand(stored, tile_cols, windows, nfb, num_bins)
+    features = 2 * stored if packed else stored
+    _count_operand(features, tile_cols, windows, nfb, num_bins,
+                   tile_cols // fblk)
+    n_pad = -(-N // row_tile) * row_tile
+    if kernel_width(num_bins) == 16:
+        return HistBins(binned, _repeated_blocks(binned, packed, fblk, nfb,
+                                                 n_pad), tile_cols, windows)
     # byte columns of one stored array, and how many arrays
     width = _LANES if windows > 1 else tile_cols
     n_arrays = -(-nfb // windows)
-    n_pad = -(-N // row_tile) * row_tile
-    fill = 0 if packed else 255
     binned_rm = jnp.pad(
         binned, ((0, n_arrays * width - stored), (0, n_pad - N)),
-        constant_values=fill).T                     # (n_pad, arrays*width)
+        constant_values=255).T                      # (n_pad, arrays*width)
     blocks = [binned_rm[:, a * width:(a + 1) * width]
               for a in range(n_arrays)]
     if resident and width % _LANES:
         blocks = [jnp.pad(b, ((0, 0), (0, -width % _LANES)),
-                          constant_values=fill) for b in blocks]
+                          constant_values=255) for b in blocks]
     return HistBins(binned, tuple(blocks), tile_cols, windows)
 
 
@@ -641,7 +705,8 @@ def hist_leaves_pallas(
     T = row_tile if row_tile > 0 else _row_tile_for(out_rows, fblk * B, B)
 
     if isinstance(binned, HistBins):
-        _count_operand(stored, tile_cols, windows, nfb, B)
+        _count_operand(2 * stored if packed else stored, tile_cols, windows,
+                       nfb, B, tile_cols // fblk)
     else:       # counted there
         with jax.named_scope(LAYOUT_SCOPE):
             binned = prepare_hist_bins(binned, B, packed, row_tile=T,
@@ -679,8 +744,7 @@ def hist_leaves_pallas(
         # block's static ``window`` of them.
         kernel = functools.partial(
             _kernel, num_leaves=L, num_bins=B, fblk=fblk,
-            precision=precision, interpret=interpret, packed=packed,
-            window=window)
+            precision=precision, interpret=interpret, window=window)
         return pl.pallas_call(
             kernel,
             grid=(1, nrt),
@@ -709,15 +773,4 @@ def hist_leaves_pallas(
     # (nfb, out_rows, B*fblk) -> (L, F, B, 3)
     h = out[:, :3 * L].reshape(nfb, 3, L, B, fblk)
     h = h.transpose(2, 0, 4, 3, 1).reshape(L, f_pad, B, 3)
-    if packed:
-        # per block the unpacked feature order is [lo nibbles | hi nibbles]
-        # = [2p0, 2p0+2, ... | 2p0+1, 2p0+3, ...]; invert it
-        perm = np.empty(f_pad, np.int64)
-        pos = 0
-        for fb in range(nfb):
-            ps = np.arange(fb * tile_cols, (fb + 1) * tile_cols)
-            perm[pos:pos + fblk] = np.concatenate([2 * ps, 2 * ps + 1])
-            pos += fblk
-        inv = np.argsort(perm)
-        h = h[:, inv]
     return h[:, :F]

@@ -394,7 +394,7 @@ def _resolve_bin_layout(config: Config, num_total_bin: int, bin_dtype,
         reason = "EFB bundle offsets address unpacked byte bins"
     elif method != "pallas":
         reason = (f"hist method {method!r} gathers unpacked bins "
-                  "(the pallas kernel unpacks nibbles in VMEM)")
+                  "(the pallas kernel's operand is unpacked at placement)")
     elif config.tree_learner == "feature":
         reason = ("tree_learner=feature shards features, not byte "
                   "pairs")
@@ -1101,8 +1101,8 @@ def build_trainer(
         use_hier = hier and ndev > 1
         use_rs = (collective == "reduce_scatter" and ndev > 1) or use_hier
         # the HISTOGRAM column axis being sharded: bundle columns under
-        # EFB, original features otherwise (4-bit packed histograms are
-        # already unpacked to F columns by the pallas kernel)
+        # EFB, original features otherwise (4-bit packed histograms come
+        # out of the pallas kernel with F columns)
         FH = binned_np.shape[0] if bundle is not None else F
         FH_pad = -(-FH // ndev) * ndev
         FH_loc = FH_pad // ndev

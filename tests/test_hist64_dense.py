@@ -110,7 +110,7 @@ def test_a_pass_reads_the_form_off_the_operand():
             4, 256, **kw)
     # the 16 rung's blocks fill an array's lanes: it has no second form
     narrow = prepare_hist_bins(jnp.asarray(bins % 16), 16, dense=True)
-    assert (narrow.tile_cols, narrow.windows) == (67, 1)
+    assert (narrow.tile_cols, narrow.windows) == (128, 1)
 
 
 # (stored columns, rows a device, bins) of the benchmark's five cells

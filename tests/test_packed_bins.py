@@ -2,10 +2,10 @@
 tests on ``hist_method=pallas`` (interpret mode on CPU).
 
 The packed contract: two 4-bit bins per byte is a pure storage-layout
-change.  The histogram kernel unpacks nibbles in VMEM onto the identical
-arithmetic and the partition decodes the split feature's nibble per row,
-so ``bin_layout=packed4`` trees are BIT-IDENTICAL to ``bin_layout=u8``
-trees.  Model text equality is the pin — structure, thresholds, leaf
+change.  The histogram kernel's operand is unpacked at placement onto the
+identical arithmetic and the partition decodes the split feature's nibble
+per row, so ``bin_layout=packed4`` trees are BIT-IDENTICAL to
+``bin_layout=u8`` trees.  Model text equality is the pin — structure, thresholds, leaf
 values and metadata all byte-compare.
 """
 
@@ -139,7 +139,7 @@ def test_packed_parity_dart():
 
 
 def test_packed_parity_int8sr(monkeypatch):
-    # the quantized lane consumes the UNPACKED VMEM view — the same
+    # the quantized lane consumes the UNPACKED operand — the same
     # sr_quantize_g3 stream, so packed int8sr == unpacked int8sr
     monkeypatch.setattr(gw, "_BUCKET_MIN_N", 1)
     _packed_parity({"num_leaves": 48, "leafwise_wave_size": 32,

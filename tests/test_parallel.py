@@ -814,9 +814,10 @@ def test_row_sharded_learner_grows_the_raw_shard_s_tree_on_prepared_bins(
     tree of what was placed."""
     from jax.sharding import PartitionSpec as P
     from lightgbmv1_tpu.ops.hist_pallas import (MAX_ROW_TILE, HistBins,
-                                                bin_matrix,
+                                                _feature_blocks, bin_matrix,
                                                 hist_leaves_pallas,
-                                                prepared_bins_bytes)
+                                                prepared_bins_bytes,
+                                                unpack4bit)
     from lightgbmv1_tpu.parallel import trainer
 
     X, y, params = _shard_operand_problem(form)
@@ -848,12 +849,22 @@ def test_row_sharded_learner_grows_the_raw_shard_s_tree_on_prepared_bins(
     m = np.asarray(bin_matrix(placed))
     b0 = np.asarray(placed.blocks[0])
     cols = placed.tile_cols
+    if g._packed:   # the 16 rung: unpacked, lane l holding feature l % fblk
+        m = unpack4bit(m, 2 * stored)
+        lane = np.arange(128) % _feature_blocks(stored, g.num_bins, True)[0]
+        live = lane < len(m)
     for d in range(shards):
         rows = b0[d * n_pad_loc:(d + 1) * n_pad_loc]
-        np.testing.assert_array_equal(
-            rows[:n_loc, :min(cols, stored)],
-            m[:min(cols, stored), d * n_loc:(d + 1) * n_loc].T)
-        assert (rows[n_loc:, :cols] == (0 if g._packed else 255)).all()
+        shard = m[:, d * n_loc:(d + 1) * n_loc].T
+        if g._packed:
+            np.testing.assert_array_equal(
+                rows[:n_loc],
+                np.where(live, shard[:, np.minimum(lane, len(m) - 1)], 255))
+            assert (rows[n_loc:] == np.where(live, 0, 255)).all()
+            continue
+        np.testing.assert_array_equal(rows[:n_loc, :min(cols, stored)],
+                                      shard[:, :min(cols, stored)])
+        assert (rows[n_loc:, :cols] == 255).all()
     num_bins = (g.train_set.padded_bundle_bin if form == "efb"
                 else g.num_bins)
     need = prepared_bins_bytes(stored, n_loc, num_bins, g._packed)

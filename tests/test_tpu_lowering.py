@@ -590,39 +590,55 @@ def test_partition_kernel_packed4_lowers(rows, slots):
 
 def test_packed_pass_and_step_compile_at_the_cell_s_size(one_chip):
     """``higgs-15b-train``'s shapes compiled for the described v5e: 28
-    features at 16 bins packed into 14 stored rows of 10,500,000.  The
-    16 rung's pass over the prepared operand (one ``u8[10500096,128]``
-    array, 14 live lanes) cuts it to its 14 columns with a bitcast, no
-    copy; the wave grower's ``grow`` calls ``partition_pallas`` in each of
-    its three buckets and leaves no ``(slots, rows)`` op under
-    ``lgbm.partition``, and its temporaries stay under one 63 x rows
-    array of bytes."""
+    features at 16 bins packed into 14 stored rows of 10,500,000.
+    Placement lays them out unpacked and repeated (one ``u8[10500096,128]``
+    array, four copies of a 32-feature block) in a loop of row steps that
+    compiles in seconds and holds no temporary as large as the array; the
+    16 rung's pass takes that array whole (no pad, transposition, slice or
+    copy makes a ``u8`` operand), its result ``f32[1,192,512]``; the wave
+    grower's ``grow`` calls ``partition_pallas`` in each of its three
+    buckets and leaves no ``(slots, rows)`` op under ``lgbm.partition``,
+    and its temporaries stay under one 63 x rows array of bytes."""
+    import time
+
     from lightgbmv1_tpu.models import grower_wave as gw
     from lightgbmv1_tpu.ops.hist_pallas import (packed_bins_of_feat,
                                                 prepare_hist_bins)
     from lightgbmv1_tpu.ops.histogram import hist_wave
     from lightgbmv1_tpu.ops.partition_pallas import KERNEL_NAME
 
-    F, N, B = 28, 10_500_000, 16
+    F, N, B, n_pad = 28, 10_500_000, 16, 10_500_096
 
     def shape(s, dt):
         return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
 
+    packed = shape((F // 2, N), jnp.uint8)
+    t0 = time.perf_counter()
+    placed = compile_for_chip(lambda b: prepare_hist_bins(b, B, packed=True),
+                              packed)
+    assert time.perf_counter() - t0 < 60
+    assert placed.memory_analysis().temp_size_in_bytes < n_pad * 128 // 4
     prepared = jax.tree_util.tree_map(
         lambda x: shape(x.shape, x.dtype),
         jax.eval_shape(lambda b: prepare_hist_bins(b, B, packed=True),
-                       shape((F // 2, N), jnp.uint8)))
-    assert [b.shape for b in prepared.blocks] == [(10_500_096, 128)]
+                       packed))
+    assert [b.shape for b in prepared.blocks] == [(n_pad, 128)]
     g3 = shape((N, 3), jnp.float32)
-    got = compile_for_chip(
-        lambda b, g, l: hist_leaves_pallas(b, g, l, 63, B, precision="bf16",
-                                           packed=True, num_features=F),
-        prepared, g3, shape((N,), jnp.int32))
-    txt = got.as_text()
-    operand = r"u8\[10500096,14\]\{1,0:"
-    assert re.search(rf"= {operand}\S* bitcast\(", txt)
-    assert not re.search(rf"= {operand}\S* (copy|slice|fusion)\(", txt)
-    assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,448\]", txt)
+
+    def fn(b, g, l):
+        return hist_leaves_pallas(b, g, l, 63, B, precision="bf16",
+                                  packed=True, num_features=F)
+
+    lowered = jax.jit(fn).trace(prepared, g3, shape((N,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert _big_u8_relayouts(lowered, N) == []
+    assert _kernel_u8_operands(lowered) == [[f"{n_pad}x128"]]
+    txt = compile_for_chip(fn, prepared, g3, shape((N,), jnp.int32)).as_text()
+    assert not re.search(rf"= u8\[{n_pad},\d+\]\S* "
+                         r"(copy|slice|fusion|transpose|pad|bitcast)\(", txt)
+    calls = _hist_calls(txt)
+    assert len(calls) == 1, calls
+    assert re.search(r"%hist_leaves_pallas[.0-9]* = f32\[1,192,512\]", txt)
 
     grow = gw.make_wave_grower(
         num_leaves=255, num_bins=B, meta=_probe_meta(F, B),
