@@ -101,6 +101,14 @@ chip; ``higgs-255b-train`` dense, one ``u8[10500096,128]`` =
 block form 10,200,023,040 B being 2.4x the rule.  At 67 columns the block
 form reaches 11.0 M rows a device, the dense form 33.0 M, the raw matrix
 whatever fits beside its passes' temporaries.
+
+**The row tile** (``_row_tile_for``; one VMEM budget, no option): a call's
+grid step takes the largest of 1024 / 512 / 256 / 128 rows whose VMEM
+estimate fits 12 MB, the same rule on every rung and form.  Every slot
+bucket a 255-leaf tree runs (1 / 4 / 16 / 63 slots) takes 1024 rows on all
+three rungs, 512 / 2,048 lanes alike; 128 slots take 512, 255 slots at
+2,048 lanes 256.  A stored operand is padded to ``MAX_ROW_TILE`` rows
+once, at placement.
 """
 
 from __future__ import annotations
@@ -119,6 +127,7 @@ from jax.experimental.pallas import tpu as pltpu
 MAX_LANES = 2048          # lanes per one-hot block: FBLK * num_bins
 MAX_ROW_TILE = 1024       # the largest row tile _row_tile_for returns: rows
                           # padded to it serve every slot bucket's tile
+_VMEM_BUDGET = 12 * 2**20  # _row_tile_for's ceiling on its VMEM estimate
 _LANES = 128              # byte columns a stored u8 row occupies at least
 _COUNT_SCALE = 64.0       # power-of-two count quantizer => exact counts
 # ``jax.named_scope`` of what a pass does to make the kernel's operands and
@@ -168,7 +177,7 @@ def pass_rows(num_leaves: int, precision: str) -> Tuple[int, int, int]:
     return -(-m_live // g) * g, m_live, -(-3 * num_leaves // 8) * 8
 
 
-def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
+def _row_tile_for(m_pad: int, num_lanes: int) -> int:
     """Row-tile size keeping the VMEM working set (chunked one-hot + repeat
     buffer + lg rows + out accumulator) within Mosaic's ~16MB scoped-vmem
     budget.  ``m_pad`` is the result block's rows (``pass_rows``' third):
@@ -176,24 +185,35 @@ def _row_tile_for(m_pad: int, num_lanes: int, num_bins: int) -> int:
     16 bytes a row.  The estimate is deliberately conservative: per-chunk f32
     temporaries (repeat buffer, compare, select, cast) can coexist.  No
     ``compiler_params`` is passed, so the chip's default scoped limit
-    applies: on TPU v5 lite every shape chip_smoke.py runs (28 features,
-    16/64/256 bins, 1-64 slots, all default-policy precisions, packed4)
-    compiles under it.
+    applies.
 
-    The 16 and 64 rungs hold the estimate to 8 MB (1024 rows up to 16
-    slots, 512 at 63).  The 256 rung holds it to 12 MB, which admits 1024
-    rows up to 64 slots: what the v5e's compiler accepted and the chip ran
-    on 2026-10-03 at 10,500,096 rows x 28 columns x 256 bins, 1 / 4 / 16 /
-    63 slots (M 16 / 32 / 80 / 192), ``bf16x2`` and ``bf16``, where 1024
-    rows took 51.6 ms a 63-slot call against 56.6 at 512 and 27.9 against
-    31.5 at 16 slots; the compiler alone also accepted 1-255 slots of all
-    five precisions at 256 and 128 bins.  The rung's blocks are always
-    whole windows of a 128-lane tile, whatever the matrix's width."""
+    One budget, ``_VMEM_BUDGET`` (12 MB), for every rung, operand form and
+    precision: the tile is a pure function of the two shapes.  It admits
+    1024 rows up to 64 slots at 2,048 lanes (M 192: 12.06 MB) and 512
+    beyond (128 slots: 16.8 MB at 1024 rows, 9.97 at 512).  Every shape
+    chip_smoke.py runs (16/64/256 bins, 1-64 slots, the default-policy
+    precisions, packed4, the 64 rung's lane-dense operand) compiles under
+    it and runs on TPU v5 lite; the compiler alone also accepted 1-255
+    slots of all five precisions at 256 and 128 bins.  A grid step has a
+    fixed cost (the pipeline step, the accumulator's read-add-write), so
+    the larger tile pays where the product is large.  Ms a pass (one call,
+    g3's layout inside) at 512 / 1024 rows, 16 slots (``bf16x2``) and 63
+    (``bf16``), on the v5e (chip run of 2026-10-18):
+
+    * 16 rung, 10,500,096 rows, 32 x 16 lanes: 9.58 / 7.33, 16.73 / 14.42;
+    * 64 rung, block form, 32 x 64 lanes: 2,271,232 rows 6.35 / 5.80,
+      12.06 / 11.24; 4,000,768 rows 11.10 / 10.13, 21.12 / 19.68; 400,384
+      rows 1.17 / 1.08, 2.16 / 2.04;
+    * 64 rung, lane-dense, 26,562,560 rows: 80.40 / 70.77, 143.45 / 131.08;
+    * 256 rung, 10,500,096 rows, 8 x 256 lanes: 31.62 / 27.96, 57.03 /
+      51.86.
+
+    The 256 rung's blocks are always whole windows of a 128-lane tile,
+    whatever the matrix's width."""
     out_bytes = m_pad * num_lanes * 4
     per_row = 14 * min(num_lanes, 512) + 16 * m_pad
-    budget = (12 if kernel_width(num_bins) == 256 else 8) * 2**20
     for t in (MAX_ROW_TILE, 512, 256, 128):
-        if out_bytes + t * per_row <= budget:
+        if out_bytes + t * per_row <= _VMEM_BUDGET:
             return t
     return 128
 
@@ -657,9 +677,12 @@ def prepare_hist_bins(binned: jax.Array, num_bins: int, packed: bool = False,
     return HistBins(binned, tuple(blocks), tile_cols, windows)
 
 
-def _count_pass_rows(slots: int, precision: str) -> None:
+def _count_pass_rows(slots: int, precision: str, num_bins: int,
+                     row_tile: int) -> None:
     """Trace time: the rows of a pass's MXU left operand, padded and live,
-    in ``hist_pass_mxu_rows`` / ``hist_pass_live_rows{slots,precision}``."""
+    in ``hist_pass_mxu_rows`` / ``hist_pass_live_rows{slots,precision}``,
+    and the rows of its grid step in
+    ``hist_pass_row_tile{rung,slots,precision}``."""
     from ..obs.metrics import default_registry
 
     m_pad, m_live, _ = pass_rows(slots, precision)
@@ -670,6 +693,11 @@ def _count_pass_rows(slots: int, precision: str) -> None:
             name, "Rows of the histogram kernel's MXU left operand " + what,
             label_names=("slots", "precision")).labels(
                 slots=str(slots), precision=precision).set(float(rows))
+    default_registry().gauge(
+        "hist_pass_row_tile", "Rows of one grid step of a histogram pass",
+        label_names=("rung", "slots", "precision")).labels(
+            rung=str(kernel_width(num_bins)), slots=str(slots),
+            precision=precision).set(float(row_tile))
 
 
 @functools.partial(
@@ -701,8 +729,8 @@ def hist_leaves_pallas(
     windows = _block_windows(tile_cols, B, dense)
     f_pad = nfb * fblk
     out_rows = pass_rows(L, precision)[2]
-    _count_pass_rows(L, precision)
-    T = row_tile if row_tile > 0 else _row_tile_for(out_rows, fblk * B, B)
+    T = row_tile if row_tile > 0 else _row_tile_for(out_rows, fblk * B)
+    _count_pass_rows(L, precision, B, T)
 
     if isinstance(binned, HistBins):
         _count_operand(2 * stored if packed else stored, tile_cols, windows,

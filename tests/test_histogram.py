@@ -232,13 +232,13 @@ _PREPARED_SHAPES = [(16, False, 130), (16, True, 131), (64, False, 37),
 
 
 @pytest.mark.parametrize("precision", ["bf16x2", "bf16", "int8sr"])
-@pytest.mark.parametrize("slots", [1, 5, 17, 64])
+@pytest.mark.parametrize("slots", [1, 5, 17, 64, 128])
 @pytest.mark.parametrize("num_bins,packed,F", _PREPARED_SHAPES)
 def test_prepared_bins_bit_identical(num_bins, packed, F, slots, precision):
     """A prepared operand and the raw matrix give the SAME bits: the layout
     is the same function run once instead of in the pass, and the rows it
     pads beyond the pass's own tile carry zero g3.  N is no multiple of
-    1024, so the 64-slot pass (512-row tiles) sees one all-padding tile
+    1024, so the 128-slot pass (512-row tiles) sees one all-padding tile
     more through the prepared operand than through the raw matrix."""
     from lightgbmv1_tpu.ops.hist_pallas import (HistBins, MAX_ROW_TILE,
                                                 hist_leaves_pallas, pack4bit,
@@ -367,7 +367,7 @@ def test_wave_pass_bit_equal_to_dead_slot_form(layout, slots, precision):
                     method="pallas", **kw)
     assert got.shape == (slots, bins.shape[0], B, 3)
     fblk = _feature_blocks(bin_matrix(binned).shape[0], B, kw["packed"])[0]
-    tile = _row_tile_for(pass_rows(slots, precision)[2], fblk * B, B)
+    tile = _row_tile_for(pass_rows(slots, precision)[2], fblk * B)
     parent = hist_leaves_pallas(
         binned, g3, jnp.asarray(np.minimum(label, slots)), slots + 1, B,
         row_tile=tile, **kw)[:slots]
@@ -432,6 +432,57 @@ def test_pass_rows_gauges(slots, precision, mxu_rows, live_rows):
     labels = '{slots="%d",precision="%s"}' % (slots, precision)
     assert snap["hist_pass_mxu_rows" + labels] == mxu_rows
     assert snap["hist_pass_live_rows" + labels] == live_rows
+
+
+# (num_bins, F, dense): the cells' operands and their lanes a call,
+# fblk x bins: higgs-15b-train 32 x 16 = 512 (the 16 rung's repeated
+# block); mslr-train, epsilon-train and criteo-dp4-train 32 x 64 = 2,048
+# (the block form); criteo-tall-train 32 x 64 (lane-dense); higgs-255b-train
+# 8 x 256 = 2,048
+_CELL_OPERANDS = [(16, 28, False), (64, 37, False), (64, 67, True),
+                  (256, 28, True)]
+
+
+@pytest.mark.parametrize("slots,precision,tiles", [
+    (1, "bf16x2", (1024, 1024)), (4, "bf16x2", (1024, 1024)),
+    (16, "bf16x2", (1024, 1024)), (63, "bf16", (1024, 1024)),
+    (63, "bf16x2", (1024, 1024)), (63, "int8sr", (1024, 1024)),
+    (128, "bf16", (512, 512)), (255, "bf16", (512, 256))])
+@pytest.mark.parametrize("num_bins,F,dense", _CELL_OPERANDS)
+def test_one_row_tile_rule_for_every_rung(num_bins, F, dense, slots,
+                                          precision, tiles):
+    """``_row_tile_for`` is one VMEM budget on every rung and form: 1024
+    rows a grid step for every slot bucket the cells run, 512 only where
+    the estimate at 1024 passes the budget (128 slots and more), 256 where
+    it passes it at 512 too (255 slots at 2,048 lanes; ``tiles`` is the
+    tile at 512 lanes, then at 2,048).  A traced pass reads the tile it
+    took in ``hist_pass_row_tile{rung,slots,precision}``."""
+    from lightgbmv1_tpu.obs.metrics import default_registry
+    from lightgbmv1_tpu.ops.hist_pallas import (_feature_blocks,
+                                                _row_tile_for,
+                                                hist_leaves_pallas,
+                                                kernel_width, pass_rows,
+                                                prepare_hist_bins)
+
+    fblk = _feature_blocks(F, num_bins, False, dense)[0]
+    lanes = fblk * num_bins
+    tile = dict(zip((512, 2048), tiles))[lanes]
+    assert _row_tile_for(pass_rows(slots, precision)[2], lanes) == tile
+
+    N = 3000
+    default_registry().reset(["hist_pass_row_tile"])
+    prepared = jax.eval_shape(
+        lambda b: prepare_hist_bins(b, num_bins, dense=dense),
+        jax.ShapeDtypeStruct((F, N), jnp.uint8))
+    jax.eval_shape(lambda b, g, l: hist_leaves_pallas(
+        b, g, l, slots, num_bins, precision=precision,
+        interpret=_PALLAS_INTERPRET),
+        prepared, jax.ShapeDtypeStruct((N, 3), jnp.float32),
+        jax.ShapeDtypeStruct((N,), jnp.int32))
+    labels = '{rung="%d",slots="%d",precision="%s"}' % (
+        kernel_width(num_bins), slots, precision)
+    assert default_registry().snapshot()["hist_pass_row_tile" + labels] \
+        == tile
 
 
 # sha256 of the model text the PARENT of PR 29 (commit 8bc3106: the kernel
