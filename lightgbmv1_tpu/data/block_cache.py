@@ -126,7 +126,7 @@ def write_block_cache(ds, path: str, block_rows: int = 65536,
     disk and the streaming trainer's H2D transfers halve; requires
     ``num_total_bin <= 16`` (raises ``BlockCacheError`` otherwise — the
     storage API fails loudly; config-driven refusal-with-warning lives in
-    parallel/trainer.select_bin_layout).  ``"auto"`` packs exactly when
+    parallel/trainer.resolve_hist_plan).  ``"auto"`` packs exactly when
     eligible; ``"u8"`` always stores unpacked bytes."""
     if ds.binned is None:
         raise BlockCacheError(

@@ -141,27 +141,31 @@ class GBDT:
         # stored matrix in HBM and what a partition round reads of it; the
         # histogram kernel's prepared operand is as wide either way
         # (ops/hist_pallas.pack4bit).
-        # Layout resolution + once-per-build logging:
-        # parallel/trainer.select_bin_layout (config.bin_layout).
-        self._packed = False
+        # The histogram method and the stored layout are chosen here, once
+        # per build, with the once-per-build logging:
+        # parallel/trainer.resolve_hist_plan (config.hist_method,
+        # config.bin_layout); every layer below reads ``self._hist``.
+        from ..parallel.trainer import resolve_hist_plan
+
         if self._is_streaming:
             # the row bulk never lands on device whole: blocks stream per
             # histogram pass (models/grower_stream.py); EFB / 4-bit
             # packing are resident-trainer representations (the block
-            # cache stores packed SHARDS separately, data/block_cache.py)
+            # cache stores packed SHARDS separately, data/block_cache.py,
+            # and its manifest says which)
             self._host_matrix = None
+            self._hist = resolve_hist_plan(
+                config, bin_dtype=self._source.block_dtype,
+                stored=self._source.bin_layout)
         else:
             self._host_matrix = train_set.train_matrix
-            from ..parallel.trainer import select_bin_layout
-
-            layout = select_bin_layout(
-                config, num_total_bin=train_set.num_total_bin,
-                bin_dtype=self._host_matrix.dtype,
+            self._hist = resolve_hist_plan(
+                config, bin_dtype=self._host_matrix.dtype,
+                num_total_bin=train_set.num_total_bin,
                 bundled=self._bundle is not None)
-            if layout == "packed4":
+            if self._hist.packed:
                 from ..ops.hist_pallas import pack4bit
 
-                self._packed = True
                 with construct_phase("pack"):
                     self._host_matrix = pack4bit(self._host_matrix)
         if self._is_streaming:
@@ -315,6 +319,11 @@ class GBDT:
         self._iter = v
         self.model_version = getattr(self, "model_version", -1) + 1
 
+    @property
+    def _packed(self) -> bool:
+        """The stored matrix holds two 4-bit bins a byte."""
+        return self._hist.packed
+
     # ------------------------------------------------------------------
     def _build_trainer(self):
         from ..parallel.trainer import build_trainer
@@ -324,6 +333,7 @@ class GBDT:
         with construct_phase("place"):
             self._grow, self._grow_binned, _ = build_trainer(
                 self.config,
+                self._hist,
                 self._host_matrix,
                 self.meta,
                 self.split_params,
@@ -333,7 +343,6 @@ class GBDT:
                 bundle_num_bins=(self.train_set.padded_bundle_bin
                                  if self._bundle is not None else None),
                 row_sharded=getattr(self.train_set, "is_row_sharded", False),
-                packed=self._packed,
             )
         if self.binned is None:     # process-sharded rows: the learner's own
             self.binned = bin_matrix(self._grow_binned)

@@ -125,13 +125,12 @@ class StreamingGBDT(GBDT):
         self._source = block_source_for(train_set, config.stream_block_rows)
         self._ledger = DeviceLedger()
         self._bag_cache = None
+        # a packed4 cache (block-cache v3) streams packed shards: the
+        # booster's plan takes its layout from the manifest, the prediction
+        # walker decodes nibbles (tree_predict_binned packed lane) and
+        # add_valid packs valid matrices to match
         super().__init__(config, train_set, objective, metrics,
                          init_raw_scores)
-        # a packed4 cache (block-cache v3) streams packed shards: the
-        # prediction walker decodes nibbles (tree_predict_binned packed
-        # lane) and add_valid packs valid matrices to match
-        self._packed = getattr(self._source, "bin_layout", "u8") \
-            == "packed4"
         if self.objective is None:
             log_fatal("streaming training requires a built-in objective "
                       "(custom fobj needs full-matrix raw scores)")
@@ -167,13 +166,11 @@ class StreamingGBDT(GBDT):
         return False
 
     def _build_trainer(self):
-        from ..ops.histogram import default_hist_method
         from ..parallel.trainer import parse_interaction_constraints
         from .grower_stream import StreamGrower
 
         cfg = self.config
-        method = default_hist_method(cfg.hist_method,
-                                     self._source.block_dtype)
+        method = self._hist.method
         if method == "pallas":
             log_warning("hist_method=pallas streams as per-block partial "
                         "sums: deterministic at fixed block order, but "

@@ -98,7 +98,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.hist_pallas import bin_matrix, packed_bins_of_feat
+from ..ops.hist_pallas import bin_matrix
 from ..ops.partition_pallas import (MAX_LEAF_IDS, assign_rows,
                                     count_partition_bytes,
                                     count_partition_round, partition_pallas,
@@ -760,6 +760,7 @@ def make_wave_grower(
     bins_of_fn: Callable = None,
     hist_method: str = "",
     pallas_interpret: bool = False,
+    stored_layout: str = "",
 ):
     """Build the jittable ``grow(binned, g3, base_mask, key)`` function.
 
@@ -795,11 +796,14 @@ def make_wave_grower(
     tables with one coalesced scatter each (_PackedStore, default) or the
     legacy per-field scatters (_FieldStore); trees are bit-identical
     either way on the exact-fp32 histogram path.
-    ``hist_method`` is the trainer's resolved histogram method and
+    ``hist_method`` is the trainer's resolved histogram method,
     ``pallas_interpret`` whether its kernels run under the Pallas
-    interpreter: where the method is ``"pallas"`` a round's partition of
-    the training rows may take the row-tiled kernel
-    (ops/partition_pallas.py ``partition_path``: a rule on shapes).
+    interpreter and ``stored_layout`` what a row of ``binned`` holds
+    (``parallel/trainer.HistPlan.layout``: ``"u8"``, ``"packed4"`` or
+    ``""``): where the method is ``"pallas"`` and the kernel decodes the
+    layout a round's partition of the training rows may take the
+    row-tiled kernel (ops/partition_pallas.py ``partition_path``: a rule
+    on shapes).
     ``async_wave_pipeline`` (default on) software-pipelines the round
     loop: the per-leaf histogram-state scatter and the valid-row routing
     of round r are DEFERRED into a pending carry and applied at the
@@ -859,7 +863,6 @@ def make_wave_grower(
         def sums_fn(g3):
             return g3.sum(axis=0)
 
-    plain_bins = bins_of_fn is None    # a feature's bins are a matrix row
     if bins_of_fn is None:
         def bins_of_fn(binned, feat):
             return binned[feat]
@@ -896,19 +899,10 @@ def make_wave_grower(
         # is by the replicated n_split, so row shards stay in lockstep.
         slot_buckets = slot_buckets_for(K, N)
 
-        # what a row of the stored matrix holds: a feature's bins, two
-        # features' nibbles (``packed_bins_of_feat`` reads them), or what
-        # the kernel does not decode (an EFB bundle column, wider bins)
-        layout = ""
-        if bins.dtype == jnp.uint8 and plain_bins and bins.shape[0] == F:
-            layout = "u8"
-        elif bins.dtype == jnp.uint8 and bins_of_fn is packed_bins_of_feat:
-            layout = "packed4"
-
         def partition_path_of(S):
             return partition_path(
                 bins.shape[0], S, N, pallas=hist_method == "pallas",
-                layout=layout, use_cat=use_cat)
+                layout=stored_layout, use_cat=use_cat)
 
         count_partition_bytes(partition_path_of(slot_buckets[-1]),
                               bins.shape[0], slot_buckets[-1], N)
@@ -1306,7 +1300,7 @@ def make_wave_grower(
                                      leafs=leafs_s, nls=nls_s, sml=sml_s,
                                      mt=mt_s, nan=nan_s, zero=zero_s),
                                 use_sub=use_sub, missing=has_missing,
-                                packed=layout == "packed4",
+                                packed=stored_layout == "packed4",
                                 interpret=pallas_interpret)
                         else:
                             leaf_id, label = (v[0] for v in assign_rows(

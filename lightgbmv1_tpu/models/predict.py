@@ -71,7 +71,7 @@ from .tree import HostTree, host_tree_depth, validate_host_tree
 # native predictor pack, native/__init__.py build_ensemble_pack)
 _MAX_CAT_BITSET = 1 << 22
 
-# process-wide log-once keys (the select_bin_layout engage/refuse idiom):
+# process-wide log-once keys (the resolve_hist_plan engage/refuse idiom):
 # a chunked streaming predict hits the same fallback on every chunk and
 # a server rebuilds predictors per publish — the reason is logged once
 _logged_once: set = set()
@@ -591,7 +591,7 @@ class BatchPredictor:
                         f"be exact ({self.binner.why_not}); using the raw "
                         "walk")
             self.prebin = False
-        # -- 4-bit packed serving codes (the select_bin_layout engage/
+        # -- 4-bit packed serving codes (the resolve_hist_plan engage/
         # refuse contract): "auto" engages exactly when eligible AND the
         # fused kernel consumes nibbles directly; an explicit "packed4"
         # engages on any prebinned walk (the staged path unpacks ON

@@ -209,7 +209,8 @@ def test_whole_grow_kernel_against_gather(monkeypatch, use_sub):
         grow = gw.make_wave_grower(
             num_leaves=40, num_bins=B, meta=_meta(F, rng=np.random.RandomState(1)),
             params=SplitParams(min_data_in_leaf=2.0), wave_size=15,
-            hist_wave_fn=hist, hist_method="pallas", pallas_interpret=True)
+            hist_wave_fn=hist, hist_method="pallas", pallas_interpret=True,
+            stored_layout="u8")
         tree, leaf_id, _ = jax.block_until_ready(jax.jit(grow)(
             bins, g3, jnp.ones(F, bool), jax.random.PRNGKey(0)))
         return tree, np.asarray(leaf_id), labels
@@ -325,7 +326,7 @@ def _trace_grow(matrix_shape, dtype=jnp.uint8, features=None, **grower):
         params=SplitParams(), wave_size=63,
         hist_wave_fn=lambda b, g, l, n, deep=False: jnp.zeros(
             (n, F, 64, 3), jnp.float32),
-        **{"hist_method": "pallas", **grower})
+        **{"hist_method": "pallas", "stored_layout": "u8", **grower})
     jax.eval_shape(grow, jax.ShapeDtypeStruct(matrix_shape, dtype),
                    jax.ShapeDtypeStruct((N, 3), jnp.float32),
                    jax.ShapeDtypeStruct((F,), jnp.bool_),
@@ -366,7 +367,8 @@ def test_counter_and_gauge_at_the_packed_higgs_shape():
     and 12 a row."""
     N = 10_500_000
     before = _traced()
-    _trace_grow((14, N), features=28, bins_of_fn=packed_bins_of_feat)
+    _trace_grow((14, N), features=28, bins_of_fn=packed_bins_of_feat,
+                stored_layout="packed4")
     assert _traced_since(before) == {
         ("kernel", 4): 1, ("kernel", 16): 1, ("kernel", 63): 1}
     assert _bytes_gauge("kernel") == 32 * N + 12 * N \
@@ -374,30 +376,44 @@ def test_counter_and_gauge_at_the_packed_higgs_shape():
 
 
 def test_grower_keeps_the_gather_form_off_the_plain_u8_matrix():
-    """What the grower can see of its input decides: a categorical column,
-    a bundled matrix (fewer stored columns than features, its own
-    ``bins_of_fn``), 16-bit bins, a histogram method that is not the
-    Pallas one keep the gather form; the plain ``u8`` matrix and the
-    ``packed4`` one (``packed_bins_of_feat`` over ``ceil(F/2)`` rows) take
-    the kernel."""
+    """What the booster's plan says a stored row holds decides
+    (``parallel/trainer.resolve_hist_plan``, its ``layout``): a
+    categorical column, a bundled matrix (fewer stored columns than
+    features, its own ``bins_of_fn``), 16-bit bins, a histogram method
+    that is not the Pallas one keep the gather form; the plain ``u8``
+    matrix and the ``packed4`` one (``packed_bins_of_feat`` over
+    ``ceil(F/2)`` rows) take the kernel."""
+    from lightgbmv1_tpu.config import Config
+    from lightgbmv1_tpu.parallel.trainer import resolve_hist_plan
+
+    def layout(**kw):
+        return resolve_hist_plan(Config(hist_method="pallas"),
+                                 **{"bin_dtype": np.uint8, **kw}).layout
+
     gather = {("gather", 4): 1, ("gather", 16): 1, ("gather", 63): 1}
     N = 100_000
     cat = _meta(28)._replace(
         is_categorical=jnp.zeros(28, bool).at[3].set(True))
     packed = pack4bit(np.zeros((28, 256), np.uint8))
+    assert layout(num_total_bin=16) == "packed4"
     for kw in (dict(matrix_shape=(28, N), meta=cat),
                dict(matrix_shape=(packed.shape[0], N), features=28,
-                    bins_of_fn=packed_bins_of_feat, meta=cat),
+                    bins_of_fn=packed_bins_of_feat, meta=cat,
+                    stored_layout="packed4"),
                dict(matrix_shape=(9, N), features=28,
-                    bins_of_fn=lambda b, f: b[f % 9]),
-               dict(matrix_shape=(28, N), dtype=jnp.uint16),
+                    bins_of_fn=lambda b, f: b[f % 9],
+                    stored_layout=layout(bundled=True)),
+               dict(matrix_shape=(28, N), dtype=jnp.uint16,
+                    stored_layout=layout(bin_dtype=np.uint16)),
                dict(matrix_shape=(28, N), hist_method="onehot")):
         before = _traced()
         _trace_grow(**kw)
         assert _traced_since(before) == gather, kw
-    for kw in (dict(matrix_shape=(28, N)),
+    for kw in (dict(matrix_shape=(28, N),
+                    stored_layout=layout(num_total_bin=64)),
                dict(matrix_shape=(packed.shape[0], N), features=28,
-                    bins_of_fn=packed_bins_of_feat)):
+                    bins_of_fn=packed_bins_of_feat,
+                    stored_layout=layout(num_total_bin=16))):
         before = _traced()
         _trace_grow(**kw)
         assert set(_traced_since(before)) == {

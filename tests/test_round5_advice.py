@@ -1,4 +1,4 @@
-"""Regression tests closing the four round-5 ADVICE.md findings.
+"""Regression tests closing three round-5 review findings.
 
 1. ``gpu_use_dp`` must not stomp an explicitly-set ``hist_dtype_deep``
    (config.py — the trainer documents "hist_dtype_deep overrides").
@@ -9,10 +9,6 @@
    ``_LEVEL_CHUNK`` splits (the wave grower's 128-slot cap applied to
    levels) — chunked and unchunked growth must be bit-identical
    (models/grower.py).
-4. ``hist_method=bench`` seeds the timed candidate list with the method
-   a ``force_col_wise``/``force_row_wise`` user forced, instead of
-   silently ignoring the force (parallel/trainer.py + ops/histogram.py;
-   the reference fatals on such conflicts in CheckParamConflict).
 """
 
 import numpy as np
@@ -36,7 +32,7 @@ def test_gpu_use_dp_defaults_deep_dtype_when_unset():
 def test_gpu_use_dp_respects_explicit_hist_dtype_deep():
     cfg = Config.from_dict({"objective": "binary", "gpu_use_dp": True,
                             "hist_dtype_deep": "bf16x2"})
-    # the explicitly-set value must survive (ADVICE r5 #1: it was stomped)
+    # the explicitly-set value must survive (round-5 finding 1: it was stomped)
     assert cfg.hist_dtype_deep == "bf16x2"
     assert cfg.hist_dtype == "f32"
 
@@ -99,46 +95,3 @@ def test_levelwise_chunked_partition_bit_identical(monkeypatch):
         np.testing.assert_array_equal(np.asarray(ta.leaf_value),
                                       np.asarray(tb.leaf_value))
     np.testing.assert_array_equal(a.predict(X), b.predict(X))
-
-
-# ---------------------------------------------------------------------------
-# 4. hist_method=bench honors force_col_wise / force_row_wise
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("force,expected", [
-    ({"force_col_wise": True}, "scatter"),
-    ({"force_row_wise": True}, "onehot"),
-    ({}, None),
-])
-def test_bench_seeds_forced_method(monkeypatch, force, expected):
-    from lightgbmv1_tpu.ops import histogram as hist_mod
-
-    seen = {}
-    real = hist_mod.benchmark_hist_methods
-
-    def capture(*args, **kwargs):
-        seen["must_include"] = kwargs.get("must_include")
-        return real(*args, **kwargs)
-
-    monkeypatch.setattr(hist_mod, "benchmark_hist_methods", capture)
-    rng = np.random.RandomState(0)
-    X = rng.randn(500, 4)
-    y = (X[:, 0] > 0).astype(float)
-    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1,
-               "hist_method": "bench", **force},
-              lgb.Dataset(X, label=y), num_boost_round=1)
-    assert seen["must_include"] == expected
-
-
-def test_bench_must_include_joins_candidates():
-    """The forced method competes in the timing even when the default
-    candidate list would exclude it."""
-    from lightgbmv1_tpu.ops.histogram import benchmark_hist_methods
-
-    rng = np.random.RandomState(1)
-    binned = rng.randint(0, 16, size=(4, 2000)).astype(np.uint8)
-    pick = benchmark_hist_methods(binned, 16, "f32", False, 4,
-                                  candidates=["onehot"],
-                                  must_include="scatter")
-    assert pick in ("onehot", "scatter")

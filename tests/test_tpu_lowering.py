@@ -648,7 +648,8 @@ def test_packed_pass_and_step_compile_at_the_cell_s_size(one_chip):
             b, g, l, n, B, method="pallas",
             precision="bf16" if deep else "bf16x2", packed=True,
             num_features=F),
-        bins_of_fn=packed_bins_of_feat, hist_method="pallas")
+        bins_of_fn=packed_bins_of_feat, hist_method="pallas",
+        stored_layout="packed4")
     got = compile_for_chip(
         lambda b, g: grow(b, g, jnp.ones(F, bool), jax.random.PRNGKey(0)),
         prepared, g3)
@@ -693,7 +694,7 @@ def test_partition_kernel_compiles_in_the_step_at_the_cells_sizes(
         hist_wave_fn=lambda b, g, l, n, deep=False: hist_wave(
             b, g, l, n, bins, method="pallas",
             precision="bf16" if deep else "bf16x2"),
-        hist_method="pallas")
+        hist_method="pallas", stored_layout="u8")
     matrix = shape((columns, rows_), jnp.uint8)
     if prepared:
         matrix = jax.tree_util.tree_map(

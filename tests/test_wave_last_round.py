@@ -211,7 +211,7 @@ def test_a_skipped_round_calls_no_histogram_pass(monkeypatch, max_depth,
     grow = gw.make_wave_grower(
         num_leaves=L, num_bins=B, meta=_meta(F, B), max_depth=max_depth,
         params=SplitParams(min_data_in_leaf=min_data), wave_size=K,
-        hist_wave_fn=hist)
+        hist_wave_fn=hist, stored_layout="u8")
     tree, _, third = jax.block_until_ready(jax.jit(grow)(
         bins, g3, jnp.ones(F, bool), jax.random.PRNGKey(0)))
     jax.effects_barrier()
